@@ -12,10 +12,8 @@
 #               host)
 #   -o DIR      output directory for logs (default artifacts)
 #
-# Pauses while artifacts/tpu.lock is held so suite (+ antagonist) CPU
-# load never distorts a benchmark window. Failures land in
-# DIR/flake_fail_<n>.log with full tracebacks; the rolling summary is
-# DIR/flake_hunt.log.
+# Failures land in DIR/flake_fail_<n>.log with full tracebacks; the
+# rolling summary is DIR/flake_hunt.log.
 set -u
 cd "$(dirname "$0")/.." || exit 1
 N=10
@@ -39,7 +37,6 @@ SPIN=""
 # single-core host (it would distort every later benchmark window)
 trap '[ -n "$SPIN" ] && kill "$SPIN" 2>/dev/null' EXIT
 for i in $(seq 1 "$N"); do
-  while [ -f artifacts/tpu.lock ]; do sleep 60; done
   if [ "$ANTAGONIST" = 1 ]; then
     # pure-CPU spinner competing for the core for the WHOLE run (no
     # time cap — a capped spinner silently unloads the late tests)

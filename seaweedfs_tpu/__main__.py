@@ -163,15 +163,15 @@ def _run_version(argv: list[str]) -> int:
     import jax
 
     from . import __version__
-    backends = []
+    head = (f"seaweedfs-tpu {__version__} "
+            f"(python {platform.python_version()}, jax {jax.__version__}")
     try:
-        backends = [d.platform for d in jax.devices()]
-    except Exception:  # noqa: BLE001 — no accelerator attached
-        pass
-    print(f"seaweedfs-tpu {__version__} "
-          f"(python {platform.python_version()}, jax {jax.__version__}"
-          + (f", devices {sorted(set(backends))}" if backends else "")
-          + ")")
+        devices = jax.devices()
+    except RuntimeError as e:
+        print(f"{head}): backend failed to start: {e}")
+        return 1
+    print(f"{head}, {len(devices)} x {devices[0].device_kind} "
+          f"[{devices[0].platform}])")
     return 0
 
 

@@ -147,6 +147,16 @@ class VolumeServer:
     def start(self) -> "VolumeServer":
         import grpc
 
+        # A volume server is the process that owns this host's
+        # accelerator: claim it before binding anything, so a second
+        # server on a one-chip host fails here, with words, and not at
+        # its first EC rpc. Likewise build/load the host codec now: a
+        # missing g++ is said at start-up (rs_native logs it), not
+        # discovered by the first degraded read.
+        from ..ops import rs_jax, rs_native
+        glog.info("volume server %s computes on %s (host codec: %s)",
+                  self.url, rs_jax.backend(),
+                  "native" if rs_native.available() else "XLA network")
         # With a signing key, the whole gRPC plane (admin + EC reads)
         # requires a cluster bearer token — the reference's gRPC TLS
         # role (SURVEY.md §2 Security row), HMAC-keyed here.
@@ -791,10 +801,13 @@ class _VolumeServicer:
         total = scheme.total_shards
         local = set(ec_files.present_shards(base, total))
         # Cluster-wide view: a shard is missing only if neither we nor
-        # any peer holds it.
+        # any OTHER server holds it. The master's (briefly cached) map
+        # may still list this server for a shard just deleted here; the
+        # local disk is the authority on what this server holds.
         missing = [sid for sid in range(total)
                    if sid not in local
-                   and not vs.ec_shard_peers(request.volume_id, sid)]
+                   and not [u for u in vs.ec_shard_peers(
+                       request.volume_id, sid) if u != vs.url]]
         resp = volume_server_pb2.VolumeEcShardsRebuildResponse()
         if not missing:
             return resp

@@ -17,6 +17,14 @@ without a real cluster").
 Ports: masters at portBase, portBase+1, ...; volumes at portBase+100+i;
 filer at portBase+200; s3 at portBase+300; webdav at portBase+400. Each
 server's gRPC twin rides the usual +10000 offset.
+
+An accelerator belongs to one process: every volume server claims its
+backend at start-up (VolumeServer.start), so a host runs ONE
+chip-owning volume server per chip. On a TPU host ``-volumes N`` with
+N above that makes the extra servers exit at once with that message,
+and the launcher stops the cluster and repeats it; run ``-volumes 1``
+there, or the whole launcher under ``JAX_PLATFORMS=cpu`` for a
+host-codec cluster.
 """
 
 from __future__ import annotations
@@ -153,7 +161,8 @@ class LocalCluster:
 
     def wait_ready(self, timeout: float = 30.0) -> None:
         """Block until a master answers /cluster/status with every
-        volume server registered (raises TimeoutError otherwise)."""
+        volume server registered and every gateway asked for accepts
+        connections (raises TimeoutError otherwise)."""
         import urllib.request
         deadline = time.time() + timeout
         last = ""
@@ -175,18 +184,46 @@ class LocalCluster:
                     for dc in (topo.get("DataCenters") or {}).values()
                     for nodes in dc.values())
                 if count >= self.n_volumes:
-                    return
+                    last = self._gateways_down()
+                    if not last:
+                        return
+                    break
                 last = f"{murl}: {count}/{self.n_volumes} volumes"
             time.sleep(0.3)
         raise TimeoutError(f"cluster not ready: {last}")
+
+    def _gateways_down(self) -> str:
+        """Which of the filer / s3 / webdav processes asked for does
+        not accept connections yet ('' = all up)."""
+        import socket
+        for name, on, url in (("filer", self.with_filer, self.filer_url),
+                              ("s3", self.with_s3, self.s3_url),
+                              ("webdav", self.with_webdav,
+                               self.webdav_url)):
+            if not on:
+                continue
+            host, port = url.rsplit(":", 1)
+            try:
+                # seaweedlint: disable=SW601 — launcher readiness poll on localhost: a bare connect bounded by its 2s timeout and the caller's deadline loop
+                with socket.create_connection((host, int(port)),
+                                              timeout=2):
+                    pass
+            except OSError as e:
+                return f"{name} {url}: {e}"
+        return ""
 
     def _reap_dead(self) -> None:
         dead = [k for k, p in self.procs.items()
                 if p.poll() is not None]
         if dead:
+            # repeat why the first one died (e.g. a second volume
+            # server refused the chip) instead of only pointing at logs
+            log = (self.base / f"{dead[0]}.log").read_text(
+                errors="replace").strip()
             raise RuntimeError(
                 f"cluster processes died: {dead} "
-                f"(see logs under {self.base})")
+                f"(see logs under {self.base}); {dead[0]} ended with:\n"
+                f"{log[-1200:]}")
 
     def stop(self) -> None:
         for p in self.procs.values():
@@ -222,7 +259,11 @@ def main(argv: Optional[list[str]] = None) -> int:
                     "(docker/local-cluster-compose.yml analog)")
     p.add_argument("-dir", required=True, help="base data/log directory")
     p.add_argument("-masters", type=int, default=1)
-    p.add_argument("-volumes", type=int, default=2)
+    p.add_argument("-volumes", type=int, default=2,
+                   help="volume servers to start; each claims an "
+                        "accelerator at start-up, so on a TPU host at "
+                        "most one per chip (JAX_PLATFORMS=cpu for a "
+                        "host-codec cluster)")
     p.add_argument("-filer", action="store_true")
     p.add_argument("-s3", action="store_true")
     p.add_argument("-webdav", action="store_true")

@@ -81,11 +81,24 @@ def _load():
     return _lib
 
 
+_unavailable_said = False
+
+
 def available() -> bool:
     try:
         _load()
         return True
-    except NativeUnavailable:
+    except NativeUnavailable as e:
+        global _unavailable_said
+        if not _unavailable_said:
+            # one-way latch; a racing second warning is harmless
+            # seaweedlint: disable=SW801 — idempotent latch
+            _unavailable_said = True
+            from ..util import glog
+            glog.warning(
+                "native GF(2^8) codec unavailable (%s): the hybrid "
+                "dispatch policy and small interval repairs fall to "
+                "the XLA network on this process", e)
         return False
 
 

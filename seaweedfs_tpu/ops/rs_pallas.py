@@ -173,8 +173,11 @@ def _make_swar_kernel(rows: tuple[tuple[int, ...], ...],
 
 
 #: Row granularity of the SWAR kernel: S must divide into
-#: 4 (bytes/word) * SWAR_ROWS * 128 (lanes) byte segments.
-SWAR_ROWS = 512
+#: 4 (bytes/word) * SWAR_ROWS * 128 (lanes) byte segments. 128 is the
+#: largest power of two the v5e compiler accepts: the kernel keeps all
+#: 8*n_in masked planes live, and 256 rows already ask more scoped
+#: VMEM than the 16 MiB limit (tests/test_tpu_compile.py).
+SWAR_ROWS = 128
 SWAR_SEG_BYTES = 4 * SWAR_ROWS * LANES
 
 

@@ -66,23 +66,13 @@ def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
     present = present[:scheme.data_shards]
     k = scheme.data_shards
     reconstruct = _pick_reconstruct_fn(scheme, present, missing)
-    # Grouped dispatch on a single accelerator (one shared policy —
-    # pipe.pick_grouped_dispatch); a chunk's input bytes are k x the
-    # per-shard take, so the clamp converts back through k. Multi-chip
-    # keeps per-chunk mesh sharding via _pick_reconstruct_fn.
+    # Grouped dispatch on a single accelerator; multi-chip keeps
+    # per-chunk mesh sharding via _pick_reconstruct_fn.
+    group, chunk_bytes = plan_chunking(k, chunk_bytes)
     enc = scheme.encoder
-    reconstruct_multi, group, grouped_total = pipe.pick_grouped_dispatch(
+    reconstruct_multi = None if group == 1 else (
         lambda chunks: enc.reconstruct_batch_host_multi(
-            chunks, present, missing),
-        k * chunk_bytes)
-    if group > 1:
-        # the per-shard take IS the word-form S here, so it must stay a
-        # multiple of both kernels' segment sizes or _host_word_form
-        # rejects every chunk and the fast path never engages (k=10
-        # makes a naive //k non-aligned)
-        from ..ops import rs_pallas
-        align = max(rs_pallas.SEG_BYTES, rs_pallas.SWAR_SEG_BYTES)
-        chunk_bytes = max(align, (grouped_total // k) // align * align)
+            chunks, present, missing))
 
     cfg = pipe.current()
     depth_eff = max(cfg.depth, group)
@@ -155,6 +145,25 @@ def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
 
     cache_invalidation.base_invalidated(base, reason="ec-rebuild")
     return missing
+
+
+def plan_chunking(k: int, chunk_bytes: int = DEFAULT_CHUNK_BYTES
+                  ) -> tuple[int, int]:
+    """(dispatch group width, per-shard bytes of one chunk) for a
+    rebuild in this process — one shared grouping policy
+    (pipe.pick_grouped_dispatch); a chunk's input is k x the per-shard
+    take, so the grouped clamp converts back through k."""
+    _, group, grouped_total = pipe.pick_grouped_dispatch(
+        None, k * chunk_bytes)
+    if group > 1:
+        # the per-shard take IS the word-form S here, so it must stay a
+        # multiple of both kernels' segment sizes or _host_word_form
+        # rejects every chunk and the fast path never engages (k=10
+        # makes a naive //k non-aligned)
+        from ..ops import rs_pallas
+        align = max(rs_pallas.SEG_BYTES, rs_pallas.SWAR_SEG_BYTES)
+        chunk_bytes = max(align, (grouped_total // k) // align * align)
+    return group, chunk_bytes
 
 
 def _pread_into(fd: int, view: np.ndarray, offset: int) -> None:

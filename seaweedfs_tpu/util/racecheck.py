@@ -42,6 +42,7 @@ Static counterpart: ``python -m seaweedfs_tpu.analysis`` (SW801-804).
 from __future__ import annotations
 
 import _thread
+import collections
 import os
 import sys
 import threading
@@ -158,6 +159,10 @@ class _RaceTracker:
         # raw C lock: instrumented writes happen on every thread and
         # the tracker must never recurse through a TrackedLock
         self._mu = _thread.allocate_lock()
+        # ids of registered objects that died, queued by their weakref
+        # finalizers (see purge_dead) and dropped by the next holder
+        # of _mu
+        self._dead: collections.deque = collections.deque()
 
     # -- state machine -----------------------------------------------
 
@@ -205,6 +210,7 @@ class _RaceTracker:
                         return None
         hit = None
         with self._mu:
+            self._drop_dead()
             st = self.states.get(key)
             if st is not None and st.state == _EXCLUSIVE \
                     and tid == st.owner:
@@ -254,9 +260,33 @@ class _RaceTracker:
 
     def purge(self, oid: int) -> None:
         with self._mu:
-            for key in [k for k in self.states if k[0] == oid]:
-                del self.states[key]
-            self.names.pop(oid, None)
+            self._drop_dead()
+            self._purge_locked(oid)
+
+    def _purge_locked(self, oid: int) -> None:
+        for key in [k for k in self.states if k[0] == oid]:
+            del self.states[key]
+        self.names.pop(oid, None)
+
+    def _drop_dead(self) -> None:
+        """Forget every object whose finalizer has fired; _mu held."""
+        while self._dead:
+            self._purge_locked(self._dead.popleft())
+
+    def purge_dead(self, oid: int) -> None:
+        """The weakref finalizer of a registered object. A finalizer
+        runs wherever the collector happens to fire — including inside
+        ``_transition`` on a thread that already holds ``_mu``, where
+        taking the raw lock again would block that thread on itself
+        for good (and, behind it, every instrumented write in the
+        process). So this never waits: the id is queued (deque.append
+        is atomic) and dropped by whoever holds the lock next."""
+        self._dead.append(oid)
+        if self._mu.acquire(False):
+            try:
+                self._drop_dead()
+            finally:
+                self._mu.release()
 
 
 TRACKER = _RaceTracker()
@@ -334,12 +364,16 @@ def register(obj, name: str | None = None) -> bool:
         obj.__class__ = _instrument_class(cls)
     except TypeError:
         return False
+    # ids are reused: a dead predecessor still queued under this id
+    # must go before the name is set, not after
+    with TRACKER._mu:
+        TRACKER._drop_dead()
     TRACKER.names[id(obj)] = name or \
         f"{cls.__module__}.{cls.__qualname__}"
     # not weakref-able: per-attr state outlives the object (bounded
     # by the handful of registered singletons, so acceptable)
     try:
-        weakref.finalize(obj, TRACKER.purge, id(obj))
+        weakref.finalize(obj, TRACKER.purge_dead, id(obj))
     except TypeError:  # seaweedlint: disable=SW301 — tracking stays correct, only cleanup is lost
         pass
     return True
@@ -372,5 +406,6 @@ def races() -> list[RaceReport]:
 def reset() -> None:
     """Clear all state machines and reports (tests)."""
     with TRACKER._mu:
+        TRACKER._dead.clear()
         TRACKER.states.clear()
         TRACKER.reports.clear()

@@ -37,6 +37,15 @@ def _pipeline_payload() -> dict:
     return mod.debug_payload()
 
 
+def _codec_payload() -> dict:
+    # lazy like the pipeline payload: ops/rs_jax imports jax, and a
+    # gateway must not load it to serve /debug/vars
+    mod = sys.modules.get("seaweedfs_tpu.ops.rs_jax")
+    if mod is None:
+        return {}
+    return mod.debug_payload()
+
+
 def _flight_payload() -> dict:
     # lazy like the pipeline payload: only meaningful once the flight
     # recorder module is loaded (any pipeline import pulls it in)
@@ -97,6 +106,7 @@ def payload(component: str, metrics: Optional[Metrics] = None,
         "faults": faults.debug_payload(),
         "profiler": profiler.debug_payload(),
         "pipeline": _pipeline_payload(),
+        "codec": _codec_payload(),
         "flight": _flight_payload(),
         "mesh": _mesh_payload(),
         "ingress": _ingress_payload(),

@@ -6,10 +6,11 @@ bytes exactly as the device bitcast does, and (b) the u8 and u32 folds
 produce identical tiles for identical logical bytes."""
 
 import sys
+from pathlib import Path
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import bench  # noqa: E402
 
 import jax  # noqa: E402

@@ -54,7 +54,7 @@ def test_transpose_words_fast_path(forced_pallas, monkeypatch):
 
 
 def test_swar_words_fast_path(forced_pallas, monkeypatch):
-    # swar_conforms uses SWAR_ROWS=512 -> need S % 256 KiB == 0
+    # swar_conforms needs S % SWAR_SEG_BYTES == 0
     _check(4, 2, rs_pallas.SWAR_SEG_BYTES, b=1, kernel="swar",
            monkeypatch=monkeypatch)
 
@@ -86,7 +86,7 @@ def test_hybrid_policy_routes_by_bandwidth(forced_pallas, monkeypatch):
     x = rng.integers(0, 256, (1, k, s), dtype=np.uint8)
     enc = rs_jax.Encoder(k, m)
     want = np.stack([rs_ref.ReferenceEncoder(k, m).encode_parity(x[0])])
-    # slow link (tunnel-like): stays host-side, still byte-exact
+    # slow link: stays host-side, still byte-exact
     monkeypatch.setattr(rs_jax, "_link_gibps", 0.02)
     monkeypatch.setattr(rs_jax, "_native_gibps", 2.0)
     out = enc.encode_parity_host(x)

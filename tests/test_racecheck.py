@@ -233,3 +233,35 @@ def test_install_from_env_modes(armed, monkeypatch):
 def test_install_implies_lockcheck(armed):
     assert lockcheck.enabled(), \
         "racecheck without the held-locks ledger sees every lock as unheld"
+
+
+def test_finalizer_firing_under_the_tracker_lock_never_blocks(armed):
+    """A registered object's weakref finalizer runs wherever the
+    collector fires — also inside _transition, on a thread that holds
+    the tracker's raw lock. It must queue, not wait: waiting there
+    froze that thread, then every instrumented write in the process
+    (the tier-1 run's hang)."""
+    tracker = racecheck.TRACKER
+    p = Probe()
+    racecheck.register(p, "doomed")
+    p.x = 1
+    oid = id(p)
+    assert any(k[0] == oid for k in tracker.states)
+
+    done = threading.Event()
+
+    def finalizer_while_locked():
+        with tracker._mu:               # as _transition's slow path
+            tracker.purge_dead(oid)     # what the collector would run
+        done.set()
+
+    t = threading.Thread(target=finalizer_while_locked, daemon=True)
+    t.start()
+    t.join(timeout=5)
+    assert done.is_set(), "finalizer blocked on the lock its thread held"
+    # queued, and dropped by the next holder of the lock
+    q = Probe()
+    racecheck.register(q, "next")
+    q.y = 1
+    assert not any(k[0] == oid for k in tracker.states) or id(q) == oid
+    assert not tracker._dead

@@ -124,16 +124,3 @@ def test_split_encode_reconstruct_join_roundtrip():
         shards[i] = None
     enc.reconstruct(shards)
     assert enc.join(shards, len(payload)) == payload
-
-
-def test_measured_kernel_default(tmp_path):
-    from seaweedfs_tpu.ops.rs_jax import _measured_kernel_default
-
-    p = tmp_path / "choice.json"
-    assert _measured_kernel_default(p) == "transpose"  # absent
-    p.write_text("{not json")
-    assert _measured_kernel_default(p) == "transpose"  # corrupt
-    p.write_text('{"kernel": "swar"}')
-    assert _measured_kernel_default(p) == "swar"
-    p.write_text('{"kernel": "bogus"}')
-    assert _measured_kernel_default(p) == "transpose"  # unknown value
