@@ -27,6 +27,7 @@ from urllib.parse import parse_qs, urlparse
 
 from .. import pb
 from ..pb import master_pb2, volume_server_pb2
+from ..pipeline import flight
 from ..storage.superblock import ReplicaPlacement, Ttl
 from ..storage.types import FileId
 from ..util import config as config_mod
@@ -669,6 +670,11 @@ class MasterServer:
             metrics_address=self.metrics_address)
 
 
+#: The master's side of the lookups an EC command makes (the shell's
+#: ``LookupVolume`` / ``VolumeList``, a rebuilder's ``LookupEcVolume``).
+_lookup_step = flight.step("master_lookup")
+
+
 class _MasterServicer:
     """gRPC service impl bound via pb.generic_handler."""
 
@@ -677,7 +683,9 @@ class _MasterServicer:
 
     def SendHeartbeat(self, request_iterator, context):
         for hb in request_iterator:
-            yield self.ms.ingest_heartbeat(hb)
+            with flight.span("step_master_heartbeat"):
+                resp = self.ms.ingest_heartbeat(hb)
+            yield resp
 
     def Assign(self, request, context):
         try:
@@ -691,6 +699,7 @@ class _MasterServicer:
             fid=r["fid"], url=r["url"], public_url=r["publicUrl"],
             count=r["count"], auth=r["auth"])
 
+    @_lookup_step
     def LookupVolume(self, request, context):
         resp = master_pb2.LookupVolumeResponse()
         # Volume servers heartbeat only the leader; a follower's cold
@@ -717,6 +726,7 @@ class _MasterServicer:
                                     shards=loc.get("shards", ()))
         return resp
 
+    @_lookup_step
     def LookupEcVolume(self, request, context):
         # No per-entry error field here: raising surfaces as an RpcError
         # the client's failover loop rotates on.
@@ -732,6 +742,7 @@ class _MasterServicer:
                                     public_url=n.public_url or n.url)
         return resp
 
+    @_lookup_step
     def VolumeList(self, request, context):
         resp = master_pb2.VolumeListResponse(
             volume_size_limit_mb=self.ms.topology.volume_size_limit

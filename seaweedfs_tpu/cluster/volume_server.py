@@ -415,9 +415,11 @@ class VolumeServer:
         """One immediate snapshot push (tests / post-admin-op nudge)."""
         if not self.master_url:
             return
-        stub = self.master_stub()
-        for _ in stub.SendHeartbeat(iter([self._heartbeat_snapshot()])):
-            break
+        with flight_mod.span("step_heartbeat", trace=True):
+            stub = self.master_stub()
+            for _ in stub.SendHeartbeat(
+                    iter([self._heartbeat_snapshot()])):
+                break
 
     # ------------- EC shard location helpers -------------
 
@@ -511,6 +513,14 @@ class VolumeServer:
         return []
 
 
+def _ec_step(name: str):
+    """One of the six EC handlers: a ``step_<name>`` span under its
+    ``grpc.<Method>`` span, seconds + one call in the totals that
+    ``/debug/vars`` ``pipeline`` exports (their sum is ``rpc_seconds``).
+    It holds the steps inside the handler, so it is no leaf."""
+    return flight_mod.step(name, leaf=False)
+
+
 class _VolumeServicer:
     """gRPC service impl; 1:1 with volume_grpc_*.go handlers."""
 
@@ -528,11 +538,15 @@ class _VolumeServicer:
             request.replication or "000", request.ttl)
         return volume_server_pb2.AllocateVolumeResponse()
 
+    @_ec_step("delete_source")
     def VolumeDelete(self, request, context):
-        self.vs.store.delete_volume(request.volume_id, request.collection)
+        with flight_mod.span("step_store_delete", trace=True):
+            self.vs.store.delete_volume(request.volume_id,
+                                        request.collection)
         self.vs.heartbeat_now()
         return volume_server_pb2.VolumeDeleteResponse()
 
+    @_ec_step("mark_readonly")
     def VolumeMarkReadonly(self, request, context):
         self.vs.store.mark_readonly(request.volume_id, request.collection)
         return volume_server_pb2.VolumeMarkReadonlyResponse()
@@ -780,15 +794,18 @@ class _VolumeServicer:
             return EcScheme(data_shards, parity_shards)
         return DEFAULT_SCHEME
 
+    @_ec_step("generate")
     def VolumeEcShardsGenerate(self, request, context):
         """The §3.1 hot path: stripe + TPU encode + shard files."""
         vs = self.vs
         vol = vs.store.get_volume(request.volume_id, request.collection)
         scheme = self._scheme(request.data_shards, request.parity_shards)
-        vol.sync()
+        with flight_mod.span("step_vol_sync", trace=True):
+            vol.sync()
         encode_mod.encode_volume(vol.base, scheme)
         return volume_server_pb2.VolumeEcShardsGenerateResponse()
 
+    @_ec_step("rebuild")
     def VolumeEcShardsRebuild(self, request, context):
         """§3.5: pull sibling shards from peers, reconstruct only the
         shards missing cluster-wide, drop the temporary copies."""
@@ -813,25 +830,27 @@ class _VolumeServicer:
             return resp
         # Fetch remote siblings until k survivors are on local disk.
         fetched: list = []
-        for sid in range(total):
-            if len(local) >= scheme.data_shards:
-                break
-            if sid in local:
-                continue
-            for url in vs.ec_shard_peers(request.volume_id, sid):
-                if url == vs.url:
-                    continue
-                try:
-                    dest = ec_files.shard_path(base, sid)
-                    _copy_remote_file(
-                        vs, url, request.volume_id,
-                        request.collection, ec_files.shard_ext(sid), dest)
-                    local.add(sid)
-                    fetched.append(dest)
+        with flight_mod.span("step_rebuild_fetch", trace=True):
+            for sid in range(total):
+                if len(local) >= scheme.data_shards:
                     break
-                except Exception as e:
-                    glog.v(1, "shard %d copy from %s failed: %s",
-                           sid, url, e)
+                if sid in local:
+                    continue
+                for url in vs.ec_shard_peers(request.volume_id, sid):
+                    if url == vs.url:
+                        continue
+                    try:
+                        dest = ec_files.shard_path(base, sid)
+                        _copy_remote_file(
+                            vs, url, request.volume_id,
+                            request.collection, ec_files.shard_ext(sid),
+                            dest)
+                        local.add(sid)
+                        fetched.append(dest)
+                        break
+                    except Exception as e:
+                        glog.v(1, "shard %d copy from %s failed: %s",
+                               sid, url, e)
         try:
             rebuilt = rebuild_mod.rebuild_ec_files(base, scheme,
                                                    wanted=missing)
@@ -839,8 +858,9 @@ class _VolumeServicer:
             for p in fetched:
                 if p.exists():
                     p.unlink()
-        vs.store.mount_ec_shards(request.volume_id, rebuilt,
-                                 request.collection)
+        with flight_mod.span("step_store_mount", trace=True):
+            vs.store.mount_ec_shards(request.volume_id, rebuilt,
+                                     request.collection)
         vs.heartbeat_now()
         resp.rebuilt_shard_ids.extend(rebuilt)
         return resp
@@ -871,6 +891,7 @@ class _VolumeServicer:
         vs.heartbeat_now()
         return volume_server_pb2.VolumeEcShardsCopyResponse()
 
+    @_ec_step("shards_delete")
     def VolumeEcShardsDelete(self, request, context):
         base = self.vs.store.ec_base(request.volume_id, request.collection)
         if base is not None:
@@ -884,10 +905,12 @@ class _VolumeServicer:
         self.vs.heartbeat_now()
         return volume_server_pb2.VolumeEcShardsDeleteResponse()
 
+    @_ec_step("mount")
     def VolumeEcShardsMount(self, request, context):
-        self.vs.store.mount_ec_shards(
-            request.volume_id, list(request.shard_ids),
-            request.collection)
+        with flight_mod.span("step_store_mount", trace=True):
+            self.vs.store.mount_ec_shards(
+                request.volume_id, list(request.shard_ids),
+                request.collection)
         self.vs.heartbeat_now()
         return volume_server_pb2.VolumeEcShardsMountResponse()
 

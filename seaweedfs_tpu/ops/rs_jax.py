@@ -29,6 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..pipeline import flight
 from . import bitslice, gf256, rs_native, rs_pallas
 from .rs_ref import ShardSizeError, TooFewShardsError
 
@@ -369,8 +370,17 @@ def _jitted_apply(coefs_bytes: bytes, n_out: int, n_in: int, variant: str,
                 lambda v: bitslice.apply_gf_matrix(coefs, v), xc)
             return yc.transpose(1, 2, 0, 3)
 
+    _name_step(apply_fn, variant, 1)
     return jax.jit(apply_fn, donate_argnums=(0,)) if donate \
         else jax.jit(apply_fn)
+
+
+def _name_step(fn, variant: str, width: int) -> None:
+    """Name a step for the trace before it is jitted: the entry point
+    and the group width (``rs_pallas_words_g8``) in place of
+    ``apply_fn``, so that a device operation says which program it
+    belongs to. Names only: the computation is the same."""
+    fn.__name__ = fn.__qualname__ = f"rs_{variant}_g{width}"
 
 
 @functools.lru_cache(maxsize=64)
@@ -395,8 +405,22 @@ def _jitted_apply_multi(coefs_bytes: bytes, n_out: int, n_in: int,
         assert len(xs) == nargs
         return tuple(kern(x) for x in xs)
 
+    _name_step(apply_fn, variant, nargs)
     return jax.jit(apply_fn, donate_argnums=tuple(range(nargs))) \
         if donate else jax.jit(apply_fn)
+
+
+def _submit(words: list) -> list:
+    """The host side of H2D: hand each word-form slab to the runtime
+    (the transfer itself goes on asynchronously on its threads)."""
+    with flight.span("h2d_submit", nbytes=sum(w.nbytes for w in words)):
+        return [jnp.asarray(w) for w in words]
+
+
+def _launch(fn, xs: list, nbytes: int):
+    """The jitted call itself, apart from the submit before it."""
+    with flight.span("launch", nbytes=nbytes):
+        return fn(*xs)
 
 
 class _HostParity:
@@ -445,7 +469,8 @@ def apply_matrix_host(coefs: np.ndarray, batch):
         fn = _jitted_apply(coefs.tobytes(), n_out, n_in, variant,
                            donate=_donate())
         count_leg("device", batch.nbytes)
-        return _HostParity(fn(jnp.asarray(xw)), b, n_out, s)
+        return _HostParity(_launch(fn, _submit([xw]), batch.nbytes),
+                           b, n_out, s)
     if _host_prefers_native(n_in, batch):
         count_leg("native", batch.nbytes)
         return rs_native.apply_gf_matrix(coefs, batch)
@@ -533,7 +558,8 @@ def apply_matrix_host_multi(coefs: np.ndarray, batches):
     g_shape = g_variant = None
 
     def dispatch(ixs, xws, width):
-        count_leg("device", sum(batches[i].nbytes for i in ixs))
+        nbytes = sum(batches[i].nbytes for i in ixs)
+        count_leg("device", nbytes)
         if width == 1:
             # lone slab: the single-dispatch executable (already cached
             # for steady-state workloads) serves the word form the loop
@@ -542,11 +568,12 @@ def apply_matrix_host_multi(coefs: np.ndarray, batches):
             b, _, s = batches[i].shape
             fn = _jitted_apply(coefs.tobytes(), n_out, n_in, g_variant,
                                donate=_donate())
-            out[i] = _HostParity(fn(jnp.asarray(xws[0])), b, n_out, s)
+            out[i] = _HostParity(_launch(fn, _submit(xws), nbytes),
+                                 b, n_out, s)
             return
         fn = _jitted_apply_multi(coefs.tobytes(), n_out, n_in,
                                  g_variant, width, donate=_donate())
-        ys = fn(*[jnp.asarray(x) for x in xws])
+        ys = _launch(fn, _submit(xws), nbytes)
         for i, y in zip(ixs, ys):
             b, _, s = batches[i].shape
             out[i] = _HostParity(y, b, n_out, s)

@@ -194,7 +194,8 @@ def _expand_rows(coefs: np.ndarray, n_out: int):
 def apply_gf_matrix_swar_words(coefs: np.ndarray, x4: jnp.ndarray,
                                interpret: bool = False,
                                rows_per_block: int = SWAR_ROWS,
-                               cse: bool = True) -> jnp.ndarray:
+                               cse: bool = True,
+                               name: str = "rs_swar_words") -> jnp.ndarray:
     """SWAR kernel on the WORD form: x4 (B, n_in, R, 128) u32 ->
     (B, n_out, R, 128) u32.
 
@@ -227,6 +228,7 @@ def apply_gf_matrix_swar_words(coefs: np.ndarray, x4: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct(
             (b, n_out, r, LANES), jnp.uint32),
         interpret=interpret,
+        name=name,
     )(x4)
 
 
@@ -253,7 +255,7 @@ def apply_gf_matrix_swar(coefs: np.ndarray, x: jnp.ndarray,
     x4 = xw.reshape(b, n_in, r, LANES)
     y4 = apply_gf_matrix_swar_words(coefs, x4, interpret=interpret,
                                     rows_per_block=rows_per_block,
-                                    cse=cse)
+                                    cse=cse, name="rs_swar_u8")
     yw = y4.reshape(b, n_out, w)
     return jax.lax.bitcast_convert_type(yw, jnp.uint8).reshape(b, n_out, s)
 
@@ -291,17 +293,20 @@ def apply_gf_matrix(coefs: np.ndarray, x: jnp.ndarray,
         x.reshape(b, n_in, w, 4), jnp.uint32)
     x4 = xw.reshape(b, n_in, GROUP_WORDS, r, LANES)
     y4 = apply_gf_matrix_words(coefs, x4, interpret=interpret, rb=rb,
-                               cse=cse)
+                               cse=cse, name="rs_u8")
     yw = y4.reshape(b, n_out, w)
     return jax.lax.bitcast_convert_type(yw, jnp.uint8).reshape(b, n_out, s)
 
 
 def apply_gf_matrix_words(coefs: np.ndarray, x4: jnp.ndarray,
                           interpret: bool = False, rb: int = RB,
-                          cse: bool = True) -> jnp.ndarray:
+                          cse: bool = True,
+                          name: str = "rs_words") -> jnp.ndarray:
     """Transpose kernel on the WORD form: x4 (B, n_in, 32, R, 128) u32
     -> (B, n_out, 32, R, 128) u32 — no u8<->u32 relayout around the
-    kernel (see apply_gf_matrix_swar_words for why that matters)."""
+    kernel (see apply_gf_matrix_swar_words for why that matters).
+    ``name`` is what a profiler trace calls the kernel: the u8 entry
+    points pass their own, so a trace tells the four entries apart."""
     n_out, n_in = coefs.shape
     if (x4.ndim != 5 or x4.shape[1] != n_in
             or x4.shape[2] != GROUP_WORDS or x4.shape[4] != LANES):
@@ -326,4 +331,5 @@ def apply_gf_matrix_words(coefs: np.ndarray, x4: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct(
             (b, n_out, GROUP_WORDS, r, LANES), jnp.uint32),
         interpret=interpret,
+        name=name,
     )(x4)

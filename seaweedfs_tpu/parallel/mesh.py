@@ -34,7 +34,6 @@ import collections
 import contextlib
 import math
 import threading
-import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -365,7 +364,13 @@ def _make_apply_only_step(coefs: np.ndarray, mesh: Mesh):
         check_vma=False,
     )
     donate = (0,) if rs_jax.donation_enabled() else ()
-    return jax.jit(mapped, donate_argnums=donate)
+
+    def rs_mesh_step(x):
+        return mapped(x)
+    # the trace names the step by its mesh, not "mapped"
+    rs_mesh_step.__name__ = rs_mesh_step.__qualname__ = \
+        f"rs_mesh_dp{mesh.shape['dp']}_sp{mesh.shape['sp']}"
+    return jax.jit(rs_mesh_step, donate_argnums=donate)
 
 
 def _real_accelerator() -> bool:
@@ -533,29 +538,29 @@ def prepare_batch(batch: np.ndarray, mesh=None) -> Prepared:
     is what ``[pipeline] double_buffer`` overlaps with the previous
     batch's collective."""
     from ..pipeline import flight
-    t0 = time.perf_counter()
-    flight.record(flight.EV_H2D_SUBMIT)
-    b, n_in, s = batch.shape
-    if mesh is None or mesh is AUTO:
-        mesh = _auto_mesh_for(b)
-    dp = mesh.shape["dp"]
-    sp = mesh.shape["sp"]
-    gran = _granule(sp)
-    b_pad = -(-b // dp) * dp
-    s_pad = -(-s // gran) * gran
-    if b_pad != b or s_pad != s:
-        padded = np.zeros((b_pad, n_in, s_pad), dtype=np.uint8)
-        padded[:b, :, :s] = batch
-        batch = padded
-    arr = shard_batch(batch, mesh)
-    with _STATS_LOCK:
-        for shard in arr.addressable_shards:
-            _DEVICE_BYTES[shard.device.id] = _DEVICE_BYTES.get(
-                shard.device.id, 0) + shard.data.nbytes
-    _observe("dispatch", time.perf_counter() - t0, batch.nbytes, mesh)
-    # READY means the async device_put is ISSUED (transfer in flight),
-    # not landed — the landing is observed by the batch's sync span.
-    flight.record(flight.EV_H2D_READY, arg=batch.nbytes)
+    # the span's end means the async device_put is ISSUED (transfer in
+    # flight), not landed — the landing is observed by the batch's
+    # sync span
+    with flight.span("h2d_submit") as span:
+        b, n_in, s = batch.shape
+        if mesh is None or mesh is AUTO:
+            mesh = _auto_mesh_for(b)
+        dp = mesh.shape["dp"]
+        sp = mesh.shape["sp"]
+        gran = _granule(sp)
+        b_pad = -(-b // dp) * dp
+        s_pad = -(-s // gran) * gran
+        if b_pad != b or s_pad != s:
+            padded = np.zeros((b_pad, n_in, s_pad), dtype=np.uint8)
+            padded[:b, :, :s] = batch
+            batch = padded
+        arr = shard_batch(batch, mesh)
+        with _STATS_LOCK:
+            for shard in arr.addressable_shards:
+                _DEVICE_BYTES[shard.device.id] = _DEVICE_BYTES.get(
+                    shard.device.id, 0) + shard.data.nbytes
+        span.nbytes = batch.nbytes
+    _observe("dispatch", span.elapsed, batch.nbytes, mesh)
     return Prepared(arr, b, s, mesh)
 
 
@@ -565,14 +570,14 @@ def apply_prepared(coefs: np.ndarray, prep: Prepared):
     extents (np.asarray materializes it — callers in the 3-stage
     pipeline keep their D2H on the writer thread). The step-enqueue
     time lands in the ``pipe.compute.collective`` stage."""
-    t0 = time.perf_counter()
-    coefs = np.ascontiguousarray(coefs, dtype=np.uint8)
-    step = _step_for(coefs, prep.mesh)
-    rs_jax.count_leg("device" if _real_accelerator() else "xla",
-                     prep.arr.nbytes)
-    out = step(prep.arr)[:prep.b, :, :prep.s]  # lazy slice; no sync
-    _observe("collective", time.perf_counter() - t0, out.nbytes,
-             prep.mesh)
+    from ..pipeline import flight
+    with flight.span("launch", nbytes=prep.arr.nbytes) as span:
+        coefs = np.ascontiguousarray(coefs, dtype=np.uint8)
+        step = _step_for(coefs, prep.mesh)
+        rs_jax.count_leg("device" if _real_accelerator() else "xla",
+                         prep.arr.nbytes)
+        out = step(prep.arr)[:prep.b, :, :prep.s]  # lazy slice; no sync
+    _observe("collective", span.elapsed, out.nbytes, prep.mesh)
     return out
 
 

@@ -214,11 +214,13 @@ def write_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
     writer = writeback.WriterPool() if overlapped else None
     fds: dict[str, int] = {}
     try:
-        if writer is not None:
+        with flight.span("step_shard_files", trace=True):
+            # create and preallocate the shard files: before the run's
+            # wall begins, so a step of its own
             for p in paths:
-                writer.open_file(p, shard_size)
-        else:
-            for p in paths:
+                if writer is not None:
+                    writer.open_file(p, shard_size)
+                    continue
                 out = os.open(p, os.O_CREAT | os.O_WRONLY | os.O_TRUNC,
                               0o644)
                 fds[p] = out
@@ -358,12 +360,14 @@ def encode_volume(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
     with tracing.span("ec.encode", base=str(base)) as sp:
         dat_size = write_ec_files(base, scheme, max_batch_bytes)
         sp.n_bytes = dat_size
-    write_ecx_file(base)
+    with flight.span("step_ecx", trace=True):
+        write_ecx_file(base)
     vi = ec_files.VolumeInfo(version=version, replication=replication,
                              dat_file_size=dat_size,
                              data_shards=scheme.data_shards,
                              parity_shards=scheme.parity_shards)
-    vi.save(base)
+    with flight.span("step_vif", trace=True):
+        vi.save(base)
     if remove_source:
         os.remove(volume_mod.dat_path(base))
         os.remove(volume_mod.idx_path(base))

@@ -10,16 +10,29 @@ flowing through pipe.py / encode.py / rebuild.py / writeback.py and
 the mesh prepare/apply split emits timestamped lifecycle events into a
 bounded per-process ring.
 
-Hot-path discipline:
+Every timed site is ONE primitive, :class:`span` (a context manager;
+``ops/`` and the rpc handlers use it too). Entering and leaving it
 
-* the ring's slots are PREALLOCATED mutable records written in place —
+* always adds the elapsed ``perf_counter`` seconds and one call to a
+  process-wide total for the span's name (:func:`totals`; what
+  ``PipeStats`` and ``/debug/vars`` ``pipeline`` are fed by) — two
+  clock reads and a dict add;
+* while a ``jax.profiler`` session is active in this process, is a
+  ``jax.profiler.TraceAnnotation`` on the thread where the work
+  happens, so the span lands in the same ``.xplane.pb``, on the same
+  clock, as the device's operations (arguments ``batch``, ``bytes``,
+  ``run``, and ``trace_id`` where known). Only LEAF spans go there: an
+  enclosing span would overlap every gap and say nothing;
+* while the ring is armed, writes the start/end events below.
+
+Hot-path discipline of the ring:
+
+* its slots are PREALLOCATED mutable records written in place —
   recording an event allocates nothing;
 * timestamps are ``time.monotonic_ns()`` (one clock for the whole
   process, immune to wall-clock steps);
 * when the recorder is disarmed, :func:`record` is a single attribute
-  load + ``is None`` test — the instrumentation sites stay in the code
-  and cost nothing measurable (``bench.py --flight-overhead`` proves
-  the ARMED tax < 2% on an overlapped 256 MiB encode).
+  load + ``is None`` test.
 
 On top of the ring:
 
@@ -30,8 +43,7 @@ On top of the ring:
 * :func:`occupancy` / :func:`analyze` — per-stage busy fractions over
   the recorded wall window, bubble time, per-batch critical-path
   attribution (which stage each batch actually waited on), and a
-  bottleneck verdict with concrete ``[pipeline]`` knob recommendations
-  (the ``pipeline.analyze`` shell command);
+  bottleneck verdict (the ``pipeline.analyze`` shell command);
 * ``seaweed_pipeline_*`` gauges + a ``/debug/vars`` "flight" section
   (:func:`debug_payload`), refreshed at the end of every recorded run.
 
@@ -46,15 +58,17 @@ filters by validity — every exporter here runs after the run's join.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass
 from typing import Optional
 
-from ..util import stats
+from ..util import stats, tracing
 
 # --------------------------------------------------------------------------
 # event vocabulary
@@ -69,9 +83,9 @@ EV_READ_START = 4
 EV_READ_END = 5        # arg=bytes materialized
 EV_POOL_WAIT = 6       # reader blocked on HostBufferPool.acquire
 EV_POOL_GOT = 7        # value=in-flight buffers after acquire
-EV_H2D_SUBMIT = 8      # mesh prepare: async device_put issued
-EV_H2D_READY = 9       # prepare returned (transfer in flight); arg=bytes
-EV_DISPATCH = 10       # compute dispatch (jit enqueue) begins
+EV_H2D_SUBMIT = 8      # host side of H2D begins (jnp.asarray / device_put)
+EV_H2D_READY = 9       # submit returned (transfer in flight); arg=bytes
+EV_DISPATCH = 10       # compute dispatch (H2D submit + launch) begins
 EV_DISPATCH_DONE = 11  # dispatch returned (async); arg=group width
 EV_SYNC_START = 12     # writer blocks on np.asarray (device wait + D2H)
 EV_SYNC_END = 13       # result bytes on host; arg=bytes
@@ -82,6 +96,8 @@ EV_PWRITEV_RETIRE = 17 # one positioned write retired; value=seconds, arg=bytes
 EV_RECYCLE = 18        # pooled buffer returned; value=in-flight after
 EV_QDEPTH = 19         # counter: value=depth, arg: 0=read_q 1=write_q
 EV_POOL_OCC = 20       # counter: value=in-flight pooled buffers
+EV_LAUNCH = 21         # the jitted call itself begins
+EV_LAUNCH_DONE = 22    # the call returned (async); arg=bytes
 
 _NAMES = {
     EV_RUN_START: "run_start", EV_RUN_END: "run_end",
@@ -94,6 +110,7 @@ _NAMES = {
     EV_WRITE_END: "write_end", EV_WRITE_SUBMIT: "write_submit",
     EV_PWRITEV_RETIRE: "pwritev_retire", EV_RECYCLE: "recycle",
     EV_QDEPTH: "queue_depth", EV_POOL_OCC: "pool_occupancy",
+    EV_LAUNCH: "launch", EV_LAUNCH_DONE: "launch_done",
 }
 
 #: (start, end, track-name) pairs rendered as duration events; pairing
@@ -102,7 +119,8 @@ _NAMES = {
 _SPAN_PAIRS = (
     (EV_READ_START, EV_READ_END, "read"),
     (EV_POOL_WAIT, EV_POOL_GOT, "pool_wait"),
-    (EV_H2D_SUBMIT, EV_H2D_READY, "h2d"),
+    (EV_H2D_SUBMIT, EV_H2D_READY, "h2d_submit"),
+    (EV_LAUNCH, EV_LAUNCH_DONE, "launch"),
     (EV_DISPATCH, EV_DISPATCH_DONE, "dispatch"),
     (EV_SYNC_START, EV_SYNC_END, "d2h_sync"),
     (EV_WRITE_START, EV_WRITE_END, "write"),
@@ -259,6 +277,225 @@ def record(event: int, batch: int = -1, value: float = 0.0,
 
 
 # --------------------------------------------------------------------------
+# the span primitive: totals always, profiler when a session is active,
+# ring when armed
+# --------------------------------------------------------------------------
+
+#: span name -> (ring start code, ring end code). A start code of 0
+#: means the span is one retire record carrying its own duration.
+#: Names that are not here (the rpc steps) write nothing to the ring.
+_RING_CODES = {
+    "read": (EV_READ_START, EV_READ_END),
+    "pool_wait": (EV_POOL_WAIT, EV_POOL_GOT),
+    "h2d_submit": (EV_H2D_SUBMIT, EV_H2D_READY),
+    "launch": (EV_LAUNCH, EV_LAUNCH_DONE),
+    "dispatch": (EV_DISPATCH, EV_DISPATCH_DONE),
+    "d2h_sync": (EV_SYNC_START, EV_SYNC_END),
+    "write": (EV_WRITE_START, EV_WRITE_END),
+    "pwritev": (0, EV_PWRITEV_RETIRE),
+}
+
+#: The EC handlers of the volume server (each a ``step_<name>`` span
+#: around the whole handler; their sum is ``rpc_seconds``) and the
+#: steps inside them and on the master's side. ``/debug/vars`` lists
+#: every one from process start, so a reader finds the key before the
+#: first call.
+HANDLER_STEPS = ("mark_readonly", "generate", "mount", "delete_source",
+                 "shards_delete", "rebuild")
+INNER_STEPS = ("vol_sync", "shard_files", "ecx", "vif", "rebuild_fetch",
+               "store_mount", "store_delete", "heartbeat",
+               "master_heartbeat", "master_lookup")
+
+#: span name -> [seconds, calls], cumulative since process start
+_TOTALS: dict[str, list] = {}
+_TOTALS_LOCK = threading.Lock()
+_TLS = threading.local()
+_RUN_IDS = itertools.count(1)
+
+
+def totals() -> dict[str, tuple[float, int]]:
+    """(seconds, calls) per span name since process start (or the last
+    :func:`reset_totals`). A span that enclosed other LEAF spans on its
+    thread counts only its own time: ``read`` excludes ``pool_wait``."""
+    with _TOTALS_LOCK:
+        return {k: (v[0], v[1]) for k, v in _TOTALS.items()}
+
+
+def reset_totals() -> None:
+    with _TOTALS_LOCK:
+        _TOTALS.clear()
+
+
+class Run:
+    """What the spans of one ``run_pipeline`` call share: an id, the
+    per-run sink (``PipeStats.add``) and, until a first span has taken
+    it, the Dapper trace id of the rpc that started the run. Bound to
+    each stage thread with :func:`bind`."""
+
+    __slots__ = ("id", "stats", "trace_id")
+
+    def __init__(self, stats):
+        self.id = next(_RUN_IDS)
+        self.stats = stats
+        cur = tracing.current_span()
+        self.trace_id = cur.trace_id if cur is not None else ""
+
+
+def bind(run: Optional[Run]) -> Optional[Run]:
+    """Make ``run`` this thread's run; returns the one it replaces."""
+    prev = getattr(_TLS, "run", None)
+    _TLS.run = run
+    return prev
+
+
+def _profiling():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is
+    active in this process, else None. A process that never imported
+    JAX (the shell, a lone master) has no session to be in."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation
+    return ann if ann.is_enabled() else None
+
+
+def step(name: str, leaf: bool = True):
+    """Decorator: run an rpc handler (or any call) under a
+    ``step_<name>`` span that is also a child of the thread's Dapper
+    span. ``leaf=False`` for a handler that holds other steps."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def stepped(*args, **kwargs):
+            with span(f"step_{name}", leaf=leaf, trace=True):
+                return fn(*args, **kwargs)
+        return stepped
+    return deco
+
+
+class span:
+    """Time one stage or rpc step: ``with flight.span("read", batch=n)
+    as sp: ...``. Set ``sp.nbytes`` (and ``sp.arg`` / ``sp.value`` where
+    the ring's end event carries something else) inside the block.
+    Afterwards ``sp.elapsed`` is the whole time and ``sp.seconds`` the
+    time not spent in nested leaf spans, which is what the totals get.
+
+    ``leaf=False`` marks a span that encloses others (``dispatch``, an
+    rpc handler): totals and ring only, never the profiler plane, and
+    nothing is carved out of it. ``trace=True`` makes it a child of the
+    thread's Dapper span as well (the rpc steps). A leaf span without a
+    ``batch`` of its own reports the batch of the span around it in the
+    profiler; the ring keeps what the site gave."""
+
+    __slots__ = ("name", "batch", "nbytes", "arg", "value", "leaf",
+                 "elapsed", "seconds", "_t0", "_carved", "_parent",
+                 "_outer_batch", "_ann", "_args", "_dapper")
+
+    def __init__(self, name: str, batch: int = -1, nbytes: int = 0,
+                 leaf: bool = True, trace: bool = False):
+        self.name = name
+        self.batch = batch
+        self.nbytes = nbytes
+        self.arg = None
+        self.value = 0.0
+        self.leaf = leaf
+        self.elapsed = self.seconds = self._carved = 0.0
+        self._parent = self._ann = self._args = None
+        self._dapper = tracing.span(name) if trace else None
+
+    def __enter__(self) -> "span":
+        tls = _TLS
+        self._outer_batch = getattr(tls, "batch", -1)
+        if self.batch >= 0:
+            tls.batch = self.batch
+        if self._dapper is not None:
+            self._dapper.__enter__()
+        if self.leaf:
+            parent = self._parent = getattr(tls, "open", None)
+            if parent is not None:
+                parent._pause()
+            tls.open = self
+            if _profiling() is not None:
+                self._args = self._annotation_args(tls)
+                self._resume()
+        r = _REC
+        if r is not None:
+            start = _RING_CODES.get(self.name, (0, 0))[0]
+            if start:
+                r.record(start, self.batch)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.elapsed = dt = time.perf_counter() - self._t0
+        self.seconds = own = max(0.0, dt - self._carved)
+        tls = _TLS
+        r = _REC
+        if r is not None and et is None:
+            # a span that raised (the reader's last next()) leaves its
+            # start unpaired, as the hand-written sites did
+            start, end = _RING_CODES.get(self.name, (0, 0))
+            if end:
+                r.record(end, self.batch,
+                         self.value if start else dt,
+                         self.nbytes if self.arg is None else self.arg)
+        self._pause()
+        if self.leaf:
+            parent = tls.open = self._parent
+            if parent is not None:
+                parent._carved += dt
+                parent._resume()
+        if self._dapper is not None:
+            self._dapper.__exit__(et, ev, tb)
+        tls.batch = self._outer_batch
+        with _TOTALS_LOCK:
+            tot = _TOTALS.get(self.name)
+            if tot is None:
+                _TOTALS[self.name] = [own, 1]
+            else:
+                tot[0] += own
+                tot[1] += 1
+        run = getattr(tls, "run", None)
+        if run is not None:
+            run.stats.add(self.name, own)
+        return False
+
+    def _annotation_args(self, tls) -> dict:
+        # "bytes" is added as each piece closes: most sites know it
+        # only then
+        args = {"batch": self.batch if self.batch >= 0
+                else self._outer_batch, "run": 0}
+        run = getattr(tls, "run", None)
+        trace_id = ""
+        if run is not None:
+            args["run"] = run.id
+            # the run's first span names the rpc that started it
+            trace_id, run.trace_id = run.trace_id, ""
+        else:
+            cur = tracing.current_span()
+            if cur is not None:
+                trace_id = cur.trace_id
+        if trace_id:
+            args["trace_id"] = trace_id
+        return args
+
+    # the annotation is closed at the span's end, and by a nested leaf
+    # span for its own duration, so that the profiler plane holds no
+    # enclosing span
+    def _pause(self) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(bytes=self.nbytes)
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    def _resume(self) -> None:
+        if self._args is not None:
+            ann = _profiling()
+            if ann is not None:
+                self._ann = ann(self.name, **self._args)
+                self._ann.__enter__()
+
+
+# --------------------------------------------------------------------------
 # Chrome trace-event export
 # --------------------------------------------------------------------------
 
@@ -276,8 +513,8 @@ def _thread_names(events: list[tuple]) -> dict[int, str]:
             roles.setdefault(tid, "writer")
         elif kind == EV_PWRITEV_RETIRE:
             roles.setdefault(tid, "writeback")
-        elif kind in (EV_DISPATCH, EV_DISPATCH_DONE,
-                      EV_H2D_SUBMIT, EV_H2D_READY):
+        elif kind in (EV_DISPATCH, EV_DISPATCH_DONE, EV_LAUNCH,
+                      EV_LAUNCH_DONE, EV_H2D_SUBMIT, EV_H2D_READY):
             roles.setdefault(tid, "compute")
     # distinct writeback workers get numbered tracks
     n_wb = 0
@@ -399,8 +636,11 @@ def occupancy(events: Optional[list[tuple]] = None,
     * ``read`` — reader thread materializing batches (pool-acquire
       wait EXCLUDED: that sub-window is ``pool_wait``, backpressure
       from the writer/recycle side, not read cost);
-    * ``dispatch`` — compute-stage enqueue time (the Python + jit
-      dispatch floor), H2D prepare included;
+    * ``h2d_submit`` — the host side of H2D (``jnp.asarray`` of each
+      slab, mesh ``prepare``); ``launch`` — the jitted call itself;
+    * ``dispatch`` — what is left of the compute stage's enqueue time
+      once those two are taken out (a host codec computing inline,
+      Python around the call);
     * ``d2h`` — writer blocked in ``np.asarray``: the device finishing
       the batch plus the D2H copy — on a link-bound box this is where
       the dispatch-link floor shows up;
@@ -427,12 +667,14 @@ def occupancy(events: Optional[list[tuple]] = None,
     window = max(1e-9, (t_hi - t_lo) / 1e9)
 
     busy = {"read": 0.0, "pool_wait": 0.0, "dispatch": 0.0,
-            "d2h": 0.0, "write": 0.0, "writeback": 0.0}
+            "h2d_submit": 0.0, "launch": 0.0, "d2h": 0.0, "write": 0.0,
+            "writeback": 0.0}
     # per-batch timeline marks for critical-path attribution
     marks: dict[int, dict] = {}
     open_spans: dict[tuple, tuple] = {}
     span_stage = {
-        "read": "read", "pool_wait": "pool_wait", "h2d": "dispatch",
+        "read": "read", "pool_wait": "pool_wait",
+        "h2d_submit": "h2d_submit", "launch": "launch",
         "dispatch": "dispatch", "d2h_sync": "d2h", "write": "write",
     }
     starts = {code: (end, name) for code, end, name in _SPAN_PAIRS}
@@ -465,18 +707,22 @@ def occupancy(events: Optional[list[tuple]] = None,
     # carved out after the walk — at POOL_GOT time the enclosing read
     # span is still open and has contributed nothing to subtract from
     busy["read"] = max(0.0, busy["read"] - busy["pool_wait"])
+    # likewise H2D submit and launch nest inside the dispatch span
+    # (the mesh path's prepare may run outside one: never below zero)
+    busy["dispatch"] = max(0.0, busy["dispatch"] - busy["h2d_submit"]
+                           - busy["launch"])
 
     # a start with no matching end (e.g. the reader's final next() that
     # hit StopIteration) is not a batch — keep only completed spans
     marks = {b: m for b, m in marks.items()
-             if any(k in m for k in ("read", "dispatch", "h2d",
-                                     "d2h_sync", "write"))}
+             if any(k in m for k in ("read", "dispatch", "h2d_submit",
+                                     "launch", "d2h_sync", "write"))}
     waited: dict[str, int] = {}
     for b, m in marks.items():
         comp = {
             "read": m.get("read", 0.0),
-            "dispatch/h2d": m.get("dispatch", 0.0) + m.get("h2d", 0.0)
-            + m.get("d2h_sync", 0.0),
+            "dispatch": m.get("dispatch", 0.0),
+            "d2h": m.get("d2h_sync", 0.0),
             "write": m.get("write", 0.0),
         }
         if "read_end" in m and "dispatch_start" in m:
@@ -502,76 +748,48 @@ def occupancy(events: Optional[list[tuple]] = None,
     }
 
 
-#: bottleneck -> (headline, [pipeline] knob advice) for the analyzer
-_ADVICE = {
-    "dispatch/h2d": (
-        "the dispatch/H2D link stage is the floor — batches sit in "
-        "the device round-trip, not on the host",
-        ["raise [pipeline] depth (deeper lookahead keeps more "
-         "transfers in flight)",
-         "enable [pipeline] double_buffer = true on the mesh path "
-         "(overlap the next batch's H2D with the current collective)",
-         "grow [pipeline] batch_bytes / grouped_batch_bytes so each "
-         "dispatch amortizes the fixed per-call floor",
-         "raise [pipeline] group_cap (wider grouped dispatch on a "
-         "single accelerator)"]),
-    "read": (
-        "the reader is the floor — compute and writer idle waiting "
-        "for batch materialization",
-        ["raise [pipeline] pool_buffers so the reader can run ahead",
-         "shrink [pipeline] grouped_batch_bytes for finer overlap",
-         "check the source filesystem (bench disk_write_gibps)"]),
-    "pool_wait": (
-        "the reader is blocked on buffer recycle — writeback "
-        "backpressure, not read cost",
-        ["raise [pipeline] pool_buffers",
-         "raise [pipeline] writer_threads / writer_queue_depth so "
-         "writes retire (and recycle buffers) sooner"]),
-    "write": (
-        "the writer stage is the floor — shard writeback gates the "
-        "pipeline",
-        ["raise [pipeline] writer_threads / writer_queue_depth",
-         "confirm preallocate = true (growing files serializes)",
-         "check the destination filesystem (bench disk_write_gibps)"]),
+#: lane -> what it means when that lane is the busiest
+_HEADLINE = {
+    "read": "the reader is the floor: compute and writer idle waiting "
+            "for batch materialization",
+    "pool_wait": "the reader is blocked on buffer recycle: writeback "
+                 "backpressure, not read cost",
+    "dispatch": "the compute stage's own host time is the floor (a host "
+                "codec computing inline, or Python around the call)",
+    "h2d_submit": "the host side of H2D is the floor: handing each slab "
+                  "to the runtime",
+    "launch": "the jitted call is the floor: launch cost, or a compile "
+              "on the dispatch path",
+    "d2h": "the writer's wait for the device's result is the floor: "
+           "transfer in, kernel and D2H copy",
+    "write": "the writer stage is the floor: shard writeback gates the "
+             "pipeline",
 }
 
 
 def analyze(events: Optional[list[tuple]] = None,
             last_run_only: bool = True) -> dict:
-    """Name the bottleneck stage of the recorded window and recommend
-    concrete ``[pipeline]`` knob changes, with the occupancy evidence
-    attached. Stage grouping for the verdict: ``dispatch`` + ``d2h``
-    merge into "dispatch/h2d" (host-side enqueue and device/link
-    round-trip are one serialized lane on the compute path)."""
+    """Name the busiest lane of the recorded window, with the occupancy
+    evidence attached. H2D submit, launch and the D2H wait are lanes of
+    their own; ``dispatch`` is what the compute stage spent outside the
+    first two."""
     occ = occupancy(events, last_run_only=last_run_only)
     if not occ["batches"]:
         return {"verdict": "no recorded batches", "occupancy": occ,
-                "bottleneck": None, "recommendations": []}
+                "bottleneck": None}
     frac = occ["busy_fraction"]
-    lanes = {
-        "dispatch/h2d": frac.get("dispatch", 0.0) + frac.get("d2h", 0.0),
-        "read": frac.get("read", 0.0),
-        "pool_wait": frac.get("pool_wait", 0.0),
-        "write": frac.get("write", 0.0),
-    }
+    lanes = {k: frac.get(k, 0.0) for k in _HEADLINE}
     bottleneck = max(lanes, key=lanes.get)
-    headline, recs = _ADVICE[bottleneck]
-    # refine dispatch/h2d advice ordering: if the device wait (d2h)
-    # dominates the host enqueue, deeper overlap beats wider groups
-    if bottleneck == "dispatch/h2d" and \
-            frac.get("dispatch", 0.0) > frac.get("d2h", 0.0):
-        recs = [recs[2], recs[3], recs[0], recs[1]]
     waited = occ["waited_on"]
     top_wait = max(waited, key=waited.get) if waited else None
     return {
         "verdict": f"bottleneck: {bottleneck} "
                    f"({lanes[bottleneck]:.0%} of the "
                    f"{occ['window_seconds']:.3f}s window busy) — "
-                   f"{headline}",
+                   f"{_HEADLINE[bottleneck]}",
         "bottleneck": bottleneck,
         "lane_fraction": {k: round(v, 4) for k, v in lanes.items()},
         "waited_on_top": top_wait,
-        "recommendations": recs,
         "occupancy": occ,
     }
 

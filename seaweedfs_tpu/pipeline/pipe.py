@@ -58,7 +58,8 @@ bufcheck.install_from_env()
 
 # Same deal for the flight recorder: SEAWEED_FLIGHT=1 arms per-batch
 # lifecycle recording (scripts/flight_smoke.sh); unset means every
-# flight.record() below is one attribute load + None test.
+# flight.span() below only adds to its total and every flight.record()
+# is one attribute load + None test.
 flight.install_from_env()
 
 # And for the Eraser lockset race checker: SEAWEED_RACECHECK=raise
@@ -198,13 +199,12 @@ class HostBufferPool:
     def acquire(self, timeout: Optional[float] = None) -> np.ndarray:
         """A free (nbytes,) uint8 buffer; blocks until one is
         recycled. Raises ``queue.Empty`` on timeout."""
-        flight.record(flight.EV_POOL_WAIT)
-        buf = self._free.get(timeout=timeout) if timeout is not None \
-            else self._free.get()
-        bufcheck.on_acquire(buf)
-        occ = self.in_flight()
-        flight.record(flight.EV_POOL_GOT, value=float(occ))
-        flight.record(flight.EV_POOL_OCC, value=float(occ))
+        with flight.span("pool_wait") as sp:
+            buf = self._free.get(timeout=timeout) \
+                if timeout is not None else self._free.get()
+            bufcheck.on_acquire(buf)
+            sp.value = occ = float(self.in_flight())
+        flight.record(flight.EV_POOL_OCC, value=occ)
         return buf
 
     def release(self, buf: np.ndarray) -> None:
@@ -223,10 +223,19 @@ class HostBufferPool:
 # stage metrics
 # --------------------------------------------------------------------------
 
+#: span name -> the ``PipeStats`` field its seconds go to
+_SPAN_FIELD = {"read": "read_seconds", "pool_wait": "pool_wait_seconds",
+               "dispatch": "dispatch_seconds",
+               "h2d_submit": "h2d_submit_seconds",
+               "launch": "launch_seconds", "d2h_sync": "sync_seconds",
+               "write": "write_seconds"}
+
+
 @dataclass
 class PipeStats:
-    """Per-run stage accounting. Each field is written by exactly one
-    stage thread and read after the join, so no locking is needed."""
+    """Per-run stage accounting, fed by the run's ``flight.span``s
+    (:meth:`add`). Each field is written by exactly one stage thread
+    and read after the join, so no locking is needed."""
 
     batches: int = 0
     groups: int = 0                 # compute dispatch steps
@@ -234,10 +243,18 @@ class PipeStats:
     bytes_in: int = 0
     bytes_out: int = 0
     read_seconds: float = 0.0       # batch materialization (reader)
+    pool_wait_seconds: float = 0.0  # reader blocked on a free buffer
     dispatch_seconds: float = 0.0   # encode_fn enqueue (main thread)
+    h2d_submit_seconds: float = 0.0  # of dispatch: jnp.asarray per slab
+    launch_seconds: float = 0.0     # of dispatch: the jitted call
     sync_seconds: float = 0.0       # np.asarray device wait (writer)
     write_seconds: float = 0.0      # write_fn + positioned writes
     wall_seconds: float = 0.0
+
+    def add(self, span: str, seconds: float) -> None:
+        name = _SPAN_FIELD.get(span)
+        if name is not None:
+            setattr(self, name, getattr(self, name) + seconds)
 
     @property
     def compute_seconds(self) -> float:
@@ -256,8 +273,10 @@ class PipeStats:
         d.update(batches=self.batches, groups=self.groups,
                  max_group=self.max_group, bytes_in=self.bytes_in,
                  bytes_out=self.bytes_out,
-                 dispatch_seconds=round(self.dispatch_seconds, 6),
-                 sync_seconds=round(self.sync_seconds, 6))
+                 **{name: round(getattr(self, name), 6)
+                    for name in ("pool_wait_seconds", "dispatch_seconds",
+                                 "h2d_submit_seconds", "launch_seconds",
+                                 "sync_seconds")})
         if self.wall_seconds > 0:
             d["gibps"] = round(
                 self.bytes_in / (1 << 30) / self.wall_seconds, 3)
@@ -266,11 +285,12 @@ class PipeStats:
 
 #: Process-lifetime totals + a short ring of completed-run snapshots,
 #: surfaced at /debug/vars on every server (util/varz.py) and by the
-#: pipeline.status shell command.
+#: pipeline.status shell command. Counters and wall seconds of the
+#: published runs live here; every other ``*_seconds`` key is read from
+#: the process-wide span totals (flight.totals()).
 _TELEMETRY_LOCK = threading.Lock()
 _TOTALS = {"runs": 0, "batches": 0, "bytes_in": 0, "bytes_out": 0,
-           "read_seconds": 0.0, "compute_seconds": 0.0,
-           "write_seconds": 0.0, "wall_seconds": 0.0}
+           "wall_seconds": 0.0}
 RECENT: deque = deque(maxlen=8)
 
 
@@ -281,9 +301,6 @@ def publish_stats(stats: "PipeStats", kind: str = "pipe") -> None:
         _TOTALS["batches"] += stats.batches
         _TOTALS["bytes_in"] += stats.bytes_in
         _TOTALS["bytes_out"] += stats.bytes_out
-        _TOTALS["read_seconds"] += stats.read_seconds
-        _TOTALS["compute_seconds"] += stats.compute_seconds
-        _TOTALS["write_seconds"] += stats.write_seconds
         _TOTALS["wall_seconds"] += stats.wall_seconds
         entry = {"kind": kind}
         entry.update(stats.to_dict())
@@ -297,11 +314,37 @@ def last_run() -> Optional[dict]:
 
 
 def debug_payload() -> dict:
-    """/debug/vars section: totals + the recent-run ring."""
+    """/debug/vars section, every key flat and cumulative since process
+    start: the published runs' counters and wall, the stage spans'
+    seconds (``compute`` = dispatch + sync; ``write`` = writer stage +
+    positioned writes), ``rpc_seconds`` (the EC handlers, each counted
+    once, pipeline run included), ``step_<name>_seconds`` / ``_calls``
+    for every server-side rpc step, and the recent-run ring."""
+    spans = flight.totals()
+
+    def sec(*names: str) -> float:
+        return sum(spans.get(n, (0.0, 0))[0] for n in names)
+
     with _TELEMETRY_LOCK:
-        out = {k: (round(v, 6) if isinstance(v, float) else v)
-               for k, v in _TOTALS.items()}
-        out["recent"] = [dict(e) for e in RECENT]
+        out = dict(_TOTALS)
+        recent = [dict(e) for e in RECENT]
+    out.update(read_seconds=sec("read"),
+               compute_seconds=sec("dispatch", "d2h_sync"),
+               write_seconds=sec("write", "pwritev"),
+               pool_wait_seconds=sec("pool_wait"),
+               dispatch_seconds=sec("dispatch"),
+               sync_seconds=sec("d2h_sync"),
+               h2d_submit_seconds=sec("h2d_submit"),
+               launch_seconds=sec("launch"),
+               rpc_seconds=sec(*(f"step_{n}"
+                                 for n in flight.HANDLER_STEPS)))
+    for name in flight.HANDLER_STEPS + flight.INNER_STEPS:
+        seconds, calls = spans.get(f"step_{name}", (0.0, 0))
+        out[f"step_{name}_seconds"] = seconds
+        out[f"step_{name}_calls"] = calls
+    out = {k: (round(v, 6) if isinstance(v, float) else v)
+           for k, v in out.items()}
+    out["recent"] = recent
     return out
 
 
@@ -311,6 +354,7 @@ def reset_telemetry() -> None:
         for k in _TOTALS:
             _TOTALS[k] = 0 if isinstance(_TOTALS[k], int) else 0.0
         RECENT.clear()
+    flight.reset_totals()
 
 
 #: stage name -> latency histogram + bytes counter in the tracing
@@ -493,6 +537,10 @@ def run_pipeline(batches: Iterable[tuple[Any, np.ndarray]],
             "the mesh path)")
     if grouping and controller is None and cfg.feedback:
         controller = GroupController(group)
+    # one id for every span of this run (with the Dapper trace id of
+    # the rpc on this thread, if any); the stage threads bind it too
+    run = flight.Run(st)
+    outer = flight.bind(run)
     t_wall = time.perf_counter()
     flight.record(flight.EV_RUN_START, arg=hash(kind) & 0x7FFFFFFF)
     try:
@@ -505,10 +553,11 @@ def run_pipeline(batches: Iterable[tuple[Any, np.ndarray]],
                                 group, recycle_fn, st, controller,
                                 prepare_fn,
                                 cfg.double_buffer and
-                                prepare_fn is not None)
+                                prepare_fn is not None, run)
     finally:
         st.wall_seconds = time.perf_counter() - t_wall
         flight.record(flight.EV_RUN_END)
+        flight.bind(outer)
         if publish:
             publish_stats(st, kind=kind)
         if flight.armed():
@@ -535,39 +584,26 @@ def _run_sync(batches, encode_fn, write_fn, recycle_fn,
     (prepare runs immediately before encode, so the split changes
     nothing here — that is what makes it the byte-identity oracle for
     the double-buffered path)."""
-    n = 0
     it = iter(batches)
     while True:
         seq = st.batches
-        flight.record(flight.EV_READ_START, batch=seq)
-        t0 = time.perf_counter()
         try:
-            item = next(it)
+            with flight.span("read", batch=seq) as sp:
+                meta, batch = next(it)
+                sp.nbytes = _batch_nbytes(batch)
         except StopIteration:
             break
-        t1 = time.perf_counter()
-        st.read_seconds += t1 - t0
-        meta, batch = item
-        flight.record(flight.EV_READ_END, batch=seq,
-                      arg=_batch_nbytes(batch))
-        flight.record(flight.EV_DISPATCH, batch=seq)
-        result = encode_fn(batch if prepare_fn is None
-                           else prepare_fn(batch))
-        t2 = time.perf_counter()
-        st.dispatch_seconds += t2 - t1
-        flight.record(flight.EV_DISPATCH_DONE, batch=seq, arg=1)
-        flight.record(flight.EV_SYNC_START, batch=seq)
-        result_np = np.asarray(result)
-        t3 = time.perf_counter()
-        st.sync_seconds += t3 - t2
-        flight.record(flight.EV_SYNC_END, batch=seq,
-                      arg=result_np.nbytes)
-        flight.record(flight.EV_WRITE_START, batch=seq)
-        write_fn(meta, batch, result_np)
-        if recycle_fn is not None:
-            recycle_fn(meta, batch)
-        st.write_seconds += time.perf_counter() - t3
-        flight.record(flight.EV_WRITE_END, batch=seq)
+        with flight.span("dispatch", batch=seq, leaf=False) as sp:
+            sp.arg = 1
+            result = encode_fn(batch if prepare_fn is None
+                               else prepare_fn(batch))
+        with flight.span("d2h_sync", batch=seq) as sp:
+            result_np = np.asarray(result)
+            sp.nbytes = result_np.nbytes
+        with flight.span("write", batch=seq):
+            write_fn(meta, batch, result_np)
+            if recycle_fn is not None:
+                recycle_fn(meta, batch)
         # PipeStats fields have exactly one writer per run (the
         # driving thread of THIS encode); the roles the analyzer
         # unions are alternative drivers, never concurrent on one
@@ -582,14 +618,14 @@ def _run_sync(batches, encode_fn, write_fn, recycle_fn,
         st.bytes_in += _batch_nbytes(batch)
         # seaweedlint: disable=SW801 — same single-driver contract
         st.bytes_out += result_np.nbytes
-    return n or st.batches
+    return st.batches
 
 
 def _run_overlapped(batches, encode_fn, write_fn, depth,
                     encode_multi_fn, group, recycle_fn,
                     st: PipeStats,
                     controller: Optional[GroupController],
-                    prepare_fn=None, lookahead: bool = False) -> int:
+                    prepare_fn, lookahead: bool, run: flight.Run) -> int:
     if encode_multi_fn is not None and group > 1:
         depth = max(depth, group)
     read_q: queue.Queue = queue.Queue(maxsize=depth)
@@ -604,24 +640,20 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
         # — independent counters per stage align per batch without
         # widening the queue tuples.
         seq = 0
+        flight.bind(run)
         try:
             it = iter(batches)
             while True:
-                flight.record(flight.EV_READ_START, batch=seq)
-                t0 = time.perf_counter()
                 try:
-                    item = next(it)
+                    with flight.span("read", batch=seq) as sp:
+                        item = next(it)
+                        sp.nbytes = _batch_nbytes(item[1])
                 except StopIteration:
                     return
-                dt = time.perf_counter() - t0
-                st.read_seconds += dt
-                flight.record(flight.EV_READ_END, batch=seq,
-                              arg=_batch_nbytes(item[1]))
                 seq += 1
-                _stage_observe("pipe.read", dt,
-                               _batch_nbytes(item[1]))
+                _stage_observe("pipe.read", sp.elapsed, sp.nbytes)
                 if controller is not None:
-                    controller.note_read(dt)
+                    controller.note_read(sp.elapsed)
                 if stop.is_set():
                     return
                 read_q.put(item)
@@ -634,6 +666,7 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
 
     def writer():
         seq = 0
+        flight.bind(run)
         try:
             while True:
                 item = write_q.get()
@@ -642,24 +675,17 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                 flight.record(flight.EV_QDEPTH,
                               value=float(write_q.qsize()), arg=1)
                 meta, batch, result, disp_share = item
-                flight.record(flight.EV_SYNC_START, batch=seq)
-                t0 = time.perf_counter()
-                result_np = np.asarray(result)
-                t1 = time.perf_counter()
-                st.sync_seconds += t1 - t0
-                flight.record(flight.EV_SYNC_END, batch=seq,
-                              arg=result_np.nbytes)
-                _stage_observe("pipe.compute", disp_share + (t1 - t0),
+                with flight.span("d2h_sync", batch=seq) as sp:
+                    result_np = np.asarray(result)
+                    sp.nbytes = result_np.nbytes
+                _stage_observe("pipe.compute", disp_share + sp.elapsed,
                                result_np.nbytes)
-                flight.record(flight.EV_WRITE_START, batch=seq)
-                write_fn(meta, batch, result_np)
-                if recycle_fn is not None:
-                    recycle_fn(meta, batch)
-                dt = time.perf_counter() - t1
-                st.write_seconds += dt
-                flight.record(flight.EV_WRITE_END, batch=seq)
+                with flight.span("write", batch=seq) as sp:
+                    write_fn(meta, batch, result_np)
+                    if recycle_fn is not None:
+                        recycle_fn(meta, batch)
                 seq += 1
-                _stage_observe("pipe.write", dt)
+                _stage_observe("pipe.write", sp.elapsed)
                 st.batches += 1
                 st.bytes_in += _batch_nbytes(batch)
                 st.bytes_out += result_np.nbytes
@@ -722,10 +748,18 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                 continue
             if encode_multi_fn is None:
                 meta, batch = item
-                t0 = time.perf_counter()
+                dt = 0.0
                 try:
-                    payload = batch if prepare_fn is None \
-                        else prepare_fn(batch)
+                    payload = batch
+                    if prepare_fn is not None:
+                        # the prepared batch is cseq, or the one after
+                        # it while cseq's own dispatch is still pending
+                        with flight.span(
+                                "dispatch", leaf=False,
+                                batch=cseq + (pending is not None)) as sp:
+                            sp.arg = 0
+                            payload = prepare_fn(batch)
+                        dt = sp.elapsed
                 except BaseException as e:  # noqa: BLE001 — _fail
                     drop = [(meta, batch)]
                     if pending is not None:
@@ -740,12 +774,13 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                     # mesh step overlaps this transfer
                     pending, prev = (meta, batch, payload), pending
                     if prev is None:
-                        st.dispatch_seconds += time.perf_counter() - t0
                         continue
                     meta, batch, payload = prev
-                flight.record(flight.EV_DISPATCH, batch=cseq)
                 try:
-                    result = encode_fn(payload)
+                    with flight.span("dispatch", batch=cseq,
+                                     leaf=False) as sp:
+                        sp.arg = 1
+                        result = encode_fn(payload)
                 except BaseException as e:  # noqa: BLE001 — see _fail
                     # compute failed: surface through the same
                     # PipelineError path as reader/writer failures
@@ -755,14 +790,10 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                         pending = None
                     _fail(e, drop)
                     break
-                dt = time.perf_counter() - t0
-                st.dispatch_seconds += dt
-                flight.record(flight.EV_DISPATCH_DONE, batch=cseq,
-                              arg=1)
                 cseq += 1
                 st.groups += 1
                 st.max_group = max(st.max_group, 1)
-                write_q.put((meta, batch, result, dt))
+                write_q.put((meta, batch, result, dt + sp.elapsed))
                 flight.record(flight.EV_QDEPTH,
                               value=float(write_q.qsize()), arg=1)
                 n += 1
@@ -795,10 +826,11 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                 items.append(nxt)
             if controller is not None and len(items) >= target:
                 controller.note_supplied()
-            t0 = time.perf_counter()
-            flight.record(flight.EV_DISPATCH, batch=cseq)
             try:
-                results = encode_multi_fn([b for _, b in items])
+                with flight.span("dispatch", batch=cseq,
+                                 leaf=False) as sp:
+                    sp.arg = len(items)
+                    results = encode_multi_fn([b for _, b in items])
             except BaseException as e:  # noqa: BLE001 — as single path
                 errors.append(e)
                 stop.set()
@@ -809,16 +841,12 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                         except BaseException:  # seaweedlint: disable=SW301 — best-effort recycle on shutdown; first error already recorded
                             pass
                 break
-            dt = time.perf_counter() - t0
-            st.dispatch_seconds += dt
-            flight.record(flight.EV_DISPATCH_DONE, batch=cseq,
-                          arg=len(items))
             cseq += len(items)
             st.groups += 1
             st.max_group = max(st.max_group, len(items))
             if controller is not None:
-                controller.note_dispatch(dt, len(items))
-            share = dt / len(items)
+                controller.note_dispatch(sp.elapsed, len(items))
+            share = sp.elapsed / len(items)
             for (meta, batch), result in zip(items, results):
                 write_q.put((meta, batch, result, share))
                 flight.record(flight.EV_QDEPTH,
@@ -836,21 +864,18 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                     except BaseException:  # seaweedlint: disable=SW301 — best-effort recycle on shutdown; first error already recorded
                         pass
             else:
-                t0 = time.perf_counter()
-                flight.record(flight.EV_DISPATCH, batch=cseq)
                 try:
-                    result = encode_fn(payload)
+                    with flight.span("dispatch", batch=cseq,
+                                     leaf=False) as sp:
+                        sp.arg = 1
+                        result = encode_fn(payload)
                 except BaseException as e:  # noqa: BLE001 — see _fail
                     _fail(e, [(meta, batch)])
                 else:
-                    dt = time.perf_counter() - t0
-                    st.dispatch_seconds += dt
-                    flight.record(flight.EV_DISPATCH_DONE,
-                                  batch=cseq, arg=1)
                     cseq += 1
                     st.groups += 1
                     st.max_group = max(st.max_group, 1)
-                    write_q.put((meta, batch, result, dt))
+                    write_q.put((meta, batch, result, sp.elapsed))
                     n += 1
     finally:
         write_q.put(_END)

@@ -227,7 +227,6 @@ class WriterPool:
     # -- worker ----------------------------------------------------------
 
     def _worker(self, q: queue.Queue) -> None:
-        import time
         bytes_acc, busy_acc = 0, 0.0
         try:
             while True:
@@ -243,18 +242,15 @@ class WriterPool:
                     if token is not None:
                         token.done_one()
                     continue
-                t0 = time.perf_counter()
                 try:
-                    bufcheck.verify_rows(tags, where="before pwritev")
-                    wrote = pwrite_rows(fd, offset, rows)
-                    # re-check AFTER the write: a recycle that raced
-                    # the pwritev corrupted the bytes already on disk
-                    bufcheck.verify_rows(tags, where="after pwritev")
-                    dt = time.perf_counter() - t0
-                    flight.record(flight.EV_PWRITEV_RETIRE, value=dt,
-                                  arg=wrote)
-                    bytes_acc += wrote
-                    busy_acc += dt
+                    with flight.span("pwritev") as sp:
+                        bufcheck.verify_rows(tags, where="before pwritev")
+                        sp.nbytes = pwrite_rows(fd, offset, rows)
+                        # re-check AFTER the write: a recycle that raced
+                        # the pwritev corrupted the bytes already on disk
+                        bufcheck.verify_rows(tags, where="after pwritev")
+                    bytes_acc += sp.nbytes
+                    busy_acc += sp.elapsed
                 except BaseException as e:  # noqa: BLE001 — re-raised at submit/close
                     # list.append is GIL-atomic and the list is only
                     # drained after the workers join

@@ -18,6 +18,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Optional
 
 from ..pb import master_pb2, volume_server_pb2
+from ..pipeline import flight
 from ..storage.ec_files import ShardBits
 from .commands import ShellError, _parser
 
@@ -422,7 +423,8 @@ def cmd_ec_encode(env: ClusterEnv, argv: list[str]) -> None:
         raise ShellError("ec.encode: -volumeId required "
                          "(or use -distributed)")
 
-    locs = env.volume_locations(vid)
+    with flight.span("step_locate", trace=True):
+        locs = env.volume_locations(vid)
     if not locs:
         raise ShellError(f"volume {vid} not found")
     source = locs[0]
@@ -439,7 +441,8 @@ def cmd_ec_encode(env: ClusterEnv, argv: list[str]) -> None:
     src.VolumeEcShardsMount(volume_server_pb2.VolumeEcShardsMountRequest(
         volume_id=vid, collection=col, shard_ids=list(range(total))))
 
-    targets = _spread_targets(env.collect_ec_nodes(), total)
+    with flight.span("step_spread_plan", trace=True):
+        targets = _spread_targets(env.collect_ec_nodes(), total)
     per_target: dict[str, list[int]] = {}
     for sid, node in enumerate(targets):
         per_target.setdefault(node.url, []).append(sid)
@@ -475,7 +478,8 @@ def cmd_ec_rebuild(env: ClusterEnv, argv: list[str]) -> None:
     p.add_argument("-volumeId", type=int, default=0)
     p.add_argument("-collection", default="")
     args = p.parse_args(argv)
-    nodes = env.collect_ec_nodes()
+    with flight.span("step_locate", trace=True):
+        nodes = env.collect_ec_nodes()
     # vid -> {shard ids present anywhere}; collection comes from the
     # heartbeat-reported shard info, NOT from the flag, so the RPC always
     # names the volume's real collection.
@@ -2374,15 +2378,20 @@ def run_cluster_command(env: ClusterEnv, line: str) -> None:
     fn = CLUSTER_COMMANDS.get(name)
     if fn is None:
         raise ShellError(f"unknown command {name!r} (try 'help')")
+    from ..util import tracing
     try:
-        if name in DESTRUCTIVE_COMMANDS:
-            # mutating choreography runs under the master's exclusive
-            # admin lease: held REPL locks pass through, one-shots
-            # acquire/release around this single command
-            with env.exclusive():
+        # one trace per command, as the store-mode shell opens: the
+        # channels carry it, so every rpc the command makes continues
+        # it under its grpc.<Method> span on the server
+        with tracing.start_trace(f"shell.{name}"):
+            if name in DESTRUCTIVE_COMMANDS:
+                # mutating choreography runs under the master's
+                # exclusive admin lease: held REPL locks pass through,
+                # one-shots acquire/release around this single command
+                with env.exclusive():
+                    fn(env, argv)
+            else:
                 fn(env, argv)
-        else:
-            fn(env, argv)
     except ShellError:
         raise
     except (argparse.ArgumentError, SystemExit) as e:

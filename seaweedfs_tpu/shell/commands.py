@@ -646,9 +646,8 @@ def cmd_pipeline_dump(env: CommandEnv, argv: list[str]) -> None:
 
 @command("pipeline.analyze")
 def cmd_pipeline_analyze(env: CommandEnv, argv: list[str]) -> None:
-    """Name the recorded window's bottleneck stage and recommend
-    [pipeline] knob changes, with the occupancy evidence printed
-    alongside (docs/pipeline.md)."""
+    """Name the recorded window's busiest lane, with the occupancy
+    evidence printed alongside (docs/pipeline.md)."""
     p = _parser("pipeline.analyze")
     p.add_argument("-all", action="store_true",
                    help="analyze the whole ring, not just the last run")
@@ -680,9 +679,6 @@ def cmd_pipeline_analyze(env: CommandEnv, argv: list[str]) -> None:
                                           key=lambda kv: -kv[1]))
         env.println(f"  per-batch critical path (batches that waited "
                     f"longest on each stage): {waits}")
-    env.println("  recommendations:")
-    for rec in ana["recommendations"]:
-        env.println(f"   - {rec}")
 
 
 @command("trace.status")

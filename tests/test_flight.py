@@ -42,11 +42,12 @@ def _ev(ts_ms, event, batch=-1, tid=1, value=0.0, arg=0):
 
 
 def synthetic_two_stage(n_batches=4, read_ms=1.0, dispatch_ms=20.0,
-                        write_ms=1.0):
+                        write_ms=1.0, h2d_ms=0.0, launch_ms=0.0):
     """A serialized two-stage pipeline with a bubble of known shape:
     each batch is read fast, then sits in a LONG dispatch, then is
-    written fast — by construction the dispatch/h2d lane dominates the
-    window, so analyze() must name it."""
+    written fast — by construction the dispatch lane dominates the
+    window, so analyze() must name it. ``h2d_ms`` / ``launch_ms`` of
+    each dispatch are an H2D submit and a launch nested inside it."""
     evs = [_ev(0.0, flight.EV_RUN_START)]
     t = 1.0
     for b in range(n_batches):
@@ -55,6 +56,13 @@ def synthetic_two_stage(n_batches=4, read_ms=1.0, dispatch_ms=20.0,
         evs.append(_ev(t, flight.EV_READ_END, batch=b, tid=1,
                        arg=1 << 20))
         evs.append(_ev(t, flight.EV_DISPATCH, batch=b, tid=2))
+        if h2d_ms:
+            evs.append(_ev(t, flight.EV_H2D_SUBMIT, tid=2))
+            evs.append(_ev(t + h2d_ms, flight.EV_H2D_READY, tid=2))
+        if launch_ms:
+            evs.append(_ev(t + h2d_ms, flight.EV_LAUNCH, tid=2))
+            evs.append(_ev(t + h2d_ms + launch_ms,
+                           flight.EV_LAUNCH_DONE, tid=2))
         t += dispatch_ms
         evs.append(_ev(t, flight.EV_DISPATCH_DONE, batch=b, tid=2,
                        arg=1))
@@ -215,23 +223,38 @@ class TestChromeTrace:
 class TestAnalyzer:
     def test_synthetic_bubble_named_dispatch(self):
         """The constructed stream spends ~20ms/batch in dispatch vs
-        ~1ms in read and write — the analyzer must name dispatch/h2d
-        and attribute every batch's critical path to it."""
+        ~1ms in read and write — the analyzer must name dispatch and
+        attribute every batch's critical path to it."""
         ana = flight.analyze(synthetic_two_stage())
-        assert ana["bottleneck"] == "dispatch/h2d"
-        assert "dispatch/h2d" in ana["verdict"]
-        assert ana["waited_on_top"] == "dispatch/h2d"
+        assert ana["bottleneck"] == "dispatch"
+        assert "dispatch" in ana["verdict"]
+        assert ana["waited_on_top"] == "dispatch"
         occ = ana["occupancy"]
         assert occ["batches"] == 4
         assert occ["busy_fraction"]["dispatch"] > \
             occ["busy_fraction"]["read"]
-        assert ana["recommendations"]
+        # H2D submit, launch and the D2H wait are lanes of their own
+        assert set(ana["lane_fraction"]) == {
+            "read", "pool_wait", "dispatch", "h2d_submit", "launch",
+            "d2h", "write"}
+        assert "recommendations" not in ana
 
-    def test_synthetic_bubble_named_write(self):
-        ana = flight.analyze(synthetic_two_stage(
-            dispatch_ms=0.5, write_ms=30.0))
-        assert ana["bottleneck"] == "write"
-        assert any("[pipeline]" in r for r in ana["recommendations"])
+    @pytest.mark.parametrize("kw, lane", [
+        (dict(dispatch_ms=0.5, write_ms=30.0), "write"),
+        (dict(h2d_ms=15.0, launch_ms=3.0), "h2d_submit"),
+        (dict(h2d_ms=3.0, launch_ms=15.0), "launch"),
+    ])
+    def test_synthetic_bubble_named_by_its_lane(self, kw, lane):
+        """A dispatch that is mostly H2D submit (or mostly launch) is
+        named so: the two are carved out of the dispatch lane."""
+        ana = flight.analyze(synthetic_two_stage(**kw))
+        assert ana["bottleneck"] == lane
+        lanes = ana["lane_fraction"]
+        busy = ana["occupancy"]["busy_seconds"]
+        assert lanes[lane] == max(lanes.values())
+        if lane != "write":
+            assert busy["dispatch"] == pytest.approx(
+                4 * (20.0 - 18.0) * 1e-3, rel=1e-3)
 
     def test_pool_wait_carved_out_of_read(self):
         """A read span that spends most of its time blocked on
@@ -363,7 +386,10 @@ class TestCommands:
         COMMANDS["pipeline.analyze"](env2, [])
         text = env2.out.getvalue()
         assert "bottleneck:" in text
-        assert "[pipeline]" in text  # knob recommendations printed
+        # every lane is printed with its share; no knob advice
+        for lane in ("h2d_submit", "launch", "d2h", "dispatch"):
+            assert f"  {lane}: busy=" in text
+        assert "[pipeline]" not in text
 
     def test_status_mentions_flight_state(self, tmp_path):
         env = _shell_env(tmp_path)

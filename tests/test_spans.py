@@ -1,0 +1,525 @@
+"""The one span primitive (pipeline/flight.py ``span``) and what it feeds.
+
+Totals always; the ring's events as the hand-written sites wrote them
+when it is armed; ``jax.profiler`` annotations on the stage threads while
+a session is active, leaves only; ``/debug/vars`` ``pipeline`` with every
+stage and rpc-step total of a live mini-cluster; the step spans as
+children of their ``grpc.<Method>`` spans under the shell's trace id.
+"""
+
+from __future__ import annotations
+
+import io
+import json
+import threading
+import time
+import urllib.request
+
+import numpy as np
+import pytest
+
+from seaweedfs_tpu.ops import rs_jax, rs_pallas
+from seaweedfs_tpu.pipeline import flight, pipe
+from seaweedfs_tpu.util import tracing
+
+WATCHDOG = 120.0
+
+
+def run_guarded(fn):
+    """``fn`` on a thread with a join timeout: a hung profiler, server or
+    pipeline fails the test instead of holding the suite."""
+    box: dict = {}
+
+    def target():
+        try:
+            box["value"] = fn()
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            box["error"] = e
+
+    t = threading.Thread(target=target, daemon=True)
+    t.start()
+    t.join(WATCHDOG)
+    assert not t.is_alive(), "hung (watchdog expired)"
+    if "error" in box:
+        raise box["error"]
+    return box["value"]
+
+
+@pytest.fixture(autouse=True)
+def disarmed():
+    flight.disarm()
+    flight.reset()
+    yield
+    flight.disarm()
+    flight.reset()
+
+
+def delta(before: dict, after: dict, name: str) -> tuple[float, int]:
+    s0, c0 = before.get(name, (0.0, 0))
+    s1, c1 = after.get(name, (0.0, 0))
+    return s1 - s0, c1 - c0
+
+
+# --------------------------------------------------------------------------
+# totals: always on
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("leaf", [True, False])
+def test_totals_add_with_the_ring_disarmed_and_no_profiler(leaf):
+    assert not flight.armed() and flight._profiling() is None
+    name = f"t_plain_{leaf}"
+    before = flight.totals()
+    for _ in range(3):
+        with flight.span(name, leaf=leaf) as sp:
+            time.sleep(0.002)
+        assert sp.elapsed >= 0.002 and sp.seconds == sp.elapsed
+    seconds, calls = delta(before, flight.totals(), name)
+    assert calls == 3 and 0.006 <= seconds < 1.0
+    assert flight.recorder() is None
+
+
+def test_a_span_that_raises_still_counts_and_passes_the_error_on():
+    before = flight.totals()
+    with pytest.raises(KeyError):
+        with flight.span("t_raises"):
+            raise KeyError("x")
+    assert delta(before, flight.totals(), "t_raises")[1] == 1
+
+
+@pytest.mark.parametrize("outer_leaf, carved", [(True, True),
+                                                (False, False)])
+def test_a_nested_leaf_is_carved_out_of_a_leaf_only(outer_leaf, carved):
+    """``read`` holds ``pool_wait`` and loses its time to it; ``dispatch``
+    (not a leaf) holds ``h2d_submit`` + ``launch`` and keeps the whole."""
+    before = flight.totals()
+    with flight.span("t_outer", leaf=outer_leaf) as outer:
+        time.sleep(0.002)
+        with flight.span("t_inner") as inner:
+            time.sleep(0.01)
+    after = flight.totals()
+    assert delta(before, after, "t_inner")[0] == \
+        pytest.approx(inner.elapsed)
+    want = outer.elapsed - inner.elapsed if carved else outer.elapsed
+    assert outer.seconds == pytest.approx(want)
+    assert delta(before, after, "t_outer")[0] == pytest.approx(want)
+
+
+def test_pool_wait_is_carved_out_of_read_in_the_totals():
+    """A reader that blocks on the pool for most of a batch's read: the
+    run's ``read_seconds`` loses that time to ``pool_wait_seconds``, in
+    the per-run stats and in the process totals alike."""
+    pool = pipe.HostBufferPool(4096, 1)
+    held = []
+
+    def batches():
+        for i in range(3):
+            buf = pool.acquire()       # blocks until the write recycles
+            held.append(buf)
+            yield i, buf[:1024]
+
+    def write(meta, batch, result):
+        time.sleep(0.02)               # the reader waits this long
+
+    st = pipe.PipeStats()
+    before = flight.totals()
+    run_guarded(lambda: pipe.run_pipeline(
+        batches(), lambda b: b.copy(), write,
+        recycle_fn=lambda meta, batch: pool.release(held[meta]),
+        stats=st, publish=False))
+    after = flight.totals()
+    assert st.batches == 3
+    assert st.pool_wait_seconds >= 0.03      # two waits of ~0.02 s
+    assert st.read_seconds < st.pool_wait_seconds
+    assert delta(before, after, "pool_wait") == \
+        (pytest.approx(st.pool_wait_seconds), 3)
+    assert delta(before, after, "read")[0] == \
+        pytest.approx(st.read_seconds, abs=1e-4)
+    # what the old read stage measured is the two together
+    assert st.read_seconds + st.pool_wait_seconds < st.wall_seconds + 0.05
+
+
+@pytest.mark.parametrize("overlapped", [True, False])
+def test_run_stats_are_fed_by_the_spans(overlapped):
+    st = pipe.PipeStats()
+    before = flight.totals()
+    run_guarded(lambda: pipe.run_pipeline(
+        ((i, np.zeros(2048, dtype=np.uint8)) for i in range(4)),
+        lambda b: b * 2, lambda meta, b, r: time.sleep(0.001),
+        stats=st, overlapped=overlapped, publish=False))
+    after = flight.totals()
+    for span, field in (("read", "read_seconds"),
+                        ("dispatch", "dispatch_seconds"),
+                        ("d2h_sync", "sync_seconds"),
+                        ("write", "write_seconds")):
+        seconds, calls = delta(before, after, span)
+        assert getattr(st, field) == pytest.approx(seconds, abs=1e-6)
+        assert calls == 4 + (span == "read")   # the reader's last next()
+    assert st.write_seconds >= 0.004
+    assert st.compute_seconds == st.dispatch_seconds + st.sync_seconds
+    assert set(st.to_dict()) >= {"pool_wait_seconds", "dispatch_seconds",
+                                 "sync_seconds", "h2d_submit_seconds",
+                                 "launch_seconds"}
+
+
+# --------------------------------------------------------------------------
+# the ring, armed: the events the hand-written sites wrote
+# --------------------------------------------------------------------------
+
+def test_ring_events_of_a_run_are_those_of_the_hand_written_sites():
+    """The synchronous path is one thread, so the ring's order is fixed:
+    per batch READ / DISPATCH / SYNC / WRITE pairs with the batch id, the
+    bytes on READ_END and SYNC_END, the group width on DISPATCH_DONE, and
+    the reader's last READ_START left unpaired."""
+    rec = flight.arm(capacity=1024)
+    flight.reset()
+    pipe.run_pipeline(
+        ((i, np.zeros(512, dtype=np.uint8)) for i in range(2)),
+        lambda b: b.astype(np.uint16), lambda meta, b, r: None,
+        overlapped=False, publish=False, kind="ring")
+    got = [(ev[1], ev[2], ev[5]) for ev in rec.snapshot()]
+    want = [(flight.EV_RUN_START, -1, hash("ring") & 0x7FFFFFFF)]
+    for b in range(2):
+        want += [(flight.EV_READ_START, b, 0), (flight.EV_READ_END, b, 512),
+                 (flight.EV_DISPATCH, b, 0),
+                 (flight.EV_DISPATCH_DONE, b, 1),
+                 (flight.EV_SYNC_START, b, 0),
+                 (flight.EV_SYNC_END, b, 1024),
+                 (flight.EV_WRITE_START, b, 0), (flight.EV_WRITE_END, b, 0)]
+    want += [(flight.EV_READ_START, 2, 0), (flight.EV_RUN_END, -1, 0)]
+    assert got == want
+
+
+def test_ring_events_of_the_pool_and_the_writeback(tmp_path):
+    from seaweedfs_tpu.pipeline import writeback
+    rec = flight.arm(capacity=1024)
+    flight.reset()
+    pool = pipe.HostBufferPool(4096, 2)
+    buf = pool.acquire()
+    pool.release(buf)
+    wp = writeback.WriterPool(threads=1)
+    path = str(tmp_path / "f")
+    wp.open_file(path, 4096)
+    wp.submit(path, 0, [np.ones(4096, dtype=np.uint8)])
+    wp.close()
+    evs = rec.snapshot()
+    assert [(e[1], e[2], e[4]) for e in evs[:5]] == [
+        (flight.EV_POOL_WAIT, -1, 0.0), (flight.EV_POOL_GOT, -1, 1.0),
+        (flight.EV_POOL_OCC, -1, 1.0), (flight.EV_RECYCLE, -1, 0.0),
+        (flight.EV_POOL_OCC, -1, 0.0)]
+    assert [e[1] for e in evs[5:]] == [flight.EV_WRITE_SUBMIT,
+                                       flight.EV_PWRITEV_RETIRE]
+    retire = evs[-1]
+    # a retire record carries its own duration and the bytes written
+    assert retire[5] == 4096 and 0 < retire[4] == pytest.approx(
+        wp.busy_seconds)
+
+
+# --------------------------------------------------------------------------
+# the profiler's plane: the stage spans on the clock of the device's ops
+# --------------------------------------------------------------------------
+
+@pytest.fixture()
+def one_chip_device_leg(monkeypatch):
+    """Steer ``write_ec_files`` down the one-chip word-form path on the
+    CPU: the Pallas words kernel under the interpreter, no mesh."""
+    from seaweedfs_tpu.parallel import mesh as mesh_mod
+    monkeypatch.setattr(rs_jax, "_use_pallas", lambda: True)
+    monkeypatch.setattr(rs_jax, "PALLAS_MIN_S", 1024)
+    monkeypatch.setattr(rs_jax, "HOST_DISPATCH", "device")
+    monkeypatch.setattr(rs_jax, "PALLAS_KERNEL", "transpose")
+    monkeypatch.setattr(mesh_mod, "routing_mesh", lambda: None)
+    real = rs_pallas.apply_gf_matrix_words
+    monkeypatch.setattr(rs_pallas, "apply_gf_matrix_words",
+                        lambda c, x, **kw: real(c, x, interpret=True))
+    rs_jax._jitted_apply.cache_clear()
+    yield
+    rs_jax._jitted_apply.cache_clear()
+
+
+def host_events(trace_dir) -> list[dict]:
+    """Every event of the trace's host planes: name, thread line, start
+    and end in ns, and its arguments."""
+    from jax.profiler import ProfileData
+    (path,) = sorted(trace_dir.rglob("*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            # every Python thread's line is called "python": tell them
+            # apart by their place in the plane
+            for ev in line.events:
+                out.append({"name": ev.name, "line": (plane.name, i),
+                            "start": int(ev.start_ns),
+                            "end": int(ev.start_ns + ev.duration_ns),
+                            "args": dict(ev.stats)})
+    return out
+
+
+@pytest.mark.filterwarnings("ignore::DeprecationWarning")
+def test_a_profiler_session_holds_the_stage_spans_as_leaves(
+        tmp_path, one_chip_device_leg):
+    import jax
+    from seaweedfs_tpu.pipeline import encode as encode_mod
+    from seaweedfs_tpu.pipeline.scheme import EcScheme
+    from seaweedfs_tpu.storage import superblock, volume
+
+    seg = rs_pallas.SEG_BYTES
+    scheme = EcScheme(4, 2, large_block_size=seg, small_block_size=seg)
+    base = tmp_path / "7"
+    rng = np.random.default_rng(5)
+    with open(volume.dat_path(base), "wb") as f:
+        f.write(superblock.SuperBlock().to_bytes())
+        f.write(rng.integers(0, 256, 2 * 4 * seg - 4096,
+                             dtype=np.uint8).tobytes())
+    # once outside the session: compiling is not what the trace is for
+    run_guarded(lambda: encode_mod.write_ec_files(
+        base, scheme, max_batch_bytes=4 * seg))
+
+    def traced():
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0      # as benchmark/chip_server.py
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path / "trace"),
+                                 profiler_options=options)
+        try:
+            with tracing.start_trace("test.rpc") as root:
+                encode_mod.write_ec_files(base, scheme,
+                                          max_batch_bytes=4 * seg)
+                with flight.span("step_outer", leaf=False, trace=True):
+                    with flight.span("step_inner", trace=True):
+                        time.sleep(0.001)
+            return root.trace_id
+        finally:
+            jax.profiler.stop_trace()
+
+    trace_id = run_guarded(traced)
+    assert flight._profiling() is None       # the session is over
+    events = host_events(tmp_path / "trace")
+    ours = [e for e in events if e["name"] in (
+        "read", "pool_wait", "h2d_submit", "launch", "dispatch",
+        "d2h_sync", "write", "pwritev", "step_outer", "step_inner")]
+    by_name: dict = {}
+    for e in ours:
+        by_name.setdefault(e["name"], []).append(e)
+    # two batches of one row each went down the device leg
+    for name in ("read", "h2d_submit", "launch", "d2h_sync", "write"):
+        assert len(by_name[name]) >= 2, (name, sorted(by_name))
+        for e in by_name[name]:
+            assert set(e["args"]) >= {"batch", "bytes", "run"}, e
+    assert {e["args"]["batch"] for e in by_name["launch"]} == {0, 1}
+    assert {e["args"]["bytes"] for e in by_name["h2d_submit"]} == {4 * seg}
+    assert {e["args"]["bytes"] for e in by_name["d2h_sync"]} == {2 * seg}
+    # one id for the stage threads of the run; the writeback pool's
+    # workers and the rpc steps belong to no run
+    (run_id,) = {e["args"]["run"] for e in ours
+                 if e["name"] != "pwritev"
+                 and not e["name"].startswith("step_")}
+    assert run_id > 0
+    assert {e["args"]["run"] for e in by_name["pwritev"]
+            + by_name["step_inner"]} == {0}
+    # the run's first span carries the trace id of the rpc that began it
+    first = min((e for e in ours if e["args"].get("run") == run_id),
+                key=lambda e: e["start"])
+    assert first["args"]["trace_id"] == trace_id
+    assert by_name["step_inner"][0]["args"]["trace_id"] == trace_id
+    # leaves only: no enclosing span of the program's on this plane ...
+    assert "dispatch" not in by_name and "step_outer" not in by_name
+    assert not [e for e in events if e["name"] in ("ec.encode", "test.rpc")]
+    # ... and none of its spans holds another of its spans on one thread
+    for a in ours:
+        for b in ours:
+            if a is not b and a["line"] == b["line"]:
+                assert not (a["start"] <= b["start"] and b["end"] <= a["end"]
+                            and (a["start"], a["end"]) !=
+                            (b["start"], b["end"])), (a, b)
+
+
+# --------------------------------------------------------------------------
+# a live mini-cluster: /debug/vars and the Dapper tree
+# --------------------------------------------------------------------------
+
+PIPELINE_KEYS = ["pool_wait_seconds", "dispatch_seconds", "sync_seconds",
+                 "h2d_submit_seconds", "launch_seconds", "rpc_seconds",
+                 "read_seconds", "compute_seconds", "write_seconds",
+                 "wall_seconds"] + [
+    f"step_{name}_{what}" for name in flight.HANDLER_STEPS
+    + flight.INNER_STEPS for what in ("seconds", "calls")]
+
+
+@pytest.fixture(scope="module")
+def encoded_and_rebuilt(tmp_path_factory):
+    """One master and one volume server in this process; one ``ec.encode``
+    and, after one shard is removed by rpc, one ``ec.rebuild``, with
+    ``/debug/vars`` ``pipeline`` fetched before, between and after."""
+    from seaweedfs_tpu import pb
+    from seaweedfs_tpu.cluster import operation
+    from seaweedfs_tpu.cluster.master import MasterServer
+    from seaweedfs_tpu.cluster.volume_server import VolumeServer
+    from seaweedfs_tpu.cluster.wdclient import MasterClient
+    from seaweedfs_tpu.pb import volume_server_pb2 as vpb
+    from seaweedfs_tpu.shell.cluster_commands import (
+        ClusterEnv, run_cluster_command)
+    from seaweedfs_tpu.storage.store import Store
+    from test_cluster_integration import _free_port_pair
+
+    def drive() -> dict:
+        tmp = tmp_path_factory.mktemp("spans")
+        # a pulse far longer than the test: every heartbeat is one that
+        # a handler asked for
+        master = MasterServer(port=_free_port_pair(),
+                              volume_size_limit_mb=64, pulse_seconds=60,
+                              seed=1).start()
+        vs = VolumeServer(Store([tmp], max_volumes=8),
+                          port=_free_port_pair(), master_url=master.url,
+                          pulse_seconds=60).start()
+        try:
+            deadline = time.time() + 10
+            while time.time() < deadline and not master.topology.nodes:
+                time.sleep(0.05)
+            mc = MasterClient(master.url)
+            rng = np.random.default_rng(3)
+            fids = operation.submit(mc, [
+                rng.integers(0, 256, 150_000, dtype=np.uint8).tobytes()
+                for _ in range(12)])
+            mc.close()
+            vid = int(fids[0].split(",")[0])
+            out = io.StringIO()
+            env = ClusterEnv(master_url=master.url, out=out)
+
+            def pipeline_vars() -> dict:
+                with urllib.request.urlopen(
+                        f"http://{vs.url}/debug/vars", timeout=30) as r:
+                    return json.load(r)["pipeline"]
+
+            snaps = [pipeline_vars()]
+            clocks = []
+            t0 = time.perf_counter()
+            run_cluster_command(env, f"ec.encode -volumeId {vid}")
+            clocks.append(time.perf_counter() - t0)
+            snaps.append(pipeline_vars())
+            with pb_channel(vs.url) as stub:
+                stub.VolumeEcShardsUnmount(vpb.VolumeEcShardsUnmountRequest(
+                    volume_id=vid, shard_ids=[3]))
+                stub.VolumeEcShardsDelete(vpb.VolumeEcShardsDeleteRequest(
+                    volume_id=vid, shard_ids=[3]))
+            snaps.append(pipeline_vars())
+            t0 = time.perf_counter()
+            run_cluster_command(env, f"ec.rebuild -volumeId {vid}")
+            clocks.append(time.perf_counter() - t0)
+            snaps.append(pipeline_vars())
+            assert "rebuilt [3]" in out.getvalue(), out.getvalue()
+            encode_trace = next(
+                t for t in reversed(tracing.recent_traces())
+                if t["name"] == "shell.ec.encode")
+            dump = io.StringIO()
+            env.out = dump
+            run_cluster_command(
+                env, f"trace.dump -traceId {encode_trace['trace_id']}")
+            env.close()
+            return {"snaps": snaps, "clocks": clocks,
+                    "trace_id": encode_trace["trace_id"],
+                    "dump": dump.getvalue()}
+        finally:
+            vs.stop()
+            master.stop()
+
+    import contextlib
+
+    @contextlib.contextmanager
+    def pb_channel(url: str):
+        import grpc
+        host, port = url.rsplit(":", 1)
+        with grpc.insecure_channel(f"{host}:{int(port) + 10000}") as ch:
+            yield pb.volume_stub(ch)
+
+    return run_guarded(drive)
+
+
+@pytest.mark.parametrize("key", PIPELINE_KEYS)
+def test_debug_vars_pipeline_holds_every_key_as_a_number(
+        encoded_and_rebuilt, key):
+    for snap in encoded_and_rebuilt["snaps"]:
+        assert isinstance(snap[key], (int, float)), (key, snap.get(key))
+        assert not isinstance(snap[key], bool)
+
+
+#: step -> calls made by (ec.encode, the two shard-removal rpcs, ec.rebuild)
+CALLS = {
+    "mark_readonly": (1, 0, 0), "generate": (1, 0, 0), "mount": (1, 0, 0),
+    "delete_source": (1, 0, 0), "shards_delete": (0, 1, 0),
+    "rebuild": (0, 0, 1), "vol_sync": (1, 0, 0), "shard_files": (1, 0, 0),
+    "ecx": (1, 0, 0), "vif": (1, 0, 0), "rebuild_fetch": (0, 0, 1),
+    "store_mount": (1, 0, 1), "store_delete": (1, 0, 0),
+    # mount + delete-source; unmount + shards-delete; rebuild
+    "heartbeat": (2, 2, 1), "master_heartbeat": (2, 2, 1),
+    # LookupVolume + VolumeList; none; VolumeList + LookupEcVolume
+    # (+ the VolumeList of trace.dump's host list, after the last read)
+    "master_lookup": (2, 0, 2),
+}
+
+
+@pytest.mark.parametrize("step", sorted(CALLS))
+def test_step_calls_equal_the_calls_made(encoded_and_rebuilt, step):
+    snaps = encoded_and_rebuilt["snaps"]
+    got = tuple(b[f"step_{step}_calls"] - a[f"step_{step}_calls"]
+                for a, b in zip(snaps, snaps[1:]))
+    assert got == CALLS[step]
+    seconds = snaps[-1][f"step_{step}_seconds"] - \
+        snaps[0][f"step_{step}_seconds"]
+    assert seconds > 0
+
+
+@pytest.mark.parametrize("command, a, b", [("ec.encode", 0, 1),
+                                           ("ec.rebuild", 2, 3)])
+def test_rpc_seconds_lie_between_the_pipeline_and_the_client(
+        encoded_and_rebuilt, command, a, b):
+    snaps = encoded_and_rebuilt["snaps"]
+    client = encoded_and_rebuilt["clocks"][a // 2]
+    rpc = snaps[b]["rpc_seconds"] - snaps[a]["rpc_seconds"]
+    wall = snaps[b]["wall_seconds"] - snaps[a]["wall_seconds"]
+    assert 0 < wall <= rpc + 1e-5 and rpc <= client
+    # each handler once: the sum of the six is the total
+    assert rpc == pytest.approx(sum(
+        snaps[b][f"step_{n}_seconds"] - snaps[a][f"step_{n}_seconds"]
+        for n in flight.HANDLER_STEPS), abs=1e-4)
+    # compute stays dispatch + sync: accepted metrics read it
+    for snap in (snaps[a], snaps[b]):
+        assert snap["compute_seconds"] == pytest.approx(
+            snap["dispatch_seconds"] + snap["sync_seconds"], abs=2e-6)
+
+
+def test_trace_dump_shows_the_steps_under_their_grpc_spans(
+        encoded_and_rebuilt):
+    """One trace id from the shell through every rpc: ``trace.dump``'s
+    tree has each handler's step under its ``grpc.<Method>`` span and the
+    inner steps under the handler's."""
+    text = encoded_and_rebuilt["dump"]
+    header, *lines = text.strip().splitlines()
+    assert header.startswith(
+        f"trace {encoded_and_rebuilt['trace_id']} shell.ec.encode")
+    parents: dict = {}
+    stack: list = []
+    for ln in lines:
+        depth = (len(ln) - len(ln.lstrip())) // 2
+        name = ln.split()[0]
+        del stack[depth - 1:]
+        parents.setdefault(name, set()).add(stack[-1] if stack else None)
+        stack.append(name)
+    want = {
+        "step_locate": "shell.ec.encode",
+        "step_spread_plan": "shell.ec.encode",
+        "step_mark_readonly": "grpc.VolumeMarkReadonly",
+        "step_generate": "grpc.VolumeEcShardsGenerate",
+        "step_mount": "grpc.VolumeEcShardsMount",
+        "step_delete_source": "grpc.VolumeDelete",
+        "step_vol_sync": "step_generate", "ec.encode": "step_generate",
+        "step_shard_files": "ec.encode", "step_ecx": "step_generate",
+        "step_vif": "step_generate", "step_store_mount": "step_mount",
+        "step_store_delete": "step_delete_source",
+        "step_heartbeat": {"step_mount", "step_delete_source"},
+        "step_master_lookup": {"grpc.LookupVolume", "grpc.VolumeList"},
+    }
+    for name, parent in want.items():
+        assert parents.get(name) == (
+            parent if isinstance(parent, set) else {parent}), (name, text)
