@@ -277,12 +277,11 @@ class VolumeServer:
         raise last
 
     def _heartbeat_snapshot(self) -> master_pb2.Heartbeat:
-        # disk-reality self-heal belongs to the heartbeat path, not to
-        # read-only status() callers like volume.list
-        try:
-            self.store.reconcile_ec_shards()
-        except Exception as e:  # noqa: BLE001 — never kill a heartbeat
-            glog.warning("ec reconcile failed: %s", e)
+        # The store's registry as a full Heartbeat: what every mount,
+        # unmount and delete has already written under the store's
+        # lock. No directory is listed here (status() stats each plain
+        # volume's .dat, nothing else touches the disk); aligning the
+        # registry with the disk is _pulse_snapshot()'s job.
         st = self.store.status()
         hb = master_pb2.Heartbeat(
             ip=self.ip, port=self.port, public_url=self.public_url,
@@ -339,12 +338,24 @@ class VolumeServer:
                     self._rotate_master()
             self._stop.wait(self.pulse_seconds)
 
+    def _pulse_snapshot(self) -> master_pb2.Heartbeat:
+        """What one pulse sends: the disk self-heal (one directory scan
+        per location, ``step_reconcile``), then the registry snapshot.
+        Only the pulse loop pays for the scan; ``heartbeat_now()``
+        sends the registry as the handlers left it."""
+        with flight_mod.span("step_reconcile", trace=True):
+            try:
+                self.store.reconcile_ec_shards()
+            except Exception as e:  # noqa: BLE001 — never kill a heartbeat
+                glog.warning("ec reconcile failed: %s", e)
+        return self._heartbeat_snapshot()
+
     def _run_heartbeat_stream(self) -> None:
         stub = self.master_stub()
 
         def gen():
             while not self._stop.is_set():
-                yield self._heartbeat_snapshot()
+                yield self._pulse_snapshot()
                 self._stop.wait(self.pulse_seconds)
 
         for resp in stub.SendHeartbeat(gen()):
@@ -412,7 +423,11 @@ class VolumeServer:
             stale.stop()
 
     def heartbeat_now(self) -> None:
-        """One immediate snapshot push (tests / post-admin-op nudge)."""
+        """Post-admin-op nudge: the registry snapshot, pushed
+        synchronously — when this returns the master has ingested it,
+        so a handler's return implies the master's view. No disk
+        access beyond ``status()``: a shard file that vanished under
+        the server leaves the master's view at the next pulse."""
         if not self.master_url:
             return
         with flight_mod.span("step_heartbeat", trace=True):

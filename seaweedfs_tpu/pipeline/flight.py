@@ -303,7 +303,7 @@ _RING_CODES = {
 HANDLER_STEPS = ("mark_readonly", "generate", "mount", "delete_source",
                  "shards_delete", "rebuild")
 INNER_STEPS = ("vol_sync", "shard_files", "ecx", "vif", "rebuild_fetch",
-               "store_mount", "store_delete", "heartbeat",
+               "store_mount", "store_delete", "heartbeat", "reconcile",
                "master_heartbeat", "master_lookup")
 
 #: span name -> [seconds, calls], cumulative since process start

@@ -497,9 +497,11 @@ class Store:
         topology, ec.rebuild's missing-shard view, and peers' read
         routing stay truthful instead of trusting a stale mount table.
 
-        Called from the heartbeat loop only (never from read-only
-        snapshots like volume.list): one directory scan per location
-        per pulse, shards counted present if ANY location holds them
+        Called from the heartbeat loop only (the volume server's
+        ``_pulse_snapshot``; never from the post-rpc nudge
+        ``heartbeat_now()`` nor from read-only snapshots like
+        volume.list): one directory scan per location per pulse,
+        shards counted present if ANY location holds them
         (ec.balance moves shards between locations without updating
         the mount base). Defensive pops: admin RPC threads mutate the
         mount table concurrently."""

@@ -600,7 +600,10 @@ def test_heartbeat_self_heals_vanished_shard_file(cluster):
                  if v == vid)
         lost = sorted(m.shard_ids)[0]
         ec_files.shard_path(m.base, lost).unlink()
-        # NO manual unmount: the next heartbeat snapshot must notice
+        # NO manual unmount: the next PULSE must notice. _settle's nudge
+        # sends the registry and lists no directory, so run one pulse's
+        # reconcile + snapshot through the function the loop calls.
+        victim._pulse_snapshot()
         _settle(servers)
         assert lost not in m.shard_ids
         assert lost not in master.topology.lookup_ec_volume(vid)
