@@ -1179,13 +1179,12 @@ def child_config3() -> None:
       sample size reported alongside."""
     import numpy as np
 
-    from seaweedfs_tpu.pipeline import batch as batch_mod
+    from seaweedfs_tpu.pipeline import batch as batch_mod, pipe
 
     on_acc = _require_tpu()
     n_volumes = 1000 if on_acc else 32
     vol_bytes = 30 * MIB if on_acc else MIB
-    max_batch = 128 * MIB if on_acc \
-        else batch_mod.DEFAULT_MAX_BATCH_BYTES
+    max_batch = 128 * MIB if on_acc else pipe.current().batch_bytes
     pool_n = 8
     rng = np.random.default_rng(3)
     pool = [rng.integers(0, 256, vol_bytes, dtype=np.uint8)

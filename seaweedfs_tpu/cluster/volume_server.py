@@ -14,6 +14,7 @@ the master (§3.4).
 from __future__ import annotations
 
 import json
+import os
 import threading
 import time
 from concurrent import futures
@@ -27,6 +28,7 @@ import numpy as np
 from .. import pb
 from ..cache import ChunkCache
 from ..pb import master_pb2, volume_server_pb2
+from ..pipeline import batch as batch_mod
 from ..pipeline import decode as decode_mod
 from ..pipeline import encode as encode_mod
 from ..pipeline import flight as flight_mod
@@ -544,6 +546,8 @@ class _VolumeServicer:
         # (collection, vid) -> vacuum.CompactState between the Compact
         # and Commit rpcs of a vacuum.
         self._compact_states: dict[tuple[str, int], object] = {}
+        #: the host buffers of this server's sweeps, kept between them
+        self._sweep_pools = batch_mod.PoolCache()
 
     # ---- volume admin ----
 
@@ -553,11 +557,13 @@ class _VolumeServicer:
             request.replication or "000", request.ttl)
         return volume_server_pb2.AllocateVolumeResponse()
 
+    def _delete_source(self, volume_id: int, collection: str) -> None:
+        with flight_mod.span("step_store_delete", trace=True):
+            self.vs.store.delete_volume(volume_id, collection)
+
     @_ec_step("delete_source")
     def VolumeDelete(self, request, context):
-        with flight_mod.span("step_store_delete", trace=True):
-            self.vs.store.delete_volume(request.volume_id,
-                                        request.collection)
+        self._delete_source(request.volume_id, request.collection)
         self.vs.heartbeat_now()
         return volume_server_pb2.VolumeDeleteResponse()
 
@@ -820,6 +826,66 @@ class _VolumeServicer:
         encode_mod.encode_volume(vol.base, scheme)
         return volume_server_pb2.VolumeEcShardsGenerateResponse()
 
+    @_ec_step("generate")
+    def VolumeEcShardsGenerateBatch(self, request, context):
+        """A sweep's volumes on this server, sealed by one call: their
+        rows coalesced into shared device batches (pipeline/batch.py),
+        then each volume finished as the one-volume commands finish it
+        — .ecx + .vif, mount, source deleted — and ONE heartbeat for
+        all of them.
+
+        Every volume ends plain (its .dat/.idx whole, no EC file beside
+        them, writable if it was) or EC (all shard files + .ecx + .vif
+        past the ``[storage] fsync`` barrier, mounted, its source
+        gone); the response names which. A failure of the coalesced
+        run leaves all of them plain."""
+        vs, store, col = self.vs, self.vs.store, request.collection
+        scheme = self._scheme(request.data_shards, request.parity_shards)
+        vols = {vid: store.get_volume(vid, col)
+                for vid in request.volume_ids}
+        was_writable = {vid for vid in vols
+                        if not store.is_readonly(vid, col)}
+        try:
+            for vid, vol in vols.items():
+                store.mark_readonly(vid, col)
+                with flight_mod.span("step_vol_sync", trace=True):
+                    vol.sync()
+            sizes = batch_mod.encode_volumes(
+                [vol.base for vol in vols.values()], scheme,
+                pools=self._sweep_pools)
+        except BaseException:
+            for vid in was_writable:
+                store.mark_writable(vid, col)
+            raise
+        resp = volume_server_pb2.VolumeEcShardsGenerateBatchResponse()
+        shard_ids = list(range(scheme.total_shards))
+        for vid, vol in vols.items():
+            error = ""
+            try:
+                faults.check("crash.ec.seal")
+                _write_durable_index_files(vol.base, scheme,
+                                           sizes[str(vol.base)])
+                self._mount_shards(vid, shard_ids, col)
+            except Exception as e:  # noqa: BLE001 — this volume stays plain, the sweep goes on
+                glog.warning("sweep: volume %d left plain: %r", vid, e)
+                error = f"{type(e).__name__}: {e}"
+                store.unmount_ec_shards(vid, shard_ids, col)
+                _remove_ec_files(vol.base, scheme)
+                if vid in was_writable:
+                    store.mark_writable(vid, col)
+            else:
+                # EC from here on, whatever becomes of the source
+                try:
+                    self._delete_source(vid, col)
+                except Exception as e:  # noqa: BLE001 — reported; the EC volume stands
+                    glog.warning("sweep: volume %d: source not "
+                                 "removed: %r", vid, e)
+                    error = (f"sealed, but its source is not removed: "
+                             f"{type(e).__name__}: {e}")
+            resp.results.add(volume_id=vid, error=error)
+        vs.heartbeat_now()
+        return resp
+
     @_ec_step("rebuild")
     def VolumeEcShardsRebuild(self, request, context):
         """§3.5: pull sibling shards from peers, reconstruct only the
@@ -920,12 +986,16 @@ class _VolumeServicer:
         self.vs.heartbeat_now()
         return volume_server_pb2.VolumeEcShardsDeleteResponse()
 
+    def _mount_shards(self, volume_id: int, shard_ids: list,
+                      collection: str) -> None:
+        with flight_mod.span("step_store_mount", trace=True):
+            self.vs.store.mount_ec_shards(volume_id, shard_ids,
+                                          collection)
+
     @_ec_step("mount")
     def VolumeEcShardsMount(self, request, context):
-        with flight_mod.span("step_store_mount", trace=True):
-            self.vs.store.mount_ec_shards(
-                request.volume_id, list(request.shard_ids),
-                request.collection)
+        self._mount_shards(request.volume_id, list(request.shard_ids),
+                           request.collection)
         self.vs.heartbeat_now()
         return volume_server_pb2.VolumeEcShardsMountResponse()
 
@@ -989,6 +1059,30 @@ def _dest_base(vs: VolumeServer, volume_id: int, collection: str) -> Path:
 
     loc = vs.store._pick_location()
     return loc.directory / volume_base_name(volume_id, collection)
+
+
+def _write_durable_index_files(base, scheme: EcScheme,
+                               dat_size: int) -> None:
+    """A sweep's volume: .ecx + .vif as ``ec.encode -volumeId`` writes
+    them, then both past the ``[storage] fsync`` barrier with the
+    directory that names them and the shard files, before the source
+    may go."""
+    encode_mod.write_index_files(base, scheme, dat_size)
+    for p in (ec_files.ecx_path(base), ec_files.vif_path(base)):
+        fd = os.open(p, os.O_RDONLY)
+        try:
+            durability.barrier(fd)
+        finally:
+            os.close(fd)
+    if durability.mode() != "off":
+        durability.fsync_dir(Path(base).parent)
+
+
+def _remove_ec_files(base, scheme: EcScheme) -> None:
+    for p in (ec_files.ecx_path(base), ec_files.vif_path(base),
+              *(ec_files.shard_path(base, i)
+                for i in range(scheme.total_shards))):
+        p.unlink(missing_ok=True)
 
 
 def _scheme_from_vif(base) -> EcScheme:

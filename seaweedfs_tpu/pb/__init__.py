@@ -76,6 +76,9 @@ VOLUME_METHODS = [
     Method("VolumeEcShardsGenerate",
            volume_server_pb2.VolumeEcShardsGenerateRequest,
            volume_server_pb2.VolumeEcShardsGenerateResponse),
+    Method("VolumeEcShardsGenerateBatch",
+           volume_server_pb2.VolumeEcShardsGenerateBatchRequest,
+           volume_server_pb2.VolumeEcShardsGenerateBatchResponse),
     Method("VolumeEcShardsRebuild",
            volume_server_pb2.VolumeEcShardsRebuildRequest,
            volume_server_pb2.VolumeEcShardsRebuildResponse),

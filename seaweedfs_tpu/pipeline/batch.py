@@ -1,28 +1,36 @@
 """Multi-volume coalescing batcher: many small volumes, one device batch.
 
-BASELINE.json config 3 is the cold-tier workload: ~1000 × 30 MB volumes
-sealed in one job. Encoding each volume alone would run thousands of tiny
-device calls (a 30 MB volume stripes to just 3 small rows); the batcher
+A cold tier is many small volumes sealed in one job (BASELINE.json
+config 3: 1000 x 30 MB). Encoding each alone runs one tiny device call
+per volume (a 30 MB volume stripes to just 3 small rows); the batcher
 coalesces rows from MANY volumes into shared ``(B, k, block)`` device
-batches, bucketing by row shape (k, block size) so every launch is full
-width. Rows larger than the batch bound are column-split first (the
-codec is position-wise), so one oversized large row can never breach the
-device memory bound.
+batches, bucketing by row shape (k, block size) so every launch but a
+bucket's last is full width. Rows larger than the batch bound are
+column-split first (the codec is position-wise), so one oversized
+large row can never breach the device memory bound.
 
-Scatter-back is OFFSET-ADDRESSED: every packed span records the exact
-shard-file byte offset its blocks occupy (the striping layout is
-deterministic), so per-shape buckets can flush in any order — mixed
-large/small-row volumes still coalesce across volumes without
-corrupting per-volume shard layout.
+The layout is pure arithmetic: a volume's own batch plans
+(``encode.plan_batches``) are cut into spans and laid side by side in
+shared batches (:func:`plan_packed_batches`). The reader fills a pooled
+host buffer per batch straight from the volumes' ``.dat`` files
+(``os.preadv``; the ``pack`` span), the compute stage is the grouped
+dispatch of the single-volume path, and scatter-back is
+OFFSET-ADDRESSED: every span records the shard-file byte offset its
+blocks occupy, so per-shape buckets can flush in any order.
 
-Reference analog: ``ec.encode -collection`` sealing every cold volume of
-a collection (weed/shell/command_ec_encode.go loops volumes one at a
+Served path: ``ec.encode -collection c -fullPercent p -quietFor d``
+(shell/cluster_commands.py) -> ``VolumeEcShardsGenerateBatch``
+(cluster/volume_server.py) -> :func:`encode_volumes`. Reference analog:
+weed/shell/command_ec_encode.go loops a collection's volumes one at a
 time; SURVEY.md §7 step 5 calls out the coalescing redesign as the
-TPU-first replacement).
+TPU-first replacement.
 """
 
 from __future__ import annotations
 
+import os
+import threading
+import time
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Callable, Iterable, Iterator, Optional, Sequence
@@ -30,156 +38,131 @@ from typing import Callable, Iterable, Iterator, Optional, Sequence
 import numpy as np
 
 from ..storage import ec_files, volume as volume_mod
-from . import pipe, writeback
+from . import encode as encode_mod
+from . import flight, pipe, writeback
 from .scheme import DEFAULT_SCHEME, EcScheme
-from .stripe import iter_row_batches, stripe_rows
-
-#: Bound on bytes packed into one coalesced device batch (input side);
-#: the live value is ``[pipeline] batch_bytes`` (pipe.current()).
-DEFAULT_MAX_BATCH_BYTES = 256 * 1024 * 1024
 
 
 def max_rows_per_batch(k: int, block: int, max_batch_bytes: int) -> int:
     """Row cap at which a (k, block)-shaped bucket flushes — THE flush
-    rule; bench.py's config-3 census classifies full vs tail batches
-    with the same formula, so keep them in lockstep here."""
+    rule, and the row capacity ``batch_row_slots`` counts per batch."""
     return max(1, max_batch_bytes // max(k * block, 1))
 
 
 @dataclass(frozen=True)
 class RowSpan:
     """``rows[r0:r0+n]`` of a packed batch hold volume ``key``'s shard
-    bytes ``[offset, offset + n*block)`` (per shard file)."""
+    bytes ``[offset, offset + n*block)`` (per shard file). ``segs`` says
+    where they come from: (byte offset inside the span, byte offset in
+    the volume's ``.dat``, bytes wanted, bytes the ``.dat`` has — the
+    rest is the zero padding of its last row)."""
     key: object
     r0: int
     n: int
     offset: int
+    segs: tuple = ()
 
 
-def _iter_volume_rows(sources: Iterable[tuple[object, np.ndarray]],
-                      scheme: EcScheme, max_batch_bytes: int
-                      ) -> Iterator[tuple[object, np.ndarray]]:
-    """(key, dat bytes) -> (key, (R, k, block) row tensors) in layout
-    order. A volume may yield several tensors (large rows, small rows,
-    and column chunks when one row alone exceeds the batch bound)."""
-    for key, dat in sources:
-        for rows, _is_large in stripe_rows(dat, scheme):
-            if rows.shape[1] * rows.shape[2] > max_batch_bytes:
-                # One row is bigger than a whole batch: column-split it
-                # (iter_row_batches emits (1, k, cols) chunks in order).
-                for chunk in iter_row_batches(rows, max_batch_bytes):
-                    yield key, chunk
-            else:
-                yield key, rows
+class PackedPlan:
+    """One shared batch: its shape and the spans laid into it."""
+
+    __slots__ = ("shape", "spans", "max_rows")
+
+    def __init__(self, k: int, block: int, max_rows: int):
+        self.shape = (0, k, block)
+        self.spans: list[RowSpan] = []
+        self.max_rows = max_rows
+
+    @property
+    def nbytes(self) -> int:
+        r, k, block = self.shape
+        return r * k * block
+
+    def add(self, key, plan, r: int, take: int) -> None:
+        """Rows ``[r, r+take)`` of one volume's own batch ``plan``."""
+        rows, k, block = self.shape
+        if plan.shape[0] == 1:
+            segs = tuple(plan.segs)      # one row or one column chunk
+        else:
+            # whole rows are ONE byte range of the .dat
+            (_, foff, _, have), = plan.segs
+            start, want = r * k * block, take * k * block
+            segs = ((0, foff + start, want,
+                     min(want, max(0, have - start))),)
+        self.spans.append(RowSpan(key, rows, take,
+                                  plan.shard_off + r * block, segs))
+        self.shape = (rows + take, k, block)
 
 
-class _Bucket:
-    __slots__ = ("pend", "rows")
+def plan_packed_batches(sizes: Iterable[tuple[object, int]],
+                        scheme: EcScheme, max_batch_bytes: int
+                        ) -> Iterator[PackedPlan]:
+    """(key, .dat size) pairs -> shared batches in layout order.
 
-    def __init__(self):
-        self.pend: list[tuple[object, int, np.ndarray]] = []
-        self.rows = 0
+    Rows are grouped into per-shape buckets (so volumes that mix large
+    and small rows still coalesce with their neighbours); a bucket
+    flushes when it reaches the batch bound, and what is left in the
+    buckets flushes at the end."""
+    buckets: dict[tuple[int, int], PackedPlan] = {}
+    for key, size in sizes:
+        for plan in encode_mod.plan_batches(size, scheme, max_batch_bytes):
+            r_n, k, block = plan.shape
+            r = 0
+            while r < r_n:
+                b = buckets.get((k, block))
+                if b is None:
+                    b = buckets[(k, block)] = PackedPlan(
+                        k, block,
+                        max_rows_per_batch(k, block, max_batch_bytes))
+                take = min(r_n - r, b.max_rows - b.shape[0])
+                b.add(key, plan, r, take)
+                r += take
+                if b.shape[0] >= b.max_rows:
+                    yield buckets.pop((k, block))
+    yield from buckets.values()
 
-    def flush(self) -> Optional[tuple[list[RowSpan], np.ndarray]]:
-        if not self.pend:
-            return None
-        spans, r0 = [], 0
-        for key, offset, rows in self.pend:
-            spans.append(RowSpan(key, r0, rows.shape[0], offset))
-            r0 += rows.shape[0]
-        packed = np.concatenate([r for _, _, r in self.pend], axis=0) \
-            if len(self.pend) > 1 else \
-            np.ascontiguousarray(self.pend[0][2])
-        self.pend, self.rows = [], 0
-        return spans, packed
+
+def _fill(plan: PackedPlan, view: np.ndarray,
+          fetch: Callable[[object, int, np.ndarray], None],
+          span_done: Optional[Callable[[object], None]] = None) -> None:
+    """Lay every span's bytes into the flat batch ``view``;
+    ``span_done(key)`` after each span, where a caller counts them."""
+    _, k, block = plan.shape
+    for sp in plan.spans:
+        base = sp.r0 * k * block
+        for boff, soff, want, have in sp.segs:
+            at = base + boff
+            if have > 0:
+                fetch(sp.key, soff, view[at:at + have])
+            if have < want:
+                view[at + have:at + want] = 0
+        if span_done is not None:
+            span_done(sp.key)
+
+
+def _array_fetch(arrays: dict):
+    def fetch(key, offset: int, out: np.ndarray) -> None:
+        out[:] = arrays[key][offset:offset + out.size]
+    return fetch
 
 
 def iter_packed_batches(sources: Iterable[tuple[object, np.ndarray]],
                         scheme: EcScheme = DEFAULT_SCHEME,
-                        max_batch_bytes: int = DEFAULT_MAX_BATCH_BYTES
+                        max_batch_bytes: Optional[int] = None
                         ) -> Iterator[tuple[list[RowSpan], np.ndarray]]:
-    """Pack per-volume row tensors into shared (B, k, block) batches.
-
-    Rows are grouped into per-shape buckets (so volumes that mix large
-    and small rows still coalesce with their neighbours); a bucket
-    flushes when it reaches the batch bound, and every span carries its
-    shard-file offset so results scatter back position-addressed."""
-    buckets: dict[tuple[int, int], _Bucket] = {}
-    cursor: dict[object, int] = {}
-    for key, rows in _iter_volume_rows(sources, scheme,
-                                       max_batch_bytes):
-        shape = (rows.shape[1], rows.shape[2])
-        block = shape[1]
-        max_rows = max_rows_per_batch(shape[0], block, max_batch_bytes)
-        b = buckets.setdefault(shape, _Bucket())
-        r = 0
-        while r < rows.shape[0]:
-            take = min(rows.shape[0] - r, max_rows - b.rows)
-            off = cursor.get(key, 0)
-            b.pend.append((key, off, rows[r:r + take]))
-            cursor[key] = off + take * block
-            b.rows += take
-            r += take
-            if b.rows >= max_rows:
-                out = b.flush()
-                if out:
-                    yield out
-    for b in buckets.values():
-        out = b.flush()
-        if out:
-            yield out
-
-
-def encode_packed(sources: Iterable[tuple[object, np.ndarray]],
-                  sink: Callable[[object, int, int, np.ndarray], None],
-                  scheme: EcScheme = DEFAULT_SCHEME,
-                  max_batch_bytes: Optional[int] = None) -> int:
-    """Coalesced encode over many volumes with the 3-stage pipeline.
-
-    ``sink(key, shard_id, offset, blocks)`` receives each span's bytes
-    addressed by shard-file offset (spans of one (key, shard) are
-    disjoint and cover the file). ``blocks`` may be a strided (n,
-    block) VIEW whose rows are contiguous — sinks either write row-wise
-    (zero-copy) or flatten (ravel/reshape copies on demand). Data
-    shards come straight from the host batch, parity from the device.
-    Returns total input bytes."""
+    """In-memory form of the layout: (spans, packed (B, k, block)
+    array) per shared batch, each a fresh array."""
     if max_batch_bytes is None:
         max_batch_bytes = pipe.current().batch_bytes
-    k = scheme.data_shards
-    total = 0
-
-    def batches():
-        nonlocal total
-        for spans, packed in iter_packed_batches(sources, scheme,
-                                                 max_batch_bytes):
-            total += packed.size
-            yield spans, packed
-
-    def write(spans, batch, parity):
-        # Views, not np.ascontiguousarray: each span row is already
-        # contiguous, and the gather-copy per (span, shard) cost ~0.5x
-        # the volume in extra DRAM traffic (the e2e host ceiling on a
-        # bandwidth-poor host — see PERF.md). Sinks that need flat
-        # bytes (ravel/reshape/tofile) still get them; the file sink
-        # writes row-wise with no copy at all.
-        for sp in spans:
-            for s in range(k):
-                sink(sp.key, s, sp.offset, batch[sp.r0:sp.r0 + sp.n, s])
-            for j in range(parity.shape[1]):
-                sink(sp.key, k + j, sp.offset,
-                     parity[sp.r0:sp.r0 + sp.n, j])
-
-    # Grouped dispatch on a single accelerator (one shared policy —
-    # pipe.pick_grouped_dispatch): runs of same-shaped coalesced
-    # batches share one device call (the buckets emit equal shapes
-    # until the tail, so steady state groups fully); multi-chip keeps
-    # per-batch mesh sharding via _pick_encode_fn.
-    multi, group, max_batch_bytes = pipe.pick_grouped_dispatch(
-        scheme.encoder.encode_parity_host_multi, max_batch_bytes)
-    pipe.run_pipeline(batches(), _pick_encode_fn(scheme), write,
-                      encode_multi_fn=multi, group=group,
-                      kind="ec.batch")
-    return total
+    arrays = {key: np.asarray(dat, dtype=np.uint8).ravel()
+              for key, dat in sources}
+    fetch = _array_fetch(arrays)
+    for plan in plan_packed_batches(
+            ((key, a.size) for key, a in arrays.items()), scheme,
+            max_batch_bytes):
+        packed = np.empty(plan.nbytes, dtype=np.uint8)
+        _fill(plan, packed, fetch)
+        yield plan.spans, packed.reshape(plan.shape)
 
 
 def _pick_encode_fn(scheme: EcScheme):
@@ -200,6 +183,141 @@ def _pick_encode_fn(scheme: EcScheme):
     return scheme.encoder.encode_parity_host
 
 
+class PoolCache:
+    """One :class:`pipe.HostBufferPool` kept between runs by whoever
+    owns this object (the volume server, for its sweeps). A fresh
+    pool's buffers are untouched anonymous memory: filling one pays a
+    page fault and a zeroed page for every 4 KiB, about two thirds of
+    the time a ``preadv`` into it takes; buffers that an earlier sweep
+    touched do not. What is kept is ``count`` buffers of the largest
+    batch seen (4 x 60 MiB for a cold tier's 10 MiB rows)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._pool: Optional[pipe.HostBufferPool] = None
+
+    def get(self, nbytes: int, count: int) -> pipe.HostBufferPool:
+        with self._lock:
+            pool = self._pool
+            if pool is None or pool.nbytes < nbytes or pool.count < count:
+                pool = self._pool = pipe.HostBufferPool(nbytes, count)
+            return pool
+
+    def drop(self, pool: pipe.HostBufferPool) -> None:
+        """Forget ``pool``: a run that failed may not have handed every
+        buffer back, and a pool short of buffers would stall the next."""
+        with self._lock:
+            if self._pool is pool:
+                self._pool = None
+
+
+def _plan(sizes: Iterable[tuple[object, int]], scheme: EcScheme,
+          max_batch_bytes: Optional[int]):
+    """(plans, encode_multi_fn, group): the shared batches under the
+    one grouping policy of the encode / batcher / rebuild pipelines
+    (pipe.pick_grouped_dispatch). On a single accelerator runs of
+    same-shaped coalesced batches share one device call (the buckets
+    emit equal shapes until the tail, so steady state groups fully)
+    and the batch bound is the grouped one; multi-chip keeps per-batch
+    mesh sharding via _pick_encode_fn."""
+    if max_batch_bytes is None:
+        max_batch_bytes = pipe.current().batch_bytes
+    multi, group, max_batch_bytes = pipe.pick_grouped_dispatch(
+        scheme.encoder.encode_parity_host_multi, max_batch_bytes)
+    return (list(plan_packed_batches(sizes, scheme, max_batch_bytes)),
+            multi, group)
+
+
+def _run_packed(planned, fetch, write_fn: Callable, scheme: EcScheme,
+                stats: pipe.PipeStats, publish: bool,
+                span_done: Optional[Callable[[object], None]] = None,
+                pools: Optional[PoolCache] = None) -> None:
+    """Drive :func:`_plan`'s batches through the 3-stage pipeline: the
+    reader packs each into a pooled host buffer (``fetch(key, offset,
+    out)`` fills ``out`` from a volume's bytes), and
+    ``write_fn(plan, batch, parity, release)`` runs on the writer
+    thread and owes one ``release()`` once nothing views ``batch`` any
+    more. ``pools`` lends a pool that outlives the run."""
+    plans, multi, group = planned
+    cfg = pipe.current()
+    nbytes = max((p.nbytes for p in plans), default=1)
+    if pools is None:
+        pool = pipe.HostBufferPool(
+            nbytes, cfg.pool_buffers or max(4, max(cfg.depth, group) + 2))
+    else:
+        # a kept pool is sized without the group width: a sweep's
+        # reader fills one slab while the device needs a fifth of a
+        # millisecond for it, so groups do not form, and what is kept
+        # is held by an idle server too
+        pool = pools.get(nbytes, cfg.pool_buffers or max(4, cfg.depth + 2))
+
+    def batches():
+        for seq, plan in enumerate(plans):
+            buf = pool.acquire()
+            view = buf[:plan.nbytes]
+            with flight.span("pack", batch=seq) as sp:
+                _fill(plan, view, fetch, span_done)
+                sp.nbytes = plan.nbytes
+            yield (encode_mod._BatchMeta(plan, buf),
+                   view.reshape(plan.shape))
+
+    def write(meta, batch, parity):
+        # from here the write stage owns the buffer: it goes back when
+        # write_fn's release() is called, not when write_fn returns
+        meta.submitted = True
+        write_fn(meta.plan, batch, parity,
+                 lambda: pool.release(meta.buf))
+
+    def recycle(meta, _batch):
+        # the pipeline's failure drain, for batches whose write never
+        # ran; a no-op after every write
+        if not meta.submitted:
+            meta.submitted = True
+            pool.release(meta.buf)
+
+    try:
+        pipe.run_pipeline(batches(), _pick_encode_fn(scheme), write,
+                          encode_multi_fn=multi, group=group,
+                          recycle_fn=recycle,
+                          stats=stats, kind="ec.batch", publish=publish)
+    except BaseException:
+        if pools is not None:
+            pools.drop(pool)
+        raise
+
+
+def encode_packed(sources: Iterable[tuple[object, np.ndarray]],
+                  sink: Callable[[object, int, int, np.ndarray], None],
+                  scheme: EcScheme = DEFAULT_SCHEME,
+                  max_batch_bytes: Optional[int] = None) -> int:
+    """Coalesced encode over many in-memory volumes.
+
+    ``sink(key, shard_id, offset, blocks)`` receives each span's bytes
+    addressed by shard-file offset (spans of one (key, shard) are
+    disjoint and cover the file). ``blocks`` is a (n, block) VIEW of a
+    pooled batch (data shards) or of the device's result (parity),
+    valid during the call only: a sink copies what it keeps. Returns
+    total input bytes, padding included."""
+    arrays = {key: np.asarray(dat, dtype=np.uint8).ravel()
+              for key, dat in sources}
+    k = scheme.data_shards
+
+    def write(plan, batch, parity, release):
+        for sp in plan.spans:
+            rows = slice(sp.r0, sp.r0 + sp.n)
+            for s in range(k):
+                sink(sp.key, s, sp.offset, batch[rows, s])
+            for j in range(parity.shape[1]):
+                sink(sp.key, k + j, sp.offset, parity[rows, j])
+        release()
+
+    planned = _plan(((key, a.size) for key, a in arrays.items()),
+                    scheme, max_batch_bytes)
+    _run_packed(planned, _array_fetch(arrays), write, scheme,
+                pipe.PipeStats(), publish=True)
+    return sum(p.nbytes for p in planned[0])
+
+
 def encode_many(payloads: Sequence[np.ndarray],
                 scheme: EcScheme = DEFAULT_SCHEME,
                 max_batch_bytes: Optional[int] = None,
@@ -207,25 +325,19 @@ def encode_many(payloads: Sequence[np.ndarray],
     """In-memory coalesced encode of many volume payloads.
 
     Returns (total_input_bytes, shards) where shards[i][s] is volume
-    i's shard-s bytes when ``keep_output`` — or None otherwise (the
-    benchmark path: parity still crosses D2H and is materialized, so
-    the measured time includes the full data path)."""
+    i's shard-s bytes when ``keep_output`` — or None otherwise (parity
+    still crosses D2H and is materialized, so a timing of this call
+    includes the full data path)."""
     pieces: Optional[dict] = {} if keep_output else None
 
     def sink(key, shard_id, offset, blocks):
         if pieces is not None:
-            # keep_output must own the bytes: copy the (possibly
-            # strided) span view into a flat array
+            # flatten() always copies: the view dies with the call
             pieces.setdefault((key, shard_id), []).append(
-                (offset, np.ascontiguousarray(blocks).reshape(-1)))
-        # else: true no-op. Parity was already materialized by the
-        # pipeline's D2H (np.asarray in pipe.run_pipeline) and data
-        # spans view the host batch — flattening here would re-add the
-        # gather copy the view-passing write path just removed.
+                (offset, blocks.flatten()))
 
-    sources = ((i, np.asarray(p, dtype=np.uint8).ravel())
-               for i, p in enumerate(payloads))
-    total = encode_packed(sources, sink, scheme, max_batch_bytes)
+    total = encode_packed(enumerate(payloads), sink, scheme,
+                          max_batch_bytes)
     if pieces is None:
         return total, None
     out = []
@@ -241,50 +353,121 @@ def encode_many(payloads: Sequence[np.ndarray],
 
 def encode_volumes(bases: Sequence[str | Path],
                    scheme: EcScheme = DEFAULT_SCHEME,
-                   max_batch_bytes: Optional[int] = None
-                   ) -> int:
+                   max_batch_bytes: Optional[int] = None,
+                   pools: Optional[PoolCache] = None
+                   ) -> dict[str, int]:
     """Seal many volumes' .dat files into shard files via coalesced
-    batches (the file-level config-3 path used by ``ec.encode`` over a
-    collection). Writes <base>.ec00.. for every base; the caller runs
-    write_ecx_file / VolumeInfo per volume as in single-volume encode.
-    Returns total .dat bytes encoded."""
+    batches: the file-level path of ``ec.encode`` over a collection.
+    Writes <base>.ec00.. for every base and returns base -> .dat size;
+    the caller finishes each volume (``encode.write_index_files``).
+
+    Every shard file has passed the ``[storage] fsync`` barrier when
+    this returns: a volume's files are fsynced behind its last write,
+    on the writeback pool's threads, while later batches compute. A
+    volume's files are open only from its first span to its last, so
+    a sweep of any length holds a few dozen descriptors. On failure no
+    shard file of any base is left behind. ``pools`` (a
+    :class:`PoolCache` of the caller's) keeps the host buffers for the
+    next call."""
     bases = [str(b) for b in bases]
-    shard_sizes: dict[str, int] = {}
+    k = scheme.data_shards
+    sizes = {b: encode_mod._require_local_dat(b).stat().st_size
+             for b in bases}
+    paths = {b: [str(ec_files.shard_path(b, i))
+                 for i in range(scheme.total_shards)] for b in bases}
+    planned = _plan(sizes.items(), scheme, max_batch_bytes)
+    plans = planned[0]
+    #: spans of each volume not yet read / not yet written
+    unread: dict[str, int] = {}
+    for plan in plans:
+        for sp in plan.spans:
+            unread[sp.key] = unread.get(sp.key, 0) + 1
+    unwritten = dict(unread)
+    opened: set[str] = set()       # volumes whose shard files exist
+    dat_fds: dict[str, int] = {}
+    st = pipe.PipeStats()
     # spans address disjoint shard-file byte ranges, so writes go to
     # the positioned-write pool (preallocated files, pwritev) and
     # retire while the next batch packs/computes — same writeback
-    # plane as single-volume encode (pipeline/writeback.py). The span
-    # views keep the source memmap alive until their write lands.
+    # plane as single-volume encode (pipeline/writeback.py)
     writer = writeback.WriterPool()
 
-    def sources():
-        for b in bases:
-            datp = volume_mod.dat_path(b)
-            size = datp.stat().st_size
-            shard_sizes[b] = scheme.shard_file_size(size)
-            dat = np.memmap(datp, dtype=np.uint8, mode="r") \
-                if size else np.zeros(0, dtype=np.uint8)
-            yield b, dat
+    def fetch(base, offset: int, out: np.ndarray) -> None:
+        fd = dat_fds.get(base)
+        if fd is None:
+            fd = dat_fds[base] = os.open(volume_mod.dat_path(base),
+                                         os.O_RDONLY)
+        encode_mod._pread_into(fd, out, offset)
 
-    def sink(base, shard_id, offset, blocks):
-        path = str(ec_files.shard_path(base, shard_id))
-        writer.open_file(path, shard_sizes[base])
-        if blocks.ndim > 1 and \
-                blocks.shape[-1] >= pipe.ROW_WRITE_MIN_BLOCK:
-            # (n, block) span view: rows are contiguous even when the
-            # span itself is strided — queue them without a gather copy
-            # (tiny blocks take the copy path; see pipe.py)
-            writer.submit(path, offset,
-                          [blocks[r] for r in range(blocks.shape[0])])
-        else:
-            writer.submit(path, offset,
-                          [np.ascontiguousarray(blocks).reshape(-1)])
+    def span_read(base) -> None:
+        unread[base] -= 1
+        if not unread[base]:
+            os.close(dat_fds.pop(base))
 
+    def open_shards(base) -> None:
+        with flight.span("step_shard_files"):
+            for p in paths[base]:
+                writer.open_file(p, scheme.shard_file_size(sizes[base]))
+
+    def write(plan, batch, parity, release):
+        row_ok = plan.shape[2] >= pipe.ROW_WRITE_MIN_BLOCK
+        # data rows VIEW the pooled buffer: recycle it only once every
+        # data-shard write of every span has retired
+        token = writeback.BatchToken(len(plan.spans) * k, release) \
+            if row_ok else None
+        done = 0
+        try:
+            for sp in plan.spans:
+                base, rows = sp.key, slice(sp.r0, sp.r0 + sp.n)
+                if base not in opened:
+                    opened.add(base)
+                    open_shards(base)
+                for s in range(k):
+                    writer.submit(paths[base][s], sp.offset,
+                                  encode_mod.shard_rows(
+                                      batch[rows, s], row_ok, pooled=True),
+                                  token)
+                    done += 1
+                for j in range(parity.shape[1]):
+                    writer.submit(paths[base][k + j], sp.offset,
+                                  encode_mod.shard_rows(parity[rows, j],
+                                                        row_ok))
+                unwritten[base] -= 1
+                if not unwritten[base]:
+                    for p in paths[base]:
+                        writer.finish(p)
+        except writeback.WriterError:
+            # fire the unreached counts so the buffer still recycles
+            # and the reader can drain out
+            if token is not None:
+                for _ in range(len(plan.spans) * k - done):
+                    token.done_one()
+            raise
+        if token is None:
+            release()        # the copy path took its own bytes
+
+    t0 = time.perf_counter()
     try:
-        total = encode_packed(sources(), sink, scheme, max_batch_bytes)
+        for b in bases:
+            if b not in unwritten:    # an empty .dat: 14 empty files
+                open_shards(b)
+                for p in paths[b]:
+                    writer.finish(p)
+        _run_packed(planned, fetch, write, scheme, st, publish=False,
+                    span_done=span_read, pools=pools)
         writer.close()
-        writer = None
-        return total
+    except BaseException:
+        writer.abort()
+        for ps in paths.values():
+            for p in ps:
+                Path(p).unlink(missing_ok=True)
+        raise
     finally:
-        if writer is not None:
-            writer.abort()
+        for fd in dat_fds.values():
+            os.close(fd)
+    st.write_seconds += writer.busy_seconds
+    st.wall_seconds = time.perf_counter() - t0
+    pipe.publish_stats(st, kind="ec.batch")
+    pipe.publish_packed(len(bases), sum(p.shape[0] for p in plans),
+                        sum(p.max_rows for p in plans), st.groups)
+    return sizes

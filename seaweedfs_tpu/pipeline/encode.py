@@ -155,6 +155,24 @@ class _BatchMeta:
         self.submitted = False
 
 
+def shard_rows(col2d: np.ndarray, row_ok: bool, pooled: bool = False):
+    """One shard's rows of a batch as the buffers of a positioned
+    write. Rows of a (R, block) column view are contiguous even though
+    the view is strided; below ROW_WRITE_MIN_BLOCK the per-row overhead
+    beats the gather-copy it avoids, so tiny blocks flatten first (and
+    stop referencing the source)."""
+    if row_ok:
+        return [col2d[r] for r in range(col2d.shape[0])]
+    if pooled:
+        # the copy path releases the pooled buffer as soon as the
+        # submits return (token=None), so data rows must NOT view it:
+        # for R=1 the column view is already contiguous and
+        # ascontiguousarray would alias the buffer the reader is about
+        # to refill — flatten() always copies
+        return [col2d.flatten()]
+    return [np.ascontiguousarray(col2d).reshape(-1)]
+
+
 def write_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
                    max_batch_bytes: Optional[int] = None,
                    stats: Optional[pipe.PipeStats] = None,
@@ -238,24 +256,6 @@ def write_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
                     if have < want:
                         view[boff + have:boff + want] = 0
                 yield _BatchMeta(plan, buf), view.reshape(plan.shape)
-
-        def shard_rows(col2d: np.ndarray, row_ok: bool,
-                       pooled: bool = False):
-            # rows of a (R, block) column view are contiguous even
-            # though the view is strided; below ROW_WRITE_MIN_BLOCK the
-            # per-row overhead beats the gather-copy it avoids, so tiny
-            # blocks flatten first (and stop referencing the source).
-            if row_ok:
-                return [col2d[r] for r in range(col2d.shape[0])]
-            if pooled:
-                # the copy path releases the pooled buffer as soon as
-                # the submits return (token=None), so data rows must
-                # NOT view it: for R=1 the column view is already
-                # contiguous and ascontiguousarray would alias the
-                # buffer the reader is about to refill — flatten()
-                # always copies
-                return [col2d.flatten()]
-            return [np.ascontiguousarray(col2d).reshape(-1)]
 
         def write_pooled(meta: _BatchMeta, batch, parity):
             plan = meta.plan
@@ -345,21 +345,16 @@ def write_ecx_file(base: str | Path) -> int:
     return idx_mod.write_sorted_ecx_from_idx(ip, ec_files.ecx_path(base))
 
 
-def encode_volume(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
-                  max_batch_bytes: Optional[int] = None,
-                  replication: str = "",
-                  remove_source: bool = False) -> ec_files.VolumeInfo:
-    """Full seal: shards + .ecx + .vif (and optionally drop .dat/.idx the
-    way `ec.encode` deletes the source volume after spreading shards).
-    The .vif records the volume's actual needle version (from the
-    superblock) so readers and decode parse records correctly."""
-    from ..util import tracing
-
+def write_index_files(base: str | Path, scheme: EcScheme, dat_size: int,
+                      replication: str = "") -> ec_files.VolumeInfo:
+    """What a volume needs beside its shard files to be an EC volume:
+    the sorted .ecx and the .vif. One finishing step for ``ec.encode``
+    of one volume and for a sweep's every volume (pipeline/batch.py
+    writes the shards there). The .vif records the volume's actual
+    needle version (from the superblock) so readers and decode parse
+    records correctly."""
     with open(_require_local_dat(base), "rb") as f:
         version = superblock_mod.SuperBlock.parse(f.read(8)).version
-    with tracing.span("ec.encode", base=str(base)) as sp:
-        dat_size = write_ec_files(base, scheme, max_batch_bytes)
-        sp.n_bytes = dat_size
     with flight.span("step_ecx", trace=True):
         write_ecx_file(base)
     vi = ec_files.VolumeInfo(version=version, replication=replication,
@@ -368,6 +363,21 @@ def encode_volume(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
                              parity_shards=scheme.parity_shards)
     with flight.span("step_vif", trace=True):
         vi.save(base)
+    return vi
+
+
+def encode_volume(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
+                  max_batch_bytes: Optional[int] = None,
+                  replication: str = "",
+                  remove_source: bool = False) -> ec_files.VolumeInfo:
+    """Full seal: shards + .ecx + .vif (and optionally drop .dat/.idx the
+    way `ec.encode` deletes the source volume after spreading shards)."""
+    from ..util import tracing
+
+    with tracing.span("ec.encode", base=str(base)) as sp:
+        dat_size = write_ec_files(base, scheme, max_batch_bytes)
+        sp.n_bytes = dat_size
+    vi = write_index_files(base, scheme, dat_size, replication)
     if remove_source:
         os.remove(volume_mod.dat_path(base))
         os.remove(volume_mod.idx_path(base))

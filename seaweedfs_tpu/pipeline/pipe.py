@@ -225,6 +225,7 @@ class HostBufferPool:
 
 #: span name -> the ``PipeStats`` field its seconds go to
 _SPAN_FIELD = {"read": "read_seconds", "pool_wait": "pool_wait_seconds",
+               "pack": "pack_seconds",
                "dispatch": "dispatch_seconds",
                "h2d_submit": "h2d_submit_seconds",
                "launch": "launch_seconds", "d2h_sync": "sync_seconds",
@@ -244,6 +245,7 @@ class PipeStats:
     bytes_out: int = 0
     read_seconds: float = 0.0       # batch materialization (reader)
     pool_wait_seconds: float = 0.0  # reader blocked on a free buffer
+    pack_seconds: float = 0.0       # batcher: volumes' rows into the batch
     dispatch_seconds: float = 0.0   # encode_fn enqueue (main thread)
     h2d_submit_seconds: float = 0.0  # of dispatch: jnp.asarray per slab
     launch_seconds: float = 0.0     # of dispatch: the jitted call
@@ -274,7 +276,8 @@ class PipeStats:
                  max_group=self.max_group, bytes_in=self.bytes_in,
                  bytes_out=self.bytes_out,
                  **{name: round(getattr(self, name), 6)
-                    for name in ("pool_wait_seconds", "dispatch_seconds",
+                    for name in ("pool_wait_seconds", "pack_seconds",
+                                 "dispatch_seconds",
                                  "h2d_submit_seconds", "launch_seconds",
                                  "sync_seconds")})
         if self.wall_seconds > 0:
@@ -290,7 +293,10 @@ class PipeStats:
 #: the process-wide span totals (flight.totals()).
 _TELEMETRY_LOCK = threading.Lock()
 _TOTALS = {"runs": 0, "batches": 0, "bytes_in": 0, "bytes_out": 0,
-           "wall_seconds": 0.0}
+           "wall_seconds": 0.0,
+           # the coalescing batcher's runs alone (pipeline/batch.py)
+           "batch_volumes": 0, "batch_rows": 0, "batch_row_slots": 0,
+           "batch_launches": 0}
 RECENT: deque = deque(maxlen=8)
 
 
@@ -307,6 +313,18 @@ def publish_stats(stats: "PipeStats", kind: str = "pipe") -> None:
         RECENT.append(entry)
 
 
+def publish_packed(volumes: int, rows: int, row_slots: int,
+                   launches: int) -> None:
+    """Fold one completed run of the coalescing batcher into the
+    totals: volumes sealed, rows packed, the row capacity of the
+    batches they went in, and device dispatches."""
+    with _TELEMETRY_LOCK:
+        _TOTALS["batch_volumes"] += volumes
+        _TOTALS["batch_rows"] += rows
+        _TOTALS["batch_row_slots"] += row_slots
+        _TOTALS["batch_launches"] += launches
+
+
 def last_run() -> Optional[dict]:
     """Most recent completed run's snapshot (bench stage breakdown)."""
     with _TELEMETRY_LOCK:
@@ -315,10 +333,13 @@ def last_run() -> Optional[dict]:
 
 def debug_payload() -> dict:
     """/debug/vars section, every key flat and cumulative since process
-    start: the published runs' counters and wall, the stage spans'
-    seconds (``compute`` = dispatch + sync; ``write`` = writer stage +
-    positioned writes), ``rpc_seconds`` (the EC handlers, each counted
-    once, pipeline run included), ``step_<name>_seconds`` / ``_calls``
+    start: the published runs' counters and wall, the batcher's
+    ``batch_*`` counts, the stage spans' seconds (``compute`` =
+    dispatch + sync; ``write`` = writer stage + positioned writes;
+    ``fsync`` = the sweep's shard-file barriers, on the writeback
+    pool's threads; ``pack`` is carved out of ``read``),
+    ``rpc_seconds`` (the EC handlers, each counted once, pipeline run
+    included), ``step_<name>_seconds`` / ``_calls``
     for every server-side rpc step, and the recent-run ring."""
     spans = flight.totals()
 
@@ -331,7 +352,9 @@ def debug_payload() -> dict:
     out.update(read_seconds=sec("read"),
                compute_seconds=sec("dispatch", "d2h_sync"),
                write_seconds=sec("write", "pwritev"),
+               fsync_seconds=sec("fsync"),
                pool_wait_seconds=sec("pool_wait"),
+               pack_seconds=sec("pack"),
                dispatch_seconds=sec("dispatch"),
                sync_seconds=sec("d2h_sync"),
                h2d_submit_seconds=sec("h2d_submit"),

@@ -28,7 +28,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
-from ..util import bufcheck, faults, racecheck
+from ..util import bufcheck, durability, faults, racecheck
 from . import flight
 
 #: Linux UIO_MAXIOV; one pwritev can scatter at most this many
@@ -183,6 +183,29 @@ class WriterPool:
         # slab being recycled while the write is still in flight.
         q.put((fd, offset, rows, token, bufcheck.tag_rows(rows)))
 
+    def finish(self, path: str) -> None:
+        """No more writes to ``path``: behind every write already
+        submitted for it (one path, one worker, FIFO) its worker runs
+        the ``[storage] fsync`` barrier and closes the file, while
+        later batches still compute. Done when :meth:`close` returns.
+        (Barriers on threads of their own, beside the writers, were
+        tried and gained nothing: PERF.md, PR 27.)"""
+        if self._errors:
+            self._raise()
+        fd = self._fds.pop(path, None)
+        if fd is None:
+            raise WriterError(f"writeback: {path!r} not opened")
+        self._queues[hash(path) % self.threads].put(
+            (fd, 0, None, None, None))
+
+    @staticmethod
+    def _barrier_close(fd: int) -> None:
+        try:
+            with flight.span("fsync"):
+                durability.barrier(fd)
+        finally:
+            os.close(fd)
+
     def failed(self) -> bool:
         return bool(self._errors)
 
@@ -241,8 +264,13 @@ class WriterPool:
                     # error path)
                     if token is not None:
                         token.done_one()
+                    if rows is None:
+                        os.close(fd)
                     continue
                 try:
+                    if rows is None:        # finish(): its writes retired
+                        self._barrier_close(fd)
+                        continue
                     with flight.span("pwritev") as sp:
                         bufcheck.verify_rows(tags, where="before pwritev")
                         sp.nbytes = pwrite_rows(fd, offset, rows)

@@ -36,6 +36,10 @@ def main(argv: Optional[list[str]] = None) -> int:
     p.add_argument("-filer", action="store_true",
                    help="also run a filer")
     p.add_argument("-filer.db", dest="filer_db", default="")
+    p.add_argument("-master.volumeSizeLimitMB", dest="volume_size_limit_mb",
+                   type=int, default=None,
+                   help="master's volume size limit (default 30 GiB; "
+                        "[master] volumeSizeLimitMB in -config)")
     p.add_argument("-master.peers", dest="peers", default="",
                    help="comma-separated master urls for HA")
     p.add_argument("-mdir", default="",
@@ -67,8 +71,13 @@ def main(argv: Optional[list[str]] = None) -> int:
     from .cluster.volume_server import VolumeServer
     from .storage.store import Store
 
+    # flag > TOML > default, as every other setting
+    limit_mb = args.volume_size_limit_mb \
+        if args.volume_size_limit_mb is not None \
+        else config_mod.lookup(conf, "master.volumeSizeLimitMB", 30 * 1024)
     master = MasterServer(
         ip=args.ip, port=args.master_port, secret=secret,
+        volume_size_limit_mb=int(limit_mb),
         pulse_seconds=args.pulseSeconds,
         peers=[x for x in args.peers.split(",") if x],
         meta_dir=args.mdir or None,

@@ -364,16 +364,166 @@ def _spread_targets(nodes: list[EcNode], total: int) -> list[EcNode]:
     return out
 
 
+_DURATION_UNITS = {"ns": 1e-9, "us": 1e-6, "ms": 1e-3, "s": 1.0,
+                   "m": 60.0, "h": 3600.0}
+
+
+def parse_duration(text: str) -> float:
+    """Seconds of a duration as upstream's flags spell it (Go's
+    ``time.ParseDuration``): ``1h``, ``90m``, ``1h30m``, ``1.5h``."""
+    import re
+    parts = re.findall(r"(\d+(?:\.\d*)?|\.\d+)(ns|us|ms|s|m|h)", text)
+    if not parts or "".join(n + u for n, u in parts) != text:
+        raise ShellError(f"bad duration {text!r} (want e.g. 1h, 90m, "
+                         f"1h30m)")
+    return sum(float(n) * _DURATION_UNITS[u] for n, u in parts)
+
+
+def sweep_candidates(resp: master_pb2.VolumeListResponse, collection: str,
+                     full_percent: float, quiet_seconds: float,
+                     now: float) -> dict[int, list[str]]:
+    """vid -> holders of the volumes ``ec.encode -collection`` seals:
+    upstream's ``collectVolumeIdsForEcEncode`` (command_ec_encode.go).
+    A volume of the collection qualifies when it is over
+    ``full_percent`` % of the master's volume size limit and was last
+    modified more than ``quiet_seconds`` ago, read-only or not."""
+    threshold = full_percent / 100.0 * resp.volume_size_limit_mb \
+        * 1024 * 1024
+    holders: dict[int, list[str]] = {}
+    for dc in resp.topology_info.data_center_infos:
+        for rack in dc.rack_infos:
+            for dn in rack.data_node_infos:
+                for v in dn.volume_infos:
+                    if v.collection == collection \
+                            and v.modified_at_second + quiet_seconds < now \
+                            and v.size > threshold:
+                        holders.setdefault(v.id, []).append(dn.id)
+    return holders
+
+
+def _spread_and_drop(env: ClusterEnv, vid: int, col: str, source: str,
+                     replicas: list[str], targets: list[EcNode]) -> int:
+    """The tail of ``ec.encode`` for one volume whose shards are
+    mounted on ``source``: copy + mount each target's shards there and
+    delete them here, then drop the plain volume from ``replicas``.
+    Returns the number of servers the shards ended on."""
+    src = env.volume(source)
+    per_target: dict[str, list[int]] = {}
+    for sid, node in enumerate(targets):
+        per_target.setdefault(node.url, []).append(sid)
+    for url, sids in per_target.items():
+        if url == source:
+            continue
+        tgt = env.volume(url)
+        tgt.VolumeEcShardsCopy(volume_server_pb2.VolumeEcShardsCopyRequest(
+            volume_id=vid, collection=col, shard_ids=sids,
+            copy_ecx_file=True, copy_ecj_file=True, copy_vif_file=True,
+            source_data_node=source))
+        tgt.VolumeEcShardsMount(
+            volume_server_pb2.VolumeEcShardsMountRequest(
+                volume_id=vid, collection=col, shard_ids=sids))
+        src.VolumeEcShardsDelete(
+            volume_server_pb2.VolumeEcShardsDeleteRequest(
+                volume_id=vid, collection=col, shard_ids=sids))
+    # Every replica of the now-sealed volume is dropped (the EC copy is
+    # authoritative from here on).
+    for url in replicas:
+        env.volume(url).VolumeDelete(
+            volume_server_pb2.VolumeDeleteRequest(volume_id=vid,
+                                                  collection=col))
+    return len(per_target)
+
+
+def _ec_encode_sweep(env: ClusterEnv, col: str, full_percent: float,
+                     quiet_for: str, data_shards: int,
+                     parity_shards: int) -> None:
+    """``ec.encode -collection c -fullPercent p -quietFor d``: seal
+    every qualifying volume of the collection as one job. One
+    ``VolumeEcShardsGenerateBatch`` per owning server (mark read-only,
+    coalesced encode, .ecx/.vif, mount, source deleted, one
+    heartbeat), then per volume the spread and the dropping of other
+    replicas, as the one-volume form does them. A volume that does not
+    qualify is not touched."""
+    import time as time_mod
+
+    quiet_seconds = parse_duration(quiet_for)
+    with flight.span("step_sweep_select", trace=True):
+        resp = env.volume_list()
+        holders = sweep_candidates(resp, col, full_percent, quiet_seconds,
+                                   time_mod.time())
+    by_server: dict[str, list[int]] = {}
+    for vid in sorted(holders):
+        by_server.setdefault(holders[vid][0], []).append(vid)
+    total = (data_shards + parity_shards) \
+        if data_shards and parity_shards else 14
+    sealed: dict[int, str] = {}
+    failed: dict[int, str] = {}
+    for source, vids in by_server.items():
+        try:
+            out = env.volume(source).VolumeEcShardsGenerateBatch(
+                volume_server_pb2.VolumeEcShardsGenerateBatchRequest(
+                    volume_ids=vids, collection=col,
+                    data_shards=data_shards, parity_shards=parity_shards))
+        except Exception as e:  # noqa: BLE001 — one server's failure must not abort the others' volumes
+            failed.update((vid, f"{source}: {e}") for vid in vids)
+            continue
+        for r in out.results:
+            if r.error:
+                failed[r.volume_id] = r.error
+            else:
+                sealed[r.volume_id] = source
+    nodes: list[EcNode] = []
+    if sealed:
+        with flight.span("step_spread_plan", trace=True):
+            nodes = env.collect_ec_nodes()
+    by_url = {n.url: n for n in nodes}
+    for vid in sorted(holders):
+        if vid in failed:
+            env.println(f"ec.encode volume {vid}: not sealed, left "
+                        f"plain: {failed[vid]}")
+            continue
+        source = sealed[vid]
+        targets = _spread_targets(nodes, total)
+        try:
+            servers = _spread_and_drop(env, vid, col, source,
+                                       holders[vid][1:], targets)
+        except Exception as e:  # noqa: BLE001 — sealed on its source; say so and go on
+            failed[vid] = f"sealed on {source}, not spread: {e}"
+            env.println(f"ec.encode volume {vid}: {failed[vid]}")
+            continue
+        # the plan of the next volume sees where this one's shards went
+        by_url[source].shards.pop(vid, None)
+        for sid, node in enumerate(targets):
+            node.shards.setdefault(vid, []).append(sid)
+        env.println(f"ec.encode volume {vid}: {total} shards over "
+                    f"{servers} servers")
+    env.println(f"ec.encode collection {col!r}: sealed "
+                f"{len(holders) - len(failed)} of {len(holders)} volumes "
+                f"over {full_percent:g}% full and quiet for {quiet_for}, "
+                f"{len(by_server)} generate rpc(s)")
+    if failed:
+        raise ShellError(f"ec.encode: {len(failed)} volume(s) not sealed")
+
+
 @cluster_command("ec.encode")
 def cmd_ec_encode(env: ClusterEnv, argv: list[str]) -> None:
     """Full §3.1 choreography: mark readonly -> generate on the owning
     server -> spread shards rack-aware (copy+mount, delete moved) ->
-    delete the source volume. With ``-distributed`` the shell only
-    submits a JobManager sweep — every volume server encodes its own
-    volumes in parallel under leases (docs/jobs.md) — and waits."""
+    delete the source volume. Without ``-volumeId`` it is upstream's
+    sweep: every volume of ``-collection`` over ``-fullPercent`` of the
+    volume size limit and unmodified for ``-quietFor``, sealed as one
+    job (:func:`_ec_encode_sweep`). With ``-distributed`` the shell
+    only submits a JobManager sweep — every volume server encodes its
+    own volumes in parallel under leases (docs/jobs.md) — and waits."""
     p = _parser("ec.encode")
     p.add_argument("-volumeId", type=int, default=0)
     p.add_argument("-collection", default="")
+    p.add_argument("-fullPercent", type=float, default=95.0,
+                   help="without -volumeId: seal volumes over this "
+                        "share of the volume size limit")
+    p.add_argument("-quietFor", default="1h",
+                   help="without -volumeId: ... and not modified for "
+                        "this long (1h, 90m, 1h30m)")
     p.add_argument("-dataShards", type=int, default=0)
     p.add_argument("-parityShards", type=int, default=0)
     p.add_argument("-distributed", action="store_true",
@@ -420,8 +570,9 @@ def cmd_ec_encode(env: ClusterEnv, argv: list[str]) -> None:
             raise ShellError(f"job {job['jobId']} {job['state']}")
         return
     if not vid:
-        raise ShellError("ec.encode: -volumeId required "
-                         "(or use -distributed)")
+        _ec_encode_sweep(env, col, args.fullPercent, args.quietFor,
+                         args.dataShards, args.parityShards)
+        return
 
     with flight.span("step_locate", trace=True):
         locs = env.volume_locations(vid)
@@ -443,31 +594,9 @@ def cmd_ec_encode(env: ClusterEnv, argv: list[str]) -> None:
 
     with flight.span("step_spread_plan", trace=True):
         targets = _spread_targets(env.collect_ec_nodes(), total)
-    per_target: dict[str, list[int]] = {}
-    for sid, node in enumerate(targets):
-        per_target.setdefault(node.url, []).append(sid)
-    for url, sids in per_target.items():
-        if url == source:
-            continue
-        tgt = env.volume(url)
-        tgt.VolumeEcShardsCopy(volume_server_pb2.VolumeEcShardsCopyRequest(
-            volume_id=vid, collection=col, shard_ids=sids,
-            copy_ecx_file=True, copy_ecj_file=True, copy_vif_file=True,
-            source_data_node=source))
-        tgt.VolumeEcShardsMount(
-            volume_server_pb2.VolumeEcShardsMountRequest(
-                volume_id=vid, collection=col, shard_ids=sids))
-        src.VolumeEcShardsDelete(
-            volume_server_pb2.VolumeEcShardsDeleteRequest(
-                volume_id=vid, collection=col, shard_ids=sids))
-    # Every replica of the now-sealed volume is dropped (the EC copy is
-    # authoritative from here on).
-    for url in locs:
-        env.volume(url).VolumeDelete(
-            volume_server_pb2.VolumeDeleteRequest(volume_id=vid,
-                                                  collection=col))
+    servers = _spread_and_drop(env, vid, col, source, locs, targets)
     env.println(f"ec.encode volume {vid}: {total} shards over "
-                f"{len(per_target)} servers")
+                f"{servers} servers")
 
 
 @cluster_command("ec.rebuild")

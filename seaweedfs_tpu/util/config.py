@@ -37,6 +37,11 @@ key = ""            # e.g. certs/cluster.key
 """,
     "master": """\
 # master.toml
+[master]
+volumeSizeLimitMB = 30720        # `server -config`: the master's volume
+                                 # size limit, what ec.encode -fullPercent
+                                 # is a share of (-master.volumeSizeLimitMB)
+
 [master.volume_growth]
 copy_1 = 7
 copy_2 = 6

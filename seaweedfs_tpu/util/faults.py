@@ -74,6 +74,7 @@ CATALOG = (
     "crash.tier.download",     # .dat.part complete, not yet renamed
     "crash.ckpt.save",         # shards written, manifest not yet PUT
     "crash.ec.writeback",      # EC shard slice positioned-write issued
+    "crash.ec.seal",           # a sweep's volume: shards fsynced, no .ecx yet
 )
 
 

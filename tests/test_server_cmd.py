@@ -11,6 +11,8 @@ import sys
 import time
 import urllib.request
 
+import pytest
+
 
 def _free_port_block(span=600):
     for _ in range(60):
@@ -94,3 +96,51 @@ def test_server_all_in_one(tmp_path):
             proc.wait(timeout=10)
     # SIGTERM produces a clean exit
     assert proc.returncode in (0, -signal.SIGTERM)
+
+
+@pytest.mark.parametrize("flag, toml, want", [
+    (None, None, 30 * 1024),        # the master's default
+    (None, 30, 30),                 # [master] volumeSizeLimitMB of -config
+    (64, 30, 64),                   # the flag goes before the file
+])
+def test_server_takes_the_masters_volume_size_limit(tmp_path, flag, toml,
+                                                    want):
+    """``-master.volumeSizeLimitMB`` as upstream's ``weed server`` spells
+    it, and the same from the TOML: what ``ec.encode -fullPercent`` is a
+    share of, as ``VolumeList`` reports it."""
+    from seaweedfs_tpu.shell.cluster_commands import ClusterEnv
+
+    base = _free_port_block()
+    (tmp_path / "data").mkdir()
+    argv = [sys.executable, "-m", "seaweedfs_tpu", "server",
+            "-dir", str(tmp_path / "data"), "-master.port", str(base),
+            "-volume.port", str(base + 100), "-pulseSeconds", "0.3"]
+    if toml is not None:
+        conf = tmp_path / "server.toml"
+        conf.write_text(f"[master]\nvolumeSizeLimitMB = {toml}\n")
+        argv += ["-config", str(conf)]
+    if flag is not None:
+        argv += ["-master.volumeSizeLimitMB", str(flag)]
+    proc = subprocess.Popen(
+        argv, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+    env = ClusterEnv(master_url=f"127.0.0.1:{base}")
+    try:
+        deadline = time.time() + 60
+        got = None
+        while got is None and time.time() < deadline:
+            assert proc.poll() is None, f"server died rc={proc.returncode}"
+            try:
+                got = env.volume_list().volume_size_limit_mb
+            except Exception:  # noqa: BLE001 — still booting
+                time.sleep(0.3)
+        assert got == want
+    finally:
+        env.close()
+        proc.send_signal(signal.SIGTERM)
+        try:
+            proc.wait(timeout=10)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait(timeout=10)
