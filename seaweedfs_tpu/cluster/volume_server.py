@@ -32,6 +32,7 @@ from ..pipeline import batch as batch_mod
 from ..pipeline import decode as decode_mod
 from ..pipeline import encode as encode_mod
 from ..pipeline import flight as flight_mod
+from ..pipeline import pipe as pipe_mod
 from ..pipeline import rebuild as rebuild_mod
 from ..pipeline.read import EcVolumeReader
 from ..pipeline.scheme import DEFAULT_SCHEME, EcScheme
@@ -546,8 +547,9 @@ class _VolumeServicer:
         # (collection, vid) -> vacuum.CompactState between the Compact
         # and Commit rpcs of a vacuum.
         self._compact_states: dict[tuple[str, int], object] = {}
-        #: the host buffers of this server's sweeps, kept between them
-        self._sweep_pools = batch_mod.PoolCache()
+        #: the host buffers of this server's EC pipeline runs (encode
+        #: of one volume, sweep, rebuild), kept between them
+        self._ec_pools = pipe_mod.PoolCache()
 
     # ---- volume admin ----
 
@@ -823,7 +825,7 @@ class _VolumeServicer:
         scheme = self._scheme(request.data_shards, request.parity_shards)
         with flight_mod.span("step_vol_sync", trace=True):
             vol.sync()
-        encode_mod.encode_volume(vol.base, scheme)
+        encode_mod.encode_volume(vol.base, scheme, pools=self._ec_pools)
         return volume_server_pb2.VolumeEcShardsGenerateResponse()
 
     @_ec_step("generate")
@@ -852,7 +854,7 @@ class _VolumeServicer:
                     vol.sync()
             sizes = batch_mod.encode_volumes(
                 [vol.base for vol in vols.values()], scheme,
-                pools=self._sweep_pools)
+                pools=self._ec_pools)
         except BaseException:
             for vid in was_writable:
                 store.mark_writable(vid, col)
@@ -933,8 +935,8 @@ class _VolumeServicer:
                         glog.v(1, "shard %d copy from %s failed: %s",
                                sid, url, e)
         try:
-            rebuilt = rebuild_mod.rebuild_ec_files(base, scheme,
-                                                   wanted=missing)
+            rebuilt = rebuild_mod.rebuild_ec_files(
+                base, scheme, wanted=missing, pools=self._ec_pools)
         finally:
             for p in fetched:
                 if p.exists():
@@ -1416,7 +1418,6 @@ def main(argv: Optional[list[str]] = None) -> int:
     profiler.configure_from(conf)
     httpserver.configure_from(conf)
     profiler.ensure_started()
-    from ..pipeline import pipe as pipe_mod
     pipe_mod.configure_from(conf)
     flight_mod.configure_from(conf)
     if config_mod.lookup(conf, "mesh") is not None:

@@ -29,7 +29,6 @@ TPU-first replacement.
 from __future__ import annotations
 
 import os
-import threading
 import time
 from dataclasses import dataclass
 from pathlib import Path
@@ -183,34 +182,6 @@ def _pick_encode_fn(scheme: EcScheme):
     return scheme.encoder.encode_parity_host
 
 
-class PoolCache:
-    """One :class:`pipe.HostBufferPool` kept between runs by whoever
-    owns this object (the volume server, for its sweeps). A fresh
-    pool's buffers are untouched anonymous memory: filling one pays a
-    page fault and a zeroed page for every 4 KiB, about two thirds of
-    the time a ``preadv`` into it takes; buffers that an earlier sweep
-    touched do not. What is kept is ``count`` buffers of the largest
-    batch seen (4 x 60 MiB for a cold tier's 10 MiB rows)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._pool: Optional[pipe.HostBufferPool] = None
-
-    def get(self, nbytes: int, count: int) -> pipe.HostBufferPool:
-        with self._lock:
-            pool = self._pool
-            if pool is None or pool.nbytes < nbytes or pool.count < count:
-                pool = self._pool = pipe.HostBufferPool(nbytes, count)
-            return pool
-
-    def drop(self, pool: pipe.HostBufferPool) -> None:
-        """Forget ``pool``: a run that failed may not have handed every
-        buffer back, and a pool short of buffers would stall the next."""
-        with self._lock:
-            if self._pool is pool:
-                self._pool = None
-
-
 def _plan(sizes: Iterable[tuple[object, int]], scheme: EcScheme,
           max_batch_bytes: Optional[int]):
     """(plans, encode_multi_fn, group): the shared batches under the
@@ -228,28 +199,32 @@ def _plan(sizes: Iterable[tuple[object, int]], scheme: EcScheme,
             multi, group)
 
 
+def _pool_size(planned, kept: bool) -> tuple[int, int]:
+    """(nbytes, count) of the buffer pool :func:`_run_packed` wants for
+    :func:`_plan`'s batches."""
+    plans, _, group = planned
+    cfg = pipe.current()
+    # a pool that is kept is asked for without the group width: a
+    # sweep's reader fills one slab while the device needs a fifth of a
+    # millisecond for it, so groups do not form, and the buffers a
+    # sweep has touched stay resident in an idle server
+    depth = cfg.depth if kept else max(cfg.depth, group)
+    return (max((p.nbytes for p in plans), default=1),
+            cfg.pool_buffers or max(4, depth + 2))
+
+
 def _run_packed(planned, fetch, write_fn: Callable, scheme: EcScheme,
                 stats: pipe.PipeStats, publish: bool,
-                span_done: Optional[Callable[[object], None]] = None,
-                pools: Optional[PoolCache] = None) -> None:
+                pool: pipe.HostBufferPool,
+                span_done: Optional[Callable[[object], None]] = None
+                ) -> None:
     """Drive :func:`_plan`'s batches through the 3-stage pipeline: the
-    reader packs each into a pooled host buffer (``fetch(key, offset,
+    reader packs each into a buffer of ``pool`` (``fetch(key, offset,
     out)`` fills ``out`` from a volume's bytes), and
     ``write_fn(plan, batch, parity, release)`` runs on the writer
     thread and owes one ``release()`` once nothing views ``batch`` any
-    more. ``pools`` lends a pool that outlives the run."""
+    more."""
     plans, multi, group = planned
-    cfg = pipe.current()
-    nbytes = max((p.nbytes for p in plans), default=1)
-    if pools is None:
-        pool = pipe.HostBufferPool(
-            nbytes, cfg.pool_buffers or max(4, max(cfg.depth, group) + 2))
-    else:
-        # a kept pool is sized without the group width: a sweep's
-        # reader fills one slab while the device needs a fifth of a
-        # millisecond for it, so groups do not form, and what is kept
-        # is held by an idle server too
-        pool = pools.get(nbytes, cfg.pool_buffers or max(4, cfg.depth + 2))
 
     def batches():
         for seq, plan in enumerate(plans):
@@ -275,15 +250,10 @@ def _run_packed(planned, fetch, write_fn: Callable, scheme: EcScheme,
             meta.submitted = True
             pool.release(meta.buf)
 
-    try:
-        pipe.run_pipeline(batches(), _pick_encode_fn(scheme), write,
-                          encode_multi_fn=multi, group=group,
-                          recycle_fn=recycle,
-                          stats=stats, kind="ec.batch", publish=publish)
-    except BaseException:
-        if pools is not None:
-            pools.drop(pool)
-        raise
+    pipe.run_pipeline(batches(), _pick_encode_fn(scheme), write,
+                      encode_multi_fn=multi, group=group,
+                      recycle_fn=recycle,
+                      stats=stats, kind="ec.batch", publish=publish)
 
 
 def encode_packed(sources: Iterable[tuple[object, np.ndarray]],
@@ -314,7 +284,8 @@ def encode_packed(sources: Iterable[tuple[object, np.ndarray]],
     planned = _plan(((key, a.size) for key, a in arrays.items()),
                     scheme, max_batch_bytes)
     _run_packed(planned, _array_fetch(arrays), write, scheme,
-                pipe.PipeStats(), publish=True)
+                pipe.PipeStats(), publish=True,
+                pool=pipe.HostBufferPool(*_pool_size(planned, kept=False)))
     return sum(p.nbytes for p in planned[0])
 
 
@@ -354,7 +325,7 @@ def encode_many(payloads: Sequence[np.ndarray],
 def encode_volumes(bases: Sequence[str | Path],
                    scheme: EcScheme = DEFAULT_SCHEME,
                    max_batch_bytes: Optional[int] = None,
-                   pools: Optional[PoolCache] = None
+                   pools: Optional[pipe.PoolCache] = None
                    ) -> dict[str, int]:
     """Seal many volumes' .dat files into shard files via coalesced
     batches: the file-level path of ``ec.encode`` over a collection.
@@ -367,8 +338,8 @@ def encode_volumes(bases: Sequence[str | Path],
     volume's files are open only from its first span to its last, so
     a sweep of any length holds a few dozen descriptors. On failure no
     shard file of any base is left behind. ``pools`` (a
-    :class:`PoolCache` of the caller's) keeps the host buffers for the
-    next call."""
+    :class:`pipe.PoolCache` of the caller's) lends the host buffers and
+    keeps them for the next call."""
     bases = [str(b) for b in bases]
     k = scheme.data_shards
     sizes = {b: encode_mod._require_local_dat(b).stat().st_size
@@ -453,9 +424,12 @@ def encode_volumes(bases: Sequence[str | Path],
                 open_shards(b)
                 for p in paths[b]:
                     writer.finish(p)
-        _run_packed(planned, fetch, write, scheme, st, publish=False,
-                    span_done=span_read, pools=pools)
-        writer.close()
+        # the pool is out until the last write that views it retires
+        with pipe.lend_pool(pools, *_pool_size(
+                planned, kept=pools is not None)) as pool:
+            _run_packed(planned, fetch, write, scheme, st, publish=False,
+                        pool=pool, span_done=span_read)
+            writer.close()
     except BaseException:
         writer.abort()
         for ps in paths.values():

@@ -11,10 +11,13 @@ Ingest is the overlapped plane from pipe.py/writeback.py (ROADMAP open
 item #1): the striping layout makes every batch a set of fixed byte
 ranges of the .dat and a fixed offset in each shard file, so the
 reader ``os.preadv``s file bytes straight into pooled page-aligned
-host buffers (no per-batch allocation, no memmap page-fault copies),
-the device computes PARITY ONLY (data shards are written straight
-from the host batch — k/m of the D2H traffic never happens), and a
-positioned-write pool retires ``pwritev`` calls into preallocated
+host buffers (no per-batch allocation, no memmap page-fault copies;
+a pool per call unless the caller lends one that outlives it — the
+volume server's ``pipe.PoolCache`` — and then no per-command
+allocation either: the buffers' pages were faulted in by an earlier
+command), the device computes PARITY ONLY (data shards are written
+straight from the host batch — k/m of the D2H traffic never happens),
+and a positioned-write pool retires ``pwritev`` calls into preallocated
 shard files while the next batch's transfer and compute are in
 flight. A pooled buffer is recycled only after every data-shard write
 that views it has retired (writeback.BatchToken).
@@ -176,7 +179,8 @@ def shard_rows(col2d: np.ndarray, row_ok: bool, pooled: bool = False):
 def write_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
                    max_batch_bytes: Optional[int] = None,
                    stats: Optional[pipe.PipeStats] = None,
-                   overlapped: Optional[bool] = None) -> int:
+                   overlapped: Optional[bool] = None,
+                   pools: Optional[pipe.PoolCache] = None) -> int:
     """Generate <base>.ec00..ec<k+m-1> from <base>.dat. Returns the
     .dat size. Mirrors ec_encoder.go WriteEcFiles (data movement)
     wrapped around the device codec (parity math).
@@ -189,6 +193,8 @@ def write_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
     ``overlapped=False`` (or ``[pipeline] overlapped = false``) is the
     single-threaded reference path — identical plans and offsets, so
     output bytes match exactly (scripts/pipeline_smoke.sh asserts it).
+    ``pools`` (a :class:`pipe.PoolCache` of the caller's) lends the
+    host buffers and keeps them for the next call.
     """
     cfg = pipe.current()
     if max_batch_bytes is None:
@@ -223,9 +229,7 @@ def write_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
     shard_size = scheme.shard_file_size(dat_size)
 
     pool_nbytes = max((p.nbytes for p in plans), default=1)
-    depth_eff = max(cfg.depth, group)
-    pool = pipe.HostBufferPool(
-        pool_nbytes, cfg.pool_buffers or max(4, depth_eff + 2))
+    pool_count = cfg.pool_buffers or max(4, max(cfg.depth, group) + 2)
     st = stats if stats is not None else pipe.PipeStats()
 
     fd = os.open(datp, os.O_RDONLY)
@@ -307,22 +311,25 @@ def write_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
                 pool.release(meta.buf)
 
         t0 = time.perf_counter()
-        try:
-            pipe.run_pipeline(
-                batches(), encode_fn,
-                write_pooled if writer is not None else write_inline,
-                encode_multi_fn=encode_multi, group=group,
-                recycle_fn=recycle, stats=st, overlapped=overlapped,
-                publish=False, prepare_fn=prepare_fn)
-        except pipe.PipelineError:
+        # the pool is out for the run and its writeback: the last
+        # buffer comes back when the last data-shard write retires
+        with pipe.lend_pool(pools, pool_nbytes, pool_count) as pool:
+            try:
+                pipe.run_pipeline(
+                    batches(), encode_fn,
+                    write_pooled if writer is not None else write_inline,
+                    encode_multi_fn=encode_multi, group=group,
+                    recycle_fn=recycle, stats=st, overlapped=overlapped,
+                    publish=False, prepare_fn=prepare_fn)
+            except pipe.PipelineError:
+                if writer is not None:
+                    writer.abort()
+                    writer = None
+                raise
             if writer is not None:
-                writer.abort()
+                writer.close()
+                st.write_seconds += writer.busy_seconds
                 writer = None
-            raise
-        if writer is not None:
-            writer.close()
-            st.write_seconds += writer.busy_seconds
-            writer = None
         st.wall_seconds = time.perf_counter() - t0
         pipe.publish_stats(st, kind="ec.encode")
     finally:
@@ -369,13 +376,17 @@ def write_index_files(base: str | Path, scheme: EcScheme, dat_size: int,
 def encode_volume(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
                   max_batch_bytes: Optional[int] = None,
                   replication: str = "",
-                  remove_source: bool = False) -> ec_files.VolumeInfo:
+                  remove_source: bool = False,
+                  pools: Optional[pipe.PoolCache] = None
+                  ) -> ec_files.VolumeInfo:
     """Full seal: shards + .ecx + .vif (and optionally drop .dat/.idx the
-    way `ec.encode` deletes the source volume after spreading shards)."""
+    way `ec.encode` deletes the source volume after spreading shards).
+    ``pools``: as :func:`write_ec_files`."""
     from ..util import tracing
 
     with tracing.span("ec.encode", base=str(base)) as sp:
-        dat_size = write_ec_files(base, scheme, max_batch_bytes)
+        dat_size = write_ec_files(base, scheme, max_batch_bytes,
+                                  pools=pools)
         sp.n_bytes = dat_size
     vi = write_index_files(base, scheme, dat_size, replication)
     if remove_source:

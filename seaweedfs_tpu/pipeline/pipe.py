@@ -36,6 +36,7 @@ accelerator's async queue instead of a synchronous SIMD call.
 
 from __future__ import annotations
 
+import contextlib
 import mmap
 import queue
 import threading
@@ -179,15 +180,28 @@ class HostBufferPool:
     steady-state ingest never pays per-batch allocation + zeroing, and
     readv/preadv can scatter file bytes straight into them.
     ``acquire`` blocks when every buffer is in flight — that blocking
-    IS the ingest plane's host-memory bound."""
+    IS the ingest plane's host-memory bound.
+
+    The free list is LIFO: ``acquire`` hands out the buffer returned
+    last. An ``mmap``ed buffer costs nothing until it is written, and
+    then a page fault and a zeroed page per 4 KiB, most of what a
+    ``preadv`` into it takes; so a run touches as many distinct buffers
+    as it had in flight at once (a 3-row cold volume: one), not all
+    ``count`` in turn, and a pool that outlives the run
+    (:class:`PoolCache`) keeps resident what was in flight and no more.
+    Every lend counts in ``/debug/vars`` ``pipeline`` as
+    ``pool_acquires``, and as ``pool_fresh_acquires`` when the buffer
+    was never lent before."""
 
     def __init__(self, nbytes: int, count: int):
         if nbytes <= 0 or count <= 0:
             raise ValueError("nbytes and count must be positive")
         self.nbytes = nbytes
         self.count = count
-        self._free: queue.Queue = queue.Queue()
+        self._free: queue.LifoQueue = queue.LifoQueue()
         self._maps: list[mmap.mmap] = []
+        #: addresses of the buffers lent at least once
+        self._touched: set[int] = set()
         for _ in range(count):
             m = mmap.mmap(-1, nbytes)
             self._maps.append(m)
@@ -197,14 +211,22 @@ class HostBufferPool:
         racecheck.register(self, "pipeline.HostBufferPool")
 
     def acquire(self, timeout: Optional[float] = None) -> np.ndarray:
-        """A free (nbytes,) uint8 buffer; blocks until one is
-        recycled. Raises ``queue.Empty`` on timeout."""
+        """A free (nbytes,) uint8 buffer, the most recently returned
+        one; blocks until one is recycled. Raises ``queue.Empty`` on
+        timeout."""
         with flight.span("pool_wait") as sp:
             buf = self._free.get(timeout=timeout) \
                 if timeout is not None else self._free.get()
             bufcheck.on_acquire(buf)
             sp.value = occ = float(self.in_flight())
         flight.record(flight.EV_POOL_OCC, value=occ)
+        addr = buf.ctypes.data
+        fresh = addr not in self._touched
+        if fresh:
+            self._touched.add(addr)
+        with _TELEMETRY_LOCK:
+            _TOTALS["pool_acquires"] += 1
+            _TOTALS["pool_fresh_acquires"] += fresh
         return buf
 
     def release(self, buf: np.ndarray) -> None:
@@ -217,6 +239,67 @@ class HostBufferPool:
 
     def in_flight(self) -> int:
         return self.count - self._free.qsize()
+
+    def touched(self) -> int:
+        """Buffers lent at least once: what of the pool can be
+        resident."""
+        return len(self._touched)
+
+
+class PoolCache:
+    """One :class:`HostBufferPool` kept between runs by whoever owns
+    this object: the volume server, which lends it to every EC pipeline
+    run it serves (``ec.encode`` of one volume, a sweep, ``ec.rebuild``),
+    so that a command's reader fills pages an earlier command faulted
+    in. One pool, grown to the largest ``(nbytes, count)`` asked so
+    far: on a one-chip host 18 buffers (the group width + 2) of 64 MiB
+    mapped at most, resident those that were in flight at once
+    (:class:`HostBufferPool`) — all of them after a 1 GiB volume, whose
+    reader runs ahead of the dispatch by the whole queue, one after a
+    30 MB volume.
+
+    A pool has one borrower at a time. A run that arrives while the
+    kept pool is out gets a fresh pool of its own, which goes with the
+    run; a run that fails may not have handed every buffer back, and a
+    pool short of buffers would stall the next, so its pool is
+    dropped."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._pool: Optional[HostBufferPool] = None
+        self._lent = False
+
+    @contextlib.contextmanager
+    def lend(self, nbytes: int, count: int):
+        """The pool for one run, for the length of the ``with``."""
+        with self._lock:
+            kept = not self._lent
+            pool = self._pool if kept else None
+            if pool is None or pool.nbytes < nbytes or pool.count < count:
+                if pool is not None:
+                    nbytes = max(nbytes, pool.nbytes)
+                    count = max(count, pool.count)
+                pool = HostBufferPool(nbytes, count)
+            if kept:
+                self._pool, self._lent = pool, True
+        failed = True
+        try:
+            yield pool
+            failed = False
+        finally:
+            if kept:
+                with self._lock:
+                    self._lent = False
+                    if failed:
+                        self._pool = None
+
+
+def lend_pool(pools: Optional[PoolCache], nbytes: int, count: int):
+    """Context manager giving one run its buffer pool: ``pools``' kept
+    one, or without a cache a pool that lives as long as the run."""
+    if pools is None:
+        return contextlib.nullcontext(HostBufferPool(nbytes, count))
+    return pools.lend(nbytes, count)
 
 
 # --------------------------------------------------------------------------
@@ -296,7 +379,10 @@ _TOTALS = {"runs": 0, "batches": 0, "bytes_in": 0, "bytes_out": 0,
            "wall_seconds": 0.0,
            # the coalescing batcher's runs alone (pipeline/batch.py)
            "batch_volumes": 0, "batch_rows": 0, "batch_row_slots": 0,
-           "batch_launches": 0}
+           "batch_launches": 0,
+           # every HostBufferPool.acquire, and those of a buffer never
+           # lent before (its pages are faulted in by the fill)
+           "pool_acquires": 0, "pool_fresh_acquires": 0}
 RECENT: deque = deque(maxlen=8)
 
 
@@ -334,7 +420,9 @@ def last_run() -> Optional[dict]:
 def debug_payload() -> dict:
     """/debug/vars section, every key flat and cumulative since process
     start: the published runs' counters and wall, the batcher's
-    ``batch_*`` counts, the stage spans' seconds (``compute`` =
+    ``batch_*`` counts, ``pool_acquires`` / ``pool_fresh_acquires``
+    (every buffer a :class:`HostBufferPool` lent, and those it had
+    never lent before), the stage spans' seconds (``compute`` =
     dispatch + sync; ``write`` = writer stage + positioned writes;
     ``fsync`` = the sweep's shard-file barriers, on the writeback
     pool's threads; ``pack`` is carved out of ``read``),

@@ -12,7 +12,10 @@ into pooled host buffers, reconstruction overlaps the next chunk's
 reads, and missing-shard chunks land at deterministic offsets in
 preallocated files via the positioned-write pool. Rebuilt bytes are
 fresh arrays (the D2H copy), so input buffers recycle as soon as a
-chunk's compute has synced — no writeback token needed.
+chunk's compute has synced — no writeback token needed. The pool is
+the caller's where it lends one (``pools``: the volume server's
+``pipe.PoolCache``), so a rebuild's reader fills buffers that an
+earlier command touched.
 """
 
 from __future__ import annotations
@@ -40,9 +43,12 @@ class EcRebuildError(RuntimeError):
 
 def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
                      wanted: Optional[Sequence[int]] = None,
-                     chunk_bytes: int = DEFAULT_CHUNK_BYTES) -> list[int]:
+                     chunk_bytes: int = DEFAULT_CHUNK_BYTES,
+                     pools: Optional[pipe.PoolCache] = None) -> list[int]:
     """Rebuild missing (or explicitly ``wanted``) shard files in place.
-    Returns the list of shard ids written."""
+    Returns the list of shard ids written. ``pools`` (a
+    :class:`pipe.PoolCache` of the caller's) lends the host buffers and
+    keeps them for the next call."""
     total = scheme.total_shards
     present = ec_files.present_shards(base, total)
     missing = sorted(set(range(total)) - set(present)) if wanted is None \
@@ -75,10 +81,8 @@ def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
             chunks, present, missing))
 
     cfg = pipe.current()
-    depth_eff = max(cfg.depth, group)
-    pool = pipe.HostBufferPool(
-        max(1, k * min(chunk_bytes, size or 1)),
-        cfg.pool_buffers or max(4, depth_eff + 2))
+    pool_nbytes = max(1, k * min(chunk_bytes, size or 1))
+    pool_count = cfg.pool_buffers or max(4, max(cfg.depth, group) + 2)
     in_fds = [os.open(ec_files.shard_path(base, i), os.O_RDONLY)
               for i in present]
     out_paths = [str(ec_files.shard_path(base, i)) for i in missing]
@@ -120,15 +124,16 @@ def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
             t0 = time.perf_counter()
             for path in out_paths:
                 writer.open_file(path, size)
-            try:
-                pipe.run_pipeline(chunks(), reconstruct, write,
-                                  encode_multi_fn=reconstruct_multi,
-                                  group=group, recycle_fn=recycle,
-                                  stats=st, publish=False)
-            except pipe.PipelineError:
-                writer.abort()
-                writer = None
-                raise
+            with pipe.lend_pool(pools, pool_nbytes, pool_count) as pool:
+                try:
+                    pipe.run_pipeline(chunks(), reconstruct, write,
+                                      encode_multi_fn=reconstruct_multi,
+                                      group=group, recycle_fn=recycle,
+                                      stats=st, publish=False)
+                except pipe.PipelineError:
+                    writer.abort()
+                    writer = None
+                    raise
             writer.close()
             st.write_seconds += writer.busy_seconds
             writer = None

@@ -155,7 +155,8 @@ def _root(arr) -> _BufInfo | None:
     escapes tracking — copies are exactly the safe case (the PR 12
     fix)."""
     addr = arr.ctypes.data
-    for info in _STATE.registry.values():
+    # a snapshot: another run's new pool may register() meanwhile
+    for info in list(_STATE.registry.values()):
         if info.addr <= addr < info.addr + info.nbytes:
             return info
     return None

@@ -7,6 +7,8 @@ import pytest
 
 from seaweedfs_tpu.pipeline import batch as batch_mod
 from seaweedfs_tpu.pipeline import encode as encode_mod
+from seaweedfs_tpu.pipeline import pipe
+from seaweedfs_tpu.pipeline import rebuild as rebuild_mod
 from seaweedfs_tpu.pipeline.scheme import EcScheme
 from seaweedfs_tpu.pipeline.stripe import stripe
 from seaweedfs_tpu.storage import ec_files
@@ -150,36 +152,68 @@ def _write_dats(tmp_path, rng, n, tag):
     return bases
 
 
-def test_a_kept_pool_serves_the_next_run_and_a_failed_run_drops_it(
-        tmp_path, monkeypatch):
-    """``encode_volumes(pools=...)``: the second run fills the buffers
-    the first one touched (same shard bytes as a run with a pool of its
-    own), and a run that fails may have kept a buffer, so its pool is
-    not lent again."""
-    rng = np.random.default_rng(5)
-    cache = batch_mod.PoolCache()
-    first = _write_dats(tmp_path, rng, 4, "a")
-    batch_mod.encode_volumes(first, SCHEME, max_batch_bytes=256 * 1024,
+def _all_shards(base):
+    return [open(ec_files.shard_path(base, s), "rb").read()
+            for s in range(SCHEME.total_shards)]
+
+
+def _sweep(bases, cache):
+    batch_mod.encode_volumes(bases, SCHEME, max_batch_bytes=256 * 1024,
                              pools=cache)
+
+
+def _encode_each(bases, cache):
+    for base in bases:
+        encode_mod.write_ec_files(base, SCHEME, max_batch_bytes=256 * 1024,
+                                  pools=cache)
+
+
+def _rebuild_each(bases, cache):
+    """Two shards of each (encoded) volume lost and rebuilt, several
+    chunks to a shard file."""
+    for base in bases:
+        if not ec_files.present_shards(base, SCHEME.total_shards):
+            encode_mod.write_ec_files(base, SCHEME)
+        for s in (1, 11):
+            os.remove(ec_files.shard_path(base, s))
+        assert rebuild_mod.rebuild_ec_files(
+            base, SCHEME, chunk_bytes=16 * 1024, pools=cache) == [1, 11]
+
+
+@pytest.mark.parametrize("run, cleans_up", [
+    (_sweep, True), (_encode_each, False), (_rebuild_each, False)],
+    ids=["sweep", "encode", "rebuild"])
+def test_a_kept_pool_serves_the_next_run_and_a_failed_run_drops_it(
+        tmp_path, monkeypatch, run, cleans_up):
+    """``encode_volumes``, ``write_ec_files`` and ``rebuild_ec_files``
+    with ``pools=``: the second run fills the buffers the first one
+    touched (same shard bytes as a run with a pool of its own), every
+    buffer is back after each, and a run that fails may have kept a
+    buffer, so its pool is not lent again."""
+    rng = np.random.default_rng(5)
+    cache = pipe.PoolCache()
+    run(_write_dats(tmp_path, rng, 4, "a"), cache)
     kept = cache._pool
     assert kept is not None and kept.in_flight() == 0
     second = _write_dats(tmp_path, rng, 4, "b")
-    batch_mod.encode_volumes(second, SCHEME, max_batch_bytes=256 * 1024,
-                             pools=cache)
+    run(second, cache)
     assert cache._pool is kept and kept.in_flight() == 0
     for base in second:
-        got = [open(ec_files.shard_path(base, s), "rb").read()
-               for s in range(SCHEME.total_shards)]
+        got = _all_shards(base)
         encode_mod.write_ec_files(base, SCHEME)
-        assert got == [open(ec_files.shard_path(base, s), "rb").read()
-                       for s in range(SCHEME.total_shards)]
+        assert got == _all_shards(base)
 
     third = _write_dats(tmp_path, rng, 4, "c")
-    monkeypatch.setattr(batch_mod.encode_mod, "_pread_into",
-                        lambda *a: (_ for _ in ()).throw(OSError("disk")))
+    if run is _rebuild_each:
+        _encode_each(third, None)
+
+    def broken_read(*_a):
+        raise OSError("disk")
+    monkeypatch.setattr(encode_mod, "_pread_into", broken_read)
+    monkeypatch.setattr(rebuild_mod, "_pread_into", broken_read)
     with pytest.raises(Exception, match="disk"):
-        batch_mod.encode_volumes(third, SCHEME, max_batch_bytes=256 * 1024,
-                                 pools=cache)
+        run(third, cache)
     assert cache._pool is None
-    assert not any(os.path.exists(ec_files.shard_path(b, s))
-                   for b in third for s in range(SCHEME.total_shards))
+    if cleans_up:
+        assert not any(os.path.exists(ec_files.shard_path(b, s))
+                       for b in third for s in range(SCHEME.total_shards))

@@ -342,7 +342,7 @@ def test_a_profiler_session_holds_the_stage_spans_as_leaves(
 PIPELINE_KEYS = ["pool_wait_seconds", "dispatch_seconds", "sync_seconds",
                  "h2d_submit_seconds", "launch_seconds", "rpc_seconds",
                  "read_seconds", "compute_seconds", "write_seconds",
-                 "wall_seconds"] + [
+                 "wall_seconds", "pool_acquires", "pool_fresh_acquires"] + [
     f"step_{name}_{what}" for name in flight.HANDLER_STEPS
     + flight.INNER_STEPS for what in ("seconds", "calls")]
 
