@@ -611,7 +611,7 @@ def calibration_line(codec: dict) -> dict:
     return {"phase": "calibration",
             **{key: codec.get(key) for key in (
                 "host_dispatch", "link_gibps", "native_gibps",
-                "auto_choice", "kernel")}}
+                "auto_choice")}}
 
 
 def cache_entries(path: str | None) -> int | None:

@@ -167,13 +167,6 @@ if [ "$rc" -ne 0 ]; then
     exit "$rc"
 fi
 
-# Bench drift report (ADVISORY — never fails the gate): diff the two
-# newest banked BENCH_r*.json rounds so a silent throughput slide is
-# visible in every lint run. scripts/bench_diff.py exits nonzero on a
-# >10% same-platform headline regression, but correctness gating is
-# this script's job, not throughput gating — hence `|| true`.
-python scripts/bench_diff.py || true
-
 # Simulation smoke (docs/simulation.md): 200 simulated volume servers
 # drive one real master through a traffic-shift and a rack-loss wave
 # on a virtual clock; every convergence invariant must hold and the

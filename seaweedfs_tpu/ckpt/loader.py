@@ -7,8 +7,7 @@ data-parallel worker derives its own disjoint order from the same
 seed), fetching up to ``prefetch_depth`` objects ahead of the consumer
 on a small thread pool — the same bounded-lookahead shape as the mount
 layer's readahead, but at object granularity. ``depth=0`` degrades to
-synchronous GETs, which is exactly the no-readahead baseline
-``bench.py --child-ckpt`` compares against.
+synchronous GETs: the no-readahead baseline.
 """
 
 from __future__ import annotations

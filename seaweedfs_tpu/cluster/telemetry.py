@@ -18,9 +18,8 @@ digests are drained per heartbeat window so the master's sliding
 window only ever holds recent samples.
 
 The collector hot path is gated on a module flag
-(:func:`configure` / ``[telemetry] enabled`` in the server config), so
-``bench.py --telemetry-overhead`` can toggle it at runtime the same
-way the tracing bench does.
+(:func:`configure` / ``[telemetry] enabled`` in the server config)
+that can be flipped at runtime, as tracing's can.
 """
 
 from __future__ import annotations

@@ -26,9 +26,9 @@ key absent from a full sketch that sketch's minimum counter — the most
 it could have absorbed — so the bounds survive distribution.
 
 The collector hot path is gated on a module flag (:func:`configure` /
-``[usage] enabled`` in the server config) so
-``bench.py --usage-overhead`` can toggle it at runtime, same as the
-tracing/telemetry benches. Prometheus export is cardinality-capped:
+``[usage] enabled`` in the server config) that can be flipped at
+runtime, same as tracing and telemetry. Prometheus export is
+cardinality-capped:
 only the first :data:`TENANT_GAUGE_CAP` distinct tenants get their own
 ``seaweed_tenant_*`` label; later ones fold into ``tenant="other"``.
 """

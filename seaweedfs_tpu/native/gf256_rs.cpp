@@ -6,9 +6,8 @@
 // lookups. This is the same classical kernel rebuilt from the algorithm
 // (Plank/Greenan/Miller "screaming fast Galois field arithmetic"):
 // runtime-dispatched AVX2 / scalar paths behind one C ABI, driven from
-// Python over ctypes. It serves two roles: the XLA:CPU-independent host
-// fallback, and the AVX2-class baseline the TPU numbers are compared
-// against in bench.py.
+// Python over ctypes. It is the codec's host leg: the XLA:CPU-independent
+// path for payloads that do not cross to the device (ops/rs_jax.py).
 //
 // Build: g++ -O3 -shared -fPIC gf256_rs.cpp -o _gf256_rs.so
 // (seaweedfs_tpu/ops/rs_native.py does this on demand).

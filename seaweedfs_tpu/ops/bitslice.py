@@ -1,4 +1,12 @@
-"""Bitsliced GF(2^8) linear maps as GF(2) XOR networks — the TPU hot path.
+"""Bitsliced GF(2^8) linear maps as GF(2) XOR networks, under plain XLA.
+
+What this module is today: the codec's ``xla`` leg (ops/rs_jax.py) — what
+a backend with neither a TPU nor the native codec computes with, what a
+device-resident array too short for the kernel takes, and the per-shard
+step of a mesh on virtual CPU devices — and the home of the algebra the
+Pallas kernel (ops/rs_pallas.py) is built from: ``expand_gf2`` and the
+masked-swap transpose constants. It runs no EC command on a TPU; the
+fused kernel does.
 
 The reference's hot loop is ``codeSomeShards`` in klauspost/reedsolomon
 (reedsolomon.go), whose per-byte GF(2^8) multiply-accumulate runs as PSHUFB

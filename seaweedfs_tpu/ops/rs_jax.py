@@ -1,19 +1,35 @@
-"""Batched, jittable Reed-Solomon codec for TPU (and XLA:CPU fallback).
+"""Batched Reed-Solomon codec: the klauspost ``Encoder`` method set over
+three legs, and the one place that chooses between them.
 
-This is the device-side replacement for the reference's
+This is the replacement for the reference's
 ``klauspost/reedsolomon.Encoder`` (SURVEY.md §2 L0): the same method
-surface as ops/rs_ref.py, but operating on batched ``(B, k, S)`` uint8
-arrays through the bitsliced GF(2) XOR network in ops/bitslice.py. One
+surface as ops/rs_ref.py, over batched ``(B, k, S)`` uint8 arrays. One
 ``Encoder`` instance serves any batch size; jitted executables are cached
-per (coefficient-matrix, shape) pair, and shard length is padded to the
-128-byte packing group internally (zero bytes encode to zero parity, so
-padding is transparent).
+per (coefficient matrix, variant) and jit's own cache holds the shapes.
+The legs, as /debug/vars ("codec") counts their bytes:
+
+* ``device`` — the Pallas kernel of ops/rs_pallas.py, on a TPU, for
+  shards of at least PALLAS_MIN_S bytes. A HOST slab whose S conforms
+  to the kernel's segment is viewed (zero-copy) in word form and runs
+  ``rs_words`` through apply_matrix_host_multi, grouped up to
+  host_dispatch_group() slabs per dispatch: every EC pipeline's path.
+  Anything else that reaches the device — a tail that does not conform,
+  a device-resident array, the mesh route — goes through apply_matrix
+  to the u8 entry ``rs_u8``, which pads S and relays the bytes out on
+  the device.
+* ``native`` — the host SIMD codec (ops/rs_native.py): every payload
+  shorter than PALLAS_MIN_S on every backend, and large host slabs when
+  SEAWEEDFS_TPU_HOST_DISPATCH keeps them off the link ("native", or
+  "auto" with a link slower than the codec).
+* ``xla`` — the bitslice network of ops/bitslice.py under plain XLA: a
+  backend with neither a TPU nor the native codec, and device-resident
+  arrays too short for the kernel.
 
 Reconstruction follows klauspost ``reconstruct`` semantics: take the first
 k surviving shard indices, invert those k rows of the code matrix on the
-host (tiny GF(2^8) Gauss-Jordan), and apply the needed rows on-device via
-the same bitsliced primitive used for encode. The inverted matrices are
-memoized per survivor set, mirroring klauspost's inversion_tree.go cache.
+host (tiny GF(2^8) Gauss-Jordan), and apply the needed rows through the
+same legs as encode. The inverted matrices are memoized per survivor
+set, mirroring klauspost's inversion_tree.go cache.
 """
 
 from __future__ import annotations
@@ -41,8 +57,7 @@ PALLAS_MIN_S = 256 * 1024
 #: Chunk the pure-XLA path along S above this, bounding the ~12x word
 #: expansion its unfused pack/XOR/unpack intermediates cost in HBM/RAM.
 XLA_CHUNK_S = 4 * 1024 * 1024
-#: Test/debug override: "pallas" | "pallas_swar" | "native" | "xla" |
-#: None (auto).
+#: Test/debug override: "pallas" | "native" | "xla" | None (auto).
 FORCE: Optional[str] = None
 #: Hybrid policy, part 2 (large HOST payloads): "auto" measures the
 #: host->device link and the native codec once and sends host-resident
@@ -54,18 +69,9 @@ HOST_DISPATCH = os.environ.get("SEAWEEDFS_TPU_HOST_DISPATCH", "auto")
 #: How many equally-shaped host slabs one device dispatch may carry on
 #: the word-form path (apply_matrix_host_multi): one jitted call over
 #: several slab-sized args pays the per-dispatch launch+sync floor
-#: once for the whole group.
-DISPATCH_GROUP = os.environ.get("SEAWEEDFS_TPU_DISPATCH_GROUP", "16")
-#: HBM reuse on the host-slab fast path: donate the freshly transferred
-#: word-form arg to the jitted call (jax.jit donate_argnums) so XLA may
-#: recycle its device memory for the computation instead of holding
-#: input and output live together — a streaming encode keeps up to
-#: group x batch slabs in flight, so without donation peak HBM is
-#: roughly double the working set. "auto" (default) donates only on
-#: accelerator backends: on CPU, jnp.asarray may ALIAS the host numpy
-#: buffer (no transfer happens), and donating an aliased buffer would
-#: hand the pooled batch the writer still references to XLA as scratch.
-DONATE = os.environ.get("SEAWEEDFS_TPU_DONATE", "auto")
+#: once for the whole group. A power of two: runs split into
+#: power-of-two widths, so the jit cache holds log2 of this per shape.
+DISPATCH_GROUP = 16
 _link_gibps: Optional[float] = None
 _native_gibps: Optional[float] = None
 _calibrate_lock = threading.Lock()
@@ -91,7 +97,7 @@ _place_compile_cache()
 
 #: Input bytes each codec leg has computed in this process: "device"
 #: (Pallas kernel), "native" (host SIMD codec), "xla" (bitslice
-#: network). Counted where the leg is decided — apply_matrix_host,
+#: network). Counted where the leg is decided —
 #: apply_matrix_host_multi, apply_matrix and the mesh step — and
 #: served at /debug/vars ("codec"): the hybrid policy may keep an
 #: encode on the host with the chip idle, and nothing else says so.
@@ -127,7 +133,6 @@ def debug_payload() -> dict:
         if _device_info.cache_info().currsize else None,
         "leg_bytes": legs,
         "host_dispatch": HOST_DISPATCH,
-        "kernel": PALLAS_KERNEL,
         "link_gibps": link,
         "native_gibps": native,
         "auto_choice": choice,
@@ -135,24 +140,11 @@ def debug_payload() -> dict:
     }
 
 
-def _dispatch_group() -> int:
-    """Validated DISPATCH_GROUP, checked at use time (same rationale as
-    _kernel(): a typo'd env var must surface as a normal error from the
-    encode call, not an import-time traceback)."""
-    try:
-        g = int(DISPATCH_GROUP)
-    except (TypeError, ValueError):
-        g = -1
-    if g < 1:
-        raise ValueError(
-            f"SEAWEEDFS_TPU_DISPATCH_GROUP={DISPATCH_GROUP!r}: expected "
-            f"a positive integer")
-    return g
-
-
 def _dispatch_mode() -> str:
-    """Validated HOST_DISPATCH, checked at use time on every backend
-    (same rationale as _kernel())."""
+    """Validated HOST_DISPATCH, checked at use time rather than at import
+    so that a typo'd variable surfaces as a normal error from the encode
+    call, not a bare traceback from every CLI entry point that
+    transitively imports this module."""
     if HOST_DISPATCH not in ("auto", "device", "native"):
         raise ValueError(
             f"SEAWEEDFS_TPU_HOST_DISPATCH={HOST_DISPATCH!r}: expected "
@@ -163,25 +155,25 @@ def _dispatch_mode() -> str:
 _donation_warning_squelched = False
 
 
-def _donate() -> bool:
-    """Validated DONATE knob (see its comment). Donation that XLA
-    cannot alias (parity output is m/k the input size) still frees the
-    input buffer inside the computation — that early release, not
-    output aliasing, is the HBM win — but JAX warns about every such
+def donation_enabled() -> bool:
+    """Donate the freshly transferred word-form args to the jitted call
+    (jax.jit donate_argnums)? On a TPU, yes: XLA frees each input inside
+    the computation instead of holding input and output live together —
+    a streaming encode keeps up to group x batch slabs in flight, so
+    without donation peak HBM is roughly double the working set. On CPU,
+    never: jnp.asarray may ALIAS the host numpy buffer (no transfer
+    happens), and donating an aliased buffer would hand the pooled batch
+    the writer still references to XLA as scratch. The mesh plane
+    (parallel/mesh) donates its device_put shards under the same rule.
+
+    Donation that XLA cannot alias (parity output is m/k the input
+    size) still gives that early release, but JAX warns about every such
     call, so the warning is squelched once when donation first engages.
     """
-    if DONATE not in ("auto", "on", "off"):
-        raise ValueError(
-            f"SEAWEEDFS_TPU_DONATE={DONATE!r}: expected "
-            f"'auto', 'on' or 'off'")
-    if DONATE == "off":
-        return False
     # deliberately the RAW backend, not _use_pallas(): tests monkeypatch
     # that predicate to force the device path on CPU (interpret-mode
-    # kernels), and donating there is exactly the aliasing hazard the
-    # auto mode exists to rule out
-    on = True if DONATE == "on" \
-        else jax.default_backend() == "tpu"
+    # kernels), and donating there is exactly the aliasing hazard above
+    on = jax.default_backend() == "tpu"
     if on:
         global _donation_warning_squelched
         if not _donation_warning_squelched:
@@ -193,32 +185,6 @@ def _donate() -> bool:
             # seaweedlint: disable=SW801 — idempotent latch
             _donation_warning_squelched = True
     return on
-
-
-def donation_enabled() -> bool:
-    """Public form of the donation knob for the mesh plane
-    (parallel/mesh): sharded apply-only steps donate their freshly
-    device_put input shards under the same policy — and the same
-    CPU-aliasing guard — as the single-device word-form path."""
-    return _donate()
-
-
-#: Which Pallas kernel the auto "pallas" variant uses: "transpose"
-#: (default) or "swar" (transpose-free; see
-#: rs_pallas.apply_gf_matrix_swar).
-PALLAS_KERNEL = os.environ.get("SEAWEEDFS_TPU_KERNEL") or "transpose"
-
-
-def _kernel() -> str:
-    """Validated kernel selection, checked at *use* time rather than at
-    import so a typo'd SEAWEEDFS_TPU_KERNEL surfaces as a normal error
-    from the encode call instead of a bare traceback from every CLI
-    entrypoint that transitively imports this module."""
-    if PALLAS_KERNEL not in ("transpose", "swar"):
-        raise ValueError(
-            f"SEAWEEDFS_TPU_KERNEL={PALLAS_KERNEL!r}: expected "
-            f"'transpose' or 'swar'")
-    return PALLAS_KERNEL
 
 
 class BackendUnavailable(RuntimeError):
@@ -257,11 +223,11 @@ def _use_pallas() -> bool:
 def _pick_variant(s: int) -> str:
     if FORCE:
         return FORCE
-    _kernel()  # validate the env knobs on EVERY backend, not just TPU —
-    _dispatch_mode()  # a typo must not ride silently through CPU runs
-    # into a deployment
+    _dispatch_mode()  # validate the env knob on EVERY backend, not just
+    # TPU — a typo must not ride silently through CPU runs into a
+    # deployment
     if _use_pallas() and s >= PALLAS_MIN_S:
-        return "pallas_swar" if _kernel() == "swar" else "pallas"
+        return "pallas"
     if rs_native.available():
         # Hybrid policy, part 1 (sub-slab work): below PALLAS_MIN_S the
         # dispatch+grid overhead beats any device win, so small
@@ -349,15 +315,9 @@ def _jitted_apply(coefs_bytes: bytes, n_out: int, n_in: int, variant: str,
     if variant == "pallas":
         def apply_fn(x: jnp.ndarray) -> jnp.ndarray:
             return rs_pallas.apply_gf_matrix(coefs, x)
-    elif variant == "pallas_swar":
-        def apply_fn(x: jnp.ndarray) -> jnp.ndarray:
-            return rs_pallas.apply_gf_matrix_swar(coefs, x)
     elif variant == "pallas_words":
         def apply_fn(x4: jnp.ndarray) -> jnp.ndarray:
             return rs_pallas.apply_gf_matrix_words(coefs, x4)
-    elif variant == "pallas_swar_words":
-        def apply_fn(x4: jnp.ndarray) -> jnp.ndarray:
-            return rs_pallas.apply_gf_matrix_swar_words(coefs, x4)
     elif variant == "xla":
         def apply_fn(x: jnp.ndarray) -> jnp.ndarray:
             return bitslice.apply_gf_matrix(coefs, x)
@@ -385,27 +345,21 @@ def _name_step(fn, variant: str, width: int) -> None:
 
 @functools.lru_cache(maxsize=64)
 def _jitted_apply_multi(coefs_bytes: bytes, n_out: int, n_in: int,
-                        variant: str, nargs: int, donate: bool = False):
-    """One jitted executable per (coefficient matrix, words variant,
-    group width): nargs word-form slabs in, nargs parities out. One
-    dispatch for the whole group, so the launch+sync floor is paid
-    once per group instead of once per slab. ``donate`` hands every slab
-    arg to XLA — the streaming pipeline's HBM high-water mark drops
-    from (inputs + outputs) to one group of inputs, since each slab's
-    buffer frees as the computation consumes it."""
+                        nargs: int, donate: bool = False):
+    """One jitted executable per (coefficient matrix, group width):
+    nargs word-form slabs in, nargs parities out. One dispatch for the
+    whole group, so the launch+sync floor is paid once per group instead
+    of once per slab. ``donate`` hands every slab arg to XLA — the
+    streaming pipeline's HBM high-water mark drops from (inputs +
+    outputs) to one group of inputs, since each slab's buffer frees as
+    the computation consumes it."""
     coefs = np.frombuffer(coefs_bytes, dtype=np.uint8).reshape(n_out, n_in)
-    if variant == "pallas_swar_words":
-        def kern(x):
-            return rs_pallas.apply_gf_matrix_swar_words(coefs, x)
-    else:
-        def kern(x):
-            return rs_pallas.apply_gf_matrix_words(coefs, x)
 
     def apply_fn(*xs):
         assert len(xs) == nargs
-        return tuple(kern(x) for x in xs)
+        return tuple(rs_pallas.apply_gf_matrix_words(coefs, x) for x in xs)
 
-    _name_step(apply_fn, variant, nargs)
+    _name_step(apply_fn, "pallas_words", nargs)
     return jax.jit(apply_fn, donate_argnums=tuple(range(nargs))) \
         if donate else jax.jit(apply_fn)
 
@@ -446,173 +400,123 @@ class _HostParity:
 
 def apply_matrix_host(coefs: np.ndarray, batch):
     """HOST (B, n_in, S) uint8 -> async result whose ``np.asarray``
-    yields (B, n_out, S) uint8.
-
-    The zero-relayout fast path behind Encoder.encode_parity_host /
-    reconstruct_batch_host: when the Pallas dispatch applies and the
-    shape conforms, the batch is VIEWED (zero-copy) in the kernel's
-    pre-tiled word form and fed to the *_words entry point — none of
-    the XLA copy/reshape/broadcast glue of the u8 path (PERF.md, open
-    findings). Anything ineligible defers to apply_matrix."""
-    coefs = np.ascontiguousarray(coefs, dtype=np.uint8)
-    n_out, n_in = coefs.shape
-    wf = _host_word_form(n_in, batch)
-    if wf is not None:
-        if _stay_on_host():
-            # link slower than the host codec: crossing can only lose.
-            # (Pinned "native" without a built codec falls through to
-            # the device leg instead of crashing.)
-            count_leg("native", batch.nbytes)
-            return rs_native.apply_gf_matrix(coefs, batch)
-        variant, xw = wf
-        b, _, s = batch.shape
-        fn = _jitted_apply(coefs.tobytes(), n_out, n_in, variant,
-                           donate=_donate())
-        count_leg("device", batch.nbytes)
-        return _HostParity(_launch(fn, _submit([xw]), batch.nbytes),
-                           b, n_out, s)
-    if _host_prefers_native(n_in, batch):
-        count_leg("native", batch.nbytes)
-        return rs_native.apply_gf_matrix(coefs, batch)
-    return apply_matrix(coefs, batch)
+    yields (B, n_out, S) uint8: apply_matrix_host_multi on a run of one
+    (Encoder.encode_parity_host / reconstruct_batch_host)."""
+    return apply_matrix_host_multi(coefs, [batch])[0]
 
 
 def _host_eligible(n_in: int, batch) -> bool:
-    """THE host-slab device-dispatch eligibility rule, shared by
-    _host_word_form and _host_prefers_native: HOST-contiguous
+    """THE host-slab device-dispatch eligibility rule: HOST-contiguous
     (B, n_in, S) uint8 with a Pallas-eligible S."""
     return (isinstance(batch, np.ndarray) and batch.ndim == 3
             and batch.dtype == np.uint8 and batch.flags.c_contiguous
             and FORCE is None and batch.shape[1] == n_in
-            and _pick_variant(batch.shape[-1])
-            in ("pallas", "pallas_swar"))
+            and _pick_variant(batch.shape[-1]) == "pallas")
 
 
 def _stay_on_host() -> bool:
     """Hybrid rule, spelled once: large host slabs stay on the host
-    when the link can't outrun the host codec (and the codec exists)."""
+    when the link can't outrun the host codec (and the codec exists).
+    (Pinned "native" without a built codec goes to the device leg
+    instead of crashing.)"""
     return not _device_worth_it() and rs_native.available()
-
-
-def _host_prefers_native(n_in: int, batch) -> bool:
-    """Slow-link guard for host slabs that are Pallas-ELIGIBLE but not
-    word-form-CONFORMING (e.g. arbitrary-length tail chunks): crossing
-    the device link through apply_matrix's padded u8 path can only lose
-    when the link is slower than the host codec, so they take the
-    native leg — the same hybrid rule conforming slabs get."""
-    return _host_eligible(n_in, batch) and _stay_on_host()
 
 
 def host_dispatch_group() -> int:
     """Group width for the host-slab pipelines (ONE policy for encode,
-    the coalescing batcher and rebuild): >1 only on a single-device
-    accelerator backend — multi-chip paths mesh-shard each batch
-    instead (parallel/mesh), and CPU backends never take the word-form
-    device path."""
+    the coalescing batcher and rebuild): DISPATCH_GROUP on a
+    single-device accelerator backend, else 1 — multi-chip paths
+    mesh-shard each batch instead (parallel/mesh), and CPU backends
+    never take the word-form device path."""
     if not _use_pallas() or len(jax.devices()) > 1:
         return 1
-    return _dispatch_group()
+    return DISPATCH_GROUP
 
 
-def _host_word_form(n_in: int, batch):
-    """Eligibility + zero-copy word view for the device fast path.
-
-    Returns (variant, words_view) when ``batch`` can ride the
-    zero-relayout word-form dispatch — HOST-contiguous (B, n_in, S)
-    uint8, Pallas-eligible S, kernel-conforming shape — else None.
-    One predicate shared by the single and grouped call sites."""
-    if not _host_eligible(n_in, batch):
-        return None
-    b, _, s = batch.shape
-    w = s // 4
-    lanes = rs_pallas.LANES
-    if _kernel() == "swar" and rs_pallas.swar_conforms(s):
-        return "pallas_swar_words", batch.view(np.uint32).reshape(
-            b, n_in, w // lanes, lanes)
-    if _kernel() != "swar" and rs_pallas.conforms(s):
-        return "pallas_words", batch.view(np.uint32).reshape(
-            b, n_in, rs_pallas.GROUP_WORDS,
-            w // (rs_pallas.GROUP_WORDS * lanes), lanes)
-    return None
+def _host_word_form(batch: np.ndarray) -> np.ndarray:
+    """Zero-copy view of a HOST (B, n_in, S) uint8 slab whose S conforms
+    (rs_pallas.conforms) in the kernel's pre-tiled word form,
+    (B, n_in, 32, R, 128) u32 — the array rs_pallas.apply_gf_matrix
+    builds on the device with a bitcast and a reshape, here for free."""
+    b, n_in, s = batch.shape
+    return batch.view(np.uint32).reshape(
+        b, n_in, rs_pallas.GROUP_WORDS,
+        s // (4 * rs_pallas.GROUP_WORDS * rs_pallas.LANES),
+        rs_pallas.LANES)
 
 
 def apply_matrix_host_multi(coefs: np.ndarray, batches):
-    """Grouped apply_matrix_host: a list of HOST (B, n_in, S) uint8
-    slabs -> a list of async results in the same order.
+    """A list of HOST (B, n_in, S) uint8 slabs -> a list of async
+    results in the same order, each yielding (B, n_out, S) uint8 under
+    ``np.asarray``. THE host-slab dispatch: every EC pipeline's encode
+    and reconstruct call ends here.
 
-    Runs of adjacent, identically-shaped, fast-path-eligible slabs are
-    dispatched as ONE jitted call with up to ``_dispatch_group()`` slab
-    args (_jitted_apply_multi), amortizing the per-dispatch launch+sync
-    floor. Ineligible or odd-shaped slabs fall back to the single-slab paths;
-    a shape change or a full group flushes, and a flushed run is split
-    into power-of-two sub-dispatches — so the jit cache sees at most
-    log2(group) (shape, width) pairs per workload, never a retrace
-    storm (the pipeline's greedy drain yields arbitrary run lengths)."""
+    A slab the Pallas dispatch applies to (_host_eligible) stays on the
+    host codec when the hybrid rule says so; otherwise, when its S
+    conforms, it is VIEWED (zero-copy) in the kernel's word form and fed
+    to ``rs_words`` — none of the XLA copy/reshape/broadcast glue of the
+    u8 path. Everything else defers to apply_matrix.
+
+    Runs of adjacent, identically-shaped word-form slabs are dispatched
+    as ONE jitted call with up to DISPATCH_GROUP slab args
+    (_jitted_apply_multi), amortizing the per-dispatch launch+sync
+    floor; a shape change or a full group flushes, and a flushed run is
+    split into power-of-two sub-dispatches — so the jit cache sees at
+    most log2(group) (shape, width) pairs per workload, never a retrace
+    storm (the pipeline's greedy drain yields arbitrary run lengths). A
+    lone slab runs the single-slab executable (_jitted_apply)."""
     coefs = np.ascontiguousarray(coefs, dtype=np.uint8)
     n_out, n_in = coefs.shape
+    key = (coefs.tobytes(), n_out, n_in)
     out: list = [None] * len(batches)
-    cap = _dispatch_group()
     stay_host: Optional[bool] = None
-    g_ix: list[int] = []
-    g_xw: list = []
-    g_shape = g_variant = None
+    run: list[int] = []
 
-    def dispatch(ixs, xws, width):
+    def dispatch(ixs):
         nbytes = sum(batches[i].nbytes for i in ixs)
         count_leg("device", nbytes)
-        if width == 1:
-            # lone slab: the single-dispatch executable (already cached
-            # for steady-state workloads) serves the word form the loop
-            # already built
-            i = ixs[0]
-            b, _, s = batches[i].shape
-            fn = _jitted_apply(coefs.tobytes(), n_out, n_in, g_variant,
-                               donate=_donate())
-            out[i] = _HostParity(_launch(fn, _submit(xws), nbytes),
-                                 b, n_out, s)
-            return
-        fn = _jitted_apply_multi(coefs.tobytes(), n_out, n_in,
-                                 g_variant, width, donate=_donate())
-        ys = _launch(fn, _submit(xws), nbytes)
+        words = [_host_word_form(batches[i]) for i in ixs]
+        xs = _submit(words)
+        if len(ixs) == 1:
+            fn = _jitted_apply(*key, "pallas_words",
+                               donate=donation_enabled())
+            ys = [_launch(fn, xs, nbytes)]
+        else:
+            fn = _jitted_apply_multi(*key, len(ixs),
+                                     donate=donation_enabled())
+            ys = _launch(fn, xs, nbytes)
         for i, y in zip(ixs, ys):
             b, _, s = batches[i].shape
             out[i] = _HostParity(y, b, n_out, s)
 
     def flush():
-        nonlocal g_ix, g_xw, g_shape, g_variant
         # quantize to power-of-two widths (13 -> 8+4+1) so executables
         # are shared across the drain's arbitrary run lengths
         pos = 0
-        while pos < len(g_ix):
-            width = 1 << ((len(g_ix) - pos).bit_length() - 1)
-            dispatch(g_ix[pos:pos + width], g_xw[pos:pos + width], width)
+        while pos < len(run):
+            width = 1 << ((len(run) - pos).bit_length() - 1)
+            dispatch(run[pos:pos + width])
             pos += width
-        g_ix, g_xw, g_shape, g_variant = [], [], None, None
+        run.clear()
 
     for i, batch in enumerate(batches):
-        wf = _host_word_form(n_in, batch)
-        if wf is None:
-            flush()
-            if _host_prefers_native(n_in, batch):
-                count_leg("native", batch.nbytes)
-                out[i] = rs_native.apply_gf_matrix(coefs, batch)
-            else:
-                out[i] = apply_matrix(coefs, batch)
-            continue
-        if stay_host is None:
+        eligible = _host_eligible(n_in, batch)
+        if eligible and stay_host is None:
             stay_host = _stay_on_host()
-        if stay_host:
+        if eligible and stay_host:
+            # link slower than the host codec: crossing can only lose,
+            # through the word form or apply_matrix's padded u8 path
             flush()
             count_leg("native", batch.nbytes)
             out[i] = rs_native.apply_gf_matrix(coefs, batch)
-            continue
-        variant, xw = wf
-        if g_ix and (batch.shape != g_shape or variant != g_variant
-                     or len(g_ix) >= cap):
+        elif eligible and rs_pallas.conforms(batch.shape[-1]):
+            if run and (batch.shape != batches[run[0]].shape
+                        or len(run) >= DISPATCH_GROUP):
+                flush()
+            run.append(i)
+        else:
             flush()
-        g_ix.append(i)
-        g_xw.append(xw)
-        g_shape, g_variant = batch.shape, variant
+            out[i] = apply_matrix(coefs, batch)
     flush()
     return out
 
@@ -652,13 +556,10 @@ def apply_matrix(coefs: np.ndarray, x) -> "np.ndarray | jnp.ndarray":
     if squeeze:
         x = x[None]
     b, _, s = x.shape
-    count_leg("device" if variant.startswith("pallas") else "xla",
-              x.size)
+    count_leg("device" if variant == "pallas" else "xla", x.size)
     nc = 1
     if variant == "pallas":
         seg = rs_pallas.SEG_BYTES
-    elif variant == "pallas_swar":
-        seg = rs_pallas.SWAR_SEG_BYTES
     elif variant == "xla" and s > XLA_CHUNK_S:
         variant = "xla_chunked"
         nc = -(-s // XLA_CHUNK_S)

@@ -7,9 +7,11 @@ and driven over ctypes (no pybind11 in this environment). Python threads
 can fan one large apply out across column chunks because the C calls
 release the GIL.
 
-Roles: reference-class CPU baseline for bench.py, and the host-side
-fast path for small interval repairs where a device round-trip costs
-more than the math (read path, config 5). Dispatch ladder inside the
+Roles: the codec's ``native`` leg (ops/rs_jax.py) — every payload too
+short for the device kernel, such as the small interval repairs of the
+read path where a device round-trip costs more than the math (config
+5), and large host slabs when SEAWEEDFS_TPU_HOST_DISPATCH keeps them
+off the link. Dispatch ladder inside the
 library: GFNI+AVX512 (one vgf2p8affineqb per 64 bytes — klauspost's
 fastest amd64 path; bit convention self-calibrated at init) -> AVX2
 nibble-LUT -> scalar table.

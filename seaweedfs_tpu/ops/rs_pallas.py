@@ -124,140 +124,10 @@ def _eval_xor_network(planes: list, rows: tuple[tuple[int, ...], ...],
     return results
 
 
-def _make_swar_kernel(rows: tuple[tuple[int, ...], ...],
-                      n_in: int, n_out: int, cse: bool = True):
-    """Transpose-free kernel: SWAR bitplanes inside u32 words.
-
-    Bit j of each of the 4 packed bytes of a word is extracted with
-    ``(x >> j) & 0x01010101`` — plane t = 8d+j holds its 4 bits at word
-    bit positions 0, 8, 16, 24. The GF(2) XOR network then runs on
-    these quarter-density planes, and output bit i re-enters the word at
-    ``acc << i`` (disjoint positions across i, so OR == ADD == XOR).
-    Every op is a full-width shift/AND/XOR on the (rows, 128) u32 tile:
-    no reshapes, slices along sub-tile axes, stacks, or transposes for
-    Mosaic to lower into VMEM copies — probe2 measured the transpose
-    variant at ~5.5 GiB/s marginal, ~150x below HBM, pointing at
-    layout-shuffling rather than XOR arithmetic as the cost.
-
-    All 8*n_in masked planes are materialized before the network runs
-    (CSE steps cross shard boundaries, so a shard-major streaming order
-    cannot host them); instruction scheduling/liveness is left to the
-    compiler. ``cse=False`` keeps this same structure minus factoring —
-    it is an ablation of the factoring only, not a reconstruction of
-    any earlier kernel layout.
-    """
-
-    def kernel(in_ref, out_ref):
-        plane_mask = jnp.uint32(0x01010101)
-        x = in_ref[0]                       # (n_in, rows, 128) u32
-        planes = []
-        for d in range(n_in):
-            xd = x[d]
-            for j in range(8):
-                p = xd if j == 0 else (xd >> jnp.uint32(j))
-                planes.append(p & plane_mask)
-        accs = _eval_xor_network(planes, rows, 8 * n_in, cse)
-        for o in range(n_out):
-            y = None
-            for i in range(8):
-                acc = accs[8 * o + i]
-                if acc is None:
-                    continue
-                sh = acc if i == 0 else (acc << jnp.uint32(i))
-                y = sh if y is None else (y | sh)
-            if y is None:
-                y = jnp.zeros_like(x[0])
-            out_ref[0, o] = y
-
-    return kernel
-
-
-#: Row granularity of the SWAR kernel: S must divide into
-#: 4 (bytes/word) * SWAR_ROWS * 128 (lanes) byte segments. 128 is the
-#: largest power of two the v5e compiler accepts: the kernel keeps all
-#: 8*n_in masked planes live, and 256 rows already ask more scoped
-#: VMEM than the 16 MiB limit (tests/test_tpu_compile.py).
-SWAR_ROWS = 128
-SWAR_SEG_BYTES = 4 * SWAR_ROWS * LANES
-
-
-def swar_conforms(s: int, rows_per_block: int = SWAR_ROWS) -> bool:
-    return s > 0 and s % (4 * rows_per_block * LANES) == 0
-
-
 def _expand_rows(coefs: np.ndarray, n_out: int):
     mbits = bitslice.expand_gf2(np.asarray(coefs, dtype=np.uint8))
     return tuple(tuple(int(t) for t in np.nonzero(mbits[rr])[0])
                  for rr in range(8 * n_out))
-
-
-def apply_gf_matrix_swar_words(coefs: np.ndarray, x4: jnp.ndarray,
-                               interpret: bool = False,
-                               rows_per_block: int = SWAR_ROWS,
-                               cse: bool = True,
-                               name: str = "rs_swar_words") -> jnp.ndarray:
-    """SWAR kernel on the WORD form: x4 (B, n_in, R, 128) u32 ->
-    (B, n_out, R, 128) u32.
-
-    This is the zero-relayout entry point: a profiler trace of the
-    u8-API path showed the Pallas kernel itself at ~6.5 ms per 160 MiB
-    call (~24 GiB/s) with ~10x that spent in XLA copy/reshape/broadcast
-    ops materializing the (B, n, R, 128) u32 view of a (B, n, S) u8
-    array. The word form IS the array's natural tiled layout — host
-    callers produce it with a free contiguous reshape (np view) and
-    device_put lands it tiled, so nothing is shuffled on device."""
-    n_out, n_in = coefs.shape
-    if x4.ndim != 4 or x4.shape[1] != n_in or x4.shape[3] != LANES:
-        raise ValueError(
-            f"x4 must be (B, {n_in}, R, {LANES}) u32, got {x4.shape}")
-    b, _, r, _ = x4.shape
-    if r % rows_per_block:
-        raise ValueError(f"R={r} must divide by {rows_per_block}")
-    rows = _expand_rows(coefs, n_out)
-    return pl.pallas_call(
-        _make_swar_kernel(rows, n_in, n_out, cse=cse),
-        grid=(b, r // rows_per_block),
-        in_specs=[pl.BlockSpec(
-            (1, n_in, rows_per_block, LANES),
-            lambda bi, ri: (bi, 0, ri, 0),
-            memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(
-            (1, n_out, rows_per_block, LANES),
-            lambda bi, ri: (bi, 0, ri, 0),
-            memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(
-            (b, n_out, r, LANES), jnp.uint32),
-        interpret=interpret,
-        name=name,
-    )(x4)
-
-
-def apply_gf_matrix_swar(coefs: np.ndarray, x: jnp.ndarray,
-                         interpret: bool = False,
-                         rows_per_block: int = SWAR_ROWS,
-                         cse: bool = True) -> jnp.ndarray:
-    """Same contract as apply_gf_matrix, via the SWAR kernel. ``cse``
-    evaluates the XOR network with Paar-factored shared pairs (2.4x
-    fewer XORs; semantics identical — see ops/xor_cse.py)."""
-    n_out, n_in = coefs.shape
-    if x.ndim != 3 or x.shape[1] != n_in:
-        raise ValueError(f"x must be (B, {n_in}, S), got {x.shape}")
-    b, _, s = x.shape
-    if not swar_conforms(s, rows_per_block):
-        raise ValueError(
-            f"S={s} must be a positive multiple of "
-            f"{4 * rows_per_block * LANES}")
-    w = s // 4
-    r = w // LANES
-
-    xw = jax.lax.bitcast_convert_type(
-        x.reshape(b, n_in, w, 4), jnp.uint32)
-    x4 = xw.reshape(b, n_in, r, LANES)
-    y4 = apply_gf_matrix_swar_words(coefs, x4, interpret=interpret,
-                                    rows_per_block=rows_per_block,
-                                    cse=cse, name="rs_swar_u8")
-    yw = y4.reshape(b, n_out, w)
-    return jax.lax.bitcast_convert_type(yw, jnp.uint8).reshape(b, n_out, s)
 
 
 def conforms(s: int, rb: int = RB) -> bool:
@@ -302,11 +172,17 @@ def apply_gf_matrix_words(coefs: np.ndarray, x4: jnp.ndarray,
                           interpret: bool = False, rb: int = RB,
                           cse: bool = True,
                           name: str = "rs_words") -> jnp.ndarray:
-    """Transpose kernel on the WORD form: x4 (B, n_in, 32, R, 128) u32
+    """The kernel on the WORD form: x4 (B, n_in, 32, R, 128) u32
     -> (B, n_out, 32, R, 128) u32 — no u8<->u32 relayout around the
-    kernel (see apply_gf_matrix_swar_words for why that matters).
+    kernel. The word form IS the array's natural tiled layout: a host
+    caller produces it with a free contiguous reshape (a numpy view)
+    and the transfer lands it tiled, so nothing is shuffled on the
+    device, where the u8 entry's bitcast and reshape become XLA
+    copy/reshape/broadcast ops around the kernel that need over 32x
+    their input in temporaries (tests/test_tpu_compile.py, the u8 tail
+    path).
     ``name`` is what a profiler trace calls the kernel: the u8 entry
-    points pass their own, so a trace tells the four entries apart."""
+    passes its own, so a trace tells the two entries apart."""
     n_out, n_in = coefs.shape
     if (x4.ndim != 5 or x4.shape[1] != n_in
             or x4.shape[2] != GROUP_WORDS or x4.shape[4] != LANES):

@@ -340,8 +340,8 @@ def _make_apply_only_step(coefs: np.ndarray, mesh: Mesh):
     be wasted ICI traffic. On an accelerator the per-shard math is the
     fused Pallas kernel; elsewhere the XLA network.
 
-    The input shards are donated when the donation knob engages
-    (rs_jax.donation_enabled — real accelerators only): every caller
+    The input shards are donated under rs_jax.donation_enabled()'s rule
+    (a TPU backend only): every caller
     feeds a freshly device_put array that is never reused, so XLA may
     release the input HBM inside the computation — the same early-free
     win the single-device word-form path gets from _jitted_apply."""

@@ -98,15 +98,13 @@ class PipelineConfig:
     """Tuning knobs of the overlapped ingest plane (docs/pipeline.md).
 
     Flags > TOML > these defaults, like every other subsystem
-    (util/config.py). ``0`` means "derive": ``group_cap`` defers to
-    ``SEAWEEDFS_TPU_DISPATCH_GROUP``, ``pool_buffers`` is sized from
+    (util/config.py). ``pool_buffers`` 0 means "derive": sized from
     depth+group so groups can actually form.
     """
 
     depth: int = DEPTH                       # stage-queue depth
     batch_bytes: int = 256 * 1024 * 1024     # max input bytes per batch
     grouped_batch_bytes: int = GROUPED_BATCH_BYTES
-    group_cap: int = 0                       # max batches per dispatch
     writer_threads: int = 4                  # shard-writeback pool width
     writer_queue_depth: int = 4              # pending jobs per writer
     pool_buffers: int = 0                    # reusable host buffers
@@ -141,7 +139,7 @@ def configure_from(conf: dict) -> None:
     if not isinstance(sect, dict):
         return
     configure(**{k: sect.get(k) for k in (
-        "depth", "batch_bytes", "grouped_batch_bytes", "group_cap",
+        "depth", "batch_bytes", "grouped_batch_bytes",
         "writer_threads", "writer_queue_depth", "pool_buffers",
         "feedback", "overlapped", "preallocate", "double_buffer")})
 
@@ -154,15 +152,13 @@ def pick_grouped_dispatch(multi_fn, max_bytes: int,
     Group width comes from rs_jax.host_dispatch_group() — >1 only on a
     single-device accelerator (multi-chip paths mesh-shard each batch
     via parallel/mesh instead; CPU backends never take the word-form
-    device path) — clamped by ``[pipeline] group_cap`` when set. When
-    grouping is on, the per-item byte bound is clamped to ``cap_bytes``
-    (default: ``[pipeline] grouped_batch_bytes``)."""
+    device path). When grouping is on, the per-item byte bound is
+    clamped to ``cap_bytes`` (default: ``[pipeline]
+    grouped_batch_bytes``)."""
     from ..ops import rs_jax
     if cap_bytes is None:
         cap_bytes = _CONFIG.grouped_batch_bytes
     group = rs_jax.host_dispatch_group()
-    if _CONFIG.group_cap:
-        group = min(group, _CONFIG.group_cap)
     if group <= 1:
         return None, 1, max_bytes
     return multi_fn, group, min(max_bytes, cap_bytes)

@@ -162,11 +162,11 @@ def plan_chunking(k: int, chunk_bytes: int = DEFAULT_CHUNK_BYTES
         None, k * chunk_bytes)
     if group > 1:
         # the per-shard take IS the word-form S here, so it must stay a
-        # multiple of both kernels' segment sizes or _host_word_form
+        # multiple of the kernel's segment size or rs_pallas.conforms
         # rejects every chunk and the fast path never engages (k=10
         # makes a naive //k non-aligned)
         from ..ops import rs_pallas
-        align = max(rs_pallas.SEG_BYTES, rs_pallas.SWAR_SEG_BYTES)
+        align = rs_pallas.SEG_BYTES
         chunk_bytes = max(align, (grouped_total // k) // align * align)
     return group, chunk_bytes
 

@@ -567,7 +567,6 @@ def cmd_pipeline_status(env: CommandEnv, argv: list[str]) -> None:
         f"pipeline.status depth={cfg.depth} "
         f"batch_bytes={cfg.batch_bytes} "
         f"grouped_batch_bytes={cfg.grouped_batch_bytes} "
-        f"group_cap={cfg.group_cap or 'env'} "
         f"writers={cfg.writer_threads}x{cfg.writer_queue_depth} "
         f"feedback={cfg.feedback} overlapped={cfg.overlapped} "
         f"preallocate={cfg.preallocate} "

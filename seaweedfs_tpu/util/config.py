@@ -150,7 +150,6 @@ concurrency = 16
 depth = 2                        # stage-queue depth (double buffering)
 batch_bytes = 268435456          # max input bytes per device batch
 grouped_batch_bytes = 67108864   # per-batch clamp while grouping
-group_cap = 0                    # max batches/dispatch; 0 = env default
 writer_threads = 4               # positioned shard-write pool width
 writer_queue_depth = 4           # pending writes per writer thread
 pool_buffers = 0                 # reusable host buffers; 0 = derive

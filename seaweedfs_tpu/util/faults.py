@@ -5,8 +5,8 @@ Named *fault points* are compiled into every HTTP/gRPC/disk I/O path:
 sleep, or drop the call; ``faults.mangle("ec.shard_read", buf)`` runs on
 the bytes an operation returned and may truncate or corrupt them. With
 no faults armed — the default — both are one module-flag test, so the
-hot path pays a dict-is-empty check and nothing else (``bench.py
---fault-overhead`` holds that under 2%).
+hot path pays a dict-is-empty check and nothing else (its cost on a
+served read: not measured).
 
 A fault *spec* is a compact string::
 

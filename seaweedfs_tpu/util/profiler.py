@@ -10,8 +10,8 @@ Two modes share the sampling core:
   into a bounded per-process table. The top-k hot stacks ride the
   heartbeat telemetry snapshot (``TelemetrySnapshot.hot_stacks``), so
   ``volume.heatmap`` on the master can answer *what code* is hot on a
-  node without touching it. Cost is one frame walk per second —
-  ``bench.py --profile-overhead`` holds it under the 5% bar.
+  node without touching it. Cost is one frame walk per second (its
+  share of a served request: not measured).
 * **on-demand burst**: ``GET /debug/profile?seconds=N`` on any server
   runs a dedicated high-rate (default 97 Hz) capture for N seconds and
   returns the collapsed text, piped straight into

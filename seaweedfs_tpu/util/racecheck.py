@@ -178,8 +178,7 @@ class _RaceTracker:
         # worst falls through to the locked slow path). Without these,
         # a lock-protected cross-thread counter — permanently
         # shared-modified — would pay _mu contention plus a stack
-        # capture on EVERY write, and the <5% encode-overhead budget
-        # (bench.py --racecheck-overhead) is unmeetable.
+        # capture on EVERY write, which an armed encode cannot carry.
         st = self.states.get(key)
         if st is not None:
             state = st.state

@@ -107,7 +107,7 @@ def test_device_leg_is_counted(
         live, interpreted_tpu, monkeypatch):
     # what a process with ONE chip would do: grouped dispatch, no mesh
     monkeypatch.setattr(rs_jax, "host_dispatch_group",
-                        rs_jax._dispatch_group)
+                        lambda: rs_jax.DISPATCH_GROUP)
     monkeypatch.setattr(mesh_mod, "routing_mesh", lambda: None)
     lines = chip_smoke.drive_volume(live, "smoke", SIZE, seed=5, chips=1)
     by_phase = {ln["phase"]: ln for ln in lines}

@@ -21,7 +21,6 @@ def forced_pallas(monkeypatch):
     monkeypatch.setattr(rs_jax, "_use_pallas", lambda: True)
     monkeypatch.setattr(rs_jax, "PALLAS_MIN_S", 1024)
     monkeypatch.setattr(rs_jax, "HOST_DISPATCH", "device")
-    monkeypatch.setattr(rs_jax, "PALLAS_KERNEL", "transpose")
     real_w = rs_pallas.apply_gf_matrix_words
     monkeypatch.setattr(
         rs_pallas, "apply_gf_matrix_words",
@@ -53,8 +52,8 @@ def test_multi_groups_are_byte_exact(forced_pallas):
     assert rs_jax._jitted_apply_multi.cache_info().misses >= 1
 
 
-def test_multi_respects_group_cap(forced_pallas, monkeypatch):
-    monkeypatch.setattr(rs_jax, "DISPATCH_GROUP", "2")
+def test_multi_respects_dispatch_group(forced_pallas, monkeypatch):
+    monkeypatch.setattr(rs_jax, "DISPATCH_GROUP", 2)
     k, m, s = 4, 2, rs_pallas.SEG_BYTES
     rng = np.random.default_rng(2)
     enc = rs_jax.Encoder(k, m)
@@ -152,22 +151,37 @@ def test_reconstruct_multi_byte_exact(forced_pallas):
         np.testing.assert_array_equal(got[0, 1], full[5])
 
 
-def test_dispatch_group_env_validation(monkeypatch):
-    monkeypatch.setattr(rs_jax, "DISPATCH_GROUP", "banana")
-    with pytest.raises(ValueError, match="SEAWEEDFS_TPU_DISPATCH_GROUP"):
-        rs_jax._dispatch_group()
-    monkeypatch.setattr(rs_jax, "DISPATCH_GROUP", "0")
-    with pytest.raises(ValueError):
-        rs_jax._dispatch_group()
-    monkeypatch.setattr(rs_jax, "DISPATCH_GROUP", "4")
-    assert rs_jax._dispatch_group() == 4
+@pytest.mark.parametrize("n, widths", [(13, [8, 4, 1]), (20, [16, 4])])
+def test_run_splits_into_power_of_two_widths(forced_pallas, monkeypatch,
+                                             n, widths):
+    """A run of equal slabs goes out as power-of-two dispatches, a full
+    DISPATCH_GROUP first: the jit cache holds log2(16) widths per shape
+    whatever lengths the pipeline's drain hands over."""
+    assert rs_jax.DISPATCH_GROUP == 16
+    launched: list[int] = []
+    real_launch = rs_jax._launch
+
+    def launch(fn, xs, nbytes):
+        launched.append(len(xs))
+        return real_launch(fn, xs, nbytes)
+
+    monkeypatch.setattr(rs_jax, "_launch", launch)
+    k, m, s = 2, 1, rs_pallas.SEG_BYTES
+    rng = np.random.default_rng(n)
+    enc = rs_jax.Encoder(k, m)
+    batches = [rng.integers(0, 256, (1, k, s), dtype=np.uint8)
+               for _ in range(n)]
+    outs = enc.encode_parity_host_multi(batches)
+    assert launched == widths
+    for x, out in zip(batches, outs):
+        np.testing.assert_array_equal(np.asarray(out), _oracle(k, m, x))
 
 
 def test_rebuild_grouped_chunks_stay_seg_aligned(forced_pallas,
                                                  monkeypatch, tmp_path):
     """Regression (round-5 review): the grouped clamp divides the byte
     bound by k, which for most k is not segment-aligned — rebuild must
-    re-align the per-shard take or _host_word_form rejects every chunk
+    re-align the per-shard take or rs_pallas.conforms rejects every chunk
     and the fast path silently never engages. Proven end to end: an
     unaligned chunk_bytes request still rebuilds byte-identically AND
     the multi executable actually runs."""
@@ -194,7 +208,7 @@ def test_rebuild_grouped_chunks_stay_seg_aligned(forced_pallas,
     ec_files.shard_path(base, 0).unlink()
     before = rs_jax._jitted_apply_multi.cache_info()
     # deliberately unaligned request: the clamp must fix it, not
-    # forward it into _host_word_form
+    # forward it into the dispatch
     assert rebuild_ec_files(base, scheme,
                             chunk_bytes=seg + 1000) == [0]
     assert ec_files.shard_path(base, 0).read_bytes() == want0

@@ -2,9 +2,10 @@
 
 On CPU the accelerator predicate is false, so the fast path must defer
 to encode_parity (covered by every pipeline test). Here the predicate
-is forced and the words kernels run under the Pallas interpreter to
+is forced and the words kernel runs under the Pallas interpreter to
 prove the host word view -> words kernel -> u8 re-view chain is
-byte-exact vs the oracle, for both kernels."""
+byte-exact vs the oracle, and that the single-slab entry is nothing
+but the grouped one on a run of one."""
 
 import numpy as np
 import pytest
@@ -20,43 +21,91 @@ def forced_pallas(monkeypatch):
     # word-form device path, not the link-vs-codec routing (below)
     monkeypatch.setattr(rs_jax, "HOST_DISPATCH", "device")
     real_w = rs_pallas.apply_gf_matrix_words
-    real_s = rs_pallas.apply_gf_matrix_swar_words
     monkeypatch.setattr(
         rs_pallas, "apply_gf_matrix_words",
         lambda c, x, **kw: real_w(c, x, interpret=True))
-    monkeypatch.setattr(
-        rs_pallas, "apply_gf_matrix_swar_words",
-        lambda c, x, **kw: real_s(c, x, rows_per_block=8,
-                                  interpret=True))
     rs_jax._jitted_apply.cache_clear()
     yield
     rs_jax._jitted_apply.cache_clear()
 
 
-def _check(k, m, s, b=2, kernel="transpose", monkeypatch=None):
-    if monkeypatch is not None:
-        monkeypatch.setattr(rs_jax, "PALLAS_KERNEL", kernel)
+def test_words_fast_path(forced_pallas):
+    k, m, s, b = 4, 2, rs_pallas.SEG_BYTES, 2
     rng = np.random.default_rng(k * 31 + m)
     x = rng.integers(0, 256, (b, k, s), dtype=np.uint8)
     enc = rs_jax.Encoder(k, m)
     out = enc.encode_parity_host(x)
-    assert isinstance(out, rs_jax._HostParity), \
-        f"fast path not taken for {kernel}"
+    assert isinstance(out, rs_jax._HostParity), "fast path not taken"
     got = np.asarray(out)
     ref = rs_ref.ReferenceEncoder(k, m)
     want = np.stack([ref.encode_parity(xb) for xb in x])
     np.testing.assert_array_equal(got, want)
 
 
-def test_transpose_words_fast_path(forced_pallas, monkeypatch):
-    _check(4, 2, rs_pallas.SEG_BYTES, kernel="transpose",
-           monkeypatch=monkeypatch)
+def test_host_words_matches_device_bitcast():
+    """The host's zero-copy word view is the array the u8 entry builds
+    on the device with a bitcast and a reshape (rs_pallas.
+    apply_gf_matrix): the same bytes reach the kernel either way."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(0)
+    b, k, s = 2, 3, 2 * rs_pallas.SEG_BYTES
+    x = rng.integers(0, 256, (b, k, s), dtype=np.uint8)
+    w = s // 4
+    on_device = np.asarray(jax.lax.bitcast_convert_type(
+        jnp.asarray(x).reshape(b, k, w, 4), jnp.uint32).reshape(
+        b, k, rs_pallas.GROUP_WORDS,
+        w // (rs_pallas.GROUP_WORDS * rs_pallas.LANES), rs_pallas.LANES))
+    view = rs_jax._host_word_form(x)
+    assert view.dtype == np.uint32
+    np.testing.assert_array_equal(view, on_device)
+    assert np.shares_memory(view, x), "the word form copied the slab"
 
 
-def test_swar_words_fast_path(forced_pallas, monkeypatch):
-    # swar_conforms needs S % SWAR_SEG_BYTES == 0
-    _check(4, 2, rs_pallas.SWAR_SEG_BYTES, b=1, kernel="swar",
-           monkeypatch=monkeypatch)
+@pytest.mark.parametrize("s, leg", [
+    (rs_pallas.SEG_BYTES, "device"),          # conforms: rs_words
+    (rs_pallas.SEG_BYTES + 1024, "device"),   # tail: the u8 entry
+    (512, "native"),                          # under PALLAS_MIN_S
+], ids=["conforming", "nonconforming", "sub_min"])
+def test_single_entry_is_the_grouped_one(forced_pallas, monkeypatch,
+                                         s, leg):
+    """apply_matrix_host(x) is apply_matrix_host_multi([x])[0]: the
+    same bytes, the same leg counted once, and the same cached
+    single-slab executable on the second call."""
+    from seaweedfs_tpu.ops import rs_native
+    if leg == "native" and not rs_native.available():
+        leg = "xla"
+    real_u8 = rs_pallas.apply_gf_matrix
+    monkeypatch.setattr(rs_pallas, "apply_gf_matrix",
+                        lambda c, x, **kw: real_u8(c, x, interpret=True))
+    k, m = 4, 2
+    rng = np.random.default_rng(s)
+    x = rng.integers(0, 256, (1, k, s), dtype=np.uint8)
+    coefs = rs_jax.Encoder(k, m).parity_coefs
+    want = np.stack([rs_ref.ReferenceEncoder(k, m).encode_parity(x[0])])
+
+    def legs():
+        return dict(rs_jax.debug_payload()["leg_bytes"])
+
+    before = legs()
+    single = rs_jax.apply_matrix_host(coefs, x)
+    mid = legs()
+    entries = rs_jax._jitted_apply.cache_info().currsize
+    multi_entries = rs_jax._jitted_apply_multi.cache_info().currsize
+    grouped = rs_jax.apply_matrix_host_multi(coefs, [x])[0]
+    after = legs()
+    assert type(single) is type(grouped)
+    assert isinstance(single, rs_jax._HostParity) == \
+        rs_pallas.conforms(s)
+    np.testing.assert_array_equal(np.asarray(single), want)
+    np.testing.assert_array_equal(np.asarray(grouped), want)
+    for name in before:
+        step = x.nbytes if name == leg else 0
+        assert mid[name] - before[name] == step, name
+        assert after[name] - mid[name] == step, name
+    assert rs_jax._jitted_apply.cache_info().currsize == entries
+    assert rs_jax._jitted_apply_multi.cache_info().currsize == \
+        multi_entries
 
 
 def test_defers_when_not_eligible(forced_pallas):
@@ -128,8 +177,7 @@ def test_small_payloads_use_native_on_any_backend(monkeypatch):
     np.testing.assert_array_equal(np.asarray(y_dev), want)
 
 
-def test_reconstruct_batch_host_fast_path(forced_pallas, monkeypatch):
-    monkeypatch.setattr(rs_jax, "PALLAS_KERNEL", "transpose")
+def test_reconstruct_batch_host_fast_path(forced_pallas):
     k, m, s = 4, 2, rs_pallas.SEG_BYTES
     rng = np.random.default_rng(5)
     x = rng.integers(0, 256, (1, k, s), dtype=np.uint8)

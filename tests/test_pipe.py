@@ -262,12 +262,23 @@ def test_pipeline_config_scaffold_round_trips(pipe_config):
     assert cfg.feedback and cfg.overlapped and cfg.preallocate
 
 
+def test_pipeline_config_fields_are_the_scaffold_keys():
+    """[pipeline] has one list of fields: what the dataclass holds,
+    what the scaffold documents and what configure_from reads."""
+    conf = config_mod._parse_toml_subset(config_mod.scaffold("pipeline"))
+    fields = {f.name for f in dataclasses.fields(pipe.PipelineConfig)}
+    assert set(conf["pipeline"]) == fields
+    assert len(fields) == 10
+    for name, value in conf["pipeline"].items():
+        assert value == getattr(pipe.PipelineConfig(), name), name
+
+
 def test_configure_from_applies_partial_section(pipe_config):
     pipe.configure_from({"pipeline": {"depth": 7, "overlapped": False,
-                                      "group_cap": 3}})
+                                      "writer_threads": 3}})
     cfg = pipe.current()
     assert cfg.depth == 7 and cfg.overlapped is False
-    assert cfg.group_cap == 3
+    assert cfg.writer_threads == 3
     assert cfg.batch_bytes == 256 * 1024 * 1024  # untouched keys keep
     pipe.configure_from({})  # no [pipeline] section: a no-op
     assert pipe.current().depth == 7
@@ -278,14 +289,19 @@ def test_configure_rejects_unknown_keys(pipe_config):
         pipe.configure(qdepth=3)
 
 
-def test_group_cap_clamps_grouped_dispatch(pipe_config, monkeypatch):
+def test_grouped_dispatch_follows_host_dispatch_group(pipe_config,
+                                                      monkeypatch):
+    """The width is rs_jax.host_dispatch_group()'s and nothing clamps
+    it on the way: [pipeline] only bounds the bytes of a grouped item."""
     from seaweedfs_tpu.ops import rs_jax
-    monkeypatch.setattr(rs_jax, "host_dispatch_group", lambda: 16)
-    pipe.configure(group_cap=4)
+    monkeypatch.setattr(rs_jax, "host_dispatch_group", lambda: 4)
     multi, group, nbytes = pipe.pick_grouped_dispatch(
         lambda bs: bs, 256 * 1024 * 1024)
     assert multi is not None and group == 4
     assert nbytes == pipe.current().grouped_batch_bytes
+    monkeypatch.setattr(rs_jax, "host_dispatch_group", lambda: 1)
+    assert pipe.pick_grouped_dispatch(
+        lambda bs: bs, 256 * 1024 * 1024) == (None, 1, 256 * 1024 * 1024)
 
 
 # -- positioned-write pool ----------------------------------------------

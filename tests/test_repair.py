@@ -1,11 +1,10 @@
-"""Interval micro-batch aggregator + repair-under-load harness."""
+"""Interval micro-batch aggregator."""
 
 import threading
 
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.pipeline import repair_bench
 from seaweedfs_tpu.pipeline.repair import IntervalRepairAggregator
 from seaweedfs_tpu.pipeline.scheme import EcScheme
 
@@ -62,18 +61,3 @@ def test_aggregator_single_and_batched():
         # batching actually happened (fewer device calls than requests)
         assert agg.requests == 41
         assert agg.batches < agg.requests
-
-
-def test_repair_under_load_harness(tmp_path):
-    """Config-5 smoke: repairs verified under concurrency, stats sane."""
-    res = repair_bench.run(duration_s=1.5, qps=64,
-                           shard_len=256 * 1024,
-                           interval_size=1024,
-                           bulk_chunk=64 * 1024,
-                           scheme=SCHEME,
-                           workdir=str(tmp_path))
-    assert res["reads"] > 20, res
-    assert res["decode_gibps"] > 0
-    assert res["read_p99_ms"] > 0
-    assert res["agg_requests"] >= res["reads"]
-    assert res["bulk_chunks"] >= 4

@@ -124,3 +124,23 @@ def test_split_encode_reconstruct_join_roundtrip():
         shards[i] = None
     enc.reconstruct(shards)
     assert enc.join(shards, len(payload)) == payload
+
+
+def test_the_package_reads_three_variables_of_its_prefix():
+    """The knob surface: one codec variable (where large host slabs are
+    computed) and the cold tier's two credentials. A new
+    ``SEAWEEDFS_TPU_*`` read has to be added here, with its reason."""
+    import re
+    from pathlib import Path
+
+    import seaweedfs_tpu
+
+    root = Path(seaweedfs_tpu.__file__).resolve().parent
+    found = set()
+    for path in root.rglob("*.py"):
+        found |= set(re.findall(r"SEAWEEDFS_TPU_[A-Z0-9_]+",
+                                path.read_text()))
+    assert found == {"SEAWEEDFS_TPU_HOST_DISPATCH",
+                     "SEAWEEDFS_TPU_TIER_ACCESS_KEY",
+                     "SEAWEEDFS_TPU_TIER_SECRET_KEY"}
+    assert rs_jax.HOST_DISPATCH in ("auto", "device", "native")

@@ -3,7 +3,8 @@
 Mosaic only compiles for TPU, so on the CPU test backend the kernel runs
 through the Pallas interpreter — same trace, same layout trick, ~100x
 slower, hence the minimal shapes (SEG_BYTES is the kernel's granularity
-floor). The real-chip path is exercised by bench.py and the driver.
+floor). The chip's own compiler sees the kernel in test_tpu_compile.py;
+the chip itself runs it in chip_smoke.py and the driver's benchmark.
 """
 
 import numpy as np
@@ -69,43 +70,25 @@ def test_conforms_and_shape_errors():
             enc.parity_coefs, jnp.zeros((1, 3, SEG), jnp.uint8))
 
 
-@pytest.mark.parametrize("k,m", [(10, 4), (6, 3)])
-def test_swar_kernel_matches_oracle(k, m):
-    """The transpose-free SWAR kernel (in-word bitplanes) is bit-exact."""
-    rng = np.random.default_rng(k + m)
-    seg = 4 * 8 * 128  # rows_per_block=8 keeps interpret tractable
-    x = rng.integers(0, 256, (1, k, 2 * seg), dtype=np.uint8)
-    enc = rs_jax.Encoder(k, m)
-    got = np.asarray(rs_pallas.apply_gf_matrix_swar(
-        enc.parity_coefs, jnp.asarray(x), interpret=True, rows_per_block=8))
-    np.testing.assert_array_equal(got, _oracle_parity(x, k, m))
-
-
-def test_swar_kernel_reconstruct_rows():
-    rng = np.random.default_rng(9)
-    seg = 4 * 8 * 128
-    x = rng.integers(0, 256, (1, 10, seg), dtype=np.uint8)
+@pytest.mark.parametrize("lost", [[3], [0, 11], [1, 6, 13],
+                                  [1, 6, 11, 13]],
+                         ids=lambda lost: f"lost{len(lost)}")
+def test_words_kernel_rebuilds_lost_shards(lost):
+    """The word-form entry on the rebuild's shapes: decode rows for 1 to
+    4 lost shards of RS(10,4), data and parity alike, against what the
+    reference encoder had made of the same data."""
+    rng = np.random.default_rng(len(lost))
+    x = rng.integers(0, 256, (1, 10, SEG), dtype=np.uint8)
     enc = rs_jax.Encoder(10, 4)
-    parity = _oracle_parity(x, 10, 4)
-    full = np.concatenate([x, parity], axis=1)
-    present = [0, 1, 2, 3, 4, 6, 7, 8, 9, 10]  # lost shards 5, 11-13
-    rows = enc.decode_matrix_rows(present, [5, 13])
-    surv = np.ascontiguousarray(full[:, present, :])
-    got = np.asarray(rs_pallas.apply_gf_matrix_swar(
-        rows, jnp.asarray(surv[:, :10, :]), interpret=True,
-        rows_per_block=8))
-    np.testing.assert_array_equal(got, full[:, [5, 13], :])
-
-
-def test_swar_conforms_and_errors():
-    assert rs_pallas.swar_conforms(rs_pallas.SWAR_SEG_BYTES)
-    assert rs_pallas.swar_conforms(4 * 8 * 128, rows_per_block=8)
-    assert not rs_pallas.swar_conforms(0)
-    assert not rs_pallas.swar_conforms(4 * 8 * 128 - 4, rows_per_block=8)
-    enc = rs_jax.Encoder(4, 2)
-    with pytest.raises(ValueError):
-        rs_pallas.apply_gf_matrix_swar(
-            enc.parity_coefs, jnp.zeros((1, 4, 256), jnp.uint8))
+    full = np.concatenate([x, _oracle_parity(x, 10, 4)], axis=1)
+    present = [i for i in range(14) if i not in lost]
+    rows = enc.decode_matrix_rows(present, lost)
+    assert rows.shape == (len(lost), 10)
+    surv = np.ascontiguousarray(full[:, present[:10], :])
+    got = np.asarray(rs_pallas.apply_gf_matrix_words(
+        rows, jnp.asarray(rs_jax._host_word_form(surv)), interpret=True))
+    np.testing.assert_array_equal(
+        got.view(np.uint8).reshape(1, len(lost), SEG), full[:, lost, :])
 
 
 def test_chunked_xla_path_matches(monkeypatch):

@@ -226,7 +226,6 @@ def one_chip_device_leg(monkeypatch):
     monkeypatch.setattr(rs_jax, "_use_pallas", lambda: True)
     monkeypatch.setattr(rs_jax, "PALLAS_MIN_S", 1024)
     monkeypatch.setattr(rs_jax, "HOST_DISPATCH", "device")
-    monkeypatch.setattr(rs_jax, "PALLAS_KERNEL", "transpose")
     monkeypatch.setattr(mesh_mod, "routing_mesh", lambda: None)
     real = rs_pallas.apply_gf_matrix_words
     monkeypatch.setattr(rs_pallas, "apply_gf_matrix_words",

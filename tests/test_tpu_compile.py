@@ -66,12 +66,12 @@ def one_tpu_dispatch(monkeypatch):
     ask rs_jax.host_dispatch_group(), which sees this process's CPU
     devices — steered here, in the test, not by an option."""
     monkeypatch.setattr(rs_jax, "host_dispatch_group",
-                        rs_jax._dispatch_group)
+                        lambda: rs_jax.DISPATCH_GROUP)
 
 
 def _words(shape_u8, sharding):
     """Word-form spec of a (B, n_in, S) u8 batch, as _host_word_form
-    views it for the transpose kernel."""
+    views it for the kernel."""
     b, n_in, s = shape_u8
     assert rs_pallas.conforms(s)
     r = s // 4 // (rs_pallas.GROUP_WORDS * rs_pallas.LANES)
@@ -92,7 +92,7 @@ def _encode_shapes(scheme, max_batch_bytes):
 def _grouped_bytes():
     _, group, max_bytes = pipe.pick_grouped_dispatch(
         None, pipe.current().batch_bytes)
-    assert group == rs_jax._dispatch_group() > 1
+    assert group == rs_jax.DISPATCH_GROUP > 1
     return group, max_bytes
 
 
@@ -124,7 +124,7 @@ def test_grouped_encode_fits_hbm(one_chip, one_tpu_dispatch, width):
     shape = _encode_shapes(DEFAULT_SCHEME, max_bytes)[0]
     coefs = DEFAULT_SCHEME.encoder.parity_coefs
     fn = rs_jax._jitted_apply_multi(coefs.tobytes(), *coefs.shape,
-                                    "pallas_words", width, donate=True)
+                                    width, donate=True)
     mem = _compile(fn, *[_words(shape, one_chip)] * width
                    ).memory_analysis()
     per_group = (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -174,22 +174,20 @@ def test_u8_tail_path(one_chip, one_tpu_dispatch):
     assert mem.temp_size_in_bytes < V5E_HBM_BYTES
 
 
-def test_swar_words_at_its_default_block(one_chip, one_tpu_dispatch):
-    """SEAWEEDFS_TPU_KERNEL=swar must select a kernel the chip accepts:
-    SWAR_ROWS rows compile, twice that asks more scoped VMEM than the
-    compiler allows."""
-    _, max_bytes = _grouped_bytes()
-    b, k, s = _encode_shapes(DEFAULT_SCHEME, max_bytes)[0]
-    assert rs_pallas.swar_conforms(s)
-    spec = jax.ShapeDtypeStruct(
-        (b, k, s // 4 // rs_pallas.LANES, rs_pallas.LANES), jnp.uint32,
-        sharding=one_chip)
-    _compile(_encode_fn(DEFAULT_SCHEME, "pallas_swar_words"), spec)
+def test_step_and_kernel_names(one_chip):
+    """The names the benchmark's ledger and the compile cache rest on:
+    a dispatch of w slabs is the program ``rs_pallas_words_g<w>`` and
+    its kernel is ``rs_words``, for every width a run can split into."""
     coefs = DEFAULT_SCHEME.encoder.parity_coefs
-    too_big = jax.jit(lambda x: rs_pallas.apply_gf_matrix_swar_words(
-        coefs, x, rows_per_block=2 * rs_pallas.SWAR_ROWS))
-    with pytest.raises(Exception, match="(?i)vmem"):
-        too_big.lower(spec).compile()
+    spec = _words((1, coefs.shape[1], rs_pallas.SEG_BYTES), one_chip)
+    assert rs_jax.DISPATCH_GROUP == 16
+    for width in (1, 2, 4, 8, 16):
+        fn = _encode_fn(DEFAULT_SCHEME) if width == 1 else \
+            rs_jax._jitted_apply_multi(coefs.tobytes(), *coefs.shape,
+                                       width, donate=True)
+        text = fn.lower(*[spec] * width).as_text()
+        assert f"@jit_rs_pallas_words_g{width} " in text
+        assert text.count('kernel_name = "rs_words"') == width
 
 
 def test_sharded_step_on_2x2_mesh(topo, monkeypatch):
