@@ -55,18 +55,10 @@ def expand_gf2(coefs: np.ndarray) -> np.ndarray:
     """
     coefs = np.asarray(coefs, dtype=np.uint8)
     r_n, c_n = coefs.shape
-    out = np.zeros((8 * r_n, 8 * c_n), dtype=bool)
-    for r in range(r_n):
-        for c in range(c_n):
-            v = int(coefs[r, c])
-            if v == 0:
-                continue
-            for j in range(8):
-                prod = gf256.gf_mul(v, 1 << j)
-                for i in range(8):
-                    if (prod >> i) & 1:
-                        out[8 * r + i, 8 * c + j] = True
-    return out
+    # prod[r, c, j] = coefs[r, c] * x^j; bit i of it is out[8r+i, 8c+j]
+    prod = gf256.mul_table()[coefs[:, :, None], 1 << np.arange(8)]
+    bits = (prod[:, None, :, :] >> np.arange(8)[:, None, None]) & 1
+    return bits.astype(bool).reshape(8 * r_n, 8 * c_n)
 
 
 def transpose32(a: jnp.ndarray) -> jnp.ndarray:

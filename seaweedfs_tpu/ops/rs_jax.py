@@ -4,15 +4,20 @@ three legs, and the one place that chooses between them.
 This is the replacement for the reference's
 ``klauspost/reedsolomon.Encoder`` (SURVEY.md §2 L0): the same method
 surface as ops/rs_ref.py, over batched ``(B, k, S)`` uint8 arrays. One
-``Encoder`` instance serves any batch size; jitted executables are cached
-per (coefficient matrix, variant) and jit's own cache holds the shapes.
-The legs, as /debug/vars ("codec") counts their bytes:
+``Encoder`` instance serves any batch size; jit's own cache holds the
+shapes. The legs, as /debug/vars ("codec") counts their bytes:
 
 * ``device`` — the Pallas kernel of ops/rs_pallas.py, on a TPU, for
   shards of at least PALLAS_MIN_S bytes. A HOST slab whose S conforms
-  to the kernel's segment is viewed (zero-copy) in word form and runs
-  ``rs_words`` through apply_matrix_host_multi, grouped up to
-  host_dispatch_group() slabs per dispatch: every EC pipeline's path.
+  to the kernel's segment is viewed (zero-copy) in word form and goes
+  through apply_matrix_host_multi, grouped up to host_dispatch_group()
+  slabs per dispatch: every EC pipeline's path. An encode runs
+  ``rs_words``: the parity matrix is a constant of the codec, built
+  into the program with its XOR network factored. A reconstruct runs
+  ``rs_words_mat``: the decode matrix follows the shards that were
+  lost, so it is an ARGUMENT of the program (DecodeMatrix.operand),
+  and a process compiles one decode program per (rows wanted, chunk
+  shape, group width), whatever the loss patterns it meets.
   Anything else that reaches the device — a tail that does not conform,
   a device-resident array, the mesh route — goes through apply_matrix
   to the u8 entry ``rs_u8``, which pads S and relays the bytes out on
@@ -29,7 +34,11 @@ Reconstruction follows klauspost ``reconstruct`` semantics: take the first
 k surviving shard indices, invert those k rows of the code matrix on the
 host (tiny GF(2^8) Gauss-Jordan), and apply the needed rows through the
 same legs as encode. The inverted matrices are memoized per survivor
-set, mirroring klauspost's inversion_tree.go cache.
+set, mirroring klauspost's inversion_tree.go cache: host data, a
+hundred bytes each. What is keyed by a matrix's bytes and compiles per
+matrix is the u8 entry alone (apply_matrix: tails that do not conform,
+device-resident arrays, the mesh) and the encode programs, whose one
+matrix never changes.
 """
 
 from __future__ import annotations
@@ -102,6 +111,12 @@ _place_compile_cache()
 #: served at /debug/vars ("codec"): the hybrid policy may keep an
 #: encode on the host with the chip idle, and nothing else says so.
 _leg_bytes = {"device": 0, "native": 0, "xla": 0}
+#: Times a jitted codec step of this module was traced (every trace is
+#: a program compiled or fetched from the compile cache), and the
+#: distinct (survivors, wanted) sets Encoder.decode_matrix was asked
+#: for: a second loss pattern must add to the second and not the first.
+_programs_traced = 0
+_decode_patterns: set = set()
 _leg_lock = threading.Lock()
 
 
@@ -117,12 +132,20 @@ def count_leg(leg: str, nbytes: int) -> None:
         _leg_bytes[leg] += int(nbytes)
 
 
+def _count_trace() -> None:
+    """Called from the body of a step as JAX traces it."""
+    global _programs_traced
+    with _leg_lock:
+        _programs_traced += 1
+
+
 def debug_payload() -> dict:
     """``/debug/vars`` "codec" section (util/varz.py). ``device`` stays
     None until :func:`backend` has run: serving a debug page must not
     be what initialises (and claims) the accelerator."""
     with _leg_lock:
         legs = dict(_leg_bytes)
+        traced, patterns = _programs_traced, len(_decode_patterns)
     link, native = _link_gibps, _native_gibps
     if link is None or native is None:
         choice = None
@@ -132,6 +155,8 @@ def debug_payload() -> dict:
         "device": dict(_device_info())
         if _device_info.cache_info().currsize else None,
         "leg_bytes": legs,
+        "programs_traced": traced,
+        "decode_patterns": patterns,
         "host_dispatch": HOST_DISPATCH,
         "link_gibps": link,
         "native_gibps": native,
@@ -314,15 +339,19 @@ def _jitted_apply(coefs_bytes: bytes, n_out: int, n_in: int, variant: str,
 
     if variant == "pallas":
         def apply_fn(x: jnp.ndarray) -> jnp.ndarray:
+            _count_trace()
             return rs_pallas.apply_gf_matrix(coefs, x)
     elif variant == "pallas_words":
         def apply_fn(x4: jnp.ndarray) -> jnp.ndarray:
+            _count_trace()
             return rs_pallas.apply_gf_matrix_words(coefs, x4)
     elif variant == "xla":
         def apply_fn(x: jnp.ndarray) -> jnp.ndarray:
+            _count_trace()
             return bitslice.apply_gf_matrix(coefs, x)
     else:  # "xla_chunked": x is (B, n_in, nc, sc)
         def apply_fn(x: jnp.ndarray) -> jnp.ndarray:
+            _count_trace()
             # lax.map over column chunks keeps live intermediates to one
             # chunk's worth while XLA still fuses within each step.
             xc = x.transpose(2, 0, 1, 3)
@@ -357,10 +386,37 @@ def _jitted_apply_multi(coefs_bytes: bytes, n_out: int, n_in: int,
 
     def apply_fn(*xs):
         assert len(xs) == nargs
+        _count_trace()
         return tuple(rs_pallas.apply_gf_matrix_words(coefs, x) for x in xs)
 
     _name_step(apply_fn, "pallas_words", nargs)
     return jax.jit(apply_fn, donate_argnums=tuple(range(nargs))) \
+        if donate else jax.jit(apply_fn)
+
+
+@functools.lru_cache(maxsize=64)
+def _jitted_apply_mat(n_out: int, n_in: int, nargs: int,
+                      donate: bool = False):
+    """The decode callers' executable, one per (matrix shape, group
+    width) and none per matrix: the expanded matrix
+    (rs_pallas.matrix_operand) is its first argument, nargs word-form
+    slabs follow, nargs results come out. ``donate`` hands the slabs,
+    and not the matrix, to XLA. The kernel sits in a jitted function of
+    one slab that the step calls nargs times: JAX traces the kernel's
+    body once for the program and not once per slab, and tracing is
+    nearly all a new width costs (0.3 s against 3.0 s for sixteen
+    slabs, lowered for a described v5e)."""
+    @jax.jit
+    def one_slab(mat, x4):
+        return rs_pallas.apply_gf_matrix_words_mat(mat, x4, n_out)
+
+    def apply_fn(mat, *xs):
+        assert len(xs) == nargs
+        _count_trace()
+        return tuple(one_slab(mat, x) for x in xs)
+
+    _name_step(apply_fn, "pallas_words_mat", nargs)
+    return jax.jit(apply_fn, donate_argnums=tuple(range(1, nargs + 1))) \
         if donate else jax.jit(apply_fn)
 
 
@@ -401,7 +457,7 @@ class _HostParity:
 def apply_matrix_host(coefs: np.ndarray, batch):
     """HOST (B, n_in, S) uint8 -> async result whose ``np.asarray``
     yields (B, n_out, S) uint8: apply_matrix_host_multi on a run of one
-    (Encoder.encode_parity_host / reconstruct_batch_host)."""
+    (Encoder.encode_parity_host)."""
     return apply_matrix_host_multi(coefs, [batch])[0]
 
 
@@ -445,7 +501,8 @@ def _host_word_form(batch: np.ndarray) -> np.ndarray:
         rs_pallas.LANES)
 
 
-def apply_matrix_host_multi(coefs: np.ndarray, batches):
+def apply_matrix_host_multi(coefs: np.ndarray, batches,
+                            matrix: "Optional[DecodeMatrix]" = None):
     """A list of HOST (B, n_in, S) uint8 slabs -> a list of async
     results in the same order, each yielding (B, n_out, S) uint8 under
     ``np.asarray``. THE host-slab dispatch: every EC pipeline's encode
@@ -454,17 +511,24 @@ def apply_matrix_host_multi(coefs: np.ndarray, batches):
     A slab the Pallas dispatch applies to (_host_eligible) stays on the
     host codec when the hybrid rule says so; otherwise, when its S
     conforms, it is VIEWED (zero-copy) in the kernel's word form and fed
-    to ``rs_words`` — none of the XLA copy/reshape/broadcast glue of the
+    to the kernel — none of the XLA copy/reshape/broadcast glue of the
     u8 path. Everything else defers to apply_matrix.
 
+    ``matrix`` is what tells a reconstruct from an encode: a decode
+    caller hands over its DecodeMatrix (whose rows ``coefs`` are), and
+    its word-form dispatches run the program that takes the expanded
+    matrix as an argument (``rs_words_mat``, _jitted_apply_mat);
+    without it ``coefs`` is built into the program (``rs_words``),
+    which is right for the one matrix an encoder has.
+
     Runs of adjacent, identically-shaped word-form slabs are dispatched
-    as ONE jitted call with up to DISPATCH_GROUP slab args
-    (_jitted_apply_multi), amortizing the per-dispatch launch+sync
-    floor; a shape change or a full group flushes, and a flushed run is
-    split into power-of-two sub-dispatches — so the jit cache sees at
-    most log2(group) (shape, width) pairs per workload, never a retrace
-    storm (the pipeline's greedy drain yields arbitrary run lengths). A
-    lone slab runs the single-slab executable (_jitted_apply)."""
+    as ONE jitted call with up to DISPATCH_GROUP slab args, amortizing
+    the per-dispatch launch+sync floor; a shape change or a full group
+    flushes, and a flushed run is split into power-of-two
+    sub-dispatches — so the jit cache sees at most log2(group) (shape,
+    width) pairs per workload, never a retrace storm (the pipeline's
+    greedy drain yields arbitrary run lengths). An encode's lone slab
+    runs the single-slab executable (_jitted_apply)."""
     coefs = np.ascontiguousarray(coefs, dtype=np.uint8)
     n_out, n_in = coefs.shape
     key = (coefs.tobytes(), n_out, n_in)
@@ -477,7 +541,12 @@ def apply_matrix_host_multi(coefs: np.ndarray, batches):
         count_leg("device", nbytes)
         words = [_host_word_form(batches[i]) for i in ixs]
         xs = _submit(words)
-        if len(ixs) == 1:
+        if matrix is not None:
+            fn = _jitted_apply_mat(n_out, n_in, len(ixs),
+                                   donate=donation_enabled())
+            ys = _launch(functools.partial(fn, matrix.on_device()), xs,
+                         nbytes)
+        elif len(ixs) == 1:
             fn = _jitted_apply(*key, "pallas_words",
                                donate=donation_enabled())
             ys = [_launch(fn, xs, nbytes)]
@@ -581,6 +650,48 @@ def apply_matrix(coefs: np.ndarray, x) -> "np.ndarray | jnp.ndarray":
     return y[0] if squeeze else y
 
 
+class DecodeMatrix:
+    """The rows that rebuild the wanted shards from the first k
+    survivors, in the two forms the legs take: ``rows``, (n_out, k)
+    GF(2^8) coefficients (native, xla, the u8 entry), and ``operand``,
+    the same matrix expanded for ``rs_words_mat``
+    (rs_pallas.matrix_operand) — data, handed over with every dispatch,
+    so that no program is built for this loss in particular."""
+
+    __slots__ = ("rows", "operand", "_device")
+
+    def __init__(self, rows: np.ndarray):
+        self.rows = np.ascontiguousarray(rows, dtype=np.uint8)
+        self.operand = rs_pallas.matrix_operand(self.rows)
+        self._device = None
+
+    def on_device(self):
+        """``operand`` as a device array, transferred when the first
+        device dispatch asks and kept: a run's later dispatches pass
+        what is there (a host array would cross the link with each,
+        ~1.7 ms a dispatch on the v5e host, PERF.md §6)."""
+        if self._device is None:
+            self._device = jnp.asarray(self.operand)
+        return self._device
+
+    def apply_host(self, shards):
+        """HOST (B, >= k, S) uint8 survivors -> async (B, n_out, S)."""
+        return self.apply_host_multi([shards])[0]
+
+    def apply_host_multi(self, chunks):
+        """A list of HOST survivor chunks -> a list of async rebuilt
+        shards (apply_matrix_host_multi, the matrix as an argument)."""
+        k = self.rows.shape[1]
+        prepared = []
+        for c in chunks:
+            chosen = c[:, :k, :]
+            if (isinstance(chosen, np.ndarray)
+                    and not chosen.flags.c_contiguous):
+                chosen = np.ascontiguousarray(chosen)
+            prepared.append(chosen)
+        return apply_matrix_host_multi(self.rows, prepared, matrix=self)
+
+
 class Encoder:
     """Parametrized RS(k, m) with the klauspost Encoder method set,
     executing on whatever backend JAX targets (TPU v5e here; XLA:CPU is
@@ -627,14 +738,9 @@ class Encoder:
     def reconstruct_batch_host(self, shards, present: Sequence[int],
                                wanted: Optional[Sequence[int]] = None):
         """reconstruct_batch for HOST survivor arrays — rides the
-        zero-relayout word-form path when eligible (apply_matrix_host).
+        zero-relayout word-form path when eligible.
         ``shards``: (B, len(present), S) uint8 np array."""
-        rows = self._decode_rows_for(present, wanted)
-        chosen = shards[:, :self.data_shards, :]
-        if (isinstance(chosen, np.ndarray)
-                and not chosen.flags.c_contiguous):
-            chosen = np.ascontiguousarray(chosen)
-        return apply_matrix_host(rows, chosen)
+        return self.decode_matrix(present, wanted).apply_host(shards)
 
     def reconstruct_batch_host_multi(self, chunks,
                                      present: Sequence[int],
@@ -643,16 +749,19 @@ class Encoder:
         """Grouped reconstruct_batch_host: a list of HOST
         (B, len(present), S) uint8 chunks sharing one survivor set ->
         a list of async rebuilt shards, with runs of same-shaped chunks
-        dispatched as one device call (apply_matrix_host_multi)."""
-        rows = self._decode_rows_for(present, wanted)
-        prepared = []
-        for c in chunks:
-            chosen = c[:, :self.data_shards, :]
-            if (isinstance(chosen, np.ndarray)
-                    and not chosen.flags.c_contiguous):
-                chosen = np.ascontiguousarray(chosen)
-            prepared.append(chosen)
-        return apply_matrix_host_multi(rows, prepared)
+        dispatched as one device call (apply_matrix_host_multi). A
+        caller with many lists for one loss (pipeline/rebuild.py) takes
+        decode_matrix once and applies that."""
+        return self.decode_matrix(present, wanted).apply_host_multi(chunks)
+
+    def decode_matrix(self, present: Sequence[int],
+                      wanted: Optional[Sequence[int]] = None
+                      ) -> "DecodeMatrix":
+        """The host's whole share of a reconstruct, once per loss:
+        invert the survivors' rows, compose the parity rows wanted, and
+        expand the result for the kernel (span ``decode_matrix``)."""
+        with flight.span("decode_matrix"):
+            return DecodeMatrix(self._decode_rows_for(present, wanted))
 
     def _decode_rows_for(self, present: Sequence[int],
                          wanted: Optional[Sequence[int]]) -> np.ndarray:
@@ -664,7 +773,11 @@ class Encoder:
             wanted = sorted(missing)
         if not wanted:
             raise ValueError("nothing to reconstruct")
-        return self.decode_matrix_rows(present, wanted)
+        rows = self.decode_matrix_rows(present, wanted)
+        with _leg_lock:
+            _decode_patterns.add((tuple(present[:self.data_shards]),
+                                  tuple(wanted)))
+        return rows
 
     def encode_batch(self, data) -> jnp.ndarray:
         """data (..., k, S) -> all shards (..., k+m, S) (data passthrough

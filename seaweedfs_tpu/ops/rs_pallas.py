@@ -24,6 +24,17 @@ u32 tiles:
 * each bit plane (d, j) is ``a4[d, :, j]`` of shape (4, RB, 128) — the
   byte-within-word axis rides along as a leading dim, so the unrolled
   XOR network never touches a partially-filled tile.
+
+What is a constant of a program and what is data. ``rs_words`` /
+``rs_u8`` (apply_gf_matrix_words, apply_gf_matrix) take the matrix as a
+Python value: its GF(2) expansion is unrolled at trace time into a
+factored XOR network (ops/xor_cse.py), so each matrix is an executable
+of its own — right for the ENCODE matrix, of which a codec has one.
+``rs_words_mat`` (apply_gf_matrix_words_mat, at the end of this file)
+takes the matrix as an operand in SMEM and looks its terms up in a VMEM
+table: one executable per matrix SHAPE — the reconstruct paths' entry,
+whose matrix follows the shards that were lost (1001 four-shard losses
+under RS(10,4)).
 """
 
 from __future__ import annotations
@@ -209,3 +220,124 @@ def apply_gf_matrix_words(coefs: np.ndarray, x4: jnp.ndarray,
         interpret=interpret,
         name=name,
     )(x4)
+
+
+# --------------------------------------------------------------------------
+# the matrix as DATA: one program for every matrix of a shape
+# --------------------------------------------------------------------------
+
+#: Input bit planes per lookup group of the data-matrix kernel: the
+#: sixteen XOR combinations of four planes are one table.
+NIBBLE = 4
+_COMBOS = 1 << NIBBLE
+
+
+def matrix_operand(coefs: np.ndarray) -> np.ndarray:
+    """A GF(2^8) matrix as ``apply_gf_matrix_words_mat`` takes it: the
+    GF(2) expansion (bitslice.expand_gf2) cut into nibbles. Flat int32,
+    entry ``i * n_grp + g`` is the table row that output plane i takes
+    from group g (the four input planes 4g .. 4g+3): ``16 g`` plus the
+    number those four matrix bits spell. 2.5 KB for a 4 x 10 decode."""
+    mbits = bitslice.expand_gf2(np.asarray(coefs, dtype=np.uint8))
+    n_grp = mbits.shape[1] // NIBBLE
+    nib = mbits.reshape(mbits.shape[0], n_grp, NIBBLE) @ (
+        1 << np.arange(NIBBLE))
+    return (nib + _COMBOS * np.arange(n_grp)).astype(np.int32).reshape(-1)
+
+
+def _make_mat_kernel(n_in: int, n_out: int):
+    """Kernel whose GF(2) matrix is an operand (``mat_ref``, in SMEM,
+    as matrix_operand lays it out) and not a constant of the program:
+    out_plane[i] = XOR_j (bit[i, j] ? in_plane[j] : 0), evaluated four
+    input planes at a time (the method of the Four Russians) — all
+    sixteen XOR combinations of a group's planes go to a VMEM table
+    (11 XORs a group), and each output plane XORs one looked-up row
+    per group, 2 * n_in of them, whatever the matrix holds. For a
+    4 x 10 matrix that is 220 + 608 plane XORs against the ~500 of the
+    factored constant network, where masking bit by bit would take
+    2,560 ANDs and as many XORs. Both stages are loops over shards
+    (the table's rows of one input shard; the eight planes of one
+    output shard), so the program's size does not follow the matrix's
+    shape either."""
+    n_grp = 8 * n_in // NIBBLE
+    per_shard = 8 // NIBBLE
+
+    def kernel(mat_ref, in_ref, out_ref, tbl_ref):
+        rb, c = in_ref.shape[-2:]
+        zero = jnp.zeros((4, rb, c), jnp.uint32)
+
+        def build(d, carry):
+            a4 = _bit_transpose(in_ref[0, d]).reshape(4, 8, rb, c)
+            for h in range(per_shard):
+                combos = [zero]
+                for t in range(NIBBLE):
+                    p = a4[:, NIBBLE * h + t]
+                    combos += [p] + [v ^ p for v in combos[1:]]
+                tbl_ref[pl.ds((per_shard * d + h) * _COMBOS, _COMBOS)] = \
+                    jnp.stack(combos)
+            return carry
+
+        def look_up(o, carry):
+            cols = []
+            for i in range(8):
+                row = (8 * o + i) * n_grp
+                acc = tbl_ref[mat_ref[row]]
+                for g in range(1, n_grp):
+                    acc = acc ^ tbl_ref[mat_ref[row + g]]
+                cols.append(acc)
+            grp = jnp.stack(cols, axis=1)      # (4, 8, rb, c)
+            out_ref[0, o] = _bit_transpose(grp.reshape(GROUP_WORDS, rb, c))
+            return carry
+
+        jax.lax.fori_loop(0, n_in, build, 0)
+        jax.lax.fori_loop(0, n_out, look_up, 0)
+
+    return kernel
+
+
+def apply_gf_matrix_words_mat(mat: jnp.ndarray, x4: jnp.ndarray,
+                              n_out: int, interpret: bool = False,
+                              rb: int = RB,
+                              name: str = "rs_words_mat") -> jnp.ndarray:
+    """apply_gf_matrix_words with the matrix passed at call time: ``mat``
+    is matrix_operand(coefs) of an (n_out, n_in) matrix, x4 the same
+    word form (B, n_in, 32, R, 128) u32 -> (B, n_out, 32, R, 128) u32,
+    the same bit transposes and blocks around another XOR stage
+    (_make_mat_kernel). The program depends on the shapes alone, so
+    every decode matrix of a loss count shares one executable: the
+    rebuild's entry, where the matrix follows the shards that were
+    lost. The encode matrix is a constant of the codec and keeps
+    ``rs_words`` and its factored network."""
+    if (x4.ndim != 5 or x4.shape[2] != GROUP_WORDS
+            or x4.shape[4] != LANES):
+        raise ValueError(
+            f"x4 must be (B, n_in, {GROUP_WORDS}, R, {LANES}) u32, "
+            f"got {x4.shape}")
+    b, n_in, _, r, _ = x4.shape
+    if r % rb:
+        raise ValueError(f"R={r} must divide by {rb}")
+    n_grp = 8 * n_in // NIBBLE
+    if mat.shape != (8 * n_out * n_grp,):
+        raise ValueError(
+            f"mat must be matrix_operand of a ({n_out}, {n_in}) matrix, "
+            f"({8 * n_out * n_grp},) int32, got {mat.shape}")
+    return pl.pallas_call(
+        _make_mat_kernel(n_in, n_out),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, r // rb),
+            in_specs=[pl.BlockSpec(
+                (1, n_in, GROUP_WORDS, rb, LANES),
+                lambda bi, ri, mat_ref: (bi, 0, 0, ri, 0),
+                memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec(
+                (1, n_out, GROUP_WORDS, rb, LANES),
+                lambda bi, ri, mat_ref: (bi, 0, 0, ri, 0),
+                memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM(
+                (_COMBOS * n_grp, 4, rb, LANES), jnp.uint32)]),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, n_out, GROUP_WORDS, r, LANES), jnp.uint32),
+        interpret=interpret,
+        name=name,
+    )(mat, x4)

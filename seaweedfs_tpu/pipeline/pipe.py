@@ -421,7 +421,9 @@ def debug_payload() -> dict:
     never lent before), the stage spans' seconds (``compute`` =
     dispatch + sync; ``write`` = writer stage + positioned writes;
     ``fsync`` = the sweep's shard-file barriers, on the writeback
-    pool's threads; ``pack`` is carved out of ``read``),
+    pool's threads; ``pack`` is carved out of ``read``;
+    ``decode_matrix`` = the host's share of a reconstruct, once per
+    rebuild run: invert, compose, expand for the kernel),
     ``rpc_seconds`` (the EC handlers, each counted once, pipeline run
     included), ``step_<name>_seconds`` / ``_calls``
     for every server-side rpc step, and the recent-run ring."""
@@ -443,6 +445,8 @@ def debug_payload() -> dict:
                sync_seconds=sec("d2h_sync"),
                h2d_submit_seconds=sec("h2d_submit"),
                launch_seconds=sec("launch"),
+               decode_matrix_seconds=sec("decode_matrix"),
+               decode_matrix_calls=spans.get("decode_matrix", (0.0, 0))[1],
                rpc_seconds=sec(*(f"step_{n}"
                                  for n in flight.HANDLER_STEPS)))
     for name in flight.HANDLER_STEPS + flight.INNER_STEPS:

@@ -3,8 +3,10 @@
 The volume-server side of `ec.rebuild` (SURVEY.md §3.5): what
 erasure_coding ec_encoder.go RebuildEcFiles does — find which .ec?? files
 exist, and if at least k survive, produce the missing ones. The decode
-matrix composition happens host-side (ops/rs_jax.py), so every missing
-shard — data or parity — comes out of a single device pass per chunk.
+matrix is composed host-side once per run (Encoder.decode_matrix, span
+``decode_matrix``) and goes to the device as DATA with every dispatch:
+every missing shard — data or parity — comes out of a single device
+pass per chunk, through one program whatever shards were lost.
 
 Rebuild rides the same overlapped ingest plane as encode
 (pipe.py/writeback.py): survivor chunks are ``os.preadv``'d straight
@@ -71,14 +73,9 @@ def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
     # rest from disk at all.
     present = present[:scheme.data_shards]
     k = scheme.data_shards
-    reconstruct = _pick_reconstruct_fn(scheme, present, missing)
     # Grouped dispatch on a single accelerator; multi-chip keeps
     # per-chunk mesh sharding via _pick_reconstruct_fn.
     group, chunk_bytes = plan_chunking(k, chunk_bytes)
-    enc = scheme.encoder
-    reconstruct_multi = None if group == 1 else (
-        lambda chunks: enc.reconstruct_batch_host_multi(
-            chunks, present, missing))
 
     cfg = pipe.current()
     pool_nbytes = max(1, k * min(chunk_bytes, size or 1))
@@ -122,6 +119,11 @@ def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
             sp.n_bytes = size * len(missing)
             sp.tag(shards=",".join(str(i) for i in missing))
             t0 = time.perf_counter()
+            matrix = scheme.encoder.decode_matrix(present, missing)
+            reconstruct = _pick_reconstruct_fn(scheme, present, missing,
+                                               matrix)
+            reconstruct_multi = None if group == 1 \
+                else matrix.apply_host_multi
             for path in out_paths:
                 writer.open_file(path, size)
             with pipe.lend_pool(pools, pool_nbytes, pool_count) as pool:
@@ -182,18 +184,18 @@ def _pread_into(fd: int, view: np.ndarray, offset: int) -> None:
         got += n
 
 
-def _pick_reconstruct_fn(scheme: EcScheme, present, missing):
+def _pick_reconstruct_fn(scheme: EcScheme, present, missing, matrix):
     """When routing_mesh() says to shard — a multi-chip accelerator,
     or an explicit [mesh]/-mesh config (virtual CPU meshes included) —
     the rebuild chunks shard over the whole mesh
     (parallel/mesh.reconstruct_host_sharded); single-device backends
-    keep the host fast path — same routing rule as the batcher's
-    encode (pipeline/batch._pick_encode_fn)."""
+    keep the host fast path, the run's one decode ``matrix`` applied to
+    each chunk — same routing rule as the batcher's encode
+    (pipeline/batch._pick_encode_fn)."""
     from ..parallel import mesh as mesh_mod
     enc = scheme.encoder
     m = mesh_mod.routing_mesh()
     if m is not None:
         return lambda chunk: mesh_mod.reconstruct_host_sharded(
             enc, chunk, present, missing, mesh=m)
-    return lambda chunk: enc.reconstruct_batch_host(
-        chunk, present, missing)
+    return matrix.apply_host

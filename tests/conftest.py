@@ -70,6 +70,44 @@ from seaweedfs_tpu.util import racecheck  # noqa: E402
 racecheck.install_from_env()
 
 
+import pytest  # noqa: E402
+
+
+def _interpret_kernels(monkeypatch):
+    from seaweedfs_tpu.ops import rs_jax, rs_pallas
+    for name in ("apply_gf_matrix_words", "apply_gf_matrix_words_mat"):
+        real = getattr(rs_pallas, name)
+        monkeypatch.setattr(
+            rs_pallas, name,
+            lambda *a, _real=real, **kw: _real(*a, **{**kw,
+                                                      "interpret": True}))
+    caches = (rs_jax._jitted_apply, rs_jax._jitted_apply_multi,
+              rs_jax._jitted_apply_mat)
+    for cache in caches:
+        cache.cache_clear()
+    yield
+    for cache in caches:
+        cache.cache_clear()
+
+
+@pytest.fixture()
+def interpreted_kernels(monkeypatch):
+    """Both word-form kernel entries (``rs_words``, and ``rs_words_mat``
+    of the reconstruct paths) run by the Pallas interpreter, and the
+    jitted steps built around them dropped before and after, so that no
+    test meets a step another one traced."""
+    yield from _interpret_kernels(monkeypatch)
+
+
+@pytest.fixture(scope="module")
+def interpreted_kernels_module():
+    """The same for a whole test file: its cases share the steps they
+    trace (a file that counts traces, or runs one program on many
+    matrices, compiles each once)."""
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        yield from _interpret_kernels(monkeypatch)
+
+
 def pytest_configure(config):
     # Tier-1 runs with -m 'not slow'; the slow tier holds the
     # full-scale simulation acceptance run (minutes of wall time).

@@ -87,20 +87,11 @@ def live(tmp_path):
 
 
 @pytest.fixture()
-def interpreted_tpu(monkeypatch):
+def interpreted_tpu(monkeypatch, interpreted_kernels):
     """A process that believes it has an accelerator, with the kernels
     run by the Pallas interpreter."""
     monkeypatch.setattr(rs_jax, "_use_pallas", lambda: True)
     monkeypatch.setattr(rs_jax, "HOST_DISPATCH", "device")
-    real = rs_pallas.apply_gf_matrix_words
-    monkeypatch.setattr(
-        rs_pallas, "apply_gf_matrix_words",
-        lambda c, x, **kw: real(c, x, interpret=True))
-    rs_jax._jitted_apply.cache_clear()
-    rs_jax._jitted_apply_multi.cache_clear()
-    yield
-    rs_jax._jitted_apply.cache_clear()
-    rs_jax._jitted_apply_multi.cache_clear()
 
 
 def test_device_leg_is_counted(

@@ -17,19 +17,10 @@ from seaweedfs_tpu.pipeline import pipe
 
 
 @pytest.fixture()
-def forced_pallas(monkeypatch):
+def forced_pallas(monkeypatch, interpreted_kernels):
     monkeypatch.setattr(rs_jax, "_use_pallas", lambda: True)
     monkeypatch.setattr(rs_jax, "PALLAS_MIN_S", 1024)
     monkeypatch.setattr(rs_jax, "HOST_DISPATCH", "device")
-    real_w = rs_pallas.apply_gf_matrix_words
-    monkeypatch.setattr(
-        rs_pallas, "apply_gf_matrix_words",
-        lambda c, x, **kw: real_w(c, x, interpret=True))
-    rs_jax._jitted_apply.cache_clear()
-    rs_jax._jitted_apply_multi.cache_clear()
-    yield
-    rs_jax._jitted_apply.cache_clear()
-    rs_jax._jitted_apply_multi.cache_clear()
 
 
 def _oracle(k, m, x):
@@ -184,7 +175,7 @@ def test_rebuild_grouped_chunks_stay_seg_aligned(forced_pallas,
     re-align the per-shard take or rs_pallas.conforms rejects every chunk
     and the fast path silently never engages. Proven end to end: an
     unaligned chunk_bytes request still rebuilds byte-identically AND
-    the multi executable actually runs."""
+    the decode executable (the matrix its argument) actually runs."""
     from seaweedfs_tpu.pipeline.encode import encode_volume
     from seaweedfs_tpu.pipeline.rebuild import rebuild_ec_files
     from seaweedfs_tpu.pipeline.scheme import EcScheme
@@ -206,13 +197,13 @@ def test_rebuild_grouped_chunks_stay_seg_aligned(forced_pallas,
     encode_volume(base, scheme, max_batch_bytes=4 * seg)
     want0 = ec_files.shard_path(base, 0).read_bytes()
     ec_files.shard_path(base, 0).unlink()
-    before = rs_jax._jitted_apply_multi.cache_info()
+    before = rs_jax._jitted_apply_mat.cache_info()
     # deliberately unaligned request: the clamp must fix it, not
     # forward it into the dispatch
     assert rebuild_ec_files(base, scheme,
                             chunk_bytes=seg + 1000) == [0]
     assert ec_files.shard_path(base, 0).read_bytes() == want0
-    after = rs_jax._jitted_apply_multi.cache_info()
+    after = rs_jax._jitted_apply_mat.cache_info()
     assert (after.misses + after.hits) > (before.misses + before.hits), \
         "grouped word-form dispatch never engaged in rebuild"
 

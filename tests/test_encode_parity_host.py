@@ -14,19 +14,12 @@ from seaweedfs_tpu.ops import rs_jax, rs_pallas, rs_ref
 
 
 @pytest.fixture()
-def forced_pallas(monkeypatch):
+def forced_pallas(monkeypatch, interpreted_kernels):
     monkeypatch.setattr(rs_jax, "_use_pallas", lambda: True)
     monkeypatch.setattr(rs_jax, "PALLAS_MIN_S", 1024)
     # pin the hybrid policy to the device leg: these tests prove the
     # word-form device path, not the link-vs-codec routing (below)
     monkeypatch.setattr(rs_jax, "HOST_DISPATCH", "device")
-    real_w = rs_pallas.apply_gf_matrix_words
-    monkeypatch.setattr(
-        rs_pallas, "apply_gf_matrix_words",
-        lambda c, x, **kw: real_w(c, x, interpret=True))
-    rs_jax._jitted_apply.cache_clear()
-    yield
-    rs_jax._jitted_apply.cache_clear()
 
 
 def test_words_fast_path(forced_pallas):

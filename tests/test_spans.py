@@ -219,7 +219,7 @@ def test_ring_events_of_the_pool_and_the_writeback(tmp_path):
 # --------------------------------------------------------------------------
 
 @pytest.fixture()
-def one_chip_device_leg(monkeypatch):
+def one_chip_device_leg(monkeypatch, interpreted_kernels):
     """Steer ``write_ec_files`` down the one-chip word-form path on the
     CPU: the Pallas words kernel under the interpreter, no mesh."""
     from seaweedfs_tpu.parallel import mesh as mesh_mod
@@ -227,12 +227,6 @@ def one_chip_device_leg(monkeypatch):
     monkeypatch.setattr(rs_jax, "PALLAS_MIN_S", 1024)
     monkeypatch.setattr(rs_jax, "HOST_DISPATCH", "device")
     monkeypatch.setattr(mesh_mod, "routing_mesh", lambda: None)
-    real = rs_pallas.apply_gf_matrix_words
-    monkeypatch.setattr(rs_pallas, "apply_gf_matrix_words",
-                        lambda c, x, **kw: real(c, x, interpret=True))
-    rs_jax._jitted_apply.cache_clear()
-    yield
-    rs_jax._jitted_apply.cache_clear()
 
 
 def host_events(trace_dir) -> list[dict]:

@@ -136,20 +136,49 @@ def test_grouped_encode_fits_hbm(one_chip, one_tpu_dispatch, width):
     assert in_flight * per_group < V5E_HBM_BYTES
 
 
+def _operand_spec(n_lost, k, sharding):
+    return jax.ShapeDtypeStruct((8 * n_lost * 2 * k,), jnp.int32,
+                                sharding=sharding)
+
+
 @pytest.mark.parametrize("n_lost", [1, 4])
 def test_rebuild_decode_rows(one_chip, one_tpu_dispatch, n_lost):
+    """The rebuild's program at its own chunk shape: the decode matrix
+    is its first argument, so what is compiled here is what every loss
+    of ``n_lost`` shards runs."""
     k = DEFAULT_SCHEME.data_shards
     group, take = rebuild_mod.plan_chunking(k)
     assert group > 1
-    lost = [3, 0, 11, 13][:n_lost]
-    present = [i for i in range(DEFAULT_SCHEME.total_shards)
-               if i not in lost]
-    rows = DEFAULT_SCHEME.encoder.decode_matrix_rows(present, sorted(lost))
-    assert rows.shape == (n_lost, k)
-    fn = rs_jax._jitted_apply(
-        np.ascontiguousarray(rows).tobytes(), n_lost, k, "pallas_words",
-        donate=True)
-    _compile(fn, _words((1, k, take), one_chip))
+    fn = rs_jax._jitted_apply_mat(n_lost, k, 1, donate=True)
+    _compile(fn, _operand_spec(n_lost, k, one_chip),
+             _words((1, k, take), one_chip))
+
+
+def test_two_loss_patterns_lower_to_one_text(one_chip, one_tpu_dispatch):
+    """Lowered for a v5e with the operands of two different four-shard
+    losses, the rebuild's step is the same text letter for letter, named
+    ``rs_pallas_words_mat_g<w>`` around kernels named ``rs_words_mat``:
+    nothing of a pattern is in the program."""
+    k = DEFAULT_SCHEME.data_shards
+    _, take = rebuild_mod.plan_chunking(k)
+    enc = DEFAULT_SCHEME.encoder
+    x = _words((1, k, take), one_chip)
+    texts = []
+    for lost in ([1, 6, 11, 13], [0, 2, 3, 12]):
+        present = [i for i in range(14) if i not in lost]
+        operand = enc.decode_matrix(present, lost).operand
+        assert operand.shape == _operand_spec(4, k, one_chip).shape
+        texts.append({w: rs_jax._jitted_apply_mat(4, k, w, donate=True)
+                      .lower(operand, *[x] * w).as_text()
+                      for w in (1, 16)})
+    assert texts[0] == texts[1]
+    for w, text in texts[0].items():
+        assert f"@jit_rs_pallas_words_mat_g{w} " in text
+        # the kernel is traced once, in a function of one slab that
+        # the step calls once per slab
+        assert text.count('kernel_name = "rs_words_mat"') == 1
+        assert text.count("call @one_slab(") == w
+        assert 'kernel_name = "rs_words"' not in text
 
 
 @pytest.mark.parametrize("k,m", [(6, 3), (12, 4)])
