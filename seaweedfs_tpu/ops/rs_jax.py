@@ -46,6 +46,7 @@ from __future__ import annotations
 import functools
 import os
 import threading
+import time
 from pathlib import Path
 from typing import Optional, Sequence
 
@@ -267,8 +268,6 @@ def _pick_variant(s: int) -> str:
 def _measure_link_gibps(n_bytes: int = 8 * 1024 * 1024) -> float:
     """One-time h2d+d2h round-trip bandwidth probe (GiB/s of payload
     moved per second of wall time, both directions counted)."""
-    import time
-
     x = np.zeros(n_bytes, dtype=np.uint8)
     t0 = time.perf_counter()
     d = jax.device_put(x)
@@ -280,8 +279,6 @@ def _measure_link_gibps(n_bytes: int = 8 * 1024 * 1024) -> float:
 
 def _measure_native_gibps(n_bytes: int = 16 * 1024 * 1024) -> float:
     """One-time host-codec throughput probe (input GiB/s)."""
-    import time
-
     k = 10
     coefs = gf256.build_code_matrix(k, k + 4)[k:]
     x = np.zeros((k, n_bytes // k), dtype=np.uint8)
@@ -436,15 +433,29 @@ def _launch(fn, xs: list, nbytes: int):
 class _HostParity:
     """Async device parity held in word form; ``np.asarray`` (the
     pipeline writer's sync point) fetches it and re-views the bytes as
-    (B, m, S) uint8 — a zero-copy host reshape."""
+    (B, m, S) uint8 — a zero-copy host reshape. The writer takes that
+    sync in steps, as a ``jax.Array`` offers them: ask for the fetch,
+    wait until the result is ready on the device, then ``np.asarray``.
+    ``launched`` is (the clock when the dispatch's jitted call
+    returned, the dispatch's input bytes), one tuple shared by the
+    results of one dispatch: with the time a result was found ready it
+    says what rate the group's inputs crossed at."""
 
-    __slots__ = ("dev", "b", "m", "s")
+    __slots__ = ("dev", "b", "m", "s", "launched")
 
-    def __init__(self, dev, b: int, m: int, s: int):
+    def __init__(self, dev, b: int, m: int, s: int,
+                 launched: Optional[tuple] = None):
         self.dev = dev
         self.b = b
         self.m = m
         self.s = s
+        self.launched = launched
+
+    def copy_to_host_async(self) -> None:
+        self.dev.copy_to_host_async()
+
+    def block_until_ready(self) -> None:
+        self.dev.block_until_ready()
 
     def __array__(self, dtype=None, copy=None):
         w = np.asarray(self.dev)
@@ -554,9 +565,10 @@ def apply_matrix_host_multi(coefs: np.ndarray, batches,
             fn = _jitted_apply_multi(*key, len(ixs),
                                      donate=donation_enabled())
             ys = _launch(fn, xs, nbytes)
+        launched = (time.perf_counter(), nbytes)
         for i, y in zip(ixs, ys):
             b, _, s = batches[i].shape
-            out[i] = _HostParity(y, b, n_out, s)
+            out[i] = _HostParity(y, b, n_out, s, launched)
 
     def flush():
         # quantize to power-of-two widths (13 -> 8+4+1) so executables

@@ -87,7 +87,7 @@ EV_H2D_SUBMIT = 8      # host side of H2D begins (jnp.asarray / device_put)
 EV_H2D_READY = 9       # submit returned (transfer in flight); arg=bytes
 EV_DISPATCH = 10       # compute dispatch (H2D submit + launch) begins
 EV_DISPATCH_DONE = 11  # dispatch returned (async); arg=group width
-EV_SYNC_START = 12     # writer blocks on np.asarray (device wait + D2H)
+EV_SYNC_START = 12     # writer's sync begins (fetch asked, ready wait, D2H)
 EV_SYNC_END = 13       # result bytes on host; arg=bytes
 EV_WRITE_START = 14    # writer-stage write_fn begins
 EV_WRITE_END = 15      # write_fn + recycle_fn returned
@@ -98,6 +98,24 @@ EV_QDEPTH = 19         # counter: value=depth, arg: 0=read_q 1=write_q
 EV_POOL_OCC = 20       # counter: value=in-flight pooled buffers
 EV_LAUNCH = 21         # the jitted call itself begins
 EV_LAUNCH_DONE = 22    # the call returned (async); arg=bytes
+EV_READY_WAIT = 23     # writer, inside the sync: block_until_ready begins
+EV_READY = 24          # the result is ready on the device (inputs landed,
+                       # kernel done); what is left of the sync is the copy
+# the four queue waits, each on the thread that waits; batch = the one
+# it is putting or waiting for
+EV_READER_BLOCKED = 25   # reader: read_q.put begins (blocks when full)
+EV_READER_UNBLOCKED = 26
+EV_COMPUTE_STARVED = 27  # compute: read_q.get / the group's forming wait
+EV_COMPUTE_FED = 28
+EV_COMPUTE_BLOCKED = 29  # compute: write_q.put begins (blocks when full)
+EV_COMPUTE_UNBLOCKED = 30
+EV_WRITER_STARVED = 31   # writer: write_q.get begins
+EV_WRITER_FED = 32
+# the two tails: a stage thread that has handed over its last batch
+EV_READER_DONE = 33      # reader: nothing left to read ...
+EV_READER_JOINED = 34    # ... until the run's stages are joined
+EV_COMPUTE_DONE = 35     # compute: nothing left to dispatch ...
+EV_COMPUTE_JOINED = 36   # ... until the writer has drained
 
 _NAMES = {
     EV_RUN_START: "run_start", EV_RUN_END: "run_end",
@@ -111,6 +129,15 @@ _NAMES = {
     EV_PWRITEV_RETIRE: "pwritev_retire", EV_RECYCLE: "recycle",
     EV_QDEPTH: "queue_depth", EV_POOL_OCC: "pool_occupancy",
     EV_LAUNCH: "launch", EV_LAUNCH_DONE: "launch_done",
+    EV_READY_WAIT: "ready_wait", EV_READY: "ready",
+    EV_READER_BLOCKED: "reader_blocked",
+    EV_READER_UNBLOCKED: "reader_unblocked",
+    EV_COMPUTE_STARVED: "compute_starved", EV_COMPUTE_FED: "compute_fed",
+    EV_COMPUTE_BLOCKED: "compute_blocked",
+    EV_COMPUTE_UNBLOCKED: "compute_unblocked",
+    EV_WRITER_STARVED: "writer_starved", EV_WRITER_FED: "writer_fed",
+    EV_READER_DONE: "reader_done", EV_READER_JOINED: "reader_joined",
+    EV_COMPUTE_DONE: "compute_done", EV_COMPUTE_JOINED: "compute_joined",
 }
 
 #: (start, end, track-name) pairs rendered as duration events; pairing
@@ -123,8 +150,27 @@ _SPAN_PAIRS = (
     (EV_LAUNCH, EV_LAUNCH_DONE, "launch"),
     (EV_DISPATCH, EV_DISPATCH_DONE, "dispatch"),
     (EV_SYNC_START, EV_SYNC_END, "d2h_sync"),
+    (EV_READY_WAIT, EV_READY, "d2h_ready"),
     (EV_WRITE_START, EV_WRITE_END, "write"),
+    (EV_READER_BLOCKED, EV_READER_UNBLOCKED, "reader_blocked"),
+    (EV_COMPUTE_STARVED, EV_COMPUTE_FED, "compute_starved"),
+    (EV_COMPUTE_BLOCKED, EV_COMPUTE_UNBLOCKED, "compute_blocked"),
+    (EV_WRITER_STARVED, EV_WRITER_FED, "writer_starved"),
+    (EV_READER_DONE, EV_READER_JOINED, "reader_done"),
+    (EV_COMPUTE_DONE, EV_COMPUTE_JOINED, "compute_done"),
 )
+
+#: The four places where a stage thread waits for its neighbour, and
+#: the two tails in which a stage has handed over its last batch and
+#: the stages after it are still working (the pipeline's drain). With
+#: them every stage thread is accounted for from the run's start to its
+#: end: reader = read + pool_wait (+ pack) + reader_blocked +
+#: reader_done; compute = dispatch + compute_starved + compute_blocked
+#: + compute_done; writer = d2h_sync + write + writer_starved. The
+#: stage that sets the pace is the one that never waits.
+QUEUE_WAITS = ("reader_blocked", "compute_starved", "compute_blocked",
+               "writer_starved")
+WAITS = QUEUE_WAITS + ("reader_done", "compute_done")
 
 _QUEUE_NAMES = {0: "read_q_depth", 1: "write_q_depth"}
 
@@ -284,16 +330,8 @@ def record(event: int, batch: int = -1, value: float = 0.0,
 #: span name -> (ring start code, ring end code). A start code of 0
 #: means the span is one retire record carrying its own duration.
 #: Names that are not here (the rpc steps) write nothing to the ring.
-_RING_CODES = {
-    "read": (EV_READ_START, EV_READ_END),
-    "pool_wait": (EV_POOL_WAIT, EV_POOL_GOT),
-    "h2d_submit": (EV_H2D_SUBMIT, EV_H2D_READY),
-    "launch": (EV_LAUNCH, EV_LAUNCH_DONE),
-    "dispatch": (EV_DISPATCH, EV_DISPATCH_DONE),
-    "d2h_sync": (EV_SYNC_START, EV_SYNC_END),
-    "write": (EV_WRITE_START, EV_WRITE_END),
-    "pwritev": (0, EV_PWRITEV_RETIRE),
-}
+_RING_CODES = {name: (start, end) for start, end, name in _SPAN_PAIRS}
+_RING_CODES["pwritev"] = (0, EV_PWRITEV_RETIRE)
 
 #: The EC handlers of the volume server (each a ``step_<name>`` span
 #: around the whole handler; their sum is ``rpc_seconds``) and the
@@ -499,23 +537,32 @@ class span:
 # Chrome trace-event export
 # --------------------------------------------------------------------------
 
+#: span name -> the stage thread it runs on (``pool_wait`` runs on
+#: whichever thread acquires)
+_ROLE_OF_SPAN = {
+    "read": "reader", "reader_blocked": "reader", "reader_done": "reader",
+    "dispatch": "compute", "h2d_submit": "compute", "launch": "compute",
+    "compute_starved": "compute", "compute_blocked": "compute",
+    "compute_done": "compute",
+    "d2h_sync": "writer", "d2h_ready": "writer", "write": "writer",
+    "writer_starved": "writer",
+}
+_ROLE_OF_EVENT = {code: _ROLE_OF_SPAN[name]
+                  for start, end, name in _SPAN_PAIRS
+                  if name in _ROLE_OF_SPAN for code in (start, end)}
+_ROLE_OF_EVENT[EV_ENQUEUE] = "reader"
+_ROLE_OF_EVENT[EV_PWRITEV_RETIRE] = "writeback"
+
+
 def _thread_names(events: list[tuple]) -> dict[int, str]:
     """tid -> human track name, derived from the event mix each thread
     produced (pipeline threads are per-run daemons, dead by export
     time, so live-thread inspection cannot name them)."""
     roles: dict[int, str] = {}
     for ev in events:
-        tid, kind = ev[_TID], ev[_EV]
-        if kind in (EV_READ_START, EV_READ_END, EV_ENQUEUE):
-            roles.setdefault(tid, "reader")
-        elif kind in (EV_SYNC_START, EV_SYNC_END,
-                      EV_WRITE_START, EV_WRITE_END):
-            roles.setdefault(tid, "writer")
-        elif kind == EV_PWRITEV_RETIRE:
-            roles.setdefault(tid, "writeback")
-        elif kind in (EV_DISPATCH, EV_DISPATCH_DONE, EV_LAUNCH,
-                      EV_LAUNCH_DONE, EV_H2D_SUBMIT, EV_H2D_READY):
-            roles.setdefault(tid, "compute")
+        role = _ROLE_OF_EVENT.get(ev[_EV])
+        if role is not None:
+            roles.setdefault(ev[_TID], role)
     # distinct writeback workers get numbered tracks
     n_wb = 0
     for tid in sorted(t for t, r in roles.items() if r == "writeback"):
@@ -641,18 +688,30 @@ def occupancy(events: Optional[list[tuple]] = None,
     * ``dispatch`` — what is left of the compute stage's enqueue time
       once those two are taken out (a host codec computing inline,
       Python around the call);
-    * ``d2h`` — writer blocked in ``np.asarray``: the device finishing
-      the batch plus the D2H copy — on a link-bound box this is where
-      the dispatch-link floor shows up;
+    * ``d2h_ready`` — writer waiting for the result to be ready on the
+      device (``block_until_ready``): the batch's inputs landing and
+      the kernel; ``d2h_copy`` — the rest of the writer's sync: the
+      result coming home (the D2H copy, asked for before the wait);
     * ``write`` — writer-thread write_fn time;
     * ``writeback`` — positioned-write pool busy seconds (sum across
       workers, so this one alone may exceed the window).
 
-    Per batch, the exclusive wait components are: queue-wait before
-    dispatch (read_end -> dispatch start) and queue-wait before the
-    writer picks it up (dispatch done -> sync start); ``waited_on``
-    counts, per batch, the largest component — the stage that batch
-    actually waited on."""
+    ``wait_seconds`` / ``wait_fraction`` hold the four queue waits and
+    the two tails (:data:`WAITS`), each the span of the thread that
+    waited, and ``stage_wait_fraction`` each stage thread's share of
+    the window spent waiting for a neighbour (reader: ``pool_wait`` +
+    ``reader_blocked`` + ``reader_done``; compute: ``compute_starved``
+    + ``compute_blocked`` + ``compute_done``; writer:
+    ``writer_starved``): the stage that sets the pace is the one that
+    never waits.
+
+    Per batch, the exclusive wait components come from those spans
+    too: ``queue_wait_compute`` is how long the reader was held with
+    the batch before a full ``read_q`` (``reader_blocked``: the compute
+    side did not take it), ``queue_wait_writer`` how long the compute
+    stage was held with its result before a full ``write_q``
+    (``compute_blocked``); ``waited_on`` counts, per batch, the largest
+    component — the stage that batch actually waited on."""
     if events is None:
         evs = _REC.snapshot() if _REC is not None else []
     else:
@@ -662,20 +721,24 @@ def occupancy(events: Optional[list[tuple]] = None,
     if not evs:
         return {"window_seconds": 0.0, "batches": 0, "busy_seconds": {},
                 "busy_fraction": {}, "bubble_seconds": {},
-                "waited_on": {}, "events": 0}
+                "wait_seconds": {}, "wait_fraction": {},
+                "stage_wait_fraction": {}, "waited_on": {}, "events": 0}
     t_lo, t_hi = evs[0][_TS], evs[-1][_TS]
     window = max(1e-9, (t_hi - t_lo) / 1e9)
 
     busy = {"read": 0.0, "pool_wait": 0.0, "dispatch": 0.0,
-            "h2d_submit": 0.0, "launch": 0.0, "d2h": 0.0, "write": 0.0,
-            "writeback": 0.0}
+            "h2d_submit": 0.0, "launch": 0.0, "d2h_ready": 0.0,
+            "d2h_copy": 0.0, "write": 0.0, "writeback": 0.0}
+    wait = dict.fromkeys(WAITS, 0.0)
     # per-batch timeline marks for critical-path attribution
     marks: dict[int, dict] = {}
     open_spans: dict[tuple, tuple] = {}
+    # the whole sync goes to d2h_copy; d2h_ready is carved out below
     span_stage = {
         "read": "read", "pool_wait": "pool_wait",
         "h2d_submit": "h2d_submit", "launch": "launch",
-        "dispatch": "dispatch", "d2h_sync": "d2h", "write": "write",
+        "dispatch": "dispatch", "d2h_sync": "d2h_copy",
+        "d2h_ready": "d2h_ready", "write": "write",
     }
     starts = {code: (end, name) for code, end, name in _SPAN_PAIRS}
     ends = {end: (code, name) for code, end, name in _SPAN_PAIRS}
@@ -694,7 +757,10 @@ def occupancy(events: Optional[list[tuple]] = None,
             if st is None:
                 continue
             dt = (ts - st[_TS]) / 1e9
-            busy[span_stage[name]] += dt
+            if name in wait:
+                wait[name] += dt
+            else:
+                busy[span_stage[name]] += dt
             if batch >= 0:
                 m = marks.setdefault(batch, {})
                 m[f"{name}_end"] = ts
@@ -711,6 +777,8 @@ def occupancy(events: Optional[list[tuple]] = None,
     # (the mesh path's prepare may run outside one: never below zero)
     busy["dispatch"] = max(0.0, busy["dispatch"] - busy["h2d_submit"]
                            - busy["launch"])
+    # and the wait for the result to be ready inside the writer's sync
+    busy["d2h_copy"] = max(0.0, busy["d2h_copy"] - busy["d2h_ready"])
 
     # a start with no matching end (e.g. the reader's final next() that
     # hit StopIteration) is not a batch — keep only completed spans
@@ -719,24 +787,29 @@ def occupancy(events: Optional[list[tuple]] = None,
                                      "launch", "d2h_sync", "write"))}
     waited: dict[str, int] = {}
     for b, m in marks.items():
+        ready = m.get("d2h_ready", 0.0)
         comp = {
             "read": m.get("read", 0.0),
             "dispatch": m.get("dispatch", 0.0),
-            "d2h": m.get("d2h_sync", 0.0),
+            "d2h_ready": ready,
+            "d2h_copy": max(0.0, m.get("d2h_sync", 0.0) - ready),
             "write": m.get("write", 0.0),
+            "queue_wait_compute": m.get("reader_blocked", 0.0),
+            "queue_wait_writer": m.get("compute_blocked", 0.0),
         }
-        if "read_end" in m and "dispatch_start" in m:
-            comp["queue_wait_compute"] = max(
-                0.0, (m["dispatch_start"] - m["read_end"]) / 1e9)
-        if "dispatch_end" in m and "d2h_sync_start" in m:
-            comp["queue_wait_writer"] = max(
-                0.0, (m["d2h_sync_start"] - m["dispatch_end"]) / 1e9)
         top = max(comp, key=comp.get)
         waited[top] = waited.get(top, 0) + 1
 
     frac = {k: round(v / window, 4) for k, v in busy.items()}
     bubble = {k: round(max(0.0, window - v), 6)
               for k, v in busy.items() if k != "writeback"}
+    stage_wait = {
+        "reader": busy["pool_wait"] + wait["reader_blocked"]
+        + wait["reader_done"],
+        "compute": wait["compute_starved"] + wait["compute_blocked"]
+        + wait["compute_done"],
+        "writer": wait["writer_starved"],
+    }
     return {
         "window_seconds": round(window, 6),
         "batches": len(marks),
@@ -744,6 +817,11 @@ def occupancy(events: Optional[list[tuple]] = None,
         "busy_seconds": {k: round(v, 6) for k, v in busy.items()},
         "busy_fraction": frac,
         "bubble_seconds": bubble,
+        "wait_seconds": {k: round(v, 6) for k, v in wait.items()},
+        "wait_fraction": {k: round(v / window, 4)
+                          for k, v in wait.items()},
+        "stage_wait_fraction": {k: round(v / window, 4)
+                                for k, v in stage_wait.items()},
         "waited_on": waited,
     }
 
@@ -760,8 +838,11 @@ _HEADLINE = {
                   "to the runtime",
     "launch": "the jitted call is the floor: launch cost, or a compile "
               "on the dispatch path",
-    "d2h": "the writer's wait for the device's result is the floor: "
-           "transfer in, kernel and D2H copy",
+    "d2h_ready": "the writer's wait for a result to be ready on the "
+                 "device is the floor: the batch's inputs landing (its "
+                 "whole group's, when dispatched as one) and the kernel",
+    "d2h_copy": "a ready result coming home is the floor: the D2H copy "
+                "and the host memory it lands in",
     "write": "the writer stage is the floor: shard writeback gates the "
              "pipeline",
 }
@@ -770,9 +851,12 @@ _HEADLINE = {
 def analyze(events: Optional[list[tuple]] = None,
             last_run_only: bool = True) -> dict:
     """Name the busiest lane of the recorded window, with the occupancy
-    evidence attached. H2D submit, launch and the D2H wait are lanes of
-    their own; ``dispatch`` is what the compute stage spent outside the
-    first two."""
+    evidence attached. H2D submit, launch and the two halves of the
+    writer's sync (``d2h_ready``, ``d2h_copy``) are lanes of their own;
+    ``dispatch`` is what the compute stage spent outside the first two.
+    ``pacing`` is the stage thread that waited least for its neighbours
+    (``occupancy``'s ``stage_wait_fraction``): the others wait for it,
+    or for what it waits for."""
     occ = occupancy(events, last_run_only=last_run_only)
     if not occ["batches"]:
         return {"verdict": "no recorded batches", "occupancy": occ,
@@ -782,7 +866,9 @@ def analyze(events: Optional[list[tuple]] = None,
     bottleneck = max(lanes, key=lanes.get)
     waited = occ["waited_on"]
     top_wait = max(waited, key=waited.get) if waited else None
+    stage_wait = occ["stage_wait_fraction"]
     return {
+        "pacing": min(stage_wait, key=stage_wait.get),
         "verdict": f"bottleneck: {bottleneck} "
                    f"({lanes[bottleneck]:.0%} of the "
                    f"{occ['window_seconds']:.3f}s window busy) — "
@@ -828,9 +914,10 @@ def publish_run_gauges() -> Optional[dict]:
     with _ANALYSIS_LOCK:
         _LAST_ANALYSIS.clear()
         _LAST_ANALYSIS.update(
-            {k: analysis[k] for k in ("verdict", "bottleneck",
+            {k: analysis[k] for k in ("verdict", "bottleneck", "pacing",
                                       "lane_fraction")})
         _LAST_ANALYSIS["busy_fraction"] = occ["busy_fraction"]
+        _LAST_ANALYSIS["wait_fraction"] = occ["wait_fraction"]
         _LAST_ANALYSIS["window_seconds"] = occ["window_seconds"]
         _LAST_ANALYSIS["batches"] = occ["batches"]
     return analysis

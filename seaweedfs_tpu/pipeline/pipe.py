@@ -16,9 +16,10 @@ buffer so the steady state allocates nothing.
   accelerator, runs of same-shaped batches share ONE dispatch
   (``apply_matrix_host_multi``), with a :class:`GroupController`
   sizing the group from measured stage latencies;
-- a writer thread calls ``np.asarray`` on the oldest in-flight result —
-  blocking until THAT batch's compute is done while newer batches are
-  still being transferred/computed — and hands shard bytes to a
+- a writer thread syncs on the oldest in-flight result — asks for its
+  fetch, waits until THAT batch is ready on the device while newer
+  batches are still being transferred/computed, then ``np.asarray``
+  brings it home (:class:`_Sync`) — and hands shard bytes to a
   positioned-write pool (pipeline/writeback.py) that runs pwritev
   calls on preallocated files while the next batch computes.
 
@@ -307,8 +308,17 @@ _SPAN_FIELD = {"read": "read_seconds", "pool_wait": "pool_wait_seconds",
                "pack": "pack_seconds",
                "dispatch": "dispatch_seconds",
                "h2d_submit": "h2d_submit_seconds",
-               "launch": "launch_seconds", "d2h_sync": "sync_seconds",
-               "write": "write_seconds"}
+               "launch": "launch_seconds",
+               # d2h_ready is a leaf inside d2h_sync: what d2h_sync keeps
+               # of its own is the result coming home
+               "d2h_ready": "sync_ready_seconds",
+               "d2h_sync": "sync_copy_seconds",
+               "write": "write_seconds",
+               **{name: f"{name}_seconds" for name in flight.WAITS}}
+
+#: A ``d2h_ready`` longer than this really waited for the device: the
+#: moment it ended is when the result's group became ready.
+READY_WAITED = 1e-3
 
 
 @dataclass
@@ -328,14 +338,32 @@ class PipeStats:
     dispatch_seconds: float = 0.0   # encode_fn enqueue (main thread)
     h2d_submit_seconds: float = 0.0  # of dispatch: jnp.asarray per slab
     launch_seconds: float = 0.0     # of dispatch: the jitted call
-    sync_seconds: float = 0.0       # np.asarray device wait (writer)
+    sync_ready_seconds: float = 0.0  # writer: result not ready yet
+    sync_copy_seconds: float = 0.0  # writer: a ready result coming home
     write_seconds: float = 0.0      # write_fn + positioned writes
+    # a stage thread held by its neighbour (flight.WAITS)
+    reader_blocked_seconds: float = 0.0   # read_q full
+    compute_starved_seconds: float = 0.0  # read_q empty / group forming
+    compute_blocked_seconds: float = 0.0  # write_q full
+    writer_starved_seconds: float = 0.0   # write_q empty
+    reader_done_seconds: float = 0.0      # last batch read -> run's end
+    compute_done_seconds: float = 0.0     # last dispatch -> writer drained
+    # launch's return -> found ready, and the input bytes of the
+    # dispatch, for results the writer really waited for (_Sync)
+    group_ready_seconds: float = 0.0
+    group_ready_bytes: int = 0
     wall_seconds: float = 0.0
 
     def add(self, span: str, seconds: float) -> None:
         name = _SPAN_FIELD.get(span)
         if name is not None:
             setattr(self, name, getattr(self, name) + seconds)
+
+    @property
+    def sync_seconds(self) -> float:
+        """The writer's whole sync on a result: the wait until it is
+        ready on the device, then its way home."""
+        return self.sync_ready_seconds + self.sync_copy_seconds
 
     @property
     def compute_seconds(self) -> float:
@@ -358,7 +386,9 @@ class PipeStats:
                     for name in ("pool_wait_seconds", "pack_seconds",
                                  "dispatch_seconds",
                                  "h2d_submit_seconds", "launch_seconds",
-                                 "sync_seconds")})
+                                 "sync_seconds", "sync_ready_seconds",
+                                 "sync_copy_seconds",
+                                 *(f"{w}_seconds" for w in flight.WAITS))})
         if self.wall_seconds > 0:
             d["gibps"] = round(
                 self.bytes_in / (1 << 30) / self.wall_seconds, 3)
@@ -371,8 +401,12 @@ class PipeStats:
 #: published runs live here; every other ``*_seconds`` key is read from
 #: the process-wide span totals (flight.totals()).
 _TELEMETRY_LOCK = threading.Lock()
-_TOTALS = {"runs": 0, "batches": 0, "bytes_in": 0, "bytes_out": 0,
-           "wall_seconds": 0.0,
+_TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
+           "bytes_out": 0, "wall_seconds": 0.0,
+           # launch's return -> ready, and the dispatch's input bytes,
+           # of the results a writer really waited for: their ratio is
+           # the rate at which a group's inputs crossed, kernel included
+           "group_ready_seconds": 0.0, "group_ready_bytes": 0,
            # the coalescing batcher's runs alone (pipeline/batch.py)
            "batch_volumes": 0, "batch_rows": 0, "batch_row_slots": 0,
            "batch_launches": 0,
@@ -387,6 +421,9 @@ def publish_stats(stats: "PipeStats", kind: str = "pipe") -> None:
     with _TELEMETRY_LOCK:
         _TOTALS["runs"] += 1
         _TOTALS["batches"] += stats.batches
+        _TOTALS["groups"] += stats.groups
+        _TOTALS["group_ready_seconds"] += stats.group_ready_seconds
+        _TOTALS["group_ready_bytes"] += stats.group_ready_bytes
         _TOTALS["bytes_in"] += stats.bytes_in
         _TOTALS["bytes_out"] += stats.bytes_out
         _TOTALS["wall_seconds"] += stats.wall_seconds
@@ -415,11 +452,20 @@ def last_run() -> Optional[dict]:
 
 def debug_payload() -> dict:
     """/debug/vars section, every key flat and cumulative since process
-    start: the published runs' counters and wall, the batcher's
+    start: the published runs' counters and wall (``batches`` /
+    ``groups`` = slabs per dispatch; ``group_ready_bytes`` /
+    ``group_ready_seconds`` = the rate a dispatch's inputs crossed at,
+    where a writer waited for them), the batcher's
     ``batch_*`` counts, ``pool_acquires`` / ``pool_fresh_acquires``
     (every buffer a :class:`HostBufferPool` lent, and those it had
     never lent before), the stage spans' seconds (``compute`` =
-    dispatch + sync; ``write`` = writer stage + positioned writes;
+    dispatch + sync; ``sync`` = ``sync_ready``, the writer's wait for a
+    result to be ready on the device, + ``sync_copy``, the result
+    coming home; the four queue waits and two tails of flight.WAITS;
+    ``write_drain`` = the caller's wait in ``WriterPool.close`` for the
+    last positioned writes, after the stages' run and inside the wall;
+    ``write`` = writer stage + positioned writes, ``write_stage`` the
+    writer thread's share of it;
     ``fsync`` = the sweep's shard-file barriers, on the writeback
     pool's threads; ``pack`` is carved out of ``read``;
     ``decode_matrix`` = the host's share of a reconstruct, once per
@@ -436,13 +482,18 @@ def debug_payload() -> dict:
         out = dict(_TOTALS)
         recent = [dict(e) for e in RECENT]
     out.update(read_seconds=sec("read"),
-               compute_seconds=sec("dispatch", "d2h_sync"),
+               compute_seconds=sec("dispatch", "d2h_sync", "d2h_ready"),
                write_seconds=sec("write", "pwritev"),
+               write_stage_seconds=sec("write"),
                fsync_seconds=sec("fsync"),
                pool_wait_seconds=sec("pool_wait"),
                pack_seconds=sec("pack"),
                dispatch_seconds=sec("dispatch"),
-               sync_seconds=sec("d2h_sync"),
+               sync_seconds=sec("d2h_sync", "d2h_ready"),
+               sync_ready_seconds=sec("d2h_ready"),
+               sync_copy_seconds=sec("d2h_sync"),
+               write_drain_seconds=sec("write_drain"),
+               **{f"{name}_seconds": sec(name) for name in flight.WAITS},
                h2d_submit_seconds=sec("h2d_submit"),
                launch_seconds=sec("launch"),
                decode_matrix_seconds=sec("decode_matrix"),
@@ -689,6 +740,45 @@ def _batch_nbytes(batch) -> int:
     return getattr(batch, "nbytes", 0)
 
 
+class _Sync:
+    """The writer's sync point on one result, a ``d2h_sync`` span in
+    two parts. A device result (anything with ``block_until_ready``:
+    rs_jax's ``_HostParity``, a mesh step's ``jax.Array``) is asked for
+    its fetch first, where ``np.asarray`` alone would ask; then the
+    nested leaf ``d2h_ready`` waits until the result is ready on the
+    device — its inputs landed, the kernel done — and ``np.asarray``
+    brings it home, which is what the span keeps of its own. A host
+    result has nothing to wait for and reads ``d2h_ready`` 0.
+
+    Where the wait was a real one (:data:`READY_WAITED`) and the result
+    says when it was launched, the time from the launch's return to
+    ready and the dispatch's input bytes go to the run's stats, once
+    per dispatch (its results share one ``launched``)."""
+
+    def __init__(self, st: PipeStats):
+        self.st = st
+        self._counted = None
+
+    def __call__(self, result, seq: int):
+        """(the result on the host, the sync's seconds)."""
+        with flight.span("d2h_sync", batch=seq) as sp:
+            wait = getattr(result, "block_until_ready", None)
+            if wait is not None:
+                result.copy_to_host_async()
+                with flight.span("d2h_ready", batch=seq) as ready:
+                    wait()
+                launched = getattr(result, "launched", None)
+                if ready.elapsed > READY_WAITED and launched is not None \
+                        and launched is not self._counted:
+                    self._counted = launched
+                    self.st.group_ready_seconds += \
+                        time.perf_counter() - launched[0]
+                    self.st.group_ready_bytes += launched[1]
+            result_np = np.asarray(result)
+            sp.nbytes = result_np.nbytes
+        return result_np, sp.elapsed
+
+
 def _run_sync(batches, encode_fn, write_fn, recycle_fn,
               st: PipeStats, prepare_fn=None) -> int:
     """The synchronous reference path: same stages, one thread
@@ -696,6 +786,7 @@ def _run_sync(batches, encode_fn, write_fn, recycle_fn,
     nothing here — that is what makes it the byte-identity oracle for
     the double-buffered path)."""
     it = iter(batches)
+    sync = _Sync(st)
     while True:
         seq = st.batches
         try:
@@ -708,9 +799,7 @@ def _run_sync(batches, encode_fn, write_fn, recycle_fn,
             sp.arg = 1
             result = encode_fn(batch if prepare_fn is None
                                else prepare_fn(batch))
-        with flight.span("d2h_sync", batch=seq) as sp:
-            result_np = np.asarray(result)
-            sp.nbytes = result_np.nbytes
+        result_np, _ = sync(result, seq)
         with flight.span("write", batch=seq):
             write_fn(meta, batch, result_np)
             if recycle_fn is not None:
@@ -761,35 +850,42 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                         sp.nbytes = _batch_nbytes(item[1])
                 except StopIteration:
                     return
-                seq += 1
                 _stage_observe("pipe.read", sp.elapsed, sp.nbytes)
                 if controller is not None:
                     controller.note_read(sp.elapsed)
                 if stop.is_set():
                     return
-                read_q.put(item)
+                with flight.span("reader_blocked", batch=seq):
+                    read_q.put(item)
+                seq += 1
                 flight.record(flight.EV_QDEPTH,
                               value=float(read_q.qsize()), arg=0)
         except BaseException as e:  # noqa: BLE001 — re-raised in main
             errors.append(e)
         finally:
-            read_q.put(_END)
+            # the tail: nothing left to read while the stages after it
+            # work; main sets ``stop`` once the writer is joined. Not a
+            # leaf, like compute_done: on the profiler's plane a tail
+            # would overlap every gap after it and name them all
+            with flight.span("reader_done", batch=seq, leaf=False):
+                read_q.put(_END)
+                stop.wait()
 
     def writer():
         seq = 0
         flight.bind(run)
+        sync = _Sync(st)
         try:
             while True:
-                item = write_q.get()
+                with flight.span("writer_starved", batch=seq):
+                    item = write_q.get()
                 if item is _END:
                     return
                 flight.record(flight.EV_QDEPTH,
                               value=float(write_q.qsize()), arg=1)
                 meta, batch, result, disp_share = item
-                with flight.span("d2h_sync", batch=seq) as sp:
-                    result_np = np.asarray(result)
-                    sp.nbytes = result_np.nbytes
-                _stage_observe("pipe.compute", disp_share + sp.elapsed,
+                result_np, sync_s = sync(result, seq)
+                _stage_observe("pipe.compute", disp_share + sync_s,
                                result_np.nbytes)
                 with flight.span("write", batch=seq) as sp:
                     write_fn(meta, batch, result_np)
@@ -818,8 +914,14 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                           daemon=True)
     wt = threading.Thread(target=writer, name="ec-pipe-write",
                           daemon=True)
-    rt.start()
-    wt.start()
+    # Starting the reader holds this thread for about the reader's first
+    # read (measured on the chip's host, PERF.md): that is the compute
+    # stage waiting for the reader like any other, so it is spanned so,
+    # and the writer, which only waits, is started first so that its
+    # first wait begins with the run.
+    with flight.span("compute_starved", batch=0):
+        wt.start()
+        rt.start()
     n = 0
     #: compute-stage batch sequence (see reader() note: FIFO order
     #: makes per-stage counters line up per batch)
@@ -828,6 +930,12 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
     #: (meta, batch, prepared) whose H2D transfer is in flight while
     #: the previous batch computes; flushed after the loop.
     pending = None
+
+    def hand_over(seq: int, item: tuple) -> None:
+        with flight.span("compute_blocked", batch=seq):
+            write_q.put(item)
+        flight.record(flight.EV_QDEPTH,
+                      value=float(write_q.qsize()), arg=1)
 
     def _fail(e: BaseException, drop) -> None:
         # a compute-stage failure: record it, stop the stages, and
@@ -845,7 +953,8 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
     try:
         ended = False
         while not ended:
-            item = read_q.get()
+            with flight.span("compute_starved", batch=cseq):
+                item = read_q.get()
             if item is _END:
                 break
             if stop.is_set():
@@ -901,12 +1010,10 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                         pending = None
                     _fail(e, drop)
                     break
-                cseq += 1
                 st.groups += 1
                 st.max_group = max(st.max_group, 1)
-                write_q.put((meta, batch, result, dt + sp.elapsed))
-                flight.record(flight.EV_QDEPTH,
-                              value=float(write_q.qsize()), arg=1)
+                hand_over(cseq, (meta, batch, result, dt + sp.elapsed))
+                cseq += 1
                 n += 1
                 continue
             # group drain: whatever is already queued, plus — when the
@@ -915,26 +1022,28 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
             target = min(group, controller.target()) if controller \
                 else group
             items = [item]
-            while len(items) < target:
-                try:
-                    nxt = read_q.get_nowait()
-                except queue.Empty:
-                    wait = controller.wait_seconds() if controller \
-                        else 0.0
-                    if wait <= 0.0:
-                        if controller is not None:
-                            controller.note_starved()
-                        break
+            # the wait for a group to form is a wait for the reader
+            with flight.span("compute_starved", batch=cseq):
+                while len(items) < target:
                     try:
-                        nxt = read_q.get(timeout=wait)
+                        nxt = read_q.get_nowait()
                     except queue.Empty:
-                        if controller is not None:
-                            controller.note_starved()
+                        wait = controller.wait_seconds() if controller \
+                            else 0.0
+                        if wait <= 0.0:
+                            if controller is not None:
+                                controller.note_starved()
+                            break
+                        try:
+                            nxt = read_q.get(timeout=wait)
+                        except queue.Empty:
+                            if controller is not None:
+                                controller.note_starved()
+                            break
+                    if nxt is _END:
+                        ended = True
                         break
-                if nxt is _END:
-                    ended = True
-                    break
-                items.append(nxt)
+                    items.append(nxt)
             if controller is not None and len(items) >= target:
                 controller.note_supplied()
             try:
@@ -952,16 +1061,14 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                         except BaseException:  # seaweedlint: disable=SW301 — best-effort recycle on shutdown; first error already recorded
                             pass
                 break
-            cseq += len(items)
             st.groups += 1
             st.max_group = max(st.max_group, len(items))
             if controller is not None:
                 controller.note_dispatch(sp.elapsed, len(items))
             share = sp.elapsed / len(items)
             for (meta, batch), result in zip(items, results):
-                write_q.put((meta, batch, result, share))
-                flight.record(flight.EV_QDEPTH,
-                              value=float(write_q.qsize()), arg=1)
+                hand_over(cseq, (meta, batch, result, share))
+                cseq += 1
             n += len(items)
         # flush the double-buffer tail: the last prepared batch has no
         # successor to overlap with
@@ -983,14 +1090,15 @@ def _run_overlapped(batches, encode_fn, write_fn, depth,
                 except BaseException as e:  # noqa: BLE001 — see _fail
                     _fail(e, [(meta, batch)])
                 else:
-                    cseq += 1
                     st.groups += 1
                     st.max_group = max(st.max_group, 1)
-                    write_q.put((meta, batch, result, sp.elapsed))
+                    hand_over(cseq, (meta, batch, result, sp.elapsed))
+                    cseq += 1
                     n += 1
     finally:
-        write_q.put(_END)
-        wt.join()
+        with flight.span("compute_done", batch=cseq, leaf=False):
+            write_q.put(_END)
+            wt.join()
         stop.set()
         # Unblock the reader if it is waiting on a full queue, and
         # recycle anything it had already materialized.

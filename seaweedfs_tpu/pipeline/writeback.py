@@ -216,10 +216,11 @@ class WriterPool:
         maps path -> final size for files whose preallocation
         over-reserved (tail-padded stripes). Raises the first worker
         error, if any."""
-        for q in self._queues:
-            q.put(_END)
-        for t in self._workers:
-            t.join()
+        with flight.span("write_drain"):
+            for q in self._queues:
+                q.put(_END)
+            for t in self._workers:
+                t.join()
         try:
             if truncate_to and not self._errors:
                 for path, size in truncate_to.items():

@@ -672,6 +672,10 @@ def cmd_pipeline_analyze(env: CommandEnv, argv: list[str]) -> None:
         if bub is not None:
             line += f" bubble={bub}s"
         env.println(line)
+    env.println("  waits (a stage thread held by its neighbour; the pacing "
+                f"stage, here the {ana['pacing']}, is the one that never "
+                "waits): " + ", ".join(
+                    f"{k}={v:.1%}" for k, v in occ["wait_fraction"].items()))
     if occ["waited_on"]:
         waits = ", ".join(
             f"{k}={v}" for k, v in sorted(occ["waited_on"].items(),

@@ -233,10 +233,11 @@ class TestAnalyzer:
         assert occ["batches"] == 4
         assert occ["busy_fraction"]["dispatch"] > \
             occ["busy_fraction"]["read"]
-        # H2D submit, launch and the D2H wait are lanes of their own
+        # H2D submit, launch and the two halves of the writer's sync
+        # are lanes of their own
         assert set(ana["lane_fraction"]) == {
             "read", "pool_wait", "dispatch", "h2d_submit", "launch",
-            "d2h", "write"}
+            "d2h_ready", "d2h_copy", "write"}
         assert "recommendations" not in ana
 
     @pytest.mark.parametrize("kw, lane", [
@@ -387,8 +388,10 @@ class TestCommands:
         text = env2.out.getvalue()
         assert "bottleneck:" in text
         # every lane is printed with its share; no knob advice
-        for lane in ("h2d_submit", "launch", "d2h", "dispatch"):
+        for lane in ("h2d_submit", "launch", "d2h_ready", "d2h_copy",
+                     "dispatch"):
             assert f"  {lane}: busy=" in text
+        assert "  waits (" in text and "writer_starved=" in text
         assert "[pipeline]" not in text
 
     def test_status_mentions_flight_state(self, tmp_path):

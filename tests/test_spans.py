@@ -15,6 +15,7 @@ import threading
 import time
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -162,6 +163,173 @@ def test_run_stats_are_fed_by_the_spans(overlapped):
 
 
 # --------------------------------------------------------------------------
+# who waits for whom: the two halves of the sync, the queue waits, the tails
+# --------------------------------------------------------------------------
+
+class StandInResult:
+    """What the writer syncs on, as a device result offers it: a fetch
+    to ask for, a wait until ready, then ``np.asarray``."""
+
+    def __init__(self, arr, ready_s=0.0, land_s=0.0, launched=None):
+        self.arr, self.ready_s, self.land_s = arr, ready_s, land_s
+        self.launched = launched
+        self.asked = 0
+
+    def copy_to_host_async(self):
+        self.asked += 1
+
+    def block_until_ready(self):
+        assert self.asked == 1      # the fetch is asked for before the wait
+        time.sleep(self.ready_s)
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.land_s)
+        return self.arr
+
+
+SLOW = 0.015
+#: thread -> the spans that account for it from the run's start to its end
+THREADS = {
+    "reader": ("read", "pool_wait", "pack", "reader_blocked", "reader_done"),
+    "compute": ("dispatch", "compute_starved", "compute_blocked",
+                "compute_done"),
+    "writer": ("d2h_sync", "d2h_ready", "write", "writer_starved"),
+}
+
+
+BLOCKED = ("compute_blocked", "reader_blocked")
+
+
+@pytest.mark.parametrize("slow, leads", [
+    ("reader", ("compute_starved", "writer_starved")),
+    # the writer thread is the slow one, in one half of its sync or the
+    # other (``d2h_sync`` keeps what is not ``d2h_ready``: the copy), and
+    # the stages before it are held
+    ("ready", ("d2h_ready",) + BLOCKED),
+    ("landing", ("d2h_sync",) + BLOCKED),
+    ("writer", BLOCKED),
+])
+def test_the_wait_that_leads_names_the_slow_stage(slow, leads):
+    """Stand-in stages of which one sleeps: the stages after it starve,
+    those before it are blocked, the sync's two halves tell a result
+    that is not ready from one that is slow to land, and every stage
+    thread is accounted for from the run's start to its end."""
+    n = 24
+
+    def batches():
+        for i in range(n):
+            if slow == "reader":
+                time.sleep(SLOW)
+            yield i, np.zeros(256, dtype=np.uint8)
+
+    def encode(batch):
+        return StandInResult(batch, ready_s=SLOW * (slow == "ready"),
+                             land_s=SLOW * (slow == "landing"))
+
+    def write(meta, batch, result):
+        if slow == "writer":
+            time.sleep(SLOW)
+
+    st = pipe.PipeStats()
+    before = flight.totals()
+    run_guarded(lambda: pipe.run_pipeline(
+        batches(), encode, write, depth=2, stats=st, publish=False))
+    after = flight.totals()
+    got = {name: delta(before, after, name)[0]
+           for names in THREADS.values() for name in names}
+    assert st.batches == n and st.wall_seconds >= n * SLOW
+    # the slow stage's neighbours wait for it, nearly the whole run
+    for name in leads:
+        assert got[name] >= 0.6 * n * SLOW, (name, got)
+    # ... and nothing else waits nearly as long (a reader's time is in
+    # ``read``, a writer's in ``write``: neither is a wait; the tails are
+    # the pipeline's drain, a few batches whichever stage is slow)
+    waits = flight.QUEUE_WAITS + ("d2h_ready", "d2h_sync", "pool_wait")
+    for name in waits:
+        if name not in leads:
+            assert got[name] <= 0.4 * n * SLOW, (name, got)
+    # every thread: its spans and waits are its whole run
+    for thread, names in THREADS.items():
+        total = sum(got[name] for name in names)
+        assert 0.95 * st.wall_seconds <= total <= 1.02 * st.wall_seconds, \
+            (thread, total, st.wall_seconds, got)
+    # the per-run stats hold what the totals hold
+    for name in flight.WAITS:
+        assert getattr(st, f"{name}_seconds") == pytest.approx(
+            got[name], abs=1e-6)
+    assert st.sync_ready_seconds == pytest.approx(got["d2h_ready"], abs=1e-6)
+    assert st.sync_copy_seconds == pytest.approx(got["d2h_sync"], abs=1e-6)
+    assert st.sync_seconds == st.sync_ready_seconds + st.sync_copy_seconds
+    assert st.to_dict()["sync_seconds"] == pytest.approx(
+        got["d2h_ready"] + got["d2h_sync"], abs=2e-6)
+
+
+@pytest.mark.parametrize("overlapped", [True, False])
+def test_a_host_result_reads_no_ready_wait_and_the_same_bytes(overlapped):
+    """A ``numpy`` result has nothing to ask for and nothing to wait for:
+    no ``d2h_ready`` span at all, on either path, and the bytes a device
+    result's three steps give are the bytes ``np.asarray`` alone gives."""
+    rng = np.random.default_rng(11)
+    data = [rng.integers(0, 256, 512, dtype=np.uint8) for _ in range(5)]
+
+    def run(encode):
+        out = []
+        st = pipe.PipeStats()
+        before = flight.totals()
+        run_guarded(lambda: pipe.run_pipeline(
+            enumerate(data), encode,
+            lambda meta, b, r: out.append(r.copy()),
+            stats=st, overlapped=overlapped, publish=False))
+        return out, st, delta(before, flight.totals(), "d2h_ready")
+
+    host, st, ready = run(lambda b: b ^ 0x5A)
+    assert ready == (0.0, 0) and st.sync_ready_seconds == 0.0
+    assert st.sync_seconds == st.sync_copy_seconds > 0
+    assert st.group_ready_bytes == 0
+    device, st, ready = run(lambda b: StandInResult(b ^ 0x5A))
+    assert ready[1] == 5 and st.sync_ready_seconds == pytest.approx(ready[0])
+    assert all(np.array_equal(a, b) for a, b in zip(host, device))
+    assert all(np.array_equal(a, d ^ 0x5A) for a, d in zip(host, data))
+
+
+def test_a_group_counts_once_when_the_writer_really_waited():
+    """Results of one dispatch share one ``launched``; the first the writer
+    really waits for gives the group's time to ready and its input bytes,
+    the others of that dispatch and a result found ready give nothing."""
+    t0 = time.perf_counter()
+    groups = [(t0, 3000), (t0, 5000), (t0, 7000)]
+    waits = [0.02, 0.02, 0.0, 0.0, 0.02, 0.0]     # 2 + 2 + 2 results
+    results = [StandInResult(np.zeros(8, dtype=np.uint8), ready_s=w,
+                             launched=groups[i // 2])
+               for i, w in enumerate(waits)]
+    st = pipe.PipeStats()
+    run_guarded(lambda: pipe.run_pipeline(
+        ((i, r) for i, r in enumerate(results)), lambda r: r,
+        lambda meta, b, r: None, stats=st, overlapped=False, kind="groups"))
+    assert st.group_ready_bytes == 3000 + 7000
+    # launch's return -> found ready, for the two groups waited for
+    assert 0.02 + 0.06 <= st.group_ready_seconds < 1.0
+    pay = pipe.debug_payload()
+    assert pay["group_ready_bytes"] >= 10000 and pay["groups"] >= 6
+
+
+def test_rs_jax_results_offer_the_three_steps():
+    """``_HostParity`` is what the writer syncs on in every EC pipeline:
+    it passes the request and the wait to its device array, and the
+    results of one dispatch share the launch's clock and input bytes."""
+    slabs = [np.arange(2 * 3 * 64, dtype=np.uint8).reshape(2, 3, 64)] * 2
+    dev = [jax.numpy.asarray(s.view(np.uint32)) for s in slabs]
+    launched = (time.perf_counter(), sum(s.nbytes for s in slabs))
+    results = [rs_jax._HostParity(d, 2, 3, 64, launched) for d in dev]
+    for r, s in zip(results, slabs):
+        r.copy_to_host_async()
+        r.block_until_ready()
+        assert np.array_equal(np.asarray(r), s)
+    assert results[0].launched is results[1].launched
+    assert rs_jax._HostParity(dev[0], 2, 3, 64).launched is None
+
+
+# --------------------------------------------------------------------------
 # the ring, armed: the events the hand-written sites wrote
 # --------------------------------------------------------------------------
 
@@ -187,6 +355,62 @@ def test_ring_events_of_a_run_are_those_of_the_hand_written_sites():
                  (flight.EV_WRITE_START, b, 0), (flight.EV_WRITE_END, b, 0)]
     want += [(flight.EV_READ_START, 2, 0), (flight.EV_RUN_END, -1, 0)]
     assert got == want
+
+
+@pytest.mark.parametrize("slow, lane, pacing", [
+    ("ready", "d2h_ready", "writer"),
+    ("landing", "d2h_copy", "writer"),
+    ("reader", "read", "reader"),
+])
+def test_the_waits_reach_the_ring_and_the_verdict(slow, lane, pacing):
+    """Armed, a run writes the ready wait, the four queue waits and the
+    two tails into the ring; ``occupancy`` splits the sync's lane in two,
+    holds each wait as the waiting thread's span, and ``analyze`` names
+    the lane and the stage that never waited."""
+    rec = flight.arm(capacity=4096)
+    flight.reset()
+
+    def batches():
+        for i in range(8):
+            time.sleep(SLOW * (slow == "reader"))
+            yield i, np.zeros(64, dtype=np.uint8)
+
+    run_guarded(lambda: pipe.run_pipeline(
+        batches(),
+        lambda b: StandInResult(b, ready_s=SLOW * (slow == "ready"),
+                                land_s=SLOW * (slow == "landing")),
+        lambda meta, b, r: None, depth=2, publish=False))
+    evs = rec.snapshot()
+    kinds = {ev[1] for ev in evs}
+    assert kinds >= set(range(flight.EV_READY_WAIT,
+                              flight.EV_COMPUTE_JOINED + 1))
+    # each wait on the thread that waits: the reader's, the compute
+    # stage's (this thread's own runner) and the writer's are three
+    tids = {name: {ev[3] for ev in evs if ev[1] == code}
+            for code, _end, name in flight._SPAN_PAIRS}
+    assert all(len(tids[name]) == 1 for name in flight.WAITS)
+    assert tids["reader_blocked"] == tids["reader_done"] == tids["read"]
+    assert tids["compute_starved"] == tids["compute_blocked"] \
+        == tids["compute_done"] == tids["dispatch"]
+    assert tids["writer_starved"] == tids["d2h_ready"] == tids["d2h_sync"]
+    ana = flight.analyze()
+    occ = ana["occupancy"]
+    assert ana["bottleneck"] == lane and ana["pacing"] == pacing
+    # a batch held before a full queue waited as long as the slow stage
+    # took with the one before it: either may lead the per-batch count
+    assert ana["waited_on_top"] in (lane, "queue_wait_compute",
+                                    "queue_wait_writer")
+    assert set(occ["wait_seconds"]) == set(occ["wait_fraction"]) \
+        == set(flight.WAITS)
+    assert set(occ["stage_wait_fraction"]) == {"reader", "compute",
+                                               "writer"}
+    assert occ["stage_wait_fraction"][pacing] < 0.2
+    # the copy lane is the sync less the ready wait, never below zero
+    busy = occ["busy_seconds"]
+    assert busy["d2h_ready"] >= 0 and busy["d2h_copy"] >= 0
+    assert busy[lane] >= 0.6 * 8 * SLOW
+    names = {e["name"] for e in flight.chrome_trace()["traceEvents"]}
+    assert names >= set(flight.WAITS) | {"d2h_ready", "d2h_sync"}
 
 
 def test_ring_events_of_the_pool_and_the_writeback(tmp_path):
@@ -291,18 +515,24 @@ def test_a_profiler_session_holds_the_stage_spans_as_leaves(
     events = host_events(tmp_path / "trace")
     ours = [e for e in events if e["name"] in (
         "read", "pool_wait", "h2d_submit", "launch", "dispatch",
-        "d2h_sync", "write", "pwritev", "step_outer", "step_inner")]
+        "d2h_sync", "d2h_ready", "write", "pwritev", "step_outer",
+        "step_inner") + flight.QUEUE_WAITS]
     by_name: dict = {}
     for e in ours:
         by_name.setdefault(e["name"], []).append(e)
     # two batches of one row each went down the device leg
-    for name in ("read", "h2d_submit", "launch", "d2h_sync", "write"):
+    for name in ("read", "h2d_submit", "launch", "d2h_sync", "d2h_ready",
+                 "write") + flight.QUEUE_WAITS:
         assert len(by_name[name]) >= 2, (name, sorted(by_name))
         for e in by_name[name]:
             assert set(e["args"]) >= {"batch", "bytes", "run"}, e
     assert {e["args"]["batch"] for e in by_name["launch"]} == {0, 1}
     assert {e["args"]["bytes"] for e in by_name["h2d_submit"]} == {4 * seg}
-    assert {e["args"]["bytes"] for e in by_name["d2h_sync"]} == {2 * seg}
+    # d2h_ready splits its d2h_sync in two pieces on this plane: the
+    # request for the fetch before it (no bytes known yet), the result
+    # coming home after it
+    assert {e["args"]["bytes"] for e in by_name["d2h_sync"]} == {0, 2 * seg}
+    assert len(by_name["d2h_sync"]) == 2 * len(by_name["d2h_ready"])
     # one id for the stage threads of the run; the writeback pool's
     # workers and the rpc steps belong to no run
     (run_id,) = {e["args"]["run"] for e in ours
@@ -335,7 +565,11 @@ def test_a_profiler_session_holds_the_stage_spans_as_leaves(
 PIPELINE_KEYS = ["pool_wait_seconds", "dispatch_seconds", "sync_seconds",
                  "h2d_submit_seconds", "launch_seconds", "rpc_seconds",
                  "read_seconds", "compute_seconds", "write_seconds",
-                 "wall_seconds", "pool_acquires", "pool_fresh_acquires"] + [
+                 "wall_seconds", "pool_acquires", "pool_fresh_acquires",
+                 "sync_ready_seconds", "sync_copy_seconds",
+                 "write_drain_seconds", "write_stage_seconds", "groups", "group_ready_seconds",
+                 "group_ready_bytes"] + [
+    f"{name}_seconds" for name in flight.WAITS] + [
     f"step_{name}_{what}" for name in flight.HANDLER_STEPS
     + flight.INNER_STEPS for what in ("seconds", "calls")]
 
@@ -476,10 +710,18 @@ def test_rpc_seconds_lie_between_the_pipeline_and_the_client(
     assert rpc == pytest.approx(sum(
         snaps[b][f"step_{n}_seconds"] - snaps[a][f"step_{n}_seconds"]
         for n in flight.HANDLER_STEPS), abs=1e-4)
-    # compute stays dispatch + sync: accepted metrics read it
+    # compute stays dispatch + sync, and sync its two halves: accepted
+    # metrics read them
     for snap in (snaps[a], snaps[b]):
         assert snap["compute_seconds"] == pytest.approx(
             snap["dispatch_seconds"] + snap["sync_seconds"], abs=2e-6)
+        assert snap["sync_seconds"] == pytest.approx(
+            snap["sync_ready_seconds"] + snap["sync_copy_seconds"],
+            abs=2e-6)
+    # the command's run: one more group at least, its threads' waits
+    assert snaps[b]["groups"] > snaps[a]["groups"]
+    assert snaps[b]["writer_starved_seconds"] > \
+        snaps[a]["writer_starved_seconds"]
 
 
 def test_trace_dump_shows_the_steps_under_their_grpc_spans(
