@@ -756,17 +756,24 @@ class _VolumeServicer:
             raise StoreError(f"{path} does not exist")
         stop = request.stop_offset or path.stat().st_size
         start = min(request.start_offset, stop)
-        with open(path, "rb") as f:
-            if start:
-                f.seek(start)
-            sent = start
-            while sent < stop:
-                chunk = f.read(min(_COPY_CHUNK, stop - sent))
-                if not chunk:
-                    break
-                sent += len(chunk)
-                yield volume_server_pb2.CopyFileResponse(
-                    file_content=chunk)
+        # one span per stream, the puller's pace included: it stays open
+        # while gRPC hands each chunk on (the sync server drains the
+        # generator on this thread)
+        sent = start
+        with flight_mod.span("copy_file") as sp, open(path, "rb") as f:
+            try:
+                if start:
+                    f.seek(start)
+                while sent < stop:
+                    chunk = f.read(min(_COPY_CHUNK, stop - sent))
+                    if not chunk:
+                        break
+                    sent += len(chunk)
+                    yield volume_server_pb2.CopyFileResponse(
+                        file_content=chunk)
+            finally:
+                sp.nbytes = sent - start
+                pipe_mod.count("copy_file_bytes", sp.nbytes)
 
     def VolumeCopy(self, request, context):
         """Pull a whole .dat/.idx pair from the source node and register
@@ -913,7 +920,8 @@ class _VolumeServicer:
             return resp
         # Fetch remote siblings until k survivors are on local disk.
         fetched: list = []
-        with flight_mod.span("step_rebuild_fetch", trace=True):
+        # holds copy_recv / copy_commit, so it is no leaf
+        with flight_mod.span("step_rebuild_fetch", leaf=False, trace=True):
             for sid in range(total):
                 if len(local) >= scheme.data_shards:
                     break
@@ -924,10 +932,11 @@ class _VolumeServicer:
                         continue
                     try:
                         dest = ec_files.shard_path(base, sid)
-                        _copy_remote_file(
-                            vs, url, request.volume_id,
-                            request.collection, ec_files.shard_ext(sid),
-                            dest)
+                        pipe_mod.count(
+                            "rebuild_fetch_bytes", _copy_remote_file(
+                                vs, url, request.volume_id,
+                                request.collection,
+                                ec_files.shard_ext(sid), dest))
                         local.add(sid)
                         fetched.append(dest)
                         break
@@ -948,29 +957,37 @@ class _VolumeServicer:
         resp.rebuilt_shard_ids.extend(rebuilt)
         return resp
 
+    @_ec_step("shards_copy")
     def VolumeEcShardsCopy(self, request, context):
-        """Pull shards (and index files) from source_data_node to here."""
+        """Pull shards (and index files) from source_data_node to here.
+        All or nothing: when a file fails, the files this call had
+        placed are removed again, so that a shard its source still
+        holds is on no second disk."""
         vs = self.vs
         base = _dest_base(vs, request.volume_id, request.collection)
         src = request.source_data_node
-        for sid in request.shard_ids:
-            _copy_remote_file(vs, src, request.volume_id,
-                              request.collection, ec_files.shard_ext(sid),
-                              ec_files.shard_path(base, sid))
+        wanted = [(ec_files.shard_ext(sid), ec_files.shard_path(base, sid),
+                   False) for sid in request.shard_ids]
         if request.copy_ecx_file:
-            _copy_remote_file(vs, src, request.volume_id,
-                              request.collection, ".ecx",
-                              ec_files.ecx_path(base))
+            wanted.append((".ecx", ec_files.ecx_path(base), False))
         if request.copy_ecj_file:
             # .ecj may legitimately not exist (no post-seal deletes yet).
-            _copy_remote_file(vs, src, request.volume_id,
-                              request.collection, ".ecj",
-                              ec_files.ecj_path(base),
-                              ignore_missing=True)
+            wanted.append((".ecj", ec_files.ecj_path(base), True))
         if request.copy_vif_file:
-            _copy_remote_file(vs, src, request.volume_id,
-                              request.collection, ".vif",
-                              ec_files.vif_path(base))
+            wanted.append((".vif", ec_files.vif_path(base), False))
+        placed: list[Path] = []
+        try:
+            for ext, dest, ignore_missing in wanted:
+                was_here = dest.exists()
+                _copy_remote_file(vs, src, request.volume_id,
+                                  request.collection, ext, dest,
+                                  ignore_missing=ignore_missing)
+                if not was_here:
+                    placed.append(dest)
+        except Exception:
+            for p in placed:
+                p.unlink(missing_ok=True)
+            raise
         vs.heartbeat_now()
         return volume_server_pb2.VolumeEcShardsCopyResponse()
 
@@ -1100,29 +1117,40 @@ def _scheme_from_vif(base) -> EcScheme:
 
 def _copy_remote_file(vs: VolumeServer, src_url: str, volume_id: int,
                       collection: str, ext: str, dest: Path,
-                      ignore_missing: bool = False) -> None:
+                      ignore_missing: bool = False) -> int:
+    """Pull one file of a volume from ``src_url`` into ``dest``; returns
+    the bytes received. Two leaf spans: ``copy_recv`` (the stream into
+    ``<dest>.part``) and ``copy_commit`` (fsync + rename)."""
     dest.parent.mkdir(parents=True, exist_ok=True)
     tmp = dest.with_suffix(dest.suffix + ".part")
-    got_any = False
+    received = 0
     try:
-        with open(tmp, "wb") as f:
+        with flight_mod.span("copy_recv") as sp, open(tmp, "wb") as f:
             for resp in vs.peer_stub(src_url).CopyFile(
                     volume_server_pb2.CopyFileRequest(
                         volume_id=volume_id, collection=collection,
                         ext=ext,
                         ignore_source_file_not_found=ignore_missing)):
-                f.write(resp.file_content)
-                got_any = True
+                # read the field once: each access copies the chunk
+                chunk = resp.file_content
+                f.write(chunk)
+                received += len(chunk)
+                faults.check("ec.shard_copy")
+            sp.nbytes = received
     except Exception:
         tmp.unlink(missing_ok=True)
         raise
-    if ignore_missing and not got_any and tmp.stat().st_size == 0:
+    finally:
+        pipe_mod.count("copy_recv_bytes", received)
+    if ignore_missing and not received:
         tmp.unlink()
-        return
+        return 0
     # durable rename commit: the copied replica/shard file must survive
     # power loss once callers (ec.rebuild, volume copy) treat it as
     # placed — fsync the bytes AND the directory entry
-    durability.durable_replace(tmp, dest)
+    with flight_mod.span("copy_commit", nbytes=received):
+        durability.durable_replace(tmp, dest)
+    return received
 
 
 def _make_http_handler(vs: VolumeServer):

@@ -337,9 +337,10 @@ _RING_CODES["pwritev"] = (0, EV_PWRITEV_RETIRE)
 #: around the whole handler; their sum is ``rpc_seconds``) and the
 #: steps inside them and on the master's side. ``/debug/vars`` lists
 #: every one from process start, so a reader finds the key before the
-#: first call.
+#: first call. ``shards_copy`` runs on the server that *receives*
+#: shards (``ec.encode``'s spread, ``ec.balance``, ``ec.decode``).
 HANDLER_STEPS = ("mark_readonly", "generate", "mount", "delete_source",
-                 "shards_delete", "rebuild")
+                 "shards_delete", "rebuild", "shards_copy")
 INNER_STEPS = ("vol_sync", "shard_files", "ecx", "vif", "rebuild_fetch",
                "store_mount", "store_delete", "heartbeat", "reconcile",
                "master_heartbeat", "master_lookup")

@@ -412,7 +412,13 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            "batch_launches": 0,
            # every HostBufferPool.acquire, and those of a buffer never
            # lent before (its pages are faulted in by the fill)
-           "pool_acquires": 0, "pool_fresh_acquires": 0}
+           "pool_acquires": 0, "pool_fresh_acquires": 0,
+           # files between volume servers (cluster/volume_server.py):
+           # bytes a CopyFile stream served, bytes a puller wrote to
+           # its .part files, and those of them a rebuild's sibling
+           # fetch pulled
+           "copy_file_bytes": 0, "copy_recv_bytes": 0,
+           "rebuild_fetch_bytes": 0}
 RECENT: deque = deque(maxlen=8)
 
 
@@ -430,6 +436,12 @@ def publish_stats(stats: "PipeStats", kind: str = "pipe") -> None:
         entry = {"kind": kind}
         entry.update(stats.to_dict())
         RECENT.append(entry)
+
+
+def count(name: str, n: int) -> None:
+    """Add ``n`` to one of the totals' plain counts."""
+    with _TELEMETRY_LOCK:
+        _TOTALS[name] += n
 
 
 def publish_packed(volumes: int, rows: int, row_slots: int,
@@ -469,7 +481,12 @@ def debug_payload() -> dict:
     ``fsync`` = the sweep's shard-file barriers, on the writeback
     pool's threads; ``pack`` is carved out of ``read``;
     ``decode_matrix`` = the host's share of a reconstruct, once per
-    rebuild run: invert, compose, expand for the kernel),
+    rebuild run: invert, compose, expand for the kernel;
+    ``copy_file`` = one ``CopyFile`` stream served, first chunk read to
+    last chunk taken, with ``copy_file_bytes``; ``copy_recv`` /
+    ``copy_commit`` = the pulling side of it, stream -> ``.part`` with
+    ``copy_recv_bytes``, then fsync + rename; ``rebuild_fetch_bytes``
+    = what of ``copy_recv_bytes`` a rebuild's sibling fetch pulled),
     ``rpc_seconds`` (the EC handlers, each counted once, pipeline run
     included), ``step_<name>_seconds`` / ``_calls``
     for every server-side rpc step, and the recent-run ring."""
@@ -498,6 +515,10 @@ def debug_payload() -> dict:
                launch_seconds=sec("launch"),
                decode_matrix_seconds=sec("decode_matrix"),
                decode_matrix_calls=spans.get("decode_matrix", (0.0, 0))[1],
+               copy_file_seconds=sec("copy_file"),
+               copy_file_calls=spans.get("copy_file", (0.0, 0))[1],
+               copy_recv_seconds=sec("copy_recv"),
+               copy_commit_seconds=sec("copy_commit"),
                rpc_seconds=sec(*(f"step_{n}"
                                  for n in flight.HANDLER_STEPS)))
     for name in flight.HANDLER_STEPS + flight.INNER_STEPS:

@@ -415,16 +415,21 @@ def _spread_and_drop(env: ClusterEnv, vid: int, col: str, source: str,
         if url == source:
             continue
         tgt = env.volume(url)
-        tgt.VolumeEcShardsCopy(volume_server_pb2.VolumeEcShardsCopyRequest(
-            volume_id=vid, collection=col, shard_ids=sids,
-            copy_ecx_file=True, copy_ecj_file=True, copy_vif_file=True,
-            source_data_node=source))
-        tgt.VolumeEcShardsMount(
-            volume_server_pb2.VolumeEcShardsMountRequest(
-                volume_id=vid, collection=col, shard_ids=sids))
-        src.VolumeEcShardsDelete(
-            volume_server_pb2.VolumeEcShardsDeleteRequest(
-                volume_id=vid, collection=col, shard_ids=sids))
+        # one target's share: its three rpcs are this span's children.
+        # The source deletes a shard only after the target has it
+        # fsynced, renamed into place and mounted.
+        with flight.span("step_spread", trace=True):
+            tgt.VolumeEcShardsCopy(
+                volume_server_pb2.VolumeEcShardsCopyRequest(
+                    volume_id=vid, collection=col, shard_ids=sids,
+                    copy_ecx_file=True, copy_ecj_file=True,
+                    copy_vif_file=True, source_data_node=source))
+            tgt.VolumeEcShardsMount(
+                volume_server_pb2.VolumeEcShardsMountRequest(
+                    volume_id=vid, collection=col, shard_ids=sids))
+            src.VolumeEcShardsDelete(
+                volume_server_pb2.VolumeEcShardsDeleteRequest(
+                    volume_id=vid, collection=col, shard_ids=sids))
     # Every replica of the now-sealed volume is dropped (the EC copy is
     # authoritative from here on).
     for url in replicas:
@@ -594,7 +599,11 @@ def cmd_ec_encode(env: ClusterEnv, argv: list[str]) -> None:
 
     with flight.span("step_spread_plan", trace=True):
         targets = _spread_targets(env.collect_ec_nodes(), total)
-    servers = _spread_and_drop(env, vid, col, source, locs, targets)
+    try:
+        servers = _spread_and_drop(env, vid, col, source, locs, targets)
+    except Exception as e:  # noqa: BLE001 — sealed on its source; say so
+        raise ShellError(f"ec.encode volume {vid}: sealed on {source}, "
+                         f"not spread: {e}") from None
     env.println(f"ec.encode volume {vid}: {total} shards over "
                 f"{servers} servers")
 

@@ -56,6 +56,7 @@ CATALOG = (
     "master.proxy",    # follower-master HTTP proxy to the leader
     "replica.push",    # volume server fanning a write to a replica
     "ec.shard_read",   # one shard-interval read (local disk or peer)
+    "ec.shard_copy",   # one chunk of a file pulled from a peer (CopyFile)
     "filer.meta",      # filer metadata gRPC (lookup/create/delete)
     "filer.data",      # filer HTTP data path (chunked GET/PUT)
     "sink.s3",         # replication S3 sink pushes

@@ -1,0 +1,439 @@
+"""``ec.encode -volumeId`` on a rack of four: the spread every real
+cluster has, through the shell's own command.
+
+Four volume servers and a master in this process, same data centre and
+rack; server 0 holds plain volumes written with the storage library.
+The shell seals them one after the other: 14 shards generated on server
+0, eleven of them pulled off it by its peers (``VolumeEcShardsCopy``
+<- ``CopyFile``), mounted there and deleted here, 4 + 4 + 3 + 3 (the
+sealing server carries the plan's heaviest load, so it keeps 3). Held
+against the plain oracle ``ops/rs_ref.py``: placement, shard bytes,
+needles read back through the master, the counters of what moved, one
+trace across shell and servers; then a target that fails mid-copy, and
+the loss of a holder of four repaired by ``ec.rebuild`` from its
+siblings. The volume server's default geometry is steered to 64 KiB
+small blocks, as ``test_ec_sweep.py`` does.
+"""
+
+import io
+import json
+import time
+import urllib.request
+from collections import Counter
+
+import numpy as np
+import pytest
+
+from seaweedfs_tpu.cluster import operation
+from seaweedfs_tpu.cluster import volume_server as volume_server_mod
+from seaweedfs_tpu.cluster.master import MasterServer
+from seaweedfs_tpu.cluster.volume_server import VolumeServer
+from seaweedfs_tpu.cluster.wdclient import MasterClient
+from seaweedfs_tpu.ops.rs_ref import ReferenceEncoder
+from seaweedfs_tpu.pb import master_pb2
+from seaweedfs_tpu.pipeline.scheme import EcScheme
+from seaweedfs_tpu.pipeline.stripe import stripe
+from seaweedfs_tpu.shell.cluster_commands import (
+    ClusterEnv, ShellError, run_cluster_command)
+from seaweedfs_tpu.storage import ec_files, needle as needle_mod
+from seaweedfs_tpu.storage.store import Store, volume_base_name
+from seaweedfs_tpu.storage.types import FileId
+from seaweedfs_tpu.storage.volume import Volume, dat_path, idx_path
+from seaweedfs_tpu.util import faults, tracing
+
+from test_cluster_integration import _free_port_pair
+
+SCHEME = EcScheme(10, 4, large_block_size=1 << 30,
+                  small_block_size=64 * 1024)
+ROW = SCHEME.data_shards * SCHEME.small_block_size
+COL = "warm"
+VIDS = (1, 2, 3)
+TOTAL = SCHEME.total_shards
+
+
+@pytest.fixture(scope="module", autouse=True)
+def small_rows():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(volume_server_mod, "DEFAULT_SCHEME", SCHEME)
+        # the .vif carries the shard counts and not the block sizes: a
+        # reader of these volumes has to be told the test's
+        mp.setattr(volume_server_mod, "_scheme_from_vif",
+                   lambda base: SCHEME)
+        yield
+    faults.clear()
+
+
+def write_volume(directory, vid, nbytes, seed=0):
+    """A plain volume of about ``nbytes`` of .dat; returns the needles
+    written as (fid, payload)."""
+    rng = np.random.default_rng([seed, vid])
+    vol = Volume(directory / volume_base_name(vid, COL), vid).create()
+    needles = []
+    while vol.dat_size < nbytes:
+        key = len(needles) + 1
+        cookie = int(rng.integers(0, 1 << 32))
+        data = rng.bytes(int(rng.integers(2_000, 40_000)))
+        vol.write_needle(needle_mod.Needle(
+            cookie=cookie, id=key, data=data,
+            append_at_ns=1_700_000_000_000_000_000 + key))
+        needles.append((str(FileId(vid, key, cookie)), data))
+    vol.sync()
+    vol.close()
+    return needles
+
+
+def oracle_shards(dat: np.ndarray) -> list:
+    """The 14 shard files ``ops/rs_ref.py`` says a ``.dat`` seals into."""
+    shards = stripe(dat, SCHEME) + [
+        np.zeros(SCHEME.shard_file_size(dat.size), dtype=np.uint8)
+        for _ in range(SCHEME.parity_shards)]
+    ReferenceEncoder(SCHEME.data_shards, SCHEME.parity_shards).encode(shards)
+    return [s.tobytes() for s in shards]
+
+
+class Rack:
+    """One master and four volume servers of one rack, in this process,
+    with a pulse far longer than a test: every heartbeat after start-up
+    is a nudge."""
+
+    def __init__(self, root, sizes):
+        self.dirs = [root / f"vs{i}" for i in range(4)]
+        for d in self.dirs:
+            d.mkdir()
+        self.needles = {vid: write_volume(self.dirs[0], vid, nbytes)
+                        for vid, nbytes in sizes.items()}
+        self.dats = {vid: np.fromfile(dat_path(self.base(0, vid)),
+                                      dtype=np.uint8) for vid in sizes}
+        self.master = MasterServer(
+            port=_free_port_pair(), volume_size_limit_mb=64,
+            pulse_seconds=60, seed=1).start()
+        self.servers = []
+        for d in self.dirs:
+            store = Store([d], max_volumes=16)
+            store.load_existing()
+            self.servers.append(VolumeServer(
+                store, port=_free_port_pair(), master_url=self.master.url,
+                data_center="dc1", rack="r1", pulse_seconds=60).start())
+        deadline = time.time() + 10
+        while time.time() < deadline and \
+                len(self.master.topology.nodes) < 4:
+            time.sleep(0.05)
+        assert len(self.master.topology.nodes) == 4
+        for vs in self.servers:
+            vs.heartbeat_now()
+        self.stopped = set()
+
+    def base(self, server: int, vid: int):
+        return self.dirs[server] / volume_base_name(vid, COL)
+
+    def run(self, line):
+        """(reply, error message or None) of one shell command."""
+        out = io.StringIO()
+        env = ClusterEnv(master_url=self.master.url, out=out)
+        try:
+            run_cluster_command(env, line)
+            return out.getvalue(), None
+        except ShellError as e:
+            return out.getvalue(), str(e)
+        finally:
+            env.close()
+
+    def held(self, vid: int) -> list:
+        """Per server, the shard ids whose files lie in its directory."""
+        return [ec_files.present_shards(self.base(i, vid), TOTAL)
+                for i in range(4)]
+
+    def mapped(self, vid: int) -> dict:
+        """shard id -> the urls the master's ``LookupEcVolume`` names."""
+        resp = self.servers[0].master_stub().LookupEcVolume(
+            master_pb2.LookupEcVolumeRequest(volume_id=vid))
+        return {e.shard_id: sorted(loc.url for loc in e.locations)
+                for e in resp.shard_id_locations}
+
+    def pipeline_vars(self) -> dict:
+        # one process: its totals are the four servers' together
+        with urllib.request.urlopen(
+                f"http://{self.servers[0].url}/debug/vars",
+                timeout=30) as r:
+            return json.load(r)["pipeline"]
+
+    def lose(self, server: int) -> None:
+        """The server stops, and the master's failure detector reaches
+        its verdict at once (it takes five pulses, and at least 10 s)."""
+        self.servers[server].stop()
+        self.stopped.add(server)
+        self.master.topology.unregister(self.servers[server].url)
+
+    def stop(self):
+        for i, vs in enumerate(self.servers):
+            if i not in self.stopped:
+                vs.stop()
+        self.master.stop()
+
+
+@pytest.fixture()
+def racks(tmp_path):
+    made = []
+
+    def make(sizes):
+        root = tmp_path / f"rack{len(made)}"
+        root.mkdir()
+        made.append(Rack(root, sizes))
+        return made[-1]
+    yield make
+    faults.clear()
+    for r in made:
+        r.stop()
+
+
+@pytest.fixture(scope="module")
+def spread(tmp_path_factory):
+    """Three volumes of one, two and three stripe rows sealed one after
+    the other by the shell's ``ec.encode -volumeId``, with what the
+    tests below read taken after each command."""
+    sizes = {1: ROW // 2, 2: ROW + ROW // 3, 3: 2 * ROW + ROW // 5}
+    rack = Rack(tmp_path_factory.mktemp("spread"), sizes)
+    try:
+        snaps, replies, moved_bytes = [rack.pipeline_vars()], {}, {}
+        for vid in VIDS:
+            replies[vid] = rack.run(
+                f"ec.encode -volumeId {vid} -collection {COL}")
+            snaps.append(rack.pipeline_vars())
+            held = rack.held(vid)
+            # what left server 0: the shards now elsewhere, and the
+            # two index files each of the three peers pulled
+            moved_bytes[vid] = sum(
+                ec_files.shard_path(rack.base(i, vid), s).stat().st_size
+                for i in range(1, 4) for s in held[i]) + 3 * sum(
+                p(rack.base(0, vid)).stat().st_size
+                for p in (ec_files.ecx_path, ec_files.vif_path))
+        trace_id = next(t for t in reversed(tracing.recent_traces())
+                        if t["name"] == "shell.ec.encode")["trace_id"]
+        # the pieces one command left in every process's ring (here one
+        # ring: trace.dump would show each span once per host it asks)
+        spans = {s["span_id"]: s for t in tracing.recent_traces()
+                 if t["trace_id"] == trace_id for s in t["spans"]}
+        mc = MasterClient(rack.master.url)
+        yield {"rack": rack, "snaps": snaps, "replies": replies,
+               "moved_bytes": moved_bytes, "spans": spans, "mc": mc}
+        mc.close()
+    finally:
+        rack.stop()
+
+
+@pytest.mark.parametrize("vid", VIDS)
+def test_each_shard_lies_on_one_server_and_no_server_holds_over_four(
+        spread, vid):
+    """Volume after volume: the plan sees server 0's load grow (the
+    shards it kept of the earlier volumes), and still no server gets a
+    fifth shard of any volume."""
+    rack = spread["rack"]
+    reply, err = spread["replies"][vid]
+    assert err is None, (reply, err)
+    assert f"ec.encode volume {vid}: 14 shards over 4 servers" in reply
+    held = rack.held(vid)
+    assert sorted(s for ids in held for s in ids) == list(range(TOTAL))
+    assert sorted(len(ids) for ids in held) == [3, 3, 4, 4]
+    # the sealing server carries the plan's heaviest load, so it keeps 3
+    assert len(held[0]) == 3
+    # the registries say what the disks say
+    for i, vs in enumerate(rack.servers):
+        mount = vs.store.ec_mounts.get((COL, vid))
+        assert sorted(mount.shard_ids) == held[i]
+
+
+@pytest.mark.parametrize("vid", VIDS)
+def test_shard_bytes_are_the_oracles_wherever_they_lie(spread, vid):
+    rack = spread["rack"]
+    want = oracle_shards(rack.dats[vid])
+    for i, ids in enumerate(rack.held(vid)):
+        for s in ids:
+            got = ec_files.shard_path(rack.base(i, vid), s).read_bytes()
+            assert got == want[s], f"volume {vid} shard {s} on server {i}"
+
+
+@pytest.mark.parametrize("vid", VIDS)
+def test_every_holder_has_the_index_files_and_nothing_plain_is_left(
+        spread, vid):
+    rack = spread["rack"]
+    ecx = ec_files.ecx_path(rack.base(0, vid)).read_bytes()
+    for i in range(4):
+        base = rack.base(i, vid)
+        assert ec_files.ecx_path(base).read_bytes() == ecx
+        assert ec_files.vif_path(base).exists()
+        assert not dat_path(base).exists() and not idx_path(base).exists()
+        assert not rack.servers[i].store.has_volume(vid, COL)
+    assert not [p for d in rack.dirs for p in d.glob("*.part")]
+
+
+@pytest.mark.parametrize("vid", VIDS)
+def test_the_masters_map_names_the_server_whose_disk_holds_the_shard(
+        spread, vid):
+    rack = spread["rack"]
+    on_disk = {s: [rack.servers[i].url]
+               for i, ids in enumerate(rack.held(vid)) for s in ids}
+    assert rack.mapped(vid) == on_disk
+
+
+@pytest.mark.parametrize("vid", VIDS)
+def test_seeded_needles_read_back_through_the_master(spread, vid):
+    """Every needle of a sealed volume crosses servers now: its
+    intervals lie on shards that three peers hold."""
+    rack = spread["rack"]
+    rng = np.random.default_rng([11, vid])
+    needles = rack.needles[vid]
+    for j in rng.choice(len(needles), size=min(8, len(needles)),
+                        replace=False):
+        fid, want = needles[int(j)]
+        assert operation.download(spread["mc"], fid, COL) == want, fid
+
+
+def test_the_counters_say_what_moved(spread):
+    """What the source served is what the targets received, and both are
+    the moved shards' and index files' sizes, command by command; the
+    receiving handler is counted among the rpc steps."""
+    snaps = spread["snaps"]
+    for vid, a, b in zip(VIDS, snaps, snaps[1:]):
+        d = {k: b[k] - a[k] for k in b if isinstance(b[k], (int, float))}
+        assert d["copy_file_bytes"] == d["copy_recv_bytes"] == \
+            spread["moved_bytes"][vid], vid
+        # 11 shards (the sealing server keeps 3), and .ecx + .vif for
+        # each of three peers (no .ecj exists yet: nothing to stream)
+        assert d["copy_file_calls"] == 11 + 3 * 2
+        assert d["step_shards_copy_calls"] == 3
+        assert d["step_mount_calls"] == 1 + 3
+        assert d["step_shards_delete_calls"] == 3
+        assert d["rebuild_fetch_bytes"] == 0
+        assert 0 < d["copy_recv_seconds"] and 0 < d["copy_commit_seconds"]
+        assert 0 < d["copy_file_seconds"]
+        assert d["copy_recv_seconds"] + d["copy_commit_seconds"] <= \
+            d["step_shards_copy_seconds"]
+        assert d["step_shards_copy_seconds"] < d["rpc_seconds"]
+
+
+def test_one_trace_runs_through_the_shell_and_four_servers(spread):
+    """One trace id from the shell's root through every rpc of a
+    command: a ``step_spread`` per target under the root, its three rpcs
+    beneath it, and the source's ``CopyFile`` streams beneath the
+    target's handler."""
+    spans = spread["spans"]
+    roots = [s for s in spans.values() if s["parent_id"] not in spans]
+    assert [s["name"] for s in roots] == ["shell.ec.encode"]
+    children = Counter((spans[s["parent_id"]]["name"], s["name"])
+                       for s in spans.values() if s["parent_id"] in spans)
+    assert children[("shell.ec.encode", "step_spread")] == 3
+    for rpc in ("VolumeEcShardsCopy", "VolumeEcShardsMount",
+                "VolumeEcShardsDelete"):
+        assert children[("step_spread", f"grpc.{rpc}")] == 3
+    assert children[("grpc.VolumeEcShardsCopy", "step_shards_copy")] == 3
+    # 11 shards, and .ecx, .ecj (an empty stream: none exists yet) and
+    # .vif for each of three peers
+    assert children[("step_shards_copy", "grpc.CopyFile")] == 11 + 3 * 3
+    assert children[("step_shards_copy", "step_heartbeat")] == 3
+
+
+def test_on_one_server_the_spread_copies_nothing(racks):
+    """The accepted cells' cluster: every target is the source."""
+    rack = racks({1: ROW + ROW // 3})
+    for i in (1, 2, 3):
+        rack.lose(i)
+    before = rack.pipeline_vars()
+    reply, err = rack.run(f"ec.encode -volumeId 1 -collection {COL}")
+    assert err is None and "14 shards over 1 servers" in reply
+    after = rack.pipeline_vars()
+    for key in ("copy_file_bytes", "copy_file_calls", "copy_recv_bytes",
+                "step_shards_copy_calls", "step_shards_delete_calls"):
+        assert after[key] == before[key], key
+    assert rack.held(1)[0] == list(range(TOTAL))
+
+
+def first_hit(spec: str, seed: int) -> int:
+    """The call at which a fault spec first fires, by its own coin."""
+    probe = faults.FaultSpec("ec.shard_copy", spec, seed=seed)
+    return next(i for i in range(10_000) if probe.fire())
+
+
+def test_a_target_that_fails_mid_copy_leaves_its_shards_on_the_source(
+        racks):
+    rack = racks({1: 2 * ROW + ROW // 5})
+    # one chunk per file here: the ninth chunk is the second target's
+    # third file (6 files to the first: 4 shards, .ecx, .vif)
+    spec = "error@0.2#1"
+    seed = next(s for s in range(1000) if first_hit(spec, s) == 8)
+    faults.inject("ec.shard_copy", spec, seed=seed)
+    reply, err = rack.run(f"ec.encode -volumeId 1 -collection {COL}")
+    faults.clear()
+    assert err is not None and reply == ""
+    source = rack.servers[0].url
+    assert f"ec.encode volume 1: sealed on {source}, not spread" in err
+    held = rack.held(1)
+    # the first target has its four; the one that failed holds none of
+    # the files it had pulled; the third was never asked
+    assert sorted(len(ids) for ids in held[1:]) == [0, 0, 4]
+    assert len(held[0]) == 10
+    assert sorted(s for ids in held for s in ids) == list(range(TOTAL))
+    assert not [p for d in rack.dirs for p in d.glob("*.part")]
+    for i in (1, 2, 3):
+        if not held[i]:
+            assert not list(rack.dirs[i].glob(f"{COL}_1.*")), i
+    # every shard mounted where it lies, and once in the master's map
+    assert sorted(rack.servers[0].store.ec_mounts[(COL, 1)].shard_ids) \
+        == held[0]
+    on_disk = {s: [rack.servers[i].url]
+               for i, ids in enumerate(held) for s in ids}
+    assert rack.mapped(1) == on_disk
+    # sealed: the needles read back from shards on two servers; the
+    # plain volume is still there, read-only, for the operator to drop
+    want = oracle_shards(rack.dats[1])
+    for i, ids in enumerate(held):
+        for s in ids:
+            assert ec_files.shard_path(rack.base(i, 1), s).read_bytes() \
+                == want[s]
+    assert dat_path(rack.base(0, 1)).exists()
+
+
+@pytest.mark.parametrize("which", [0, 1], ids=["first", "second"])
+def test_a_lost_holder_of_four_is_rebuilt_from_its_siblings(racks, which):
+    """One of the two servers that hold four shards is gone: the shell's
+    ``ec.rebuild`` picks the other as the rebuilder, which fetches six
+    siblings from the two holders of three, restores the lost four
+    byte-exact and mounts them."""
+    rack = racks({1: 2 * ROW + ROW // 5})
+    reply, err = rack.run(f"ec.encode -volumeId 1 -collection {COL}")
+    assert err is None, (reply, err)
+    held = rack.held(1)
+    fours = [i for i, ids in enumerate(held) if len(ids) == 4]
+    lost, rebuilder = fours[which], fours[1 - which]
+    gone = held[lost]
+    rack.lose(lost)
+    assert sorted(rack.mapped(1)) == sorted(set(range(TOTAL)) - set(gone))
+    before = rack.pipeline_vars()
+    reply, err = rack.run(f"ec.rebuild -volumeId 1 -collection {COL}")
+    assert err is None, (reply, err)
+    assert f"rebuilt {gone} on {rack.servers[rebuilder].url}" in reply
+    after = rack.pipeline_vars()
+    shard_size = SCHEME.shard_file_size(rack.dats[1].size)
+    # ten survivors are needed and four are local: six come over
+    assert after["rebuild_fetch_bytes"] - before["rebuild_fetch_bytes"] \
+        == 6 * shard_size
+    assert after["copy_file_bytes"] - before["copy_file_bytes"] \
+        == 6 * shard_size
+    assert after["step_rebuild_fetch_seconds"] > \
+        before["step_rebuild_fetch_seconds"]
+    now = rack.held(1)
+    # the fetched siblings were temporary: each is back to one disk
+    assert now[rebuilder] == sorted(held[rebuilder] + gone)
+    alive = [i for i in range(4) if i != lost]
+    assert sorted(s for i in alive for s in now[i]) == list(range(TOTAL))
+    want = oracle_shards(rack.dats[1])
+    for s in gone:
+        got = ec_files.shard_path(rack.base(rebuilder, 1), s).read_bytes()
+        assert got == want[s], f"restored shard {s}"
+    assert rack.mapped(1) == {s: [rack.servers[i].url]
+                              for i in alive for s in now[i]}
+    mc = MasterClient(rack.master.url)
+    try:
+        for fid, data in rack.needles[1][:6]:
+            assert operation.download(mc, fid, COL) == data
+    finally:
+        mc.close()
