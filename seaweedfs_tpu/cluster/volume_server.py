@@ -144,6 +144,15 @@ class VolumeServer:
         self._ec_loc_cache: dict[int, tuple[float, dict[int, list[str]]]] = {}
         self._metrics_pusher = None
         self._lock = threading.RLock()
+        #: One nudge at a time, snapshot to ingest: the master takes
+        #: heartbeats as they arrive, so a snapshot taken earlier must
+        #: not reach it after one taken later (the spread's three
+        #: VolumeEcShardsDelete handlers overlap on the source).
+        self._nudge_lock = threading.Lock()
+        #: The CopyFile streams this server has open, for
+        #: ``copy_file_shared_seconds``.
+        self.copy_streams = pipe_mod.SharedSeconds(
+            "copy_file_shared_seconds")
 
     # ------------- lifecycle -------------
 
@@ -433,7 +442,9 @@ class VolumeServer:
         the server leaves the master's view at the next pulse."""
         if not self.master_url:
             return
-        with flight_mod.span("step_heartbeat", trace=True):
+        # seaweedlint: disable=SW103 — the lock's whole job: the send is ordered with its snapshot
+        with flight_mod.span("step_heartbeat", trace=True), \
+                self._nudge_lock:
             stub = self.master_stub()
             for _ in stub.SendHeartbeat(
                     iter([self._heartbeat_snapshot()])):
@@ -760,7 +771,8 @@ class _VolumeServicer:
         # while gRPC hands each chunk on (the sync server drains the
         # generator on this thread)
         sent = start
-        with flight_mod.span("copy_file") as sp, open(path, "rb") as f:
+        with flight_mod.span("copy_file") as sp, open(path, "rb") as f, \
+                self.vs.copy_streams.stream():
             try:
                 if start:
                     f.seek(start)

@@ -66,7 +66,7 @@ import sys
 import threading
 import time
 from dataclasses import dataclass
-from typing import Optional
+from typing import Optional, Union
 
 from ..util import stats, tracing
 
@@ -421,7 +421,10 @@ class span:
     ``leaf=False`` marks a span that encloses others (``dispatch``, an
     rpc handler): totals and ring only, never the profiler plane, and
     nothing is carved out of it. ``trace=True`` makes it a child of the
-    thread's Dapper span as well (the rpc steps). A leaf span without a
+    thread's Dapper span as well (the rpc steps); given another
+    thread's ``tracing.outbound_value()`` instead, a worker thread with
+    no Dapper span of its own continues that trace, as that span's
+    child. A leaf span without a
     ``batch`` of its own reports the batch of the span around it in the
     profiler; the ring keeps what the site gave."""
 
@@ -430,7 +433,7 @@ class span:
                  "_outer_batch", "_ann", "_args", "_dapper")
 
     def __init__(self, name: str, batch: int = -1, nbytes: int = 0,
-                 leaf: bool = True, trace: bool = False):
+                 leaf: bool = True, trace: Union[bool, str] = False):
         self.name = name
         self.batch = batch
         self.nbytes = nbytes
@@ -439,7 +442,12 @@ class span:
         self.leaf = leaf
         self.elapsed = self.seconds = self._carved = 0.0
         self._parent = self._ann = self._args = None
-        self._dapper = tracing.span(name) if trace else None
+        if isinstance(trace, str):
+            # nested, a start_trace degrades to a child span: a header
+            # taken on this very thread gives what trace=True gives
+            self._dapper = tracing.start_trace(name, header=trace)
+        else:
+            self._dapper = tracing.span(name) if trace else None
 
     def __enter__(self) -> "span":
         tls = _TLS

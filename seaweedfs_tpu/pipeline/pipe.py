@@ -418,7 +418,10 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            # its .part files, and those of them a rebuild's sibling
            # fetch pulled
            "copy_file_bytes": 0, "copy_recv_bytes": 0,
-           "rebuild_fetch_bytes": 0}
+           "rebuild_fetch_bytes": 0,
+           # stream-seconds of CopyFile served while another stream of
+           # the same server was open (SharedSeconds)
+           "copy_file_shared_seconds": 0.0}
 RECENT: deque = deque(maxlen=8)
 
 
@@ -438,10 +441,44 @@ def publish_stats(stats: "PipeStats", kind: str = "pipe") -> None:
         RECENT.append(entry)
 
 
-def count(name: str, n: int) -> None:
+def count(name: str, n: float) -> None:
     """Add ``n`` to one of the totals' plain counts."""
     with _TELEMETRY_LOCK:
         _TOTALS[name] += n
+
+
+class SharedSeconds:
+    """The seconds streams of one kind spent with company: over every
+    stretch in which two or more were open, the stretch times the
+    number open (two streams that overlap for half their time share
+    half of each; a lone stream shares nothing), added to the total
+    ``name``. Bookkeeping at a stream's open and close alone: the open
+    count and the time of the last event, under one lock."""
+
+    def __init__(self, name: str, clock=time.perf_counter):
+        self._name = name
+        self._clock = clock
+        self._lock = threading.Lock()
+        self._open = 0
+        self._last = 0.0
+
+    def _event(self, step: int) -> None:
+        with self._lock:
+            now = self._clock()
+            shared = self._open * (now - self._last) \
+                if self._open > 1 else 0.0
+            self._open += step
+            self._last = now
+        if shared:
+            count(self._name, shared)
+
+    @contextlib.contextmanager
+    def stream(self):
+        self._event(+1)
+        try:
+            yield
+        finally:
+            self._event(-1)
 
 
 def publish_packed(volumes: int, rows: int, row_slots: int,
@@ -483,7 +520,9 @@ def debug_payload() -> dict:
     ``decode_matrix`` = the host's share of a reconstruct, once per
     rebuild run: invert, compose, expand for the kernel;
     ``copy_file`` = one ``CopyFile`` stream served, first chunk read to
-    last chunk taken, with ``copy_file_bytes``; ``copy_recv`` /
+    last chunk taken, with ``copy_file_bytes``, and
+    ``copy_file_shared_seconds`` = what of it was spent while another
+    stream of the same server was open; ``copy_recv`` /
     ``copy_commit`` = the pulling side of it, stream -> ``.part`` with
     ``copy_recv_bytes``, then fsync + rename; ``rebuild_fetch_bytes``
     = what of ``copy_recv_bytes`` a rebuild's sibling fetch pulled),

@@ -405,20 +405,29 @@ def _spread_and_drop(env: ClusterEnv, vid: int, col: str, source: str,
                      replicas: list[str], targets: list[EcNode]) -> int:
     """The tail of ``ec.encode`` for one volume whose shards are
     mounted on ``source``: copy + mount each target's shards there and
-    delete them here, then drop the plain volume from ``replicas``.
-    Returns the number of servers the shards ended on."""
+    delete them here, every remote target at once (upstream's
+    ``parallelCopyEcShardsFromSource``), then drop the plain volume
+    from ``replicas``. Returns the number of servers the shards ended
+    on."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from ..util import tracing
+
     src = env.volume(source)
     per_target: dict[str, list[int]] = {}
     for sid, node in enumerate(targets):
         per_target.setdefault(node.url, []).append(sid)
-    for url, sids in per_target.items():
-        if url == source:
-            continue
-        tgt = env.volume(url)
+    # every stub is made here: the env's channel table has no lock
+    remote = [(env.volume(url), sids) for url, sids in per_target.items()
+              if url != source]
+    # what a worker thread continues the command's trace from
+    parent = tracing.outbound_value() or True
+
+    def chain(tgt, sids: list[int]) -> None:
         # one target's share: its three rpcs are this span's children.
         # The source deletes a shard only after the target has it
         # fsynced, renamed into place and mounted.
-        with flight.span("step_spread", trace=True):
+        with flight.span("step_spread", trace=parent):
             tgt.VolumeEcShardsCopy(
                 volume_server_pb2.VolumeEcShardsCopyRequest(
                     volume_id=vid, collection=col, shard_ids=sids,
@@ -430,6 +439,19 @@ def _spread_and_drop(env: ClusterEnv, vid: int, col: str, source: str,
             src.VolumeEcShardsDelete(
                 volume_server_pb2.VolumeEcShardsDeleteRequest(
                     volume_id=vid, collection=col, shard_ids=sids))
+
+    if len(remote) > 1:
+        # a thread per target. Every chain runs to its end or its own
+        # error; a target that failed keeps its shards on the source,
+        # the others theirs.
+        with ThreadPoolExecutor(len(remote), "spread") as pool:
+            chains = [pool.submit(chain, tgt, sids)
+                      for tgt, sids in remote]
+        for done in chains:
+            done.result()
+    else:
+        for tgt, sids in remote:
+            chain(tgt, sids)
     # Every replica of the now-sealed volume is dropped (the EC copy is
     # authoritative from here on).
     for url in replicas:

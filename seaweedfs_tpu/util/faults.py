@@ -142,15 +142,20 @@ class FaultSpec:
         self.hits = 0
 
     def fire(self) -> bool:
-        """Seeded coin flip + count budget; True = inject this call."""
-        if self.remaining == 0:
-            return False
-        if self.probability < 1.0 and self.rng.random() >= self.probability:
-            return False
-        if self.remaining > 0:
-            self.remaining -= 1
-        self.hits += 1
-        return True
+        """Seeded coin flip + count budget; True = inject this call.
+        One caller at a time: points are reached from several threads
+        (three peers pull their shards at once), and a budget of one
+        injects once."""
+        with _LOCK:
+            if self.remaining == 0:
+                return False
+            if self.probability < 1.0 \
+                    and self.rng.random() >= self.probability:
+                return False
+            if self.remaining > 0:
+                self.remaining -= 1
+            self.hits += 1
+            return True
 
     def to_dict(self) -> dict:
         return {"point": self.point, "spec": self.spec,

@@ -9,14 +9,19 @@ The shell seals them one after the other: 14 shards generated on server
 sealing server carries the plan's heaviest load, so it keeps 3). Held
 against the plain oracle ``ops/rs_ref.py``: placement, shard bytes,
 needles read back through the master, the counters of what moved, one
-trace across shell and servers; then a target that fails mid-copy, and
+trace across shell and servers; the three targets served at once (their
+streams made to meet at a barrier: the source's streams share seconds,
+each chain keeps copy -> mount -> delete, the source's three nudges
+reach the master one at a time); then a target that fails mid-copy, and
 the loss of a holder of four repaired by ``ec.rebuild`` from its
 siblings. The volume server's default geometry is steered to 64 KiB
 small blocks, as ``test_ec_sweep.py`` does.
 """
 
+import contextlib
 import io
 import json
+import threading
 import time
 import urllib.request
 from collections import Counter
@@ -31,6 +36,7 @@ from seaweedfs_tpu.cluster.volume_server import VolumeServer
 from seaweedfs_tpu.cluster.wdclient import MasterClient
 from seaweedfs_tpu.ops.rs_ref import ReferenceEncoder
 from seaweedfs_tpu.pb import master_pb2
+from seaweedfs_tpu.pipeline import pipe
 from seaweedfs_tpu.pipeline.scheme import EcScheme
 from seaweedfs_tpu.pipeline.stripe import stripe
 from seaweedfs_tpu.shell.cluster_commands import (
@@ -347,6 +353,166 @@ def test_on_one_server_the_spread_copies_nothing(racks):
     assert rack.held(1)[0] == list(range(TOTAL))
 
 
+class Meeting(pipe.SharedSeconds):
+    """The source's ``CopyFile`` bookkeeping with a meeting point: the
+    first ``parties`` streams opened (each target's first: a target
+    pulls its files one after the other) wait for one another before
+    they serve, so the targets' streams overlap whatever the
+    scheduler does."""
+
+    def __init__(self, parties: int):
+        super().__init__("copy_file_shared_seconds")
+        self.barrier = threading.Barrier(parties, timeout=30)
+        self.to_meet = parties
+        self.lock = threading.Lock()
+
+    @contextlib.contextmanager
+    def stream(self):
+        with super().stream():
+            with self.lock:
+                meets, self.to_meet = self.to_meet > 0, self.to_meet - 1
+            if meets:
+                self.barrier.wait()
+            yield
+
+
+@pytest.fixture(scope="module")
+def met(tmp_path_factory):
+    """One ``ec.encode`` on a rack of four whose three targets are held
+    to one another: their first streams meet on the source, and its
+    three ``VolumeEcShardsDelete`` handlers reach their nudges
+    together. What the source's nudges did between snapshot and the
+    master's ingest is kept as a log."""
+    rack = Rack(tmp_path_factory.mktemp("met"), {1: 2 * ROW + ROW // 5})
+    try:
+        source = rack.servers[0]
+        source.copy_streams = Meeting(3)
+        deletes = threading.Barrier(3, timeout=30)
+        unmount = source.store.unmount_ec_shards
+
+        def unmount_then_meet(*args, **kwargs):
+            unmount(*args, **kwargs)
+            deletes.wait()
+        source.store.unmount_ec_shards = unmount_then_meet
+
+        log, log_lock = [], threading.Lock()
+
+        def shards_in(hb) -> int:
+            return sum(s.ec_index_bits.bit_count() for s in hb.ec_shards)
+        snapshot, ingest = source._heartbeat_snapshot, \
+            rack.master.ingest_heartbeat
+
+        def logged_snapshot():
+            hb = snapshot()
+            with log_lock:
+                log.append(("snapshot", shards_in(hb)))
+            return hb
+
+        def logged_ingest(hb):
+            resp = ingest(hb)
+            if f"{hb.ip}:{hb.port}" == source.url:
+                with log_lock:
+                    log.append(("ingested", shards_in(hb)))
+            return resp
+        source._heartbeat_snapshot = logged_snapshot
+        rack.master.ingest_heartbeat = logged_ingest
+
+        before = rack.pipeline_vars()
+        reply = rack.run(f"ec.encode -volumeId 1 -collection {COL}")
+        after = rack.pipeline_vars()
+        trace_id = next(t for t in reversed(tracing.recent_traces())
+                        if t["name"] == "shell.ec.encode")["trace_id"]
+        spans = {s["span_id"]: s for t in tracing.recent_traces()
+                 if t["trace_id"] == trace_id for s in t["spans"]}
+        yield {"rack": rack, "reply": reply, "log": log, "spans": spans,
+               "delta": {k: after[k] - before[k] for k in after
+                         if isinstance(after[k], (int, float))}}
+    finally:
+        rack.stop()
+
+
+def test_three_targets_are_served_at_once(met):
+    """The source's streams to its three peers share seconds, and the
+    command ends as the one-at-a-time spread does."""
+    reply, err = met["reply"]
+    assert err is None and "14 shards over 4 servers" in reply
+    held = met["rack"].held(1)
+    assert sorted(len(ids) for ids in held) == [3, 3, 4, 4]
+    assert sorted(s for ids in held for s in ids) == list(range(TOTAL))
+    d = met["delta"]
+    assert d["copy_file_calls"] == 11 + 3 * 2
+    assert 0 < d["copy_file_shared_seconds"] <= d["copy_file_seconds"]
+
+
+def test_each_chain_keeps_its_order_while_the_chains_overlap(met):
+    """From the spans of the command's one trace: the three targets'
+    copies run at the same time, and for each target the receiver's
+    copy (its last fsync and rename inside it) and its mount have ended
+    before the source's delete of that target's shards begins."""
+    spans = met["spans"]
+    chains = [s for s in spans.values() if s["name"] == "step_spread"]
+    assert len(chains) == 3
+    root = next(s for s in spans.values()
+                if s["name"] == "shell.ec.encode")
+    copies = []
+    for chain in chains:
+        assert chain["parent_id"] == root["span_id"]
+        rpc = {s["name"]: s for s in spans.values()
+               if s["parent_id"] == chain["span_id"]}
+        copy, mount, delete = (rpc[f"grpc.VolumeEcShards{step}"]
+                               for step in ("Copy", "Mount", "Delete"))
+        copies.append(copy)
+        slack = 1e-5    # a span's seconds are rounded to the microsecond
+        assert copy["start"] + copy["duration_seconds"] \
+            <= mount["start"] + slack
+        assert mount["start"] + mount["duration_seconds"] \
+            <= delete["start"] + slack
+    assert max(c["start"] for c in copies) < min(
+        c["start"] + c["duration_seconds"] for c in copies)
+
+
+def test_three_overlapping_nudges_leave_the_masters_map_equal_to_the_disks(
+        met):
+    """The source's three deletes reach ``heartbeat_now()`` together:
+    each snapshot is ingested before the next is taken, so the master
+    ends on the newest, which says what the disks say."""
+    rack, log = met["rack"], met["log"]
+    kinds = [kind for kind, _ in log]
+    assert kinds == ["snapshot", "ingested"] * (len(log) // 2), log
+    # mount (14), then a delete's nudge after each of 4, 4 and 3 left
+    held_after = [n for kind, n in log if kind == "ingested"]
+    assert held_after[0] == TOTAL and held_after[-1] == 3
+    assert held_after == sorted(held_after, reverse=True)
+    on_disk = {s: [rack.servers[i].url]
+               for i, ids in enumerate(rack.held(1)) for s in ids}
+    assert rack.mapped(1) == on_disk
+
+
+@pytest.mark.parametrize("lost", [(1, 2, 3), (2, 3)],
+                         ids=["one_server", "one_remote_target"])
+def test_without_a_second_remote_target_no_stream_has_company(racks, lost):
+    """Nothing to serve at once: no stream shares a second, and a lone
+    remote target's chain runs on the command's own thread (its
+    ``step_spread`` closes into the root's piece of the trace)."""
+    rack = racks({1: ROW + ROW // 3})
+    for i in lost:
+        rack.lose(i)
+    before = rack.pipeline_vars()
+    reply, err = rack.run(f"ec.encode -volumeId 1 -collection {COL}")
+    assert err is None, (reply, err)
+    assert f"14 shards over {4 - len(lost)} servers" in reply
+    after = rack.pipeline_vars()
+    assert after["copy_file_shared_seconds"] \
+        == before["copy_file_shared_seconds"]
+    remote = 3 - len(lost)
+    assert after["step_shards_copy_calls"] \
+        - before["step_shards_copy_calls"] == remote
+    piece = next(t for t in reversed(tracing.recent_traces())
+                 if t["name"] == "shell.ec.encode")
+    assert [s["name"] for s in piece["spans"]].count("step_spread") \
+        == remote
+
+
 def first_hit(spec: str, seed: int) -> int:
     """The call at which a fault spec first fires, by its own coin."""
     probe = faults.FaultSpec("ec.shard_copy", spec, seed=seed)
@@ -356,8 +522,9 @@ def first_hit(spec: str, seed: int) -> int:
 def test_a_target_that_fails_mid_copy_leaves_its_shards_on_the_source(
         racks):
     rack = racks({1: 2 * ROW + ROW // 5})
-    # one chunk per file here: the ninth chunk is the second target's
-    # third file (6 files to the first: 4 shards, .ecx, .vif)
+    # one chunk per file here, 17 chunks to the three targets (4 + 4 + 3
+    # shards, .ecx and .vif each); which target pulls the ninth is the
+    # threads' business
     spec = "error@0.2#1"
     seed = next(s for s in range(1000) if first_hit(spec, s) == 8)
     faults.inject("ec.shard_copy", spec, seed=seed)
@@ -367,29 +534,38 @@ def test_a_target_that_fails_mid_copy_leaves_its_shards_on_the_source(
     source = rack.servers[0].url
     assert f"ec.encode volume 1: sealed on {source}, not spread" in err
     held = rack.held(1)
-    # the first target has its four; the one that failed holds none of
-    # the files it had pulled; the third was never asked
-    assert sorted(len(ids) for ids in held[1:]) == [0, 0, 4]
-    assert len(held[0]) == 10
+    # one target failed and holds none of the files it had pulled; its
+    # share is still the source's, beside the three the source keeps;
+    # the other two targets have theirs
+    failed = [i for i in (1, 2, 3) if not held[i]]
+    assert len(failed) == 1, held
+    assert sorted([len(ids) for ids in held[1:] if ids]
+                  + [len(held[0]) - 3]) == [3, 4, 4]
     assert sorted(s for ids in held for s in ids) == list(range(TOTAL))
     assert not [p for d in rack.dirs for p in d.glob("*.part")]
-    for i in (1, 2, 3):
-        if not held[i]:
-            assert not list(rack.dirs[i].glob(f"{COL}_1.*")), i
+    assert not list(rack.dirs[failed[0]].glob(f"{COL}_1.*"))
     # every shard mounted where it lies, and once in the master's map
-    assert sorted(rack.servers[0].store.ec_mounts[(COL, 1)].shard_ids) \
-        == held[0]
+    for i, vs in enumerate(rack.servers):
+        mount = vs.store.ec_mounts.get((COL, 1))
+        assert sorted(mount.shard_ids if mount else []) == held[i], i
     on_disk = {s: [rack.servers[i].url]
                for i, ids in enumerate(held) for s in ids}
     assert rack.mapped(1) == on_disk
-    # sealed: the needles read back from shards on two servers; the
-    # plain volume is still there, read-only, for the operator to drop
+    # sealed: the shards are the oracle's on the three servers that hold
+    # them and the needles read back; the plain volume is still there,
+    # read-only, for the operator to drop
     want = oracle_shards(rack.dats[1])
     for i, ids in enumerate(held):
         for s in ids:
             assert ec_files.shard_path(rack.base(i, 1), s).read_bytes() \
                 == want[s]
     assert dat_path(rack.base(0, 1)).exists()
+    mc = MasterClient(rack.master.url)
+    try:
+        for fid, data in rack.needles[1][:6]:
+            assert operation.download(mc, fid, COL) == data
+    finally:
+        mc.close()
 
 
 @pytest.mark.parametrize("which", [0, 1], ids=["first", "second"])

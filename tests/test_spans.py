@@ -139,6 +139,66 @@ def test_pool_wait_is_carved_out_of_read_in_the_totals():
     assert st.read_seconds + st.pool_wait_seconds < st.wall_seconds + 0.05
 
 
+class SteppedClock:
+    """A clock that reads what the test last set."""
+
+    now = 0.0
+
+    def __call__(self) -> float:
+        return self.now
+
+
+def shared_streams():
+    """(a ``SharedSeconds`` on a stepped clock, the clock, a reader of
+    what it has added to ``copy_file_shared_seconds`` since now)"""
+    clock = SteppedClock()
+    streams = pipe.SharedSeconds("copy_file_shared_seconds", clock=clock)
+    start = pipe.debug_payload()["copy_file_shared_seconds"]
+    return streams, clock, lambda: round(
+        pipe.debug_payload()["copy_file_shared_seconds"] - start, 6)
+
+
+def test_two_streams_half_overlapping_share_half_of_each():
+    streams, clock, shared = shared_streams()
+    a, b = streams.stream(), streams.stream()
+    a.__enter__()                       # a: 0 .. 2
+    clock.now = 1.0
+    b.__enter__()                       # b: 1 .. 3
+    clock.now = 2.0
+    a.__exit__(None, None, None)
+    assert shared() == 2.0              # 1 .. 2, the two of them
+    clock.now = 3.0
+    b.__exit__(None, None, None)
+    assert shared() == 2.0
+
+
+def test_a_lone_stream_shares_nothing():
+    streams, clock, shared = shared_streams()
+    for opened, closed in ((0.0, 5.0), (6.0, 7.5)):
+        clock.now = opened
+        with streams.stream():
+            clock.now = closed
+    assert shared() == 0.0
+
+
+def test_a_stream_that_raises_still_closes_its_share():
+    streams, clock, shared = shared_streams()
+    with streams.stream():              # 0 .. 4
+        clock.now = 1.0
+        with pytest.raises(KeyError):
+            with streams.stream():      # 1 .. 2, where it raises
+                clock.now = 2.0
+                raise KeyError("x")
+        assert shared() == 2.0
+        clock.now = 4.0
+    # alone from 2 to 4, and nothing is left open behind the two
+    assert shared() == 2.0
+    clock.now = 5.0
+    with streams.stream():
+        clock.now = 9.0
+    assert shared() == 2.0
+
+
 @pytest.mark.parametrize("overlapped", [True, False])
 def test_run_stats_are_fed_by_the_spans(overlapped):
     st = pipe.PipeStats()
@@ -568,7 +628,7 @@ PIPELINE_KEYS = ["pool_wait_seconds", "dispatch_seconds", "sync_seconds",
                  "wall_seconds", "pool_acquires", "pool_fresh_acquires",
                  "sync_ready_seconds", "sync_copy_seconds",
                  "write_drain_seconds", "write_stage_seconds", "groups", "group_ready_seconds",
-                 "group_ready_bytes"] + [
+                 "group_ready_bytes", "copy_file_shared_seconds"] + [
     f"{name}_seconds" for name in flight.WAITS] + [
     f"step_{name}_{what}" for name in flight.HANDLER_STEPS
     + flight.INNER_STEPS for what in ("seconds", "calls")]
