@@ -53,6 +53,8 @@ from .master import _grpc_port
 from ..util import tls as tls_mod
 
 _COPY_CHUNK = 1024 * 1024
+#: the clock of the per-chunk splits in CopyFile and _copy_remote_file
+_clock = time.perf_counter
 
 
 class VolumeServerError(RuntimeError):
@@ -769,23 +771,52 @@ class _VolumeServicer:
         start = min(request.start_offset, stop)
         # one span per stream, the puller's pace included: it stays open
         # while gRPC hands each chunk on (the sync server drains the
-        # generator on this thread)
+        # generator on this thread). Inside it a chunk's parts are told
+        # apart by clock reads into this stream's locals, three a chunk
+        # here and two in the serialiser gRPC calls between the yield
+        # and the resume (pb.copy_stream, this thread's), and folded
+        # into the totals once, at the close: no span, lock or
+        # annotation per chunk. read + build + serialize + send is the
+        # span's seconds but for the loop's own lines
         sent = start
+        chunks = 0
+        read_s = build_s = yield_s = 0.0
+        clock = _clock
         with flight_mod.span("copy_file") as sp, open(path, "rb") as f, \
                 self.vs.copy_streams.stream():
+            cpu0 = time.thread_time()
+            pb.copy_stream.serialize = 0.0
             try:
                 if start:
                     f.seek(start)
+                t = clock()
                 while sent < stop:
                     chunk = f.read(min(_COPY_CHUNK, stop - sent))
+                    t_read = clock()
                     if not chunk:
                         break
-                    sent += len(chunk)
-                    yield volume_server_pb2.CopyFileResponse(
+                    resp = volume_server_pb2.CopyFileResponse(
                         file_content=chunk)
+                    t_built = clock()
+                    sent += len(chunk)
+                    chunks += 1
+                    read_s += t_read - t
+                    build_s += t_built - t_read
+                    yield resp
+                    t = clock()
+                    yield_s += t - t_built
             finally:
                 sp.nbytes = sent - start
-                pipe_mod.count("copy_file_bytes", sp.nbytes)
+                serialize_s = pb.copy_stream.serialize
+                pipe_mod.fold(
+                    copy_file_bytes=sp.nbytes, copy_file_chunks=chunks,
+                    copy_read_seconds=read_s, copy_build_seconds=build_s,
+                    copy_serialize_seconds=serialize_s,
+                    # a stream cut inside a yield never resumed from
+                    # it: its last message's serialising is counted,
+                    # the yield around it is not
+                    copy_send_seconds=max(0.0, yield_s - serialize_s),
+                    copy_file_cpu_seconds=time.thread_time() - cpu0)
 
     def VolumeCopy(self, request, context):
         """Pull a whole .dat/.idx pair from the source node and register
@@ -1132,28 +1163,53 @@ def _copy_remote_file(vs: VolumeServer, src_url: str, volume_id: int,
                       ignore_missing: bool = False) -> int:
     """Pull one file of a volume from ``src_url`` into ``dest``; returns
     the bytes received. Two leaf spans: ``copy_recv`` (the stream into
-    ``<dest>.part``) and ``copy_commit`` (fsync + rename)."""
+    ``<dest>.part``) and ``copy_commit`` (fsync + rename). Inside
+    ``copy_recv`` each chunk's two halves are told apart by two clock
+    reads into locals, folded into the totals once per file:
+    ``copy_recv_wait_seconds`` (inside ``next()``: the source, the
+    wire, gRPC's receive and parse; the call's start and the stream's
+    end with it), ``copy_recv_write_seconds`` (the field read,
+    ``f.write``, the fault point), ``copy_recv_chunks`` and the
+    thread's ``copy_recv_cpu_seconds``; wait + write is the span's
+    seconds but for the loop's own lines."""
     dest.parent.mkdir(parents=True, exist_ok=True)
     tmp = dest.with_suffix(dest.suffix + ".part")
     received = 0
+    chunks = 0
+    wait_s = write_s = 0.0
+    clock = _clock
+    cpu0 = time.thread_time()
     try:
         with flight_mod.span("copy_recv") as sp, open(tmp, "wb") as f:
-            for resp in vs.peer_stub(src_url).CopyFile(
-                    volume_server_pb2.CopyFileRequest(
-                        volume_id=volume_id, collection=collection,
-                        ext=ext,
-                        ignore_source_file_not_found=ignore_missing)):
+            t = clock()
+            stream = iter(vs.peer_stub(src_url).CopyFile(
+                volume_server_pb2.CopyFileRequest(
+                    volume_id=volume_id, collection=collection,
+                    ext=ext,
+                    ignore_source_file_not_found=ignore_missing)))
+            while True:
+                resp = next(stream, None)
+                t_got = clock()
+                wait_s += t_got - t
+                if resp is None:
+                    break
                 # read the field once: each access copies the chunk
                 chunk = resp.file_content
                 f.write(chunk)
                 received += len(chunk)
+                chunks += 1
                 faults.check("ec.shard_copy")
+                t = clock()
+                write_s += t - t_got
             sp.nbytes = received
     except Exception:
         tmp.unlink(missing_ok=True)
         raise
     finally:
-        pipe_mod.count("copy_recv_bytes", received)
+        pipe_mod.fold(copy_recv_bytes=received, copy_recv_chunks=chunks,
+                      copy_recv_wait_seconds=wait_s,
+                      copy_recv_write_seconds=write_s,
+                      copy_recv_cpu_seconds=time.thread_time() - cpu0)
     if ignore_missing and not received:
         tmp.unlink()
         return 0

@@ -11,6 +11,8 @@ contained, minus the codegen).
 
 from __future__ import annotations
 
+import threading
+import time
 from dataclasses import dataclass
 from typing import Callable
 
@@ -148,12 +150,44 @@ FILER_METHODS = [
 ]
 
 
+class _StreamSeconds(threading.local):
+    """Per thread: the seconds gRPC's calls of the ``CopyFile``
+    response serialiser have taken since the stream served on this
+    thread last set them to 0."""
+
+    serialize = 0.0
+
+
+#: the sync server serialises a streamed response on the thread that
+#: drains the handler's generator, between the ``yield`` and the
+#: resume: ``CopyFile``'s handler zeroes this at its stream's open and
+#: reads it at the close (``copy_serialize_seconds``), so two streams
+#: at once keep their sums apart
+copy_stream = _StreamSeconds()
+_clock = time.perf_counter
+
+
+def _timed(serialize: Callable) -> Callable:
+    """``serialize``, each call's duration added to this thread's
+    :data:`copy_stream`: two clock reads and one thread-local lookup a
+    message, no lock; the bytes are ``serialize``'s own."""
+    def timed(message) -> bytes:
+        t0 = _clock()
+        data = serialize(message)
+        copy_stream.serialize += _clock() - t0
+        return data
+    return timed
+
+
 def generic_handler(service_name: str, methods: list[Method],
                     servicer) -> "grpc.GenericRpcHandler":
     """Build the server-side dispatch table for one service.
 
     ``servicer`` provides one method per Method.name; unary handlers take
     (request, context), streaming handlers follow grpc's usual shapes.
+    ``CopyFile``'s response serialiser alone is the timed one
+    (:func:`_timed`): a 1 MiB chunk a call, where every other method's
+    message is small.
     """
     import grpc
 
@@ -162,20 +196,23 @@ def generic_handler(service_name: str, methods: list[Method],
     handlers: dict[str, object] = {}
     for m in methods:
         fn: Callable = getattr(servicer, m.name)
+        serializer = m.response_cls.SerializeToString
+        if m.name == "CopyFile":
+            serializer = _timed(serializer)
         if m.kind == UNARY:
             handlers[m.name] = grpc.unary_unary_rpc_method_handler(
                 tracing.wrap_grpc_unary(fn, m.name),
                 request_deserializer=m.request_cls.FromString,
-                response_serializer=m.response_cls.SerializeToString)
+                response_serializer=serializer)
         elif m.kind == SERVER_STREAM:
             handlers[m.name] = grpc.unary_stream_rpc_method_handler(
                 tracing.wrap_grpc_stream(fn, m.name),
                 request_deserializer=m.request_cls.FromString,
-                response_serializer=m.response_cls.SerializeToString)
+                response_serializer=serializer)
         elif m.kind == BIDI_STREAM:
             handlers[m.name] = grpc.stream_stream_rpc_method_handler(
                 fn, request_deserializer=m.request_cls.FromString,
-                response_serializer=m.response_cls.SerializeToString)
+                response_serializer=serializer)
         else:  # pragma: no cover - table is static
             raise ValueError(m.kind)
     return grpc.method_handlers_generic_handler(service_name, handlers)

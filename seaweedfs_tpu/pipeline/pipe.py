@@ -421,7 +421,22 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            "rebuild_fetch_bytes": 0,
            # stream-seconds of CopyFile served while another stream of
            # the same server was open (SharedSeconds)
-           "copy_file_shared_seconds": 0.0}
+           "copy_file_shared_seconds": 0.0,
+           # what a 1 MiB chunk costs the server that serves it: the
+           # parts of copy_file_seconds, taken by clock reads in the
+           # stream's own locals and folded here once, at its close
+           # (fold()): f.read, the message built, gRPC's call of the
+           # serialiser, and the rest of yield -> resume (the send
+           # started and awaited: transport, the peer's window, the
+           # interpreter won back); the handler thread's CPU seconds
+           "copy_file_chunks": 0, "copy_read_seconds": 0.0,
+           "copy_build_seconds": 0.0, "copy_serialize_seconds": 0.0,
+           "copy_send_seconds": 0.0, "copy_file_cpu_seconds": 0.0,
+           # and the server that pulls it, the parts of
+           # copy_recv_seconds: inside next() (the source, the wire,
+           # gRPC's receive and parse), and the field read + f.write
+           "copy_recv_chunks": 0, "copy_recv_wait_seconds": 0.0,
+           "copy_recv_write_seconds": 0.0, "copy_recv_cpu_seconds": 0.0}
 RECENT: deque = deque(maxlen=8)
 
 
@@ -443,8 +458,16 @@ def publish_stats(stats: "PipeStats", kind: str = "pipe") -> None:
 
 def count(name: str, n: float) -> None:
     """Add ``n`` to one of the totals' plain counts."""
+    fold(**{name: n})
+
+
+def fold(**counts: float) -> None:
+    """Add each ``n`` to the total of its name, all of them under one
+    acquisition of the lock: what a stream kept in its own locals,
+    handed over at its close."""
     with _TELEMETRY_LOCK:
-        _TOTALS[name] += n
+        for name, n in counts.items():
+            _TOTALS[name] += n
 
 
 class SharedSeconds:
@@ -522,9 +545,20 @@ def debug_payload() -> dict:
     ``copy_file`` = one ``CopyFile`` stream served, first chunk read to
     last chunk taken, with ``copy_file_bytes``, and
     ``copy_file_shared_seconds`` = what of it was spent while another
-    stream of the same server was open; ``copy_recv`` /
+    stream of the same server was open; its ``copy_file_chunks``
+    chunks split where each is handled into ``copy_read`` (``f.read``),
+    ``copy_build`` (the response message made), ``copy_serialize``
+    (gRPC's call of the serialiser) and ``copy_send`` (the rest of
+    ``yield`` -> resume: the send started and awaited), which sum to
+    ``copy_file_seconds`` but for the loop's bookkeeping, with
+    ``copy_file_cpu_seconds`` = the handler threads' CPU time over
+    their streams; ``copy_recv`` /
     ``copy_commit`` = the pulling side of it, stream -> ``.part`` with
-    ``copy_recv_bytes``, then fsync + rename; ``rebuild_fetch_bytes``
+    ``copy_recv_bytes``, then fsync + rename; ``copy_recv`` split over
+    its ``copy_recv_chunks`` into ``copy_recv_wait`` (inside
+    ``next()``: the source, the wire, gRPC's receive and parse) and
+    ``copy_recv_write`` (the field read and ``f.write``), with
+    ``copy_recv_cpu_seconds``; ``rebuild_fetch_bytes``
     = what of ``copy_recv_bytes`` a rebuild's sibling fetch pulled),
     ``rpc_seconds`` (the EC handlers, each counted once, pipeline run
     included), ``step_<name>_seconds`` / ``_calls``

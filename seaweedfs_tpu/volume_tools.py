@@ -274,8 +274,10 @@ def backup_volume(master_url: str, volume_id: int, directory: str | Path,
                         volume_server_pb2.CopyFileRequest(
                             volume_id=volume_id, collection=collection,
                             ext=ext, start_offset=start)):
-                    f.write(resp.file_content)
-                    n += len(resp.file_content)
+                    # read the field once: each access copies the chunk
+                    chunk = resp.file_content
+                    f.write(chunk)
+                    n += len(chunk)
                 f.truncate()
             return n
 

@@ -14,7 +14,9 @@ streams made to meet at a barrier: the source's streams share seconds,
 each chain keeps copy -> mount -> delete, the source's three nudges
 reach the master one at a time); then a target that fails mid-copy, and
 the loss of a holder of four repaired by ``ec.rebuild`` from its
-siblings. The volume server's default geometry is steered to 64 KiB
+siblings; last, what one 1 MiB chunk of a stream costs either end: a
+file of 12 chunks and a ragged tail pulled over loopback with the
+splits' clock and the totals' locks counted. The volume server's default geometry is steered to 64 KiB
 small blocks, as ``test_ec_sweep.py`` does.
 """
 
@@ -24,7 +26,7 @@ import json
 import threading
 import time
 import urllib.request
-from collections import Counter
+from collections import Counter, defaultdict
 
 import numpy as np
 import pytest
@@ -35,8 +37,9 @@ from seaweedfs_tpu.cluster.master import MasterServer
 from seaweedfs_tpu.cluster.volume_server import VolumeServer
 from seaweedfs_tpu.cluster.wdclient import MasterClient
 from seaweedfs_tpu.ops.rs_ref import ReferenceEncoder
+from seaweedfs_tpu import pb
 from seaweedfs_tpu.pb import master_pb2
-from seaweedfs_tpu.pipeline import pipe
+from seaweedfs_tpu.pipeline import flight, pipe
 from seaweedfs_tpu.pipeline.scheme import EcScheme
 from seaweedfs_tpu.pipeline.stripe import stripe
 from seaweedfs_tpu.shell.cluster_commands import (
@@ -306,6 +309,8 @@ def test_the_counters_say_what_moved(spread):
         # 11 shards (the sealing server keeps 3), and .ecx + .vif for
         # each of three peers (no .ecj exists yet: nothing to stream)
         assert d["copy_file_calls"] == 11 + 3 * 2
+        # every file here is under 1 MiB: a chunk each
+        assert d["copy_file_chunks"] == d["copy_recv_chunks"] == 11 + 3 * 2
         assert d["step_shards_copy_calls"] == 3
         assert d["step_mount_calls"] == 1 + 3
         assert d["step_shards_delete_calls"] == 3
@@ -613,3 +618,174 @@ def test_a_lost_holder_of_four_is_rebuilt_from_its_siblings(racks, which):
             assert operation.download(mc, fid, COL) == data
     finally:
         mc.close()
+
+
+# --------------------------------------------------------------------------
+# what one chunk costs either end of a stream
+# --------------------------------------------------------------------------
+
+CHUNK = volume_server_mod._COPY_CHUNK
+BIG = 12 * CHUNK + 12_345
+BIG_CHUNKS = -(-BIG // CHUNK)
+SOURCE_PARTS = ("copy_read_seconds", "copy_build_seconds",
+                "copy_serialize_seconds", "copy_send_seconds")
+RECV_PARTS = ("copy_recv_wait_seconds", "copy_recv_write_seconds")
+
+
+class Tally:
+    """The clock of the per-chunk splits and a stand-in for each of the
+    two totals' locks, every use noted per thread and in order."""
+
+    def __init__(self):
+        self.events = defaultdict(list)
+
+    def clock(self) -> float:
+        self.events[threading.get_ident()].append("clock")
+        return time.perf_counter()
+
+    def lock(self, inner):
+        tally = self
+
+        class Noted:
+            def __enter__(self):
+                tally.events[threading.get_ident()].append("lock")
+                return inner.__enter__()
+
+            def __exit__(self, *exc):
+                return inner.__exit__(*exc)
+        return Noted()
+
+
+def deltas(before: dict, after: dict) -> dict:
+    return {k: after[k] - before[k] for k in after
+            if isinstance(after[k], (int, float))}
+
+
+def with_a_big_file(rack: Rack) -> bytes:
+    """A file of 12 chunks and a ragged tail beside server 0's volume 1,
+    streamed as that volume's ``.big``."""
+    data = np.random.default_rng(36).bytes(BIG)
+    (rack.dirs[0] / f"{volume_base_name(1, COL)}.big").write_bytes(data)
+    return data
+
+
+def pull_big(rack: Rack, dest) -> int:
+    return volume_server_mod._copy_remote_file(
+        rack.servers[1], rack.servers[0].url, 1, COL, ".big", dest)
+
+
+def wait_for_close(calls_before: int) -> dict:
+    """The totals once the source's handler thread has closed its
+    stream (the puller's return does not wait for it)."""
+    deadline = time.time() + 10
+    while time.time() < deadline:
+        now = pipe.debug_payload()
+        if now["copy_file_calls"] > calls_before:
+            return now
+        time.sleep(0.01)
+    raise AssertionError("the source never closed its stream")
+
+
+@pytest.fixture(scope="module")
+def pulled(tmp_path_factory):
+    """One loopback ``CopyFile`` of the big file, server 1 pulling from
+    server 0 on this thread, with the splits' clock and both totals'
+    locks tallied."""
+    root = tmp_path_factory.mktemp("pulled")
+    rack = Rack(root, {1: ROW // 2})
+    tally = Tally()
+    try:
+        data = with_a_big_file(rack)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(volume_server_mod, "_clock", tally.clock)
+            mp.setattr(pb, "_clock", tally.clock)
+            mp.setattr(pipe, "_TELEMETRY_LOCK",
+                       tally.lock(pipe._TELEMETRY_LOCK))
+            mp.setattr(flight, "_TOTALS_LOCK",
+                       tally.lock(flight._TOTALS_LOCK))
+            before = pipe.debug_payload()
+            tally.events.clear()
+            got = pull_big(rack, root / "pulled.big")
+            after = wait_for_close(before["copy_file_calls"])
+        me = threading.get_ident()
+        clocked = {t: ev for t, ev in tally.events.items() if "clock" in ev}
+        (source,) = [ev for t, ev in clocked.items() if t != me]
+        yield {"d": deltas(before, after), "got": got,
+               "same": (root / "pulled.big").read_bytes() == data,
+               "events": {"source": source, "receiver": clocked[me]}}
+    finally:
+        rack.stop()
+
+
+def test_the_chunks_of_a_stream_are_counted_at_both_ends(pulled):
+    d = pulled["d"]
+    assert pulled["got"] == BIG and pulled["same"]
+    assert d["copy_file_bytes"] == d["copy_recv_bytes"] == BIG
+    assert d["copy_file_chunks"] == d["copy_recv_chunks"] == BIG_CHUNKS == 13
+    assert d["copy_file_calls"] == 1
+
+
+@pytest.mark.parametrize("whole, parts", [
+    ("copy_file_seconds", SOURCE_PARTS), ("copy_recv_seconds", RECV_PARTS)],
+    ids=["source", "receiver"])
+def test_a_streams_parts_add_up_to_its_span(pulled, whole, parts):
+    """read + build + serialize + send is ``copy_file_seconds`` and
+    wait + write ``copy_recv_seconds`` but for the loop's own lines."""
+    d = pulled["d"]
+    assert all(d[p] > 0 for p in parts), {p: d[p] for p in parts}
+    assert 0.98 * d[whole] <= sum(d[p] for p in parts) <= d[whole] * 1.0001
+
+
+@pytest.mark.parametrize("end, cpu, whole", [
+    ("source", "copy_file_cpu_seconds", "copy_file_seconds"),
+    ("receiver", "copy_recv_cpu_seconds", "copy_recv_seconds")])
+def test_a_streams_thread_was_on_a_core_for_part_of_it(
+        pulled, end, cpu, whole):
+    d = pulled["d"]
+    assert 0 < d[cpu] <= d[whole] * 1.05, end
+
+
+@pytest.mark.parametrize("end, most", [("source", 6), ("receiver", 4)])
+def test_a_chunk_costs_a_few_clock_reads(pulled, end, most):
+    reads = pulled["events"][end].count("clock")
+    assert 2 * BIG_CHUNKS <= reads <= most * BIG_CHUNKS, reads
+
+
+@pytest.mark.parametrize("end", ["source", "receiver"])
+def test_no_totals_lock_is_taken_between_a_streams_first_and_last_chunk(
+        pulled, end):
+    """Both ends fold what they kept in locals once, at the close: the
+    first and the last clock read of a stream's thread have no
+    acquisition of ``pipe._TELEMETRY_LOCK`` or ``flight._TOTALS_LOCK``
+    between them, and the close has."""
+    events = pulled["events"][end]
+    first = events.index("clock")
+    last = len(events) - 1 - events[::-1].index("clock")
+    assert "lock" not in events[first:last]
+    assert "lock" in events[last:]
+
+
+def test_a_cut_stream_folds_what_it_had_at_both_ends(racks, tmp_path):
+    """The fault point ``ec.shard_copy`` fires behind the receiver's
+    fourth chunk: it has counted four, the source those and what it
+    had sent ahead, and both have seconds for them."""
+    rack = racks({1: ROW // 2})
+    with_a_big_file(rack)
+    spec = "error@0.3#1"
+    seed = next(s for s in range(1000) if first_hit(spec, s) == 3)
+    before = pipe.debug_payload()
+    faults.inject("ec.shard_copy", spec, seed=seed)
+    with pytest.raises(faults.FaultError):
+        pull_big(rack, tmp_path / "cut.big")
+    faults.clear()
+    d = deltas(before, wait_for_close(before["copy_file_calls"]))
+    assert not list(tmp_path.glob("cut.big*"))
+    assert d["copy_recv_chunks"] == 4
+    assert d["copy_recv_bytes"] == 4 * CHUNK
+    assert all(d[p] > 0 for p in RECV_PARTS)
+    # gRPC's window lets the source run ahead of the chunk that failed,
+    # by up to eight here
+    assert 4 <= d["copy_file_chunks"] <= BIG_CHUNKS
+    assert d["copy_file_bytes"] == min(d["copy_file_chunks"] * CHUNK, BIG)
+    assert all(d[p] > 0 for p in SOURCE_PARTS[:3])
+    assert d["copy_send_seconds"] >= 0 and d["copy_file_cpu_seconds"] > 0

@@ -13,12 +13,15 @@ import io
 import json
 import threading
 import time
+import types
 import urllib.request
+from unittest import mock
 
 import jax
 import numpy as np
 import pytest
 
+from seaweedfs_tpu import pb as pb_mod
 from seaweedfs_tpu.ops import rs_jax, rs_pallas
 from seaweedfs_tpu.pipeline import flight, pipe
 from seaweedfs_tpu.util import tracing
@@ -619,6 +622,110 @@ def test_a_profiler_session_holds_the_stage_spans_as_leaves(
 
 
 # --------------------------------------------------------------------------
+# a stream's chunks: plain totals, and the one timed serialiser
+# --------------------------------------------------------------------------
+
+#: what ``CopyFile`` and ``_copy_remote_file`` fold at a stream's close
+CHUNK_KEYS = ["copy_file_chunks", "copy_read_seconds", "copy_build_seconds",
+              "copy_serialize_seconds", "copy_send_seconds",
+              "copy_file_cpu_seconds", "copy_recv_chunks",
+              "copy_recv_wait_seconds", "copy_recv_write_seconds",
+              "copy_recv_cpu_seconds"]
+
+
+def test_the_chunk_totals_are_listed_at_zero_before_any_stream():
+    assert set(CHUNK_KEYS) <= set(pipe._TOTALS)
+    pipe.reset_telemetry()
+    payload = pipe.debug_payload()
+    assert [payload[k] for k in CHUNK_KEYS] == [0] * len(CHUNK_KEYS)
+    assert all(isinstance(payload[k], int) == k.endswith("_chunks")
+               for k in CHUNK_KEYS)
+
+
+def test_fold_adds_every_count_under_one_acquisition(monkeypatch):
+    before = pipe.debug_payload()
+    lock = mock.MagicMock()
+    monkeypatch.setattr(pipe, "_TELEMETRY_LOCK", lock)
+    pipe.fold(copy_file_chunks=3, copy_read_seconds=0.25,
+              copy_recv_chunks=2)
+    assert lock.__enter__.call_count == lock.__exit__.call_count == 1
+    monkeypatch.undo()
+    after = pipe.debug_payload()
+    assert [after[k] - before[k] for k in (
+        "copy_file_chunks", "copy_read_seconds", "copy_recv_chunks")] \
+        == [3, 0.25, 2]
+    with pytest.raises(KeyError):
+        pipe.fold(no_such_total=1)
+
+
+SERVICES = [(pb_mod.MASTER_SERVICE, pb_mod.MASTER_METHODS),
+            (pb_mod.VOLUME_SERVICE, pb_mod.VOLUME_METHODS),
+            (pb_mod.FILER_SERVICE, pb_mod.FILER_METHODS)]
+
+
+@pytest.mark.parametrize("service, methods", SERVICES,
+                         ids=[name for name, _ in SERVICES])
+def test_the_timed_serialiser_is_copy_files_alone(service, methods):
+    """``generic_handler`` registers every method with its message's own
+    ``SerializeToString`` but ``CopyFile``, whose wrapper gives the same
+    bytes and adds its seconds to the calling thread's sum."""
+    class Servicer:
+        def __getattr__(self, name):
+            return lambda request, context: None
+    handler = pb_mod.generic_handler(service, methods, Servicer())
+    timed = []
+    for m in methods:
+        registered = handler.service(types.SimpleNamespace(
+            method=f"/{service}/{m.name}",
+            invocation_metadata=())).response_serializer
+        if registered != m.response_cls.SerializeToString:
+            timed.append(m.name)
+            message = m.response_cls(file_content=bytes(range(256)) * 40)
+            pb_mod.copy_stream.serialize = 0.0
+            assert registered(message) == message.SerializeToString()
+            assert pb_mod.copy_stream.serialize > 0
+    assert timed == (["CopyFile"] if service == pb_mod.VOLUME_SERVICE
+                     else [])
+
+
+def test_two_streams_at_once_keep_their_serialise_seconds_apart(
+        monkeypatch):
+    """The serialiser's seconds go to the calling thread's sum: two
+    handler threads inside their streams at the same time, one
+    serialising three messages of 1 s by its clock and one a single
+    message of 10 s, each read their own, and a third thread none."""
+    steps = {}
+    now = threading.local()
+
+    def clock() -> float:
+        # a thread's own clock, a step of its own further at each read
+        now.t = getattr(now, "t", 0.0) + steps[threading.get_ident()]
+        return now.t
+    monkeypatch.setattr(pb_mod, "_clock", clock)
+    meet = threading.Barrier(2, timeout=30)
+    timed = pb_mod._timed(lambda message: message)
+    sums = {}
+    mine = pb_mod.copy_stream.serialize
+
+    def stream(name: str, step: float, messages: int):
+        steps[threading.get_ident()] = step
+        pb_mod.copy_stream.serialize = 0.0
+        meet.wait()
+        for _ in range(messages):
+            assert timed(b"chunk") == b"chunk"
+        meet.wait()
+        sums[name] = pb_mod.copy_stream.serialize
+    threads = [threading.Thread(target=stream, args=args, daemon=True)
+               for args in (("a", 1.0, 3), ("b", 10.0, 1))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(WATCHDOG)
+    assert sums == {"a": 3.0, "b": 10.0}
+    assert pb_mod.copy_stream.serialize == mine
+
+
+# --------------------------------------------------------------------------
 # a live mini-cluster: /debug/vars and the Dapper tree
 # --------------------------------------------------------------------------
 
@@ -628,7 +735,8 @@ PIPELINE_KEYS = ["pool_wait_seconds", "dispatch_seconds", "sync_seconds",
                  "wall_seconds", "pool_acquires", "pool_fresh_acquires",
                  "sync_ready_seconds", "sync_copy_seconds",
                  "write_drain_seconds", "write_stage_seconds", "groups", "group_ready_seconds",
-                 "group_ready_bytes", "copy_file_shared_seconds"] + [
+                 "group_ready_bytes", "copy_file_shared_seconds",
+                 *CHUNK_KEYS] + [
     f"{name}_seconds" for name in flight.WAITS] + [
     f"step_{name}_{what}" for name in flight.HANDLER_STEPS
     + flight.INNER_STEPS for what in ("seconds", "calls")]
