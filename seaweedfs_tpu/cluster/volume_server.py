@@ -6,22 +6,26 @@ Mirrors weed/server/volume_server*.go + volume_grpc_erasure_coding.go
 EC shard reads (with interval reconstruction pulling remote shards over
 ``VolumeEcShardRead``), fans replicated writes out to peer replicas, and
 executes the shell's EC choreography rpcs — generate (the TPU encode!),
-rebuild, copy (via ``CopyFile`` streaming from the source node), mount,
+rebuild, copy (one ``GET`` of the source node's HTTP plane a file,
+answered by ``sendfile``; its ``CopyFile`` stream under TLS), mount,
 unmount, to-volume. A background thread streams heartbeat snapshots to
 the master (§3.4).
 """
 
 from __future__ import annotations
 
+import contextlib
+import http.client
 import json
 import os
+import re
 import threading
 import time
 from concurrent import futures
 from http.server import BaseHTTPRequestHandler
 from pathlib import Path
 from typing import Optional
-from urllib.parse import parse_qs, urlparse
+from urllib.parse import parse_qs, urlencode, urlparse
 
 import numpy as np
 
@@ -55,6 +59,15 @@ from ..util import tls as tls_mod
 _COPY_CHUNK = 1024 * 1024
 #: the clock of the per-chunk splits in CopyFile and _copy_remote_file
 _clock = time.perf_counter
+#: The HTTP plane's route for a volume's raw files, pulled by a peer
+#: (``_http_chunks``): ``GET <route>?volume=<id>&collection=<c>&ext=
+#: <.ext>[&ignore_missing=1]``, an admin read that carries the gRPC
+#: plane's bearer token; answered by ``socket.sendfile``.
+_COPY_ROUTE = "/admin/copy_file"
+#: what the route serves of a volume the store knows: the plain pair,
+#: the EC index files and the shard files, the extensions its three
+#: callers pull (VolumeCopy, VolumeEcShardsCopy, a rebuild's fetch)
+_COPY_EXT = re.compile(r"\.(dat|idx|ecx|ecj|vif|ec\d\d)\Z")
 
 
 class VolumeServerError(RuntimeError):
@@ -749,35 +762,48 @@ class _VolumeServicer:
 
     # ---- file streaming ----
 
-    def CopyFile(self, request, context):
+    def copy_source(self, volume_id: int, collection: str, ext: str,
+                    ignore_missing: bool = False) -> Optional[Path]:
+        """What a file of a volume is served from, on either plane
+        (``CopyFile``, the HTTP route ``_COPY_ROUTE``): ``<base><ext>``
+        with a live volume's buffered appends flushed first, None where
+        the file is not there and the caller said it may not be."""
         store = self.vs.store
         # Flush buffered appends so the streamed bytes are complete
         # (the write path holds .dat/.idx open with userspace buffers).
-        if (request.ext in (".dat", ".idx")
-                and store.has_volume(request.volume_id,
-                                     request.collection)):
-            store.get_volume(request.volume_id, request.collection).sync()
-        base = self._base_for(request.volume_id, request.collection,
-                              must_exist=False)
+        if (ext in (".dat", ".idx")
+                and store.has_volume(volume_id, collection)):
+            store.get_volume(volume_id, collection).sync()
+        base = self._base_for(volume_id, collection, must_exist=False)
         if base is None:
-            raise StoreError(
-                f"volume {request.volume_id} has no local files")
-        path = Path(str(base) + request.ext)
+            raise StoreError(f"volume {volume_id} has no local files")
+        path = Path(str(base) + ext)
         if not path.exists():
-            if request.ignore_source_file_not_found:
-                return
+            if ignore_missing:
+                return None
             raise StoreError(f"{path} does not exist")
+        return path
+
+    def CopyFile(self, request, context):
+        path = self.copy_source(request.volume_id, request.collection,
+                                request.ext,
+                                request.ignore_source_file_not_found)
+        if path is None:
+            return
         stop = request.stop_offset or path.stat().st_size
         start = min(request.start_offset, stop)
-        # one span per stream, the puller's pace included: it stays open
-        # while gRPC hands each chunk on (the sync server drains the
-        # generator on this thread). Inside it a chunk's parts are told
-        # apart by clock reads into this stream's locals, three a chunk
-        # here and two in the serialiser gRPC calls between the yield
-        # and the resume (pb.copy_stream, this thread's), and folded
-        # into the totals once, at the close: no span, lock or
-        # annotation per chunk. read + build + serialize + send is the
-        # span's seconds but for the loop's own lines
+        # the gRPC transport of a file (the other is the HTTP route,
+        # _serve_copy: which of the two a puller takes is
+        # _copy_remote_file's choice). One span per stream, the
+        # puller's pace included: it stays open while gRPC hands each
+        # chunk on (the sync server drains the generator on this
+        # thread). Inside it a chunk's parts are told apart by clock
+        # reads into this stream's locals, three a chunk here and two
+        # in the serialiser gRPC calls between the yield and the resume
+        # (pb.copy_stream, this thread's), and folded into the totals
+        # once, at the close: no span, lock or annotation per chunk.
+        # read + build + serialize + send is the span's seconds but for
+        # the loop's own lines
         sent = start
         chunks = 0
         read_s = build_s = yield_s = 0.0
@@ -1158,43 +1184,115 @@ def _scheme_from_vif(base) -> EcScheme:
     return DEFAULT_SCHEME
 
 
+def _grpc_chunks(vs: VolumeServer, src_url: str, volume_id: int,
+                 collection: str, ext: str, ignore_missing: bool):
+    """A file of ``src_url`` as the ``file_content`` of its ``CopyFile``
+    stream: the transport of a cluster whose gRPC plane runs under TLS.
+    A message's wait is inside gRPC's ``next()``: the source, the wire,
+    the receive and the parse."""
+    call = vs.peer_stub(src_url).CopyFile(
+        volume_server_pb2.CopyFileRequest(
+            volume_id=volume_id, collection=collection, ext=ext,
+            ignore_source_file_not_found=ignore_missing))
+    try:
+        for resp in call:
+            # read the field once: each access copies the chunk
+            yield resp.file_content
+    finally:
+        call.cancel()  # a stream left in its middle; else nothing
+
+
+def _http_chunks(vs: VolumeServer, src_url: str, volume_id: int,
+                 collection: str, ext: str, ignore_missing: bool):
+    """The same file as the body of one ``GET`` of ``src_url``'s
+    ``_COPY_ROUTE``, read into one reused buffer of ``_COPY_CHUNK``
+    bytes and handed on as views of it: no ``bytes`` object, message or
+    frame per chunk, and the source's end is ``sendfile``. A view is
+    the caller's until it asks for the next. Any answer but the file
+    (or 204, the missing file the caller allowed) fails it, and so does
+    a body that ends before its ``Content-Length``."""
+    query = {"volume": volume_id, "collection": collection, "ext": ext}
+    if ignore_missing:
+        query["ignore_missing"] = 1
+    # the caller's trace and what is left of its deadline go along
+    headers = retry.inject({"Connection": "close"})
+    if vs.guard.enabled:
+        headers["Authorization"] = \
+            f"Bearer {security.grpc_sign(vs.guard)}"
+    host, _, port = src_url.partition(":")
+    # seaweedlint: disable=SW601 — a body streamed into a reused buffer: retry.http_request returns whole bodies and retries, a pull is never resumed mid-file
+    conn = http.client.HTTPConnection(
+        host, int(port),
+        timeout=httpserver.default_config().request_read_timeout)
+    try:
+        conn.request("GET", f"{_COPY_ROUTE}?{urlencode(query)}",
+                     headers=headers)
+        resp = conn.getresponse()
+        if resp.status == 204 and ignore_missing:
+            return
+        if resp.status != 200:
+            raise VolumeServerError(
+                f"{src_url}: GET {ext} of volume {volume_id}: "
+                f"{resp.status} {resp.read(200)!r}")
+        left = int(resp.headers["Content-Length"])
+        view = memoryview(bytearray(_COPY_CHUNK))
+        while left:
+            n = resp.readinto(view)  # at most what the body has left
+            if not n:
+                raise VolumeServerError(
+                    f"{src_url}: {ext} of volume {volume_id} ended "
+                    f"{left} bytes before its Content-Length")
+            left -= n
+            yield view[:n]
+    finally:
+        conn.close()
+
+
 def _copy_remote_file(vs: VolumeServer, src_url: str, volume_id: int,
                       collection: str, ext: str, dest: Path,
                       ignore_missing: bool = False) -> int:
     """Pull one file of a volume from ``src_url`` into ``dest``; returns
-    the bytes received. Two leaf spans: ``copy_recv`` (the stream into
-    ``<dest>.part``) and ``copy_commit`` (fsync + rename). Inside
-    ``copy_recv`` each chunk's two halves are told apart by two clock
-    reads into locals, folded into the totals once per file:
-    ``copy_recv_wait_seconds`` (inside ``next()``: the source, the
-    wire, gRPC's receive and parse; the call's start and the stream's
-    end with it), ``copy_recv_write_seconds`` (the field read,
-    ``f.write``, the fault point), ``copy_recv_chunks`` and the
-    thread's ``copy_recv_cpu_seconds``; wait + write is the span's
-    seconds but for the loop's own lines."""
+    the bytes received. Two transports under one frame. The frame: the
+    leaf span ``copy_recv`` (the stream into ``<dest>.part``, the fault
+    point ``ec.shard_copy`` behind every chunk written, the ``.part``
+    removed on any failure) and the leaf span ``copy_commit`` (fsync +
+    rename). The transport, chosen by what the process was started
+    with: the source's HTTP plane (``_http_chunks``: ``sendfile`` there,
+    one reused buffer here), or, where the gRPC plane runs under TLS —
+    which encrypts and mutually authenticates these bytes, and the HTTP
+    plane is plaintext — its ``CopyFile`` stream (``_grpc_chunks``). A
+    transport that fails fails the file: no second one is tried.
+    Inside ``copy_recv`` each chunk's two halves are told apart by two
+    clock reads into locals, folded into the totals once per file:
+    ``copy_recv_wait_seconds`` (inside the transport's ``next()``:
+    ``readinto`` or gRPC's receive, so the source and the wire; the
+    request's start and the stream's end with it),
+    ``copy_recv_write_seconds`` (``f.write``, the fault point),
+    ``copy_recv_chunks``, the thread's ``copy_recv_cpu_seconds`` and
+    ``copy_recv_http_bytes`` (what of ``copy_recv_bytes`` the HTTP
+    plane carried); wait + write is the span's seconds but for the
+    loop's own lines."""
     dest.parent.mkdir(parents=True, exist_ok=True)
     tmp = dest.with_suffix(dest.suffix + ".part")
+    over_http = tls_mod.installed() is None
+    chunks_of = _http_chunks if over_http else _grpc_chunks
     received = 0
     chunks = 0
     wait_s = write_s = 0.0
     clock = _clock
     cpu0 = time.thread_time()
     try:
-        with flight_mod.span("copy_recv") as sp, open(tmp, "wb") as f:
+        with flight_mod.span("copy_recv") as sp, open(tmp, "wb") as f, \
+                contextlib.closing(chunks_of(
+                    vs, src_url, volume_id, collection, ext,
+                    ignore_missing)) as stream:
             t = clock()
-            stream = iter(vs.peer_stub(src_url).CopyFile(
-                volume_server_pb2.CopyFileRequest(
-                    volume_id=volume_id, collection=collection,
-                    ext=ext,
-                    ignore_source_file_not_found=ignore_missing)))
             while True:
-                resp = next(stream, None)
+                chunk = next(stream, None)
                 t_got = clock()
                 wait_s += t_got - t
-                if resp is None:
+                if chunk is None:
                     break
-                # read the field once: each access copies the chunk
-                chunk = resp.file_content
                 f.write(chunk)
                 received += len(chunk)
                 chunks += 1
@@ -1207,6 +1305,7 @@ def _copy_remote_file(vs: VolumeServer, src_url: str, volume_id: int,
         raise
     finally:
         pipe_mod.fold(copy_recv_bytes=received, copy_recv_chunks=chunks,
+                      copy_recv_http_bytes=received if over_http else 0,
                       copy_recv_wait_seconds=wait_s,
                       copy_recv_write_seconds=write_s,
                       copy_recv_cpu_seconds=time.thread_time() - cpu0)
@@ -1248,8 +1347,89 @@ def _make_http_handler(vs: VolumeServer):
             fid = FileId.parse(u.path.lstrip("/"))
             return fid.volume_id, fid, q
 
+        def _serve_copy(self, query: str) -> None:
+            """``_COPY_ROUTE``: one file of a volume as one response
+            body that this process never holds — the headers, then
+            ``sendfile`` (page cache -> socket in the kernel, the
+            interpreter released for the whole file). The HTTP
+            transport of ``CopyFile``'s streams, and counted as one:
+            the leaf span ``copy_file`` among the server's open streams
+            (``copy_file_shared_seconds``), ``copy_file_bytes`` and
+            ``_cpu_seconds`` as there, ``copy_send_seconds`` = the
+            seconds inside ``sendfile``, ``copy_file_chunks`` = the MiB
+            served, rounded up, and ``copy_file_sendfile_bytes``. An
+            admin read like the rpc: with a signing key set it wants
+            the gRPC plane's bearer token. It serves ``<base><ext>`` of
+            a volume the store knows, for the extensions ``_COPY_EXT``
+            names and a collection that is a plain name: no path of the
+            caller's."""
+            q = {k: v[0] for k, v in parse_qs(query).items()}
+            scheme, _, token = \
+                self.headers.get("Authorization", "").partition(" ")
+            if vs.guard.enabled and not (
+                    scheme.lower() == "bearer"
+                    and security.grpc_verify(vs.guard, token.strip())):
+                self._json({"error": "unauthorized"}, 401)
+                return
+            collection = q.get("collection", "")
+            try:
+                volume_id, ext = int(q["volume"]), q["ext"]
+                # a collection is a file name's prefix, never a path:
+                # <base><ext> then lies in one of the store's own
+                # directories
+                if not _COPY_EXT.match(ext) or "/" in collection:
+                    raise ValueError(ext)
+            except (KeyError, ValueError):
+                self._json({"error": "want volume=<id>&ext=<one of a "
+                            "volume's files>[&collection=<name>]"}, 400)
+                return
+            try:
+                path = vs.servicer.copy_source(
+                    volume_id, collection, ext,
+                    ignore_missing="ignore_missing" in q)
+            except StoreError as e:
+                self._json({"error": str(e)}, 404)
+                return
+            if path is None:
+                self.send_response(204)
+                self.end_headers()
+                return
+            with flight_mod.span("copy_file") as sp, open(path, "rb") as f, \
+                    vs.copy_streams.stream():
+                cpu0 = time.thread_time()
+                size = os.fstat(f.fileno()).st_size
+                t0 = None
+                try:
+                    self.send_response(200)
+                    self.send_header("Content-Type",
+                                     "application/octet-stream")
+                    self.send_header("Content-Length", str(size))
+                    self.end_headers()
+                    t0 = _clock()
+                    if size:
+                        self.connection.sendfile(f, 0, size)
+                except OSError as e:
+                    # the puller went, or was silent for the socket's
+                    # timeout: no second status line can follow the
+                    # headers, the connection ends here
+                    glog.v(1, "copy of %s cut: %s", path, e)
+                    httpserver.drop_connection(self)
+                finally:
+                    send_s = _clock() - t0 if t0 is not None else 0.0
+                    # sendfile leaves the file where it stopped
+                    sp.nbytes = sent = f.tell()
+                    pipe_mod.fold(
+                        copy_file_bytes=sent,
+                        copy_file_sendfile_bytes=sent,
+                        copy_file_chunks=-(-sent // _COPY_CHUNK),
+                        copy_send_seconds=send_s,
+                        copy_file_cpu_seconds=time.thread_time() - cpu0)
+
         def do_GET(self):
             u = urlparse(self.path)
+            if u.path == _COPY_ROUTE:
+                self._serve_copy(u.query)
+                return
             if u.path == "/status":
                 self._json({"Version": "seaweedfs-tpu",
                             **vs.store.status()})

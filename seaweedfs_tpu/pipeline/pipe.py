@@ -414,9 +414,9 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            # lent before (its pages are faulted in by the fill)
            "pool_acquires": 0, "pool_fresh_acquires": 0,
            # files between volume servers (cluster/volume_server.py):
-           # bytes a CopyFile stream served, bytes a puller wrote to
-           # its .part files, and those of them a rebuild's sibling
-           # fetch pulled
+           # bytes served to a peer (a CopyFile stream or an HTTP
+           # body), bytes a puller wrote to its .part files, and those
+           # of them a rebuild's sibling fetch pulled
            "copy_file_bytes": 0, "copy_recv_bytes": 0,
            "rebuild_fetch_bytes": 0,
            # stream-seconds of CopyFile served while another stream of
@@ -432,11 +432,19 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            "copy_file_chunks": 0, "copy_read_seconds": 0.0,
            "copy_build_seconds": 0.0, "copy_serialize_seconds": 0.0,
            "copy_send_seconds": 0.0, "copy_file_cpu_seconds": 0.0,
+           # a stream served on the HTTP plane (a cluster without TLS)
+           # is one sendfile: all of its seconds are copy_send's, its
+           # chunks the MiB served, and its bytes are counted here too
+           "copy_file_sendfile_bytes": 0,
            # and the server that pulls it, the parts of
-           # copy_recv_seconds: inside next() (the source, the wire,
-           # gRPC's receive and parse), and the field read + f.write
+           # copy_recv_seconds: inside the transport's next() (the
+           # source and the wire: readinto, or gRPC's receive and
+           # parse), and f.write + the fault point
            "copy_recv_chunks": 0, "copy_recv_wait_seconds": 0.0,
-           "copy_recv_write_seconds": 0.0, "copy_recv_cpu_seconds": 0.0}
+           "copy_recv_write_seconds": 0.0, "copy_recv_cpu_seconds": 0.0,
+           # what of copy_recv_bytes came as an HTTP body, read into
+           # one reused buffer
+           "copy_recv_http_bytes": 0}
 RECENT: deque = deque(maxlen=8)
 
 
@@ -542,24 +550,29 @@ def debug_payload() -> dict:
     pool's threads; ``pack`` is carved out of ``read``;
     ``decode_matrix`` = the host's share of a reconstruct, once per
     rebuild run: invert, compose, expand for the kernel;
-    ``copy_file`` = one ``CopyFile`` stream served, first chunk read to
-    last chunk taken, with ``copy_file_bytes``, and
-    ``copy_file_shared_seconds`` = what of it was spent while another
-    stream of the same server was open; its ``copy_file_chunks``
-    chunks split where each is handled into ``copy_read`` (``f.read``),
-    ``copy_build`` (the response message made), ``copy_serialize``
-    (gRPC's call of the serialiser) and ``copy_send`` (the rest of
-    ``yield`` -> resume: the send started and awaited), which sum to
-    ``copy_file_seconds`` but for the loop's bookkeeping, with
-    ``copy_file_cpu_seconds`` = the handler threads' CPU time over
-    their streams; ``copy_recv`` /
+    ``copy_file`` = one file served to a peer, on either plane, with
+    ``copy_file_bytes``, and ``copy_file_shared_seconds`` = what of it
+    was spent while another stream of the same server was open. As a
+    ``CopyFile`` stream (first chunk read to last chunk taken) its
+    ``copy_file_chunks`` chunks are split where each is handled into
+    ``copy_read`` (``f.read``), ``copy_build`` (the response message
+    made), ``copy_serialize`` (gRPC's call of the serialiser) and
+    ``copy_send`` (the rest of ``yield`` -> resume: the send started
+    and awaited), which sum to ``copy_file_seconds`` but for the
+    loop's bookkeeping; as an HTTP body (a cluster without TLS) it is
+    one ``sendfile``: ``copy_send`` = the seconds inside it, the three
+    other parts get nothing, the chunks are the MiB served, and
+    ``copy_file_sendfile_bytes`` = its bytes; ``copy_file_cpu_seconds``
+    = the serving threads' CPU time over their streams; ``copy_recv`` /
     ``copy_commit`` = the pulling side of it, stream -> ``.part`` with
-    ``copy_recv_bytes``, then fsync + rename; ``copy_recv`` split over
-    its ``copy_recv_chunks`` into ``copy_recv_wait`` (inside
-    ``next()``: the source, the wire, gRPC's receive and parse) and
-    ``copy_recv_write`` (the field read and ``f.write``), with
-    ``copy_recv_cpu_seconds``; ``rebuild_fetch_bytes``
-    = what of ``copy_recv_bytes`` a rebuild's sibling fetch pulled),
+    ``copy_recv_bytes`` (``copy_recv_http_bytes`` of them as an HTTP
+    body), then fsync + rename; ``copy_recv`` split over its
+    ``copy_recv_chunks`` into ``copy_recv_wait`` (inside the
+    transport's ``next()``: ``readinto`` or gRPC's receive, so the
+    source and the wire) and ``copy_recv_write`` (``f.write`` and the
+    fault point), with ``copy_recv_cpu_seconds``;
+    ``rebuild_fetch_bytes`` = what of ``copy_recv_bytes`` a rebuild's
+    sibling fetch pulled),
     ``rpc_seconds`` (the EC handlers, each counted once, pipeline run
     included), ``step_<name>_seconds`` / ``_calls``
     for every server-side rpc step, and the recent-run ring."""

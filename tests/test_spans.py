@@ -630,7 +630,9 @@ CHUNK_KEYS = ["copy_file_chunks", "copy_read_seconds", "copy_build_seconds",
               "copy_serialize_seconds", "copy_send_seconds",
               "copy_file_cpu_seconds", "copy_recv_chunks",
               "copy_recv_wait_seconds", "copy_recv_write_seconds",
-              "copy_recv_cpu_seconds"]
+              "copy_recv_cpu_seconds",
+              # what of a stream's bytes the HTTP plane carried
+              "copy_file_sendfile_bytes", "copy_recv_http_bytes"]
 
 
 def test_the_chunk_totals_are_listed_at_zero_before_any_stream():
@@ -638,8 +640,8 @@ def test_the_chunk_totals_are_listed_at_zero_before_any_stream():
     pipe.reset_telemetry()
     payload = pipe.debug_payload()
     assert [payload[k] for k in CHUNK_KEYS] == [0] * len(CHUNK_KEYS)
-    assert all(isinstance(payload[k], int) == k.endswith("_chunks")
-               for k in CHUNK_KEYS)
+    assert all(isinstance(payload[k], int)
+               == k.endswith(("_chunks", "_bytes")) for k in CHUNK_KEYS)
 
 
 def test_fold_adds_every_count_under_one_acquisition(monkeypatch):
