@@ -168,6 +168,10 @@ class VolumeServer:
         #: ``copy_file_shared_seconds``.
         self.copy_streams = pipe_mod.SharedSeconds(
             "copy_file_shared_seconds")
+        #: The files a rebuild's fetch is pulling INTO this server, for
+        #: ``rebuild_fetch_shared_seconds``.
+        self.fetch_streams = pipe_mod.SharedSeconds(
+            "rebuild_fetch_shared_seconds")
 
     # ------------- lifecycle -------------
 
@@ -469,8 +473,13 @@ class VolumeServer:
 
     def ec_shard_peers(self, volume_id: int, shard_id: int) -> list[str]:
         """Servers holding one shard, from the master (cached ~1s)."""
+        return self.ec_shard_table(volume_id).get(shard_id, [])
+
+    def ec_shard_table(self, volume_id: int) -> dict[int, list[str]]:
+        """shard id -> the servers holding it, for every shard of the
+        volume the master knows of (cached ~1s)."""
         if not self.master_url:
-            return []
+            return {}
         now = time.time()
         with self._lock:
             cached = self._ec_loc_cache.get(volume_id)
@@ -482,7 +491,7 @@ class VolumeServer:
             with self._lock:
                 self._ec_loc_cache[volume_id] = (now, table)
             cached = (now, table)
-        return cached[1].get(shard_id, [])
+        return cached[1]
 
     def remote_shard_read(self, url: str, volume_id: int, shard_id: int,
                           offset: int, size: int) -> bytes:
@@ -966,65 +975,163 @@ class _VolumeServicer:
 
     @_ec_step("rebuild")
     def VolumeEcShardsRebuild(self, request, context):
-        """§3.5: pull sibling shards from peers, reconstruct only the
-        shards missing cluster-wide, drop the temporary copies."""
+        """§3.5, the server half of ``ec.rebuild``: this server is the
+        rebuilder the shell chose (upstream's ``rebuildOneEcVolume``).
+        It brings what it lacks to its own disk, restores the shards
+        that no server holds, and drops the copies:
+
+        - where it holds nothing of the volume (an empty replacement),
+          ``.vif``, ``.ecx`` and ``.ecj`` (may be absent) come first,
+          from a holder, into the directory a new shard would get,
+          past the ``[storage] fsync`` barrier: they stay (upstream's
+          ``prepareDataToRecover`` with ``copyEcxFile``), and the
+          geometry is read from the ``.vif`` fetched;
+        - surviving shards it lacks are pulled from their holders until
+          ``data_shards`` survivors are local, every source server's
+          files in turn on a chain of its own and the chains at once
+          (one source: the caller's thread); a sibling is unlinked when
+          the rebuild is over, so it is streamed with no barrier;
+        - ``generateMissingShards``: the pipeline run, then the mount
+          and one nudge of the master.
+
+        All or nothing: a file that cannot be fetched fails the call,
+        and every sibling copy, restored file and index file this call
+        placed is removed again. A volume with fewer survivors than
+        ``data_shards`` is refused as unrepairable before any shard
+        moves."""
         vs = self.vs
-        base = vs.store.ec_base(request.volume_id, request.collection)
-        if base is None:
-            raise StoreError(
-                f"no local ec files for volume {request.volume_id}")
-        scheme = _scheme_from_vif(base)
-        total = scheme.total_shards
-        local = set(ec_files.present_shards(base, total))
-        # Cluster-wide view: a shard is missing only if neither we nor
-        # any OTHER server holds it. The master's (briefly cached) map
-        # may still list this server for a shard just deleted here; the
-        # local disk is the authority on what this server holds.
-        missing = [sid for sid in range(total)
-                   if sid not in local
-                   and not [u for u in vs.ec_shard_peers(
-                       request.volume_id, sid) if u != vs.url]]
+        vid, col = request.volume_id, request.collection
         resp = volume_server_pb2.VolumeEcShardsRebuildResponse()
-        if not missing:
+        # shard id -> the OTHER servers that hold it. The master's
+        # (briefly cached) map may still list this server for a shard
+        # just deleted here; the local disk is the authority on what
+        # this server holds.
+        remote = {sid: others
+                  for sid, urls in vs.ec_shard_table(vid).items()
+                  if (others := [u for u in urls if u != vs.url])}
+        base = vs.store.ec_base(vid, col)
+        plan = None if base is None else _RebuildPlan(base, remote)
+        if plan is not None and not plan.missing:
             return resp
-        # Fetch remote siblings until k survivors are on local disk.
-        fetched: list = []
-        # holds copy_recv / copy_commit, so it is no leaf
-        with flight_mod.span("step_rebuild_fetch", leaf=False, trace=True):
-            for sid in range(total):
-                if len(local) >= scheme.data_shards:
-                    break
-                if sid in local:
-                    continue
-                for url in vs.ec_shard_peers(request.volume_id, sid):
-                    if url == vs.url:
-                        continue
-                    try:
-                        dest = ec_files.shard_path(base, sid)
-                        pipe_mod.count(
-                            "rebuild_fetch_bytes", _copy_remote_file(
-                                vs, url, request.volume_id,
-                                request.collection,
-                                ec_files.shard_ext(sid), dest))
-                        local.add(sid)
-                        fetched.append(dest)
-                        break
-                    except Exception as e:
-                        glog.v(1, "shard %d copy from %s failed: %s",
-                               sid, url, e)
+        if plan is None and not remote:
+            raise StoreError(f"no ec files for volume {vid} here, and "
+                             f"no other server holds a shard of it")
+        placed: list[Path] = []
         try:
+            # holds copy_recv / copy_commit, so it is no leaf
+            with flight_mod.span("step_rebuild_fetch", leaf=False,
+                                 trace=True):
+                if plan is None:
+                    base = _dest_base(vs, vid, col)
+                    plan = self._fetch_index_files(
+                        base, vid, col, remote, placed)
+                    if not plan.missing:
+                        for p in placed:
+                            p.unlink()
+                        return resp
+                self._fetch_siblings(plan, vid, col)
             rebuilt = rebuild_mod.rebuild_ec_files(
-                base, scheme, wanted=missing, pools=self._ec_pools)
+                base, plan.scheme, wanted=plan.missing,
+                pools=self._ec_pools)
+        except BaseException:
+            # as empty as it was: nothing of a failed call may look
+            # like a shard, or like a volume this server holds
+            for p in placed + ([] if plan is None else [
+                    ec_files.shard_path(base, sid)
+                    for sid in plan.missing]):
+                p.unlink(missing_ok=True)
+            raise
         finally:
-            for p in fetched:
-                if p.exists():
-                    p.unlink()
+            if plan is not None:
+                for _, dest, _ in plan.fetch:
+                    dest.unlink(missing_ok=True)
         with flight_mod.span("step_store_mount", trace=True):
-            vs.store.mount_ec_shards(request.volume_id, rebuilt,
-                                     request.collection)
+            vs.store.mount_ec_shards(vid, rebuilt, col)
         vs.heartbeat_now()
         resp.rebuilt_shard_ids.extend(rebuilt)
         return resp
+
+    def _pull(self, url: str, vid: int, col: str, ext: str, dest: Path,
+              ignore_missing: bool = False, durable: bool = True) -> int:
+        """One file of the rebuild's fetch, counted: its bytes, the
+        file, and the seconds its stream had company."""
+        with self.vs.fetch_streams.stream():
+            n = _copy_remote_file(self.vs, url, vid, col, ext, dest,
+                                  ignore_missing=ignore_missing,
+                                  durable=durable)
+        if n:
+            pipe_mod.fold(rebuild_fetch_bytes=n, rebuild_fetch_files=1)
+        return n
+
+    def _fetch_index_files(self, base: Path, vid: int, col: str,
+                           remote: dict, placed: list) -> "_RebuildPlan":
+        """A rebuilder that holds nothing of the volume: ``.vif`` first
+        (the geometry, and with it whether anything is missing and
+        whether enough survives), then ``.ecx`` and ``.ecj``, all from
+        the holder of the lowest surviving shard, durably: they stay.
+        What it placed is appended to ``placed`` as it lands."""
+        src = remote[min(remote)][0]
+        with flight_mod.span("step_rebuild_fetch_index", leaf=False,
+                             trace=True):
+            vif = ec_files.vif_path(base)
+            self._pull(src, vid, col, ".vif", vif)
+            placed.append(vif)
+            plan = _RebuildPlan(base, remote)
+            if plan.missing:
+                for ext, dest, optional in (
+                        (".ecx", ec_files.ecx_path(base), False),
+                        # no post-seal deletes yet: no journal
+                        (".ecj", ec_files.ecj_path(base), True)):
+                    if self._pull(src, vid, col, ext, dest,
+                                  ignore_missing=optional):
+                        placed.append(dest)
+        return plan
+
+    def _fetch_siblings(self, plan: "_RebuildPlan", vid: int,
+                        col: str) -> None:
+        """The survivors the plan wants local, each source server's in
+        turn on a chain of its own, the chains at once. A sibling that
+        its first holder cannot give is asked of the shard's other
+        holders; one that nobody gives fails the fetch."""
+        chains: dict[str, list] = {}
+        for sid, dest, urls in plan.fetch:
+            chains.setdefault(urls[0], []).append((sid, dest, urls))
+        if not chains:
+            return
+        pipe_mod.count("rebuild_fetch_sources", len(chains))
+        # what a worker thread continues the call's trace from
+        parent = tracing.outbound_value() or True
+
+        def chain(files: list) -> None:
+            with flight_mod.span("step_rebuild_fetch_source", leaf=False,
+                                 trace=parent):
+                for sid, dest, urls in files:
+                    ext = ec_files.shard_ext(sid)
+                    for url in urls[:-1]:
+                        try:
+                            self._pull(url, vid, col, ext, dest,
+                                       durable=False)
+                            break
+                        except Exception as e:
+                            glog.v(1, "shard %d copy from %s failed: %s",
+                                   sid, url, e)
+                    else:
+                        # the last holder's failure is the file's
+                        self._pull(urls[-1], vid, col, ext, dest,
+                                   durable=False)
+
+        if len(chains) == 1:
+            for files in chains.values():
+                chain(files)
+            return
+        # every chain runs to its end or its own error; the first
+        # error in the plan's order is raised after the join
+        with futures.ThreadPoolExecutor(len(chains),
+                                        "rebuild-fetch") as pool:
+            running = [pool.submit(chain, files)
+                       for files in chains.values()]
+        for done in running:
+            done.result()
 
     @_ec_step("shards_copy")
     def VolumeEcShardsCopy(self, request, context):
@@ -1062,15 +1169,28 @@ class _VolumeServicer:
 
     @_ec_step("shards_delete")
     def VolumeEcShardsDelete(self, request, context):
-        base = self.vs.store.ec_base(request.volume_id, request.collection)
+        """Remove shard files and their mounts; with the server's last
+        shard of the volume its ``.ecx`` / ``.ecj`` / ``.vif`` go too
+        (upstream's ``VolumeEcShardsDelete``): a server that holds no
+        shard of a volume holds nothing of it."""
+        store = self.vs.store
+        vid, col = request.volume_id, request.collection
+        base = store.ec_base(vid, col)
         if base is not None:
             for sid in request.shard_ids:
                 p = ec_files.shard_path(base, sid)
                 if p.exists() or p.is_symlink():
                     p.unlink()
-        self.vs.store.unmount_ec_shards(
-            request.volume_id, list(request.shard_ids),
-            request.collection)
+        store.unmount_ec_shards(vid, list(request.shard_ids), col)
+        # a shard still mounted is a shard still held: the disk is
+        # asked only when the last mount went
+        if base is not None and (col, vid) not in store.ec_mounts:
+            total = _scheme_from_vif(base).total_shards
+            if not any(ec_files.present_shards(loc.base_for(vid, col), total)
+                       for loc in store.locations):
+                for p in (ec_files.ecx_path(base), ec_files.ecj_path(base),
+                          ec_files.vif_path(base)):
+                    p.unlink(missing_ok=True)
         self.vs.heartbeat_now()
         return volume_server_pb2.VolumeEcShardsDeleteResponse()
 
@@ -1139,6 +1259,31 @@ class _VolumeServicer:
                 f"no local ec files for volume {request.volume_id}")
         ec_files.ecj_append(base, request.file_key)
         return volume_server_pb2.VolumeEcBlobDeleteResponse()
+
+
+class _RebuildPlan:
+    """What one ``VolumeEcShardsRebuild`` has to do, from the ``.vif``
+    under ``base``, the local disk and ``remote`` (shard id -> the
+    other servers that hold it): ``missing``, the shards no server
+    holds, and ``fetch``, the (shard id, local path, holders) of the
+    survivors to bring here so that ``data_shards`` are local, lowest
+    ids first. Fewer survivors than that anywhere: unrepairable."""
+
+    def __init__(self, base: Path, remote: dict):
+        self.scheme = scheme = _scheme_from_vif(base)
+        total = scheme.total_shards
+        local = set(ec_files.present_shards(base, total))
+        self.missing = [sid for sid in range(total)
+                        if sid not in local and sid not in remote]
+        elsewhere = [sid for sid in range(total)
+                     if sid not in local and sid in remote]
+        survive, k = len(local) + len(elsewhere), scheme.data_shards
+        if self.missing and survive < k:
+            raise StoreError(f"unrepairable: {survive} of the {k} shards "
+                             f"a rebuild needs survive")
+        need = max(0, k - len(local)) if self.missing else 0
+        self.fetch = [(sid, ec_files.shard_path(base, sid), remote[sid])
+                      for sid in elsewhere[:need]]
 
 
 def _dest_base(vs: VolumeServer, volume_id: int, collection: str) -> Path:
@@ -1250,13 +1395,18 @@ def _http_chunks(vs: VolumeServer, src_url: str, volume_id: int,
 
 def _copy_remote_file(vs: VolumeServer, src_url: str, volume_id: int,
                       collection: str, ext: str, dest: Path,
-                      ignore_missing: bool = False) -> int:
+                      ignore_missing: bool = False,
+                      durable: bool = True) -> int:
     """Pull one file of a volume from ``src_url`` into ``dest``; returns
     the bytes received. Two transports under one frame. The frame: the
     leaf span ``copy_recv`` (the stream into ``<dest>.part``, the fault
     point ``ec.shard_copy`` behind every chunk written, the ``.part``
     removed on any failure) and the leaf span ``copy_commit`` (fsync +
-    rename). The transport, chosen by what the process was started
+    rename). ``durable=False`` is for a copy that the caller itself
+    unlinks before it returns (a rebuild's fetched siblings): the
+    ``.part`` is renamed into place with no barrier and no
+    ``copy_commit``, so a cut stream still leaves nothing that looks
+    like a shard. The transport, chosen by what the process was started
     with: the source's HTTP plane (``_http_chunks``: ``sendfile`` there,
     one reused buffer here), or, where the gRPC plane runs under TLS —
     which encrypts and mutually authenticates these bytes, and the HTTP
@@ -1312,8 +1462,12 @@ def _copy_remote_file(vs: VolumeServer, src_url: str, volume_id: int,
     if ignore_missing and not received:
         tmp.unlink()
         return 0
+    if not durable:
+        # seaweedlint: disable=SW901 — a copy its caller unlinks before it returns: nothing to survive a power loss
+        os.replace(tmp, dest)
+        return received
     # durable rename commit: the copied replica/shard file must survive
-    # power loss once callers (ec.rebuild, volume copy) treat it as
+    # power loss once callers (ec.balance, volume copy) treat it as
     # placed — fsync the bytes AND the directory entry
     with flight_mod.span("copy_commit", nbytes=received):
         durability.durable_replace(tmp, dest)

@@ -342,6 +342,7 @@ _RING_CODES["pwritev"] = (0, EV_PWRITEV_RETIRE)
 HANDLER_STEPS = ("mark_readonly", "generate", "mount", "delete_source",
                  "shards_delete", "rebuild", "shards_copy")
 INNER_STEPS = ("vol_sync", "shard_files", "ecx", "vif", "rebuild_fetch",
+               "rebuild_fetch_index", "rebuild_fetch_source",
                "store_mount", "store_delete", "heartbeat", "reconcile",
                "master_heartbeat", "master_lookup")
 

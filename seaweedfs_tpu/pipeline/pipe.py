@@ -419,6 +419,13 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            # of them a rebuild's sibling fetch pulled
            "copy_file_bytes": 0, "copy_recv_bytes": 0,
            "rebuild_fetch_bytes": 0,
+           # the same fetch (VolumeEcShardsRebuild on a rebuilder that
+           # lacks survivors): files pulled, index files included; the
+           # source servers it pulled from, a chain each; and the
+           # stream-seconds it pulled while another of its streams was
+           # open (SharedSeconds: 0 where one source serves in turn)
+           "rebuild_fetch_files": 0, "rebuild_fetch_sources": 0,
+           "rebuild_fetch_shared_seconds": 0.0,
            # stream-seconds of CopyFile served while another stream of
            # the same server was open (SharedSeconds)
            "copy_file_shared_seconds": 0.0,
@@ -572,7 +579,9 @@ def debug_payload() -> dict:
     source and the wire) and ``copy_recv_write`` (``f.write`` and the
     fault point), with ``copy_recv_cpu_seconds``;
     ``rebuild_fetch_bytes`` = what of ``copy_recv_bytes`` a rebuild's
-    sibling fetch pulled),
+    fetch pulled, in ``rebuild_fetch_files`` files from
+    ``rebuild_fetch_sources`` servers, ``rebuild_fetch_shared_seconds``
+    of its stream-seconds in company),
     ``rpc_seconds`` (the EC handlers, each counted once, pipeline run
     included), ``step_<name>_seconds`` / ``_calls``
     for every server-side rpc step, and the recent-run ring."""

@@ -630,13 +630,47 @@ def cmd_ec_encode(env: ClusterEnv, argv: list[str]) -> None:
                 f"{servers} servers")
 
 
+def pick_rebuilder(nodes: list[EcNode], vid: int) -> EcNode:
+    """The server that rebuilds volume ``vid``, as upstream's
+    ``rebuildEcVolumes`` picks it (``sortEcNodesByFreeslotsDecending``,
+    the first): the node with most free slots by the master's
+    ``VolumeList``, whether or not it holds a shard of the volume — an
+    empty replacement of a lost server always wins. Among equals the
+    one that holds most shards of the volume (the least to fetch), then
+    the lowest url."""
+    return min(nodes, key=lambda n: (-n.free_slots,
+                                     -len(n.shards.get(vid, [])), n.url))
+
+
 @cluster_command("ec.rebuild")
 def cmd_ec_rebuild(env: ClusterEnv, argv: list[str]) -> None:
-    """§3.5: for every EC volume with missing shards, pick a rebuilder
-    holding >=1 shard and run VolumeEcShardsRebuild there."""
+    """§3.5, upstream's ``command_ec_rebuild.go``: for every EC volume
+    with a shard mounted anywhere, :func:`pick_rebuilder` names the
+    rebuilder and ``VolumeEcShardsRebuild`` runs there
+    (``rebuildOneEcVolume``): that server fetches what it lacks from
+    the holders (``prepareDataToRecover``: the index files if it holds
+    nothing of the volume, and surviving shards until ``data_shards``
+    are local), restores the shards no server holds
+    (``generateMissingShards``), mounts them (``mountEcShards``) and
+    drops the fetched copies. The server, which reads the geometry from
+    the ``.vif``, says which shards were missing; a volume it refuses
+    as unrepairable (fewer than ``data_shards`` survive) is reported
+    and the walk goes on."""
     p = _parser("ec.rebuild")
-    p.add_argument("-volumeId", type=int, default=0)
-    p.add_argument("-collection", default="")
+    p.description = (
+        "Restore the EC shards that no server holds. The rebuilder of a "
+        "volume is the server with most free slots (upstream's "
+        "rebuildEcVolumes: an empty replacement of a lost server always "
+        "wins), among equals the one holding most shards of the volume. "
+        "It fetches the index files (.ecx, .ecj, .vif) if it holds "
+        "nothing of the volume and surviving shards from their holders "
+        "until data_shards are local (prepareDataToRecover), restores "
+        "and mounts the lost ones (generateMissingShards, "
+        "mountEcShards), and deletes the fetched copies.")
+    p.add_argument("-volumeId", type=int, default=0,
+                   help="this volume only (default: every EC volume)")
+    p.add_argument("-collection", default="",
+                   help="only volumes of this collection")
     args = p.parse_args(argv)
     with flight.span("step_locate", trace=True):
         nodes = env.collect_ec_nodes()
@@ -644,12 +678,10 @@ def cmd_ec_rebuild(env: ClusterEnv, argv: list[str]) -> None:
     # heartbeat-reported shard info, NOT from the flag, so the RPC always
     # names the volume's real collection.
     present: dict[int, set[int]] = {}
-    holders: dict[int, list[EcNode]] = {}
     col_of: dict[int, str] = {}
     for n in nodes:
         for vid, sids in n.shards.items():
             present.setdefault(vid, set()).update(sids)
-            holders.setdefault(vid, []).append(n)
             col_of.setdefault(vid, n.collections.get(vid, ""))
     todo = [args.volumeId] if args.volumeId else sorted(present)
     failures = 0
@@ -665,13 +697,16 @@ def cmd_ec_rebuild(env: ClusterEnv, argv: list[str]) -> None:
         # rebuilder server is authoritative about which shards are
         # missing — never guess totals from shard ids here (a (12,4)
         # volume would silently skip, a (6,3) one would churn).
-        rebuilder = max(holders[vid],
-                        key=lambda n: len(n.shards.get(vid, [])))
+        rebuilder = pick_rebuilder(nodes, vid)
         try:
             resp = env.volume(rebuilder.url).VolumeEcShardsRebuild(
                 volume_server_pb2.VolumeEcShardsRebuildRequest(
                     volume_id=vid, collection=col))
         except Exception as e:
+            if "unrepairable" in str(e):
+                env.println(f"ec.rebuild volume {vid}: unrepairable with "
+                            f"{len(have)} shards ({rebuilder.url})")
+                continue
             # One broken volume must not abort the whole sweep.
             env.println(f"ec.rebuild volume {vid}: failed on "
                         f"{rebuilder.url}: {e}")

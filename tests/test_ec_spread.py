@@ -381,23 +381,28 @@ def test_on_one_server_the_spread_copies_nothing(racks):
 
 
 class Meeting(pipe.SharedSeconds):
-    """The source's ``CopyFile`` bookkeeping with a meeting point: the
-    first ``parties`` streams opened (each target's first: a target
-    pulls its files one after the other) wait for one another before
-    they serve, so the targets' streams overlap whatever the
-    scheduler does."""
+    """A server's stream bookkeeping with a meeting point: after the
+    ``skip`` first, the next ``parties`` streams opened (each chain's
+    first: a chain moves its files one after the other) wait for one
+    another before they go on, so the chains' streams overlap whatever
+    the scheduler does."""
 
-    def __init__(self, parties: int):
-        super().__init__("copy_file_shared_seconds")
+    def __init__(self, parties: int, name: str = "copy_file_shared_seconds",
+                 skip: int = 0):
+        super().__init__(name)
         self.barrier = threading.Barrier(parties, timeout=30)
-        self.to_meet = parties
+        self.skip, self.to_meet = skip, parties
         self.lock = threading.Lock()
 
     @contextlib.contextmanager
     def stream(self):
         with super().stream():
             with self.lock:
-                meets, self.to_meet = self.to_meet > 0, self.to_meet - 1
+                if self.skip:
+                    self.skip, meets = self.skip - 1, False
+                else:
+                    meets, self.to_meet = self.to_meet > 0, \
+                        self.to_meet - 1
             if meets:
                 self.barrier.wait()
             yield
