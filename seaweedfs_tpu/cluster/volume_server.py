@@ -18,6 +18,7 @@ import contextlib
 import http.client
 import json
 import os
+import queue
 import re
 import threading
 import time
@@ -977,8 +978,8 @@ class _VolumeServicer:
     def VolumeEcShardsRebuild(self, request, context):
         """§3.5, the server half of ``ec.rebuild``: this server is the
         rebuilder the shell chose (upstream's ``rebuildOneEcVolume``).
-        It brings what it lacks to its own disk, restores the shards
-        that no server holds, and drops the copies:
+        It brings the index files it lacks to its own disk, restores
+        the shards that no server holds, and keeps nothing else:
 
         - where it holds nothing of the volume (an empty replacement),
           ``.vif``, ``.ecx`` and ``.ecj`` (may be absent) come first,
@@ -986,17 +987,24 @@ class _VolumeServicer:
           past the ``[storage] fsync`` barrier: they stay (upstream's
           ``prepareDataToRecover`` with ``copyEcxFile``), and the
           geometry is read from the ``.vif`` fetched;
-        - surviving shards it lacks are pulled from their holders until
-          ``data_shards`` survivors are local, every source server's
-          files in turn on a chain of its own and the chains at once
-          (one source: the caller's thread); a sibling is unlinked when
-          the rebuild is over, so it is streamed with no barrier;
-        - ``generateMissingShards``: the pipeline run, then the mount
-          and one nudge of the master.
+        - surviving shards it lacks, until ``data_shards`` survivors
+          are at hand, never become files here: each is one stream off
+          its holder (``_SurvivorFeed``), opened once and read chunk by
+          chunk into the pipeline run's pooled buffers, every source
+          server's streams on a thread of its own;
+        - ``generateMissingShards``: the pipeline run, which takes a
+          survivor from its file or from its stream, so the fetch of a
+          chunk runs beside the restore of the one before; then the
+          mount and one nudge of the master.
 
-        All or nothing: a file that cannot be fetched fails the call,
-        and every sibling copy, restored file and index file this call
-        placed is removed again. A volume with fewer survivors than
+        ``step_rebuild_fetch`` counts the fetch's wall: the first index
+        file asked to the last survivor byte landed, the part after the
+        streams were open added when they are closed.
+
+        All or nothing: a stream that cannot be opened on any holder,
+        ends short or has another length than the survivors fails the
+        call, and every restored file and index file this call placed
+        is removed again. A volume with fewer survivors than
         ``data_shards`` is refused as unrepairable before any shard
         moves."""
         vs = self.vs
@@ -1029,10 +1037,12 @@ class _VolumeServicer:
                         for p in placed:
                             p.unlink()
                         return resp
-                self._fetch_siblings(plan, vid, col)
+                # every survivor local: nothing to feed, no thread
+                feed = _SurvivorFeed(vs, vid, col, plan.fetch) \
+                    if plan.fetch else None
             rebuilt = rebuild_mod.rebuild_ec_files(
                 base, plan.scheme, wanted=plan.missing,
-                pools=self._ec_pools)
+                pools=self._ec_pools, remote=feed)
         except BaseException:
             # as empty as it was: nothing of a failed call may look
             # like a shard, or like a volume this server holds
@@ -1041,10 +1051,6 @@ class _VolumeServicer:
                     for sid in plan.missing]):
                 p.unlink(missing_ok=True)
             raise
-        finally:
-            if plan is not None:
-                for _, dest, _ in plan.fetch:
-                    dest.unlink(missing_ok=True)
         with flight_mod.span("step_store_mount", trace=True):
             vs.store.mount_ec_shards(vid, rebuilt, col)
         vs.heartbeat_now()
@@ -1052,13 +1058,12 @@ class _VolumeServicer:
         return resp
 
     def _pull(self, url: str, vid: int, col: str, ext: str, dest: Path,
-              ignore_missing: bool = False, durable: bool = True) -> int:
-        """One file of the rebuild's fetch, counted: its bytes, the
-        file, and the seconds its stream had company."""
+              ignore_missing: bool = False) -> int:
+        """One index file of the rebuild's fetch, counted: its bytes,
+        the file, and the seconds its stream had company."""
         with self.vs.fetch_streams.stream():
             n = _copy_remote_file(self.vs, url, vid, col, ext, dest,
-                                  ignore_missing=ignore_missing,
-                                  durable=durable)
+                                  ignore_missing=ignore_missing)
         if n:
             pipe_mod.fold(rebuild_fetch_bytes=n, rebuild_fetch_files=1)
         return n
@@ -1086,52 +1091,6 @@ class _VolumeServicer:
                                   ignore_missing=optional):
                         placed.append(dest)
         return plan
-
-    def _fetch_siblings(self, plan: "_RebuildPlan", vid: int,
-                        col: str) -> None:
-        """The survivors the plan wants local, each source server's in
-        turn on a chain of its own, the chains at once. A sibling that
-        its first holder cannot give is asked of the shard's other
-        holders; one that nobody gives fails the fetch."""
-        chains: dict[str, list] = {}
-        for sid, dest, urls in plan.fetch:
-            chains.setdefault(urls[0], []).append((sid, dest, urls))
-        if not chains:
-            return
-        pipe_mod.count("rebuild_fetch_sources", len(chains))
-        # what a worker thread continues the call's trace from
-        parent = tracing.outbound_value() or True
-
-        def chain(files: list) -> None:
-            with flight_mod.span("step_rebuild_fetch_source", leaf=False,
-                                 trace=parent):
-                for sid, dest, urls in files:
-                    ext = ec_files.shard_ext(sid)
-                    for url in urls[:-1]:
-                        try:
-                            self._pull(url, vid, col, ext, dest,
-                                       durable=False)
-                            break
-                        except Exception as e:
-                            glog.v(1, "shard %d copy from %s failed: %s",
-                                   sid, url, e)
-                    else:
-                        # the last holder's failure is the file's
-                        self._pull(urls[-1], vid, col, ext, dest,
-                                   durable=False)
-
-        if len(chains) == 1:
-            for files in chains.values():
-                chain(files)
-            return
-        # every chain runs to its end or its own error; the first
-        # error in the plan's order is raised after the join
-        with futures.ThreadPoolExecutor(len(chains),
-                                        "rebuild-fetch") as pool:
-            running = [pool.submit(chain, files)
-                       for files in chains.values()]
-        for done in running:
-            done.result()
 
     @_ec_step("shards_copy")
     def VolumeEcShardsCopy(self, request, context):
@@ -1265,8 +1224,8 @@ class _RebuildPlan:
     """What one ``VolumeEcShardsRebuild`` has to do, from the ``.vif``
     under ``base``, the local disk and ``remote`` (shard id -> the
     other servers that hold it): ``missing``, the shards no server
-    holds, and ``fetch``, the (shard id, local path, holders) of the
-    survivors to bring here so that ``data_shards`` are local, lowest
+    holds, and ``fetch``, the (shard id, holders) of the survivors to
+    take off other servers so that ``data_shards`` are at hand, lowest
     ids first. Fewer survivors than that anywhere: unrepairable."""
 
     def __init__(self, base: Path, remote: dict):
@@ -1282,8 +1241,7 @@ class _RebuildPlan:
             raise StoreError(f"unrepairable: {survive} of the {k} shards "
                              f"a rebuild needs survive")
         need = max(0, k - len(local)) if self.missing else 0
-        self.fetch = [(sid, ec_files.shard_path(base, sid), remote[sid])
-                      for sid in elsewhere[:need]]
+        self.fetch = [(sid, remote[sid]) for sid in elsewhere[:need]]
 
 
 def _dest_base(vs: VolumeServer, volume_id: int, collection: str) -> Path:
@@ -1347,66 +1305,121 @@ def _grpc_chunks(vs: VolumeServer, src_url: str, volume_id: int,
         call.cancel()  # a stream left in its middle; else nothing
 
 
+class _HttpBody:
+    """A file of ``src_url`` as the body of one ``GET`` of its
+    ``_COPY_ROUTE``, for ``readinto``: the source's end is ``sendfile``,
+    and no ``bytes`` object, message or frame is made per chunk here.
+    ``size`` is its ``Content-Length``. Any answer but the file fails
+    the open, but the 204 of a missing file the caller allowed, which
+    reads as an empty one."""
+
+    over_http = True
+
+    def __init__(self, vs: VolumeServer, src_url: str, volume_id: int,
+                 collection: str, ext: str, ignore_missing: bool = False):
+        query = {"volume": volume_id, "collection": collection,
+                 "ext": ext}
+        if ignore_missing:
+            query["ignore_missing"] = 1
+        # the caller's trace and what is left of its deadline go along
+        headers = retry.inject({"Connection": "close"})
+        if vs.guard.enabled:
+            headers["Authorization"] = \
+                f"Bearer {security.grpc_sign(vs.guard)}"
+        host, _, port = src_url.partition(":")
+        # seaweedlint: disable=SW601 — a body streamed into the reader's buffer: retry.http_request returns whole bodies and retries, a pull is never resumed mid-file
+        self._conn = conn = http.client.HTTPConnection(
+            host, int(port),
+            timeout=httpserver.default_config().request_read_timeout)
+        try:
+            conn.request("GET", f"{_COPY_ROUTE}?{urlencode(query)}",
+                         headers=headers)
+            resp = conn.getresponse()
+            missing = resp.status == 204 and ignore_missing
+            if resp.status != 200 and not missing:
+                raise VolumeServerError(
+                    f"{src_url}: GET {ext} of volume {volume_id}: "
+                    f"{resp.status} {resp.read(200)!r}")
+            # the missing file the caller allowed: no body
+            self.size = 0 if missing \
+                else int(resp.headers["Content-Length"])
+        except BaseException:
+            conn.close()
+            raise
+        #: at most what the body has left; 0 at its end, and where the
+        #: peer went away before it
+        self.readinto = resp.readinto
+
+    def close(self) -> None:
+        self._conn.close()
+
+
+class _GrpcBody:
+    """The same file as its ``CopyFile`` stream, for ``readinto``: a
+    message's bytes are copied into the caller's buffer, as far as it
+    has room, and the rest kept for the next call. The stream says no
+    length (``size`` None): where it ends is where the file ended. The
+    first message is taken at the open, so that a source that refuses
+    refuses there."""
+
+    over_http = False
+    size = None
+
+    def __init__(self, vs: VolumeServer, src_url: str, volume_id: int,
+                 collection: str, ext: str):
+        self._chunks = _grpc_chunks(vs, src_url, volume_id, collection,
+                                    ext, False)
+        self._left = memoryview(next(self._chunks, b""))
+
+    def readinto(self, view) -> int:
+        if not self._left:
+            self._left = memoryview(next(self._chunks, b""))
+        n = min(len(view), len(self._left))
+        view[:n] = self._left[:n]
+        self._left = self._left[n:]
+        return n
+
+    def close(self) -> None:
+        self._chunks.close()
+
+
+def _open_body(vs: VolumeServer, src_url: str, volume_id: int,
+               collection: str, ext: str):
+    """A file of ``src_url`` to ``readinto``, over the transport that
+    :func:`_copy_remote_file` takes in this process."""
+    body = _HttpBody if tls_mod.installed() is None else _GrpcBody
+    return body(vs, src_url, volume_id, collection, ext)
+
+
 def _http_chunks(vs: VolumeServer, src_url: str, volume_id: int,
                  collection: str, ext: str, ignore_missing: bool):
-    """The same file as the body of one ``GET`` of ``src_url``'s
-    ``_COPY_ROUTE``, read into one reused buffer of ``_COPY_CHUNK``
-    bytes and handed on as views of it: no ``bytes`` object, message or
-    frame per chunk, and the source's end is ``sendfile``. A view is
-    the caller's until it asks for the next. Any answer but the file
-    (or 204, the missing file the caller allowed) fails it, and so does
-    a body that ends before its ``Content-Length``."""
-    query = {"volume": volume_id, "collection": collection, "ext": ext}
-    if ignore_missing:
-        query["ignore_missing"] = 1
-    # the caller's trace and what is left of its deadline go along
-    headers = retry.inject({"Connection": "close"})
-    if vs.guard.enabled:
-        headers["Authorization"] = \
-            f"Bearer {security.grpc_sign(vs.guard)}"
-    host, _, port = src_url.partition(":")
-    # seaweedlint: disable=SW601 — a body streamed into a reused buffer: retry.http_request returns whole bodies and retries, a pull is never resumed mid-file
-    conn = http.client.HTTPConnection(
-        host, int(port),
-        timeout=httpserver.default_config().request_read_timeout)
-    try:
-        conn.request("GET", f"{_COPY_ROUTE}?{urlencode(query)}",
-                     headers=headers)
-        resp = conn.getresponse()
-        if resp.status == 204 and ignore_missing:
-            return
-        if resp.status != 200:
-            raise VolumeServerError(
-                f"{src_url}: GET {ext} of volume {volume_id}: "
-                f"{resp.status} {resp.read(200)!r}")
-        left = int(resp.headers["Content-Length"])
+    """A :class:`_HttpBody` read into one reused buffer of
+    ``_COPY_CHUNK`` bytes and handed on as views of it. A view is the
+    caller's until it asks for the next. A body that ends before its
+    ``Content-Length`` fails it."""
+    with contextlib.closing(_HttpBody(vs, src_url, volume_id, collection,
+                                      ext, ignore_missing)) as body:
+        left = body.size
         view = memoryview(bytearray(_COPY_CHUNK))
         while left:
-            n = resp.readinto(view)  # at most what the body has left
+            n = body.readinto(view)
             if not n:
                 raise VolumeServerError(
                     f"{src_url}: {ext} of volume {volume_id} ended "
                     f"{left} bytes before its Content-Length")
             left -= n
             yield view[:n]
-    finally:
-        conn.close()
 
 
 def _copy_remote_file(vs: VolumeServer, src_url: str, volume_id: int,
                       collection: str, ext: str, dest: Path,
-                      ignore_missing: bool = False,
-                      durable: bool = True) -> int:
+                      ignore_missing: bool = False) -> int:
     """Pull one file of a volume from ``src_url`` into ``dest``; returns
     the bytes received. Two transports under one frame. The frame: the
     leaf span ``copy_recv`` (the stream into ``<dest>.part``, the fault
     point ``ec.shard_copy`` behind every chunk written, the ``.part``
     removed on any failure) and the leaf span ``copy_commit`` (fsync +
-    rename). ``durable=False`` is for a copy that the caller itself
-    unlinks before it returns (a rebuild's fetched siblings): the
-    ``.part`` is renamed into place with no barrier and no
-    ``copy_commit``, so a cut stream still leaves nothing that looks
-    like a shard. The transport, chosen by what the process was started
+    rename). The transport, chosen by what the process was started
     with: the source's HTTP plane (``_http_chunks``: ``sendfile`` there,
     one reused buffer here), or, where the gRPC plane runs under TLS —
     which encrypts and mutually authenticates these bytes, and the HTTP
@@ -1462,16 +1475,200 @@ def _copy_remote_file(vs: VolumeServer, src_url: str, volume_id: int,
     if ignore_missing and not received:
         tmp.unlink()
         return 0
-    if not durable:
-        # seaweedlint: disable=SW901 — a copy its caller unlinks before it returns: nothing to survive a power loss
-        os.replace(tmp, dest)
-        return received
     # durable rename commit: the copied replica/shard file must survive
     # power loss once callers (ec.balance, volume copy) treat it as
     # placed — fsync the bytes AND the directory entry
     with flight_mod.span("copy_commit", nbytes=received):
         durability.durable_replace(tmp, dest)
     return received
+
+
+class _SurvivorStream:
+    """One surviving shard of a rebuild, off a server that holds it:
+    :func:`_copy_remote_file`'s request and frame without the file. The
+    body is read slice by slice into the pooled buffers of the
+    rebuild's reader (:meth:`fill`). It counts as a file pulled does: one
+    span ``copy_recv`` from the open to the last byte (no leaf: a
+    source's streams are open together on one thread, and each keeps
+    its own seconds), among the server's ``fetch_streams``;
+    ``copy_recv_wait_seconds`` = the seconds inside ``readinto``, a
+    chunk = a slice filled, the fault point ``ec.shard_copy`` behind
+    each, nothing for ``copy_recv_write_seconds``; and once it is whole,
+    its bytes as ``rebuild_fetch_bytes`` and
+    ``rebuild_fetch_streamed_bytes`` and itself in
+    ``rebuild_fetch_files``. The first of ``urls`` that answers is the
+    source: a holder that refuses at the open is followed by the next,
+    and the last one's refusal is the stream's."""
+
+    def __init__(self, vs: VolumeServer, volume_id: int, collection: str,
+                 shard_id: int, urls: list):
+        self.name = f"shard {shard_id} of volume {volume_id}"
+        self._received = self._chunks = 0
+        self._wait_s = 0.0
+        self._whole = False
+        ext = ec_files.shard_ext(shard_id)
+        with contextlib.ExitStack() as opened:
+            opened.enter_context(vs.fetch_streams.stream())
+            self._span = opened.enter_context(
+                flight_mod.span("copy_recv", leaf=False))
+            for url in urls[:-1]:
+                try:
+                    self._body = _open_body(vs, url, volume_id,
+                                            collection, ext)
+                    break
+                except Exception as e:
+                    glog.v(1, "%s: open on %s failed: %s", self.name,
+                           url, e)
+            else:
+                self._body = _open_body(vs, urls[-1], volume_id,
+                                        collection, ext)
+            opened.callback(self._counted)
+            opened.callback(self._body.close)
+            self.close = opened.pop_all().close
+        #: what the source said the file holds, None where it did not
+        self.size = self._body.size
+
+    def fill(self, view: np.ndarray, last: bool) -> None:
+        """The body's next ``len(view)`` bytes into ``view``; after the
+        ``last`` slice the body has to be at its end, and the stream is
+        closed, whole."""
+        mv, got = memoryview(view), 0
+        readinto = self._body.readinto
+        t = _clock()
+        while got < len(mv):
+            n = readinto(mv[got:])
+            if not n:
+                raise VolumeServerError(
+                    f"{self.name} ended after "
+                    f"{self._received + got} bytes, short of the "
+                    f"survivors' size")
+            got += n
+        if last and readinto(memoryview(bytearray(1))):
+            raise VolumeServerError(
+                f"{self.name} goes on past the survivors' size")
+        self._wait_s += _clock() - t
+        self._received += got
+        self._chunks += 1
+        faults.check("ec.shard_copy")
+        if last:
+            self._whole = True
+            self.close()
+
+    def _counted(self) -> None:
+        self._span.nbytes = n = self._received
+        whole = n if self._whole else 0
+        pipe_mod.fold(copy_recv_bytes=n, copy_recv_chunks=self._chunks,
+                      copy_recv_http_bytes=n if self._body.over_http
+                      else 0,
+                      copy_recv_wait_seconds=self._wait_s,
+                      rebuild_fetch_bytes=whole,
+                      rebuild_fetch_streamed_bytes=whole,
+                      rebuild_fetch_files=int(self._whole))
+
+
+class _SurvivorChain(threading.Thread):
+    """One source server's survivors of a rebuild, on a thread of its
+    own beneath the rpc's trace (``step_rebuild_fetch_source``): it
+    opens a :class:`_SurvivorStream` for each and says their sizes, then
+    for every chunk asked of it fills its shards' slices in turn and
+    says so. What it says goes to ``done``: a set of sizes, ``None`` for
+    a chunk filled, or the error that ended it."""
+
+    def __init__(self, feed: "_SurvivorFeed", n: int, shards: list):
+        super().__init__(name=f"rebuild-fetch-{n}", daemon=True)
+        self._feed = feed
+        self.shards = shards
+        #: ({shard id: slice}, last) per chunk; None: no more
+        self.todo: queue.SimpleQueue = queue.SimpleQueue()
+        self.done: queue.SimpleQueue = queue.SimpleQueue()
+        #: when this chain's newest byte landed
+        self.landed = 0.0
+
+    def said(self):
+        """What the chain says next, once it does; its error raised."""
+        said = self.done.get()
+        if isinstance(said, BaseException):
+            raise said
+        return said
+
+    def run(self) -> None:
+        feed = self._feed
+        cpu0 = time.thread_time()
+        with flight_mod.span("step_rebuild_fetch_source", leaf=False,
+                             trace=feed.parent), \
+                contextlib.ExitStack() as streams:
+            try:
+                opened = {}
+                for sid in self.shards:
+                    opened[sid] = st = _SurvivorStream(
+                        feed.vs, feed.volume_id, feed.collection, sid,
+                        feed.holders[sid])
+                    streams.callback(st.close)
+                self.done.put({st.size for st in opened.values()}
+                              - {None})
+                while (chunk := self.todo.get()) is not None:
+                    slices, last = chunk
+                    for sid, view in slices.items():
+                        opened[sid].fill(view, last)
+                        self.landed = _clock()
+                    self.done.put(None)
+            except BaseException as e:  # noqa: BLE001 — raised by the reader that waits for this chain
+                self.done.put(e)
+        pipe_mod.fold(copy_recv_cpu_seconds=time.thread_time() - cpu0)
+
+
+class _SurvivorFeed:
+    """The surviving shards a rebuild takes off other servers, as
+    ``rebuild_ec_files`` takes them (its ``RemoteSurvivors``): the
+    (shard id, holders) of ``_RebuildPlan.fetch``, one
+    :class:`_SurvivorChain` per source server (a shard's first holder),
+    all chains filling their slices of the reader's chunk at once. Made
+    inside the handler's ``step_rebuild_fetch``, whose trace the chains
+    continue and whose seconds :meth:`close` lengthens to the moment
+    the last byte landed."""
+
+    def __init__(self, vs: VolumeServer, volume_id: int, collection: str,
+                 fetch: list):
+        self.vs, self.volume_id, self.collection = vs, volume_id, collection
+        self.holders = dict(fetch)
+        self.shards = sorted(self.holders)
+        # what a chain's thread continues the call's trace from
+        self.parent = tracing.outbound_value() or True
+        self._chains: list[_SurvivorChain] = []
+        self._made = _clock()
+
+    def open(self, shards) -> set:
+        by_source: dict[str, list] = {}
+        for sid in shards:
+            by_source.setdefault(self.holders[sid][0], []).append(sid)
+        pipe_mod.count("rebuild_fetch_sources", len(by_source))
+        self._chains = [_SurvivorChain(self, n, sids)
+                        for n, sids in enumerate(by_source.values())]
+        for chain in self._chains:
+            chain.start()
+        return set().union(*(chain.said() for chain in self._chains))
+
+    def fill(self, slices: dict, last: bool):
+        for chain in self._chains:
+            chain.todo.put(({sid: slices[sid] for sid in chain.shards},
+                            last))
+        return self._filled
+
+    def _filled(self) -> None:
+        # every chain runs its chunk to the end or to its own error;
+        # the first error in the plan's order is the chunk's
+        for chain in self._chains:
+            chain.said()
+
+    def close(self) -> None:
+        chains, self._chains = self._chains, []
+        for chain in chains:
+            chain.todo.put(None)
+        for chain in chains:
+            chain.join()
+        landed = max((chain.landed for chain in chains), default=0.0)
+        flight_mod.lengthen("step_rebuild_fetch",
+                            max(0.0, landed - self._made))
 
 
 def _make_http_handler(vs: VolumeServer):

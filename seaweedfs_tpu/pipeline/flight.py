@@ -366,6 +366,17 @@ def reset_totals() -> None:
         _TOTALS.clear()
 
 
+def lengthen(name: str, seconds: float) -> None:
+    """Add ``seconds`` to the total of span ``name`` and no call: the
+    stretch by which a step went on, on other threads, after the span
+    that counted it had closed on its own (a rebuild's fetch, whose
+    streams are read beside the pipeline run: the handler's
+    ``step_rebuild_fetch`` closes when they are open, their last byte
+    lands later)."""
+    with _TOTALS_LOCK:
+        _TOTALS.setdefault(name, [0.0, 0])[0] += seconds
+
+
 class Run:
     """What the spans of one ``run_pipeline`` call share: an id, the
     per-run sink (``PipeStats.add``) and, until a first span has taken

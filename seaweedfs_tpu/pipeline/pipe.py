@@ -415,17 +415,23 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            "pool_acquires": 0, "pool_fresh_acquires": 0,
            # files between volume servers (cluster/volume_server.py):
            # bytes served to a peer (a CopyFile stream or an HTTP
-           # body), bytes a puller wrote to its .part files, and those
-           # of them a rebuild's sibling fetch pulled
+           # body), bytes a puller received (into a .part file, or a
+           # rebuild's surviving shard into its reader's buffers), and
+           # those of them a rebuild's fetch pulled
            "copy_file_bytes": 0, "copy_recv_bytes": 0,
            "rebuild_fetch_bytes": 0,
            # the same fetch (VolumeEcShardsRebuild on a rebuilder that
-           # lacks survivors): files pulled, index files included; the
-           # source servers it pulled from, a chain each; and the
-           # stream-seconds it pulled while another of its streams was
-           # open (SharedSeconds: 0 where one source serves in turn)
+           # lacks survivors): files pulled, index files included and
+           # a streamed survivor counted as one; the source servers it
+           # pulled from, a chain each; and the stream-seconds it
+           # pulled while another of its streams was open
+           # (SharedSeconds: a source's streams are open together)
            "rebuild_fetch_files": 0, "rebuild_fetch_sources": 0,
            "rebuild_fetch_shared_seconds": 0.0,
+           # what of rebuild_fetch_bytes never was a file there: a
+           # surviving shard read off its stream into the pooled
+           # buffers of the rebuild's reader (all but the index files)
+           "rebuild_fetch_streamed_bytes": 0,
            # stream-seconds of CopyFile served while another stream of
            # the same server was open (SharedSeconds)
            "copy_file_shared_seconds": 0.0,
@@ -581,7 +587,10 @@ def debug_payload() -> dict:
     ``rebuild_fetch_bytes`` = what of ``copy_recv_bytes`` a rebuild's
     fetch pulled, in ``rebuild_fetch_files`` files from
     ``rebuild_fetch_sources`` servers, ``rebuild_fetch_shared_seconds``
-    of its stream-seconds in company),
+    of its stream-seconds in company, ``rebuild_fetch_streamed_bytes``
+    of the bytes read off a stream into the run's pooled buffers and
+    never a file: such a stream is a ``copy_recv`` from its open to its
+    last byte, a chunk a slice filled, nothing for ``copy_recv_write``),
     ``rpc_seconds`` (the EC handlers, each counted once, pipeline run
     included), ``step_<name>_seconds`` / ``_calls``
     for every server-side rpc step, and the recent-run ring."""

@@ -18,6 +18,13 @@ chunk's compute has synced — no writeback token needed. The pool is
 the caller's where it lends one (``pools``: the volume server's
 ``pipe.PoolCache``), so a rebuild's reader fills buffers that an
 earlier command touched.
+
+A survivor is a file under ``base`` or, where the caller says so
+(``remote``, :class:`RemoteSurvivors`), a stream from the server that
+holds it: the reader hands the stream's owner that shard's slice of the
+pooled buffer, reads the local survivors' slices meanwhile, and passes
+the chunk on when every slice is full. A fetched survivor is never a
+file here, and the fetch of chunk j+1 runs beside the restore of chunk j.
 """
 
 from __future__ import annotations
@@ -25,7 +32,7 @@ from __future__ import annotations
 import os
 import time
 from pathlib import Path
-from typing import Optional, Sequence
+from typing import Callable, Optional, Protocol, Sequence
 
 import numpy as np
 
@@ -43,35 +50,94 @@ class EcRebuildError(RuntimeError):
     pass
 
 
+class RemoteSurvivors(Protocol):
+    """Surviving shards that lie on other servers, as the run's reader
+    takes them (the volume server's ``_SurvivorFeed``): streams that
+    are opened once and then read chunk after chunk, each straight into
+    its shard's slice of the reader's pooled buffer."""
+
+    #: the shard ids it can deliver
+    shards: Sequence[int]
+
+    def open(self, shards: Sequence[int]) -> set[int]:
+        """Open a stream for each of ``shards``; the file sizes the
+        streams announced (none where a transport announces none)."""
+
+    def fill(self, slices: dict, last: bool) -> Callable[[], None]:
+        """Start reading each stream's next bytes into its slice
+        (shard id -> a uint8 view of the pooled buffer); the call
+        returned waits until every slice is full, and raises what a
+        stream raised. After the ``last`` chunk a stream has to be at
+        its end."""
+
+    def close(self) -> None:
+        """Close every stream, whatever state the run is in; nothing
+        touches a slice once this has returned."""
+
+
 def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
                      wanted: Optional[Sequence[int]] = None,
                      chunk_bytes: int = DEFAULT_CHUNK_BYTES,
-                     pools: Optional[pipe.PoolCache] = None) -> list[int]:
+                     pools: Optional[pipe.PoolCache] = None,
+                     remote: Optional[RemoteSurvivors] = None
+                     ) -> list[int]:
     """Rebuild missing (or explicitly ``wanted``) shard files in place.
     Returns the list of shard ids written. ``pools`` (a
     :class:`pipe.PoolCache` of the caller's) lends the host buffers and
-    keeps them for the next call."""
+    keeps them for the next call. ``remote`` delivers survivors that are
+    no files under ``base``: opened here, before the run is sized, and
+    closed here, whatever became of the run."""
     total = scheme.total_shards
-    present = ec_files.present_shards(base, total)
-    missing = sorted(set(range(total)) - set(present)) if wanted is None \
+    local = ec_files.present_shards(base, total)
+    survive = sorted({*local, *(remote.shards if remote else ())})
+    missing = sorted(set(range(total)) - set(survive)) if wanted is None \
         else sorted(wanted)
     if not missing:
         return []
-    overlap = set(missing) & set(present)
+    overlap = set(missing) & set(local)
     if wanted is not None and overlap:
         raise EcRebuildError(f"shards {sorted(overlap)} already exist")
-    if len(present) < scheme.data_shards:
+    if len(survive) < scheme.data_shards:
         raise TooFewShardsError(
             f"need {scheme.data_shards} surviving shards, "
-            f"have {len(present)}")
-    sizes = {ec_files.shard_path(base, i).stat().st_size for i in present}
-    if len(sizes) != 1:
-        raise EcRebuildError(f"surviving shard sizes differ: {sizes}")
-    size = sizes.pop()
-
+            f"have {len(survive)}")
     # Only the first k survivors feed the decode matrix — don't read the
-    # rest from disk at all.
-    present = present[:scheme.data_shards]
+    # rest from disk, or off another server, at all.
+    present = survive[:scheme.data_shards]
+    sizes = {ec_files.shard_path(base, i).stat().st_size
+             for i in present if i in local}
+    streamed = [i for i in present if i not in local]
+    try:
+        if streamed:
+            sizes |= remote.open(streamed)
+        if not sizes:
+            # no survivor is a file here and no stream said a length (a
+            # CopyFile stream says none): the .vif's, where it has one
+            dat_size = ec_files.VolumeInfo.load(base).dat_file_size
+            if dat_size:
+                sizes = {scheme.shard_file_size(dat_size)}
+        if len(sizes) != 1:
+            raise EcRebuildError(f"surviving shard sizes differ: {sizes}")
+        _restore(base, scheme, present, missing, sizes.pop(), remote,
+                 streamed, chunk_bytes, pools)
+    finally:
+        if streamed:
+            remote.close()
+    # Shard files changed under any reader holding cached post-decode
+    # needles for this volume — tell every live chunk cache.
+    from ..cache import invalidation as cache_invalidation
+
+    cache_invalidation.base_invalidated(base, reason="ec-rebuild")
+    return missing
+
+
+def _restore(base, scheme: EcScheme, present: list, missing: list,
+             size: int, remote: Optional[RemoteSurvivors], streamed: list,
+             chunk_bytes: int, pools: Optional[pipe.PoolCache]) -> None:
+    """The run: the ``missing`` shard files of ``size`` bytes out of
+    the ``present`` survivors, the ``streamed`` ones taken from
+    ``remote``'s open streams, the others files under ``base`` read by
+    ``preadv``."""
     k = scheme.data_shards
     # Grouped dispatch on a single accelerator; multi-chip keeps
     # per-chunk mesh sharding via _pick_reconstruct_fn.
@@ -80,8 +146,9 @@ def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
     cfg = pipe.current()
     pool_nbytes = max(1, k * min(chunk_bytes, size or 1))
     pool_count = cfg.pool_buffers or max(4, max(cfg.depth, group) + 2)
-    in_fds = [os.open(ec_files.shard_path(base, i), os.O_RDONLY)
-              for i in present]
+    #: a survivor's slot in a chunk -> its file, where it is one
+    in_fds = {s: os.open(ec_files.shard_path(base, i), os.O_RDONLY)
+              for s, i in enumerate(present) if i not in streamed}
     out_paths = [str(ec_files.shard_path(base, i)) for i in missing]
     writer = writeback.WriterPool()
     st = pipe.PipeStats()
@@ -93,8 +160,15 @@ def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
             flight.record(flight.EV_ENQUEUE, arg=k * take)
             buf = pool.acquire()
             view = buf[:k * take]
-            for s, fd in enumerate(in_fds):
+            # the wire first, the disk beside it
+            filled = remote.fill(
+                {i: view[s * take:(s + 1) * take]
+                 for s, i in enumerate(present) if s not in in_fds},
+                last=pos + take == size) if streamed else None
+            for s, fd in in_fds.items():
                 _pread_into(fd, view[s * take:(s + 1) * take], pos)
+            if filled is not None:
+                filled()
             yield (buf, pos), view.reshape(1, k, take)
             pos += take
 
@@ -144,14 +218,8 @@ def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
     finally:
         if writer is not None:
             writer.abort()
-        for fd in in_fds:
+        for fd in in_fds.values():
             os.close(fd)
-    # Shard files changed under any reader holding cached post-decode
-    # needles for this volume — tell every live chunk cache.
-    from ..cache import invalidation as cache_invalidation
-
-    cache_invalidation.base_invalidated(base, reason="ec-rebuild")
-    return missing
 
 
 def plan_chunking(k: int, chunk_bytes: int = DEFAULT_CHUNK_BYTES

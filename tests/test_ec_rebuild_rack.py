@@ -6,17 +6,19 @@ this process, RS(10,4), shards 4 + 4 + 3 + 3 after ``ec.encode``), then a
 server is emptied through its own rpcs — every shard of the volume
 unmounted and deleted, the index files gone with the last one — and
 stands for the empty machine that took a dead server's place. The shell
-picks it (most free slots); it pulls ``.vif`` / ``.ecx`` and ten
-surviving shards from three peers at once, restores the lost shards and
-ends holding exactly those. Held against the benchmark's plain
-reference (``benchmark/reference.py``: NumPy, imports nothing of the
-program): the restored files byte for byte; beside it the survivors
-untouched, the counters of what was fetched, the overlap of the three
-sources and its absence with one, no temporary copy outliving the call
-and none of them fsynced, a fault mid-fetch that leaves the server
-empty, a volume with too few survivors reported, and
-``VolumeEcShardsDelete`` dropping the index files with the last shard
-only.
+picks it (most free slots); it pulls ``.vif`` / ``.ecx`` to its disk and
+reads ten surviving shards off three peers' streams at once, straight
+into the pipeline run that restores the lost shards, and ends holding
+exactly those. Held against the benchmark's plain reference
+(``benchmark/reference.py``: NumPy, imports nothing of the program): the
+restored files byte for byte; beside it the survivors untouched, the
+counters of what was fetched and what of it never was a file, the three
+sources' threads and the one of one source, no survivor's copy on the
+rebuilder's disk at any moment of a round, a rebuilder that has some
+survivors and fetches the rest, either transport, a fault mid-fetch, a
+stream of another length or cut short that leave the server empty, a
+volume with too few survivors reported, and ``VolumeEcShardsDelete``
+dropping the index files with the last shard only.
 """
 
 import hashlib
@@ -34,7 +36,8 @@ from seaweedfs_tpu.storage import ec_files
 from seaweedfs_tpu.util import faults, tracing
 
 from test_ec_spread import (COL, ROW, SCHEME, TOTAL,  # noqa: F401
-                            Meeting, first_hit, racks, small_rows)
+                            Meeting, first_hit, racks, small_rows,
+                            the_plane_of)
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmark"))
 import reference  # noqa: E402  (benchmark/reference.py)
@@ -97,6 +100,33 @@ def delta(rack, before: dict) -> dict:
 def commits() -> int:
     """Calls of the span ``copy_commit`` (fsync + rename) so far."""
     return flight.totals().get("copy_commit", (0.0, 0))[1]
+
+
+@pytest.fixture()
+def chains(monkeypatch):
+    """The fetch threads made from here on, by name."""
+    from seaweedfs_tpu.cluster import volume_server as vs_mod
+    made, start = [], vs_mod._SurvivorChain.start
+
+    def noted(self):
+        made.append(self.name)
+        start(self)
+    monkeypatch.setattr(vs_mod._SurvivorChain, "start", noted)
+    return made
+
+
+def gather_on(rack, keeper: int, others) -> None:
+    """``keeper`` pulls and mounts what ``others`` hold, and they are
+    lost: their shards survive on one server."""
+    held = rack.held(1)
+    stub = rack.servers[0].peer_stub(rack.servers[keeper].url)
+    for i in others:
+        stub.VolumeEcShardsCopy(vpb.VolumeEcShardsCopyRequest(
+            volume_id=1, collection=COL, shard_ids=held[i],
+            source_data_node=rack.servers[i].url))
+        stub.VolumeEcShardsMount(vpb.VolumeEcShardsMountRequest(
+            volume_id=1, collection=COL, shard_ids=held[i]))
+        rack.lose(i)
 
 
 # --------------------------------------------------------------------------
@@ -173,18 +203,28 @@ def test_an_emptied_server_fetches_restores_and_holds_the_lost_shards(
     assert rack.mapped(1) == {s: [rack.servers[i].url]
                               for i, ids in enumerate(rack.held(1))
                               for s in ids}
-    # ten siblings and the index files came over, from three sources;
-    # only the index files, which stay, went through fsync + rename
+    # ten survivors and the index files came over, from three sources;
+    # the survivors never were files here, and only the index files,
+    # which stay, went through fsync + rename
     d = delta(rack, before)
     assert d["rebuild_fetch_bytes"] == 10 * shard_size + index_bytes
     assert d["copy_file_bytes"] == d["copy_recv_bytes"] \
         == d["rebuild_fetch_bytes"]
+    assert d["rebuild_fetch_streamed_bytes"] == 10 * shard_size
+    assert d["copy_recv_http_bytes"] == d["copy_recv_bytes"]
     assert d["rebuild_fetch_files"] == 10 + 2
     assert d["rebuild_fetch_sources"] == 3
     assert d["step_rebuild_fetch_calls"] == 1
     assert d["step_rebuild_fetch_index_calls"] == 1
     assert d["step_rebuild_fetch_source_calls"] == 3
-    assert d["step_rebuild_fetch_seconds"] > 0
+    # one chunk per file here: a stream's one slice, an index file's
+    # one write
+    assert d["copy_recv_chunks"] == 10 + 2
+    # the fetch's wall holds the longest stream, and ends before the
+    # handler does
+    assert 0 < d["step_rebuild_fetch_seconds"] < d["step_rebuild_seconds"]
+    assert d["copy_recv_wait_seconds"] <= d["copy_recv_seconds"] \
+        <= 12 * d["step_rebuild_fetch_seconds"]
     assert commits() - commits_before == 2
     mc = MasterClient(rack.master.url)
     try:
@@ -195,9 +235,10 @@ def test_an_emptied_server_fetches_restores_and_holds_the_lost_shards(
 
 
 def test_the_fetch_is_one_trace_beneath_the_rebuild_rpc(racks):
-    """``step_rebuild_fetch`` encloses the index fetch and the three
+    """``step_rebuild_fetch`` holds the index fetch and the three
     source chains, which continue the rpc's trace on their threads;
-    every pull of a peer's HTTP plane hangs beneath its chain."""
+    every stream opened on a peer's HTTP plane hangs beneath its
+    chain."""
     rack = sealed_rack(racks)
     empty(rack, 0)
     reply, err = rack.run("ec.rebuild -volumeId 1")
@@ -226,13 +267,17 @@ def test_the_fetch_is_one_trace_beneath_the_rebuild_rpc(racks):
     pulls = [s for s in named("volume.GET")
              if "step_rebuild_fetch_source" in ancestors(s)]
     assert len(pulls) == 10
+    # the restore is the handler's, beside the fetch and not beneath it
+    (restore,) = named("ec.rebuild")
+    assert "step_rebuild_fetch" not in ancestors(restore)
+    assert "grpc.VolumeEcShardsRebuild" in ancestors(restore)
 
 
-def test_three_sources_are_pulled_at_once(racks):
+def test_three_sources_are_pulled_at_once(racks, chains):
     rack = sealed_rack(racks)
     gone = empty(rack, 0)
     # .vif, .ecx and the absent .ecj are asked for in turn, then the
-    # three chains' first shards meet
+    # three chains' first streams meet, each on its chain's thread
     rack.servers[0].fetch_streams = Meeting(
         3, "rebuild_fetch_shared_seconds", skip=3)
     before = rack.pipeline_vars()
@@ -240,36 +285,21 @@ def test_three_sources_are_pulled_at_once(racks):
     assert err is None and f"rebuilt {gone}" in reply, (reply, err)
     d = delta(rack, before)
     assert d["rebuild_fetch_sources"] == 3
+    assert sorted(chains) == [f"rebuild-fetch-{n}" for n in range(3)]
+    # ten streams open together, of the twelve that were opened
     assert d["rebuild_fetch_shared_seconds"] > 0
-    # each peer serves its own files in turn: the company is on the
-    # pulling side alone
-    assert d["copy_file_shared_seconds"] == 0
+    assert d["rebuild_fetch_shared_seconds"] <= d["copy_recv_seconds"]
 
 
-def test_one_source_is_pulled_in_turn_and_no_thread_is_made(racks,
-                                                            monkeypatch):
+def test_one_source_has_one_chain_and_no_pool_of_them(racks, chains):
     """Everything that survives lies on one peer (it pulled the other
-    holders' shards before they were lost): one chain, on the handler's
-    own thread, and no stream ever has company."""
+    holders' shards before they were lost): one chain holds the ten
+    streams, on the one thread the reader needs beside itself."""
     rack = sealed_rack(racks)
     held = rack.held(1)
     keeper = next(i for i in (1, 2, 3) if len(held[i]) == 4)
-    others = [i for i in (1, 2, 3) if i != keeper]
-    stub = rack.servers[0].peer_stub(rack.servers[keeper].url)
-    for i in others:
-        stub.VolumeEcShardsCopy(vpb.VolumeEcShardsCopyRequest(
-            volume_id=1, collection=COL, shard_ids=held[i],
-            source_data_node=rack.servers[i].url))
-        stub.VolumeEcShardsMount(vpb.VolumeEcShardsMountRequest(
-            volume_id=1, collection=COL, shard_ids=held[i]))
-        rack.lose(i)
+    gather_on(rack, keeper, [i for i in (1, 2, 3) if i != keeper])
     gone = empty(rack, 0)
-    made = []
-    from seaweedfs_tpu.cluster import volume_server as vs_mod
-    real = vs_mod.futures.ThreadPoolExecutor
-    monkeypatch.setattr(
-        vs_mod.futures, "ThreadPoolExecutor",
-        lambda *a, **kw: made.append(a) or real(*a, **kw))
     before = rack.pipeline_vars()
     reply, err = rack.run("ec.rebuild -volumeId 1")
     assert err is None, (reply, err)
@@ -278,25 +308,18 @@ def test_one_source_is_pulled_in_turn_and_no_thread_is_made(racks,
     assert d["rebuild_fetch_sources"] == 1
     assert d["step_rebuild_fetch_source_calls"] == 1
     assert d["rebuild_fetch_files"] == 10 + 2
-    assert d["rebuild_fetch_shared_seconds"] == 0
-    assert d["copy_file_shared_seconds"] == 0
-    assert made == []
+    assert chains == ["rebuild-fetch-0"]
+    # its ten streams are open together all the same
+    assert d["rebuild_fetch_shared_seconds"] > 0
     assert rack.held(1)[0] == gone
 
 
-def test_a_rebuilder_with_its_survivors_local_fetches_nothing(racks):
-    """What every one-server cell does: nothing to pull, so no chain, no
+def test_a_rebuilder_with_its_survivors_local_fetches_nothing(racks,
+                                                              chains):
+    """What every one-server cell does: nothing to pull, so no feed, no
     thread, and every counter of the fetch stays where it was."""
     rack = sealed_rack(racks)
-    held = rack.held(1)
-    stub = rack.servers[0].peer_stub(rack.servers[0].url)
-    for i in (1, 2, 3):
-        stub.VolumeEcShardsCopy(vpb.VolumeEcShardsCopyRequest(
-            volume_id=1, collection=COL, shard_ids=held[i],
-            source_data_node=rack.servers[i].url))
-        stub.VolumeEcShardsMount(vpb.VolumeEcShardsMountRequest(
-            volume_id=1, collection=COL, shard_ids=held[i]))
-        rack.lose(i)
+    gather_on(rack, 0, (1, 2, 3))
     gone = empty(rack, 0, [1, 6, 11])
     before = rack.pipeline_vars()
     reply, err = rack.run("ec.rebuild -volumeId 1")
@@ -304,22 +327,132 @@ def test_a_rebuilder_with_its_survivors_local_fetches_nothing(racks):
     d = delta(rack, before)
     for key in ("rebuild_fetch_bytes", "rebuild_fetch_files",
                 "rebuild_fetch_sources", "rebuild_fetch_shared_seconds",
+                "rebuild_fetch_streamed_bytes",
                 "step_rebuild_fetch_index_calls",
-                "step_rebuild_fetch_source_calls", "copy_recv_bytes"):
+                "step_rebuild_fetch_source_calls", "copy_recv_bytes",
+                "copy_recv_chunks", "copy_recv_seconds"):
         assert d[key] == 0, key
     assert d["step_rebuild_fetch_calls"] == 1
+    assert chains == []
+
+
+def test_a_rebuilder_with_some_survivors_fetches_the_rest(racks, tmp_path,
+                                                          chains):
+    """A peer's four shards are lost and the sealing server, which
+    holds three, is told to rebuild: its own three are read from their
+    files, seven come off the two other peers' streams, and the four
+    restored are the reference's, as with every survivor local."""
+    rack = sealed_rack(racks)
+    held = rack.held(1)
+    dead = next(i for i in (1, 2, 3) if len(held[i]) == 4)
+    gone = empty(rack, dead)
+    shard_size = SCHEME.shard_file_size(rack.dats[1].size)
+    survivors = digests(rack)
+    before, commits_before = rack.pipeline_vars(), commits()
+    stub = rack.servers[0].peer_stub(rack.servers[0].url)
+    resp = stub.VolumeEcShardsRebuild(vpb.VolumeEcShardsRebuildRequest(
+        volume_id=1, collection=COL))
+    assert list(resp.rebuilt_shard_ids) == gone
+    assert rack.held(1)[0] == sorted(held[0] + gone)
+    assert not [p for d in rack.dirs for p in d.glob("*.part")]
+    restored_match_the_reference(rack, 0, gone, tmp_path)
+    now = digests(rack)
+    assert {p: now[p] for p in survivors} == survivors
+    # the lowest ids first until ten are at hand: seven off two peers
+    d = delta(rack, before)
+    assert d["rebuild_fetch_files"] == 7
+    assert d["rebuild_fetch_bytes"] == d["rebuild_fetch_streamed_bytes"] \
+        == d["copy_recv_bytes"] == 7 * shard_size
+    assert d["rebuild_fetch_sources"] == 2 and len(chains) == 2
+    assert d["step_rebuild_fetch_index_calls"] == 0
+    assert commits() == commits_before
+
+
+def test_under_tls_the_survivors_come_as_copyfile_streams(racks, tmp_path):
+    """The gRPC plane under mutual TLS: every stream is a ``CopyFile``
+    stream, whose messages are copied into the slices; it says no
+    length, so the run is sized by the ``.vif`` fetched."""
+    with the_plane_of("grpc", tmp_path):
+        rack = sealed_rack(racks)
+        gone = empty(rack, 0)
+        shard_size = SCHEME.shard_file_size(rack.dats[1].size)
+        before = rack.pipeline_vars()
+        reply, err = rack.run("ec.rebuild -volumeId 1")
+        assert err is None and f"rebuilt {gone}" in reply, (reply, err)
+        d = delta(rack, before)
+        restored_match_the_reference(rack, 0, gone, tmp_path)
+        assert files_of(rack, 0) == sorted(
+            [f"{COL}_1.ecx", f"{COL}_1.vif"]
+            + [f"{COL}_1.ec{s:02d}" for s in gone])
+    assert d["rebuild_fetch_streamed_bytes"] == 10 * shard_size
+    assert d["rebuild_fetch_files"] == 10 + 2
+    assert d["copy_recv_http_bytes"] == d["copy_file_sendfile_bytes"] == 0
+    assert d["copy_file_bytes"] == d["copy_recv_bytes"] \
+        == d["rebuild_fetch_bytes"]
+
+
+def test_no_survivor_is_a_file_on_the_rebuilder_at_any_point_of_a_round(
+        racks, monkeypatch):
+    """The replacement's directory listed from the fault point, behind
+    every chunk landed (twelve here) and from the positioned writes of
+    the restore: the index files, their ``.part`` while they come, the
+    shards being restored, and never a survivor's shard or ``.part``."""
+    rack = sealed_rack(racks)
+    gone = empty(rack, 0)
+    from seaweedfs_tpu.pipeline import writeback
+    seen, looks = set(), []
+    check, submit = faults.check, writeback.WriterPool.submit
+
+    def look(where):
+        looks.append(where)
+        seen.update(files_of(rack, 0))
+
+    def checked(name, *a, **kw):
+        if name == "ec.shard_copy":
+            look("chunk")
+        return check(name, *a, **kw)
+
+    def submitted(self, *a, **kw):
+        look("write")
+        return submit(self, *a, **kw)
+    monkeypatch.setattr(faults, "check", checked)
+    monkeypatch.setattr(writeback.WriterPool, "submit", submitted)
+    reply, err = rack.run("ec.rebuild -volumeId 1")
+    assert err is None and f"rebuilt {gone}" in reply, (reply, err)
+    assert looks.count("chunk") == 10 + 2 and "write" in looks
+    restored = {f"{COL}_1.ec{s:02d}" for s in gone}
+    index = {f"{COL}_1{ext}" for ext in (".vif", ".ecx")}
+    assert restored <= seen and index <= seen
+    assert seen <= restored | index | {n + ".part" for n in index}
 
 
 # --------------------------------------------------------------------------
 # all or nothing
 # --------------------------------------------------------------------------
 
+def left_empty_and_repairable(rack, survivors, mapped) -> None:
+    """Nothing of the volume on the replacement, every survivor and
+    the master's map as they were; and the same command, sound, repairs
+    it."""
+    assert files_of(rack, 0) == []
+    assert not [p for d in rack.dirs for p in d.glob("*.part")]
+    assert (COL, 1) not in rack.servers[0].store.ec_mounts
+    assert digests(rack) == survivors
+    assert rack.mapped(1) == mapped
+    reply, err = rack.run("ec.rebuild -volumeId 1")
+    assert err is None and "rebuilt" in reply, (reply, err)
+    assert sorted(s for ids in rack.held(1) for s in ids) \
+        == list(range(TOTAL))
+
+
 @pytest.mark.parametrize("nth", [1, 6], ids=["in_the_index_files",
-                                             "among_the_siblings"])
+                                             "among_the_survivors"])
 def test_a_fault_mid_fetch_leaves_the_server_empty(racks, nth):
-    """One chunk per file here: .vif, .ecx, then ten siblings over three
-    chains. The ``nth`` chunk written fails its file; the command
-    fails, and nothing of the volume is left on the replacement."""
+    """One chunk per file here: .vif, .ecx, then ten survivors' slices
+    over three chains. The ``nth`` chunk landed fails its file or its
+    stream; the command fails, and nothing of the volume is left on
+    the replacement: no index file, no restored shard, no file of any
+    other kind."""
     rack = sealed_rack(racks)
     empty(rack, 0)
     survivors = digests(rack)
@@ -332,24 +465,67 @@ def test_a_fault_mid_fetch_leaves_the_server_empty(racks, nth):
     faults.clear()
     assert err is not None and "1 volume(s) failed" in err
     assert f"failed on {rack.servers[0].url}" in reply
-    assert files_of(rack, 0) == []
-    assert not [p for d in rack.dirs for p in d.glob("*.part")]
-    assert (COL, 1) not in rack.servers[0].store.ec_mounts
-    assert digests(rack) == survivors
-    assert rack.mapped(1) == mapped
-    # no sibling was fsynced on its way in, fault or none
+    assert list(rack.dirs[0].iterdir()) == []
+    # only index files ever take the barrier, fault or none
     assert commits() - commits_before <= 2
-    # and the same command, sound, repairs it
+    left_empty_and_repairable(rack, survivors, mapped)
+
+
+@pytest.mark.parametrize("how, said", [
+    ("longer", "surviving shard sizes differ"),
+    ("shorter", "surviving shard sizes differ"),
+    ("cut", "short of the survivors' size"),
+])
+def test_a_stream_of_another_length_fails_the_call(racks, monkeypatch,
+                                                   how, said):
+    """A survivor whose holder announces another length than the others
+    fails the call before a chunk is read; one that ends before the
+    length it announced fails it in the run. Either way the replacement
+    is left empty."""
+    rack = sealed_rack(racks)
+    empty(rack, 0)
+    holder = next(i for i in (1, 2, 3) if rack.held(1)[i])
+    path = ec_files.shard_path(rack.base(holder, 1),
+                               rack.held(1)[holder][0])
+    whole = path.read_bytes()
+    from seaweedfs_tpu.cluster import volume_server as vs_mod
+    if how == "cut":
+        real = vs_mod._open_body
+
+        def open_body(vs, url, vid, col, ext):
+            body = real(vs, url, vid, col, ext)
+            if ext == path.suffix:
+                readinto, took = body.readinto, []
+
+                def cut(view):
+                    # half of the file, then the peer is gone
+                    if sum(took) >= len(whole) // 2:
+                        return 0
+                    took.append(readinto(view[:len(whole) // 2]))
+                    return took[-1]
+                body.readinto = cut
+            return body
+        monkeypatch.setattr(vs_mod, "_open_body", open_body)
+    else:
+        path.write_bytes(whole + b"\0" if how == "longer" else whole[:-1])
+    survivors = digests(rack)
+    mapped = rack.mapped(1)
     reply, err = rack.run("ec.rebuild -volumeId 1")
-    assert err is None and "rebuilt" in reply, (reply, err)
-    assert sorted(s for ids in rack.held(1) for s in ids) \
-        == list(range(TOTAL))
+    assert err is not None and "1 volume(s) failed" in err
+    assert said in reply + err, (reply, err)
+    if how == "cut":
+        monkeypatch.undo()
+    else:
+        assert digests(rack) == survivors
+        path.write_bytes(whole)
+        survivors = digests(rack)
+    left_empty_and_repairable(rack, survivors, mapped)
 
 
-def test_a_sibling_is_asked_of_a_second_holder_when_the_first_fails(
+def test_a_survivor_is_asked_of_a_second_holder_when_the_first_refuses(
         racks, monkeypatch):
-    """A shard mounted on two servers: the chain asks the second when
-    the first cannot give it."""
+    """A shard mounted on two servers: its stream is opened on the
+    second when the first refuses at the open."""
     rack = sealed_rack(racks)
     held = rack.held(1)
     a, b = (i for i in (1, 2, 3) if len(held[i]) == 4)
@@ -363,19 +539,24 @@ def test_a_sibling_is_asked_of_a_second_holder_when_the_first_fails(
     assert len(rack.mapped(1)[twice]) == 2
     gone = empty(rack, 0)
     from seaweedfs_tpu.cluster import volume_server as vs_mod
-    real, asked = vs_mod._copy_remote_file, []
+    real, asked = vs_mod._open_body, []
 
-    def pull(vs, src_url, vid, col, ext, dest, **how):
+    def open_body(vs, src_url, vid, col, ext):
         if ext == ec_files.shard_ext(twice):
             asked.append(src_url)
             if len(asked) == 1:
                 raise OSError("the first holder is not answering")
-        return real(vs, src_url, vid, col, ext, dest, **how)
-    monkeypatch.setattr(vs_mod, "_copy_remote_file", pull)
+        return real(vs, src_url, vid, col, ext)
+    monkeypatch.setattr(vs_mod, "_open_body", open_body)
+    before = rack.pipeline_vars()
     reply, err = rack.run("ec.rebuild -volumeId 1")
     assert err is None and f"rebuilt {gone}" in reply, (reply, err)
     assert sorted(asked) == sorted(rack.mapped(1)[twice])
     assert rack.held(1)[0] == gone
+    # the refusal moved no survivor to another chain and counted none
+    d = delta(rack, before)
+    assert d["rebuild_fetch_files"] == 10 + 2
+    assert d["rebuild_fetch_sources"] == 3
 
 
 def test_fewer_than_k_survivors_is_reported_and_the_walk_goes_on(racks):

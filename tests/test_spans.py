@@ -233,17 +233,19 @@ class StandInResult:
     """What the writer syncs on, as a device result offers it: a fetch
     to ask for, a wait until ready, then ``np.asarray``."""
 
-    def __init__(self, arr, ready_s=0.0, land_s=0.0, launched=None):
+    def __init__(self, arr, ready_s=0.0, land_s=0.0, launched=None,
+                 sleep=time.sleep):
         self.arr, self.ready_s, self.land_s = arr, ready_s, land_s
         self.launched = launched
         self.asked = 0
+        self.sleep = sleep
 
     def copy_to_host_async(self):
         self.asked += 1
 
     def block_until_ready(self):
         assert self.asked == 1      # the fetch is asked for before the wait
-        time.sleep(self.ready_s)
+        self.sleep(self.ready_s)
 
     def __array__(self, dtype=None, copy=None):
         time.sleep(self.land_s)
@@ -355,23 +357,50 @@ def test_a_host_result_reads_no_ready_wait_and_the_same_bytes(overlapped):
     assert all(np.array_equal(a, d ^ 0x5A) for a, d in zip(host, data))
 
 
-def test_a_group_counts_once_when_the_writer_really_waited():
+class StandInTime:
+    """``time`` as ``flight`` and ``pipe`` see it while a test holds it in
+    their place: ``perf_counter`` moves when a stand-in result waits
+    (``sleep``) and by nothing else, so what a loaded machine adds between
+    two clock reads is in no span."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def perf_counter(self) -> float:
+        return self.now
+
+    def sleep(self, seconds: float) -> None:
+        self.now += seconds
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_a_group_counts_once_when_the_writer_really_waited(monkeypatch):
     """Results of one dispatch share one ``launched``; the first the writer
     really waits for gives the group's time to ready and its input bytes,
-    the others of that dispatch and a result found ready give nothing."""
-    t0 = time.perf_counter()
+    the others of that dispatch and a result found ready give nothing.
+    On the stand-in's clock: a wait is 20 ms or nothing whatever the
+    machine is doing, so which results count as waited for is the test's
+    to say."""
+    clock = StandInTime()
+    monkeypatch.setattr(flight, "time", clock)
+    monkeypatch.setattr(pipe, "time", clock)
+    t0 = clock.perf_counter()
     groups = [(t0, 3000), (t0, 5000), (t0, 7000)]
     waits = [0.02, 0.02, 0.0, 0.0, 0.02, 0.0]     # 2 + 2 + 2 results
     results = [StandInResult(np.zeros(8, dtype=np.uint8), ready_s=w,
-                             launched=groups[i // 2])
+                             launched=groups[i // 2], sleep=clock.sleep)
                for i, w in enumerate(waits)]
     st = pipe.PipeStats()
     run_guarded(lambda: pipe.run_pipeline(
         ((i, r) for i, r in enumerate(results)), lambda r: r,
         lambda meta, b, r: None, stats=st, overlapped=False, kind="groups"))
     assert st.group_ready_bytes == 3000 + 7000
-    # launch's return -> found ready, for the two groups waited for
-    assert 0.02 + 0.06 <= st.group_ready_seconds < 1.0
+    # launch's return -> found ready, for the two groups waited for: the
+    # first after one wait, the third after all three
+    assert st.group_ready_seconds == pytest.approx(0.02 + 0.06)
+    assert st.sync_ready_seconds == pytest.approx(0.06)
     pay = pipe.debug_payload()
     assert pay["group_ready_bytes"] >= 10000 and pay["groups"] >= 6
 
