@@ -57,6 +57,8 @@ from . import usage as usage_mod
 from .master import _grpc_port
 from ..util import tls as tls_mod
 
+#: Volumes of a rebuild batch whose index files are fetched at once
+_INDEX_FETCHES = 8
 _COPY_CHUNK = 1024 * 1024
 #: the clock of the per-chunk splits in CopyFile and _copy_remote_file
 _clock = time.perf_counter
@@ -1058,10 +1060,13 @@ class _VolumeServicer:
         return resp
 
     def _pull(self, url: str, vid: int, col: str, ext: str, dest: Path,
-              ignore_missing: bool = False) -> int:
+              ignore_missing: bool = False, company: bool = True) -> int:
         """One index file of the rebuild's fetch, counted: its bytes,
-        the file, and the seconds its stream had company."""
-        with self.vs.fetch_streams.stream():
+        the file, and (``company``) the seconds its stream had company.
+        A batch's index files come beside each other's commits, which
+        are no stream: they are not counted there."""
+        with self.vs.fetch_streams.stream() if company \
+                else contextlib.nullcontext():
             n = _copy_remote_file(self.vs, url, vid, col, ext, dest,
                                   ignore_missing=ignore_missing)
         if n:
@@ -1091,6 +1096,158 @@ class _VolumeServicer:
                                   ignore_missing=optional):
                         placed.append(dest)
         return plan
+
+    @_ec_step("rebuild")
+    def VolumeEcShardsRebuildBatch(self, request, context):
+        """The server half of an ``ec.rebuild`` walk: every named volume
+        of the collection that this server rebuilds, repaired by one
+        call, as :meth:`VolumeEcShardsRebuild` repairs one —
+        and their restores coalesced into shared device batches, one
+        run per loss pattern (``rebuild.rebuild_volumes``):
+
+        - per volume, the plan: where this server holds nothing of it,
+          the ``.vif`` read into memory from a holder decides the
+          geometry and what is missing; a volume with nothing missing
+          puts nothing on disk here; the others get their ``.vif``,
+          ``.ecx`` and ``.ecj`` (may be absent) past the ``[storage]
+          fsync`` barrier (``step_rebuild_fetch_index``);
+        - the surviving shards it lacks come as streams, never files
+          (``_BatchSurvivorFeed``: a thread a source server, walking
+          the volumes in the order the slabs ask for them);
+        - the packed run writes each volume's restored files and
+          passes them through the barrier; then each volume is
+          mounted, and ONE nudge of the master goes for them all.
+
+        ``step_rebuild_fetch`` counts the fetch's wall, as for one
+        volume. Each volume ends restored and mounted, or with nothing
+        this call placed left of it (an unrepairable one before any
+        shard moves); the response names which, in request order."""
+        vs, col = self.vs, request.collection
+        vids = list(request.volume_ids)
+        errors: dict[int, str] = {}
+        rebuilt: dict[int, list] = {}
+        placed: dict[int, list] = {vid: [] for vid in vids}
+        repairs: list = []
+        holders: dict = {}
+        with flight_mod.span("step_rebuild_fetch", leaf=False, trace=True):
+            # what a worker thread continues the call's trace from
+            parent = tracing.outbound_value() or True
+
+            def plan_one(vid: int):
+                try:
+                    plan, base, dat_size = self._batch_plan(
+                        vid, col, placed[vid], parent)
+                    if not plan.missing:
+                        return vid, plan, None
+                    return vid, plan, rebuild_mod.plan_repair(
+                        vid, base, plan.scheme, plan.missing,
+                        [sid for sid, _ in plan.fetch], dat_size)
+                except Exception as e:  # noqa: BLE001 — this volume is left as it was, the batch goes on
+                    return vid, None, f"{type(e).__name__}: {e}"
+            # the volumes' index files at once: each commit is a wait on
+            # the file store
+            with futures.ThreadPoolExecutor(
+                    min(_INDEX_FETCHES, len(vids)) or 1,
+                    "rebuild-index") as pool:
+                planned = list(pool.map(plan_one, vids))
+            for vid, plan, repair in planned:
+                if plan is None:
+                    errors[vid] = repair
+                elif repair is None:
+                    rebuilt[vid] = []
+                else:
+                    repairs.append(repair)
+                    holders.update(((vid, sid), urls)
+                                   for sid, urls in plan.fetch
+                                   if sid in repair.streamed)
+            feed = _BatchSurvivorFeed(vs, col, holders, {
+                r.key: r.size for r in repairs}) if holders else None
+        failed = rebuild_mod.rebuild_volumes(
+            repairs, remote=feed, pools=self._ec_pools) if repairs else {}
+        for r in repairs:
+            if r.key not in failed:
+                try:
+                    self._mount_shards(r.key, list(r.missing), col)
+                    rebuilt[r.key] = list(r.missing)
+                    continue
+                except Exception as e:  # noqa: BLE001 — this volume is taken back, the others stand
+                    failed[r.key] = f"{type(e).__name__}: {e}"
+                    for i in r.missing:
+                        ec_files.shard_path(r.base, i).unlink(missing_ok=True)
+            errors[r.key] = failed[r.key]
+        for vid, why in errors.items():
+            glog.warning("rebuild batch: volume %d left as it was: %s", vid,
+                         why)
+            for p in placed[vid]:
+                p.unlink(missing_ok=True)
+        if any(rebuilt.values()):
+            vs.heartbeat_now()
+        resp = volume_server_pb2.VolumeEcShardsRebuildBatchResponse()
+        for vid in request.volume_ids:
+            resp.results.add(volume_id=vid,
+                             rebuilt_shard_ids=rebuilt.get(vid, []),
+                             error=errors.get(vid, ""))
+        return resp
+
+    def _batch_plan(self, vid: int, col: str, placed: list, trace):
+        """(plan, base, the .vif's dat size) of one volume of a batch,
+        on a thread that continues ``trace``. A server that holds
+        nothing of the volume reads the holder's ``.vif`` into memory
+        first, and only where something is missing puts it and ``.ecx``
+        / ``.ecj`` on its disk (appended to ``placed`` as they land)."""
+        vs = self.vs
+        remote = {sid: others
+                  for sid, urls in vs.ec_shard_table(vid).items()
+                  if (others := [u for u in urls if u != vs.url])}
+        base = vs.store.ec_base(vid, col)
+        if base is not None:
+            return (_RebuildPlan(base, remote), base,
+                    ec_files.VolumeInfo.load(base).dat_file_size)
+        if not remote:
+            raise StoreError(f"no ec files for volume {vid} here, and no "
+                             f"other server holds a shard of it")
+        base = _dest_base(vs, vid, col)
+        src = remote[min(remote)][0]
+        with flight_mod.span("step_rebuild_fetch_index", leaf=False,
+                             trace=trace):
+            raw = self._read_remote(src, vid, col, ".vif")
+            info = ec_files.VolumeInfo.parse(raw)
+            plan = _RebuildPlan(base, remote, _scheme_from_vif(base, info))
+            if plan.missing:
+                vif = ec_files.vif_path(base)
+                tmp = vif.with_suffix(".vif.part")
+                try:
+                    tmp.write_bytes(raw)
+                    with flight_mod.span("copy_commit", nbytes=len(raw)):
+                        durability.durable_replace(tmp, vif)
+                finally:
+                    tmp.unlink(missing_ok=True)
+                placed.append(vif)
+                for ext, dest, optional in (
+                        (".ecx", ec_files.ecx_path(base), False),
+                        (".ecj", ec_files.ecj_path(base), True)):
+                    if self._pull(src, vid, col, ext, dest,
+                                  ignore_missing=optional, company=False):
+                        placed.append(dest)
+        return plan, base, info.dat_file_size
+
+    def _read_remote(self, url: str, vid: int, col: str, ext: str) -> bytes:
+        """One small file of a volume off ``url`` into memory (a
+        ``.vif``), counted as a file of the rebuild's fetch: one
+        ``copy_recv``, its bytes, the file (as a batch's index file, not
+        among the streams whose company is counted)."""
+        over_http = tls_mod.installed() is None
+        chunks_of = _http_chunks if over_http else _grpc_chunks
+        t = _clock()
+        with flight_mod.span("copy_recv") as sp:
+            raw = b"".join(bytes(c) for c in chunks_of(
+                self.vs, url, vid, col, ext, False))
+            sp.nbytes = n = len(raw)
+        pipe_mod.fold(copy_recv_bytes=n, copy_recv_chunks=1,
+                      copy_recv_http_bytes=n if over_http else 0,
+                      copy_recv_wait_seconds=_clock() - t,
+                      rebuild_fetch_bytes=n, rebuild_fetch_files=1)
+        return raw
 
     @_ec_step("shards_copy")
     def VolumeEcShardsCopy(self, request, context):
@@ -1228,8 +1385,9 @@ class _RebuildPlan:
     take off other servers so that ``data_shards`` are at hand, lowest
     ids first. Fewer survivors than that anywhere: unrepairable."""
 
-    def __init__(self, base: Path, remote: dict):
-        self.scheme = scheme = _scheme_from_vif(base)
+    def __init__(self, base: Path, remote: dict,
+                 scheme: Optional[EcScheme] = None):
+        self.scheme = scheme = scheme or _scheme_from_vif(base)
         total = scheme.total_shards
         local = set(ec_files.present_shards(base, total))
         self.missing = [sid for sid in range(total)
@@ -1276,10 +1434,12 @@ def _remove_ec_files(base, scheme: EcScheme) -> None:
         p.unlink(missing_ok=True)
 
 
-def _scheme_from_vif(base) -> EcScheme:
-    """Geometry travels in the .vif (config-4 parametrization)."""
+def _scheme_from_vif(base, info: Optional[ec_files.VolumeInfo] = None
+                     ) -> EcScheme:
+    """Geometry travels in the .vif (config-4 parametrization): the one
+    under ``base``, or ``info`` where it was read from elsewhere."""
     try:
-        vi = ec_files.VolumeInfo.load(base)
+        vi = info or ec_files.VolumeInfo.load(base)
         if vi.data_shards and vi.parity_shards:
             return EcScheme(vi.data_shards, vi.parity_shards)
     except Exception:
@@ -1659,6 +1819,135 @@ class _SurvivorFeed:
         # the first error in the plan's order is the chunk's
         for chain in self._chains:
             chain.said()
+
+    def close(self) -> None:
+        chains, self._chains = self._chains, []
+        for chain in chains:
+            chain.todo.put(None)
+        for chain in chains:
+            chain.join()
+        landed = max((chain.landed for chain in chains), default=0.0)
+        flight_mod.lengthen("step_rebuild_fetch",
+                            max(0.0, landed - self._made))
+
+
+class _BatchChain(threading.Thread):
+    """One source server's survivors of a batch of rebuilds, on a thread
+    of its own beneath the rpc's trace (``step_rebuild_fetch_source``):
+    for every slab it is asked its pieces of, in order, opening a
+    :class:`_SurvivorStream` at a stream's first piece and closing it
+    behind its last. A piece that fails fails its volume: that stream
+    is closed, the volume's later pieces are passed over, and the chain
+    goes on with the others. ``done`` gets ``None`` per slab, or the
+    error that ended the chain."""
+
+    def __init__(self, feed: "_BatchSurvivorFeed", n: int):
+        super().__init__(name=f"rebuild-fetch-{n}", daemon=True)
+        self._feed = feed
+        self.todo: queue.SimpleQueue = queue.SimpleQueue()
+        self.done: queue.SimpleQueue = queue.SimpleQueue()
+        #: when this chain's newest byte landed
+        self.landed = 0.0
+
+    def said(self) -> None:
+        said = self.done.get()
+        if isinstance(said, BaseException):
+            raise said
+
+    def run(self) -> None:
+        feed = self._feed
+        cpu0 = time.thread_time()
+        opened: dict = {}
+        with flight_mod.span("step_rebuild_fetch_source", leaf=False,
+                             trace=feed.parent):
+            try:
+                while (pieces := self.todo.get()) is not None:
+                    for vid, sid, view, last in pieces:
+                        self._piece(opened, vid, sid, view, last)
+                    self.done.put(None)
+            except BaseException as e:  # noqa: BLE001 — raised by the reader that waits for this chain
+                self.done.put(e)
+            finally:
+                for st in opened.values():
+                    st.close()
+        pipe_mod.fold(copy_recv_cpu_seconds=time.thread_time() - cpu0)
+
+    def _piece(self, opened: dict, vid: int, sid: int, view, last: bool):
+        feed = self._feed
+        st = opened.get((vid, sid))
+        try:
+            if feed.has_failed(vid):
+                # its other streams have nothing to fill any more
+                last = True
+            else:
+                if st is None:
+                    st = opened[vid, sid] = _SurvivorStream(
+                        feed.vs, vid, feed.collection, sid,
+                        feed.holders[vid, sid])
+                    if st.size is not None and st.size != feed.sizes[vid]:
+                        raise VolumeServerError(
+                            f"surviving shard sizes differ: {st.name} is "
+                            f"{st.size} bytes, not {feed.sizes[vid]}")
+                st.fill(view, last)
+                self.landed = _clock()
+        except Exception as e:  # noqa: BLE001 — this volume fails, the chain goes on
+            feed.fail(vid, f"{type(e).__name__}: {e}")
+            last = True
+        if last and st is not None:
+            opened.pop((vid, sid)).close()
+
+
+class _BatchSurvivorFeed:
+    """The surviving shards a batch of rebuilds takes off other servers,
+    as ``rebuild_volumes`` takes them (its ``StreamedSurvivors``): the
+    holders of each (volume, shard), one :class:`_BatchChain` per source
+    server (a shard's first holder), all chains filling their pieces of
+    a slab at once, each walking the batch's volumes in slab order. At
+    most the streams of the volumes in one slab are open at a time. Made
+    inside the handler's ``step_rebuild_fetch``, whose trace the chains
+    continue and whose seconds :meth:`close` lengthens to the moment the
+    last byte landed."""
+
+    def __init__(self, vs: VolumeServer, collection: str, holders: dict,
+                 sizes: dict):
+        self.vs, self.collection = vs, collection
+        self.holders, self.sizes = holders, sizes
+        self.parent = tracing.outbound_value() or True
+        self._lock = threading.Lock()
+        self._failed: dict[int, str] = {}
+        sources: dict[str, int] = {}
+        self._chain_of = {key: sources.setdefault(urls[0], len(sources))
+                          for key, urls in sorted(holders.items())}
+        pipe_mod.count("rebuild_fetch_sources", len(sources))
+        self._chains = [_BatchChain(self, n) for n in range(len(sources))]
+        for chain in self._chains:
+            chain.start()
+        self._made = _clock()
+
+    def fill(self, pieces: list):
+        mine: dict[_BatchChain, list] = {}
+        for piece in pieces:
+            chain = self._chains[self._chain_of[piece[:2]]]
+            mine.setdefault(chain, []).append(piece)
+        for chain, its in mine.items():
+            chain.todo.put(its)
+
+        def filled() -> None:
+            for chain in mine:
+                chain.said()
+        return filled
+
+    def fail(self, vid: int, why: str) -> None:
+        with self._lock:
+            self._failed.setdefault(vid, why)
+
+    def has_failed(self, vid: int) -> bool:
+        with self._lock:
+            return vid in self._failed
+
+    def failed(self) -> dict:
+        with self._lock:
+            return dict(self._failed)
 
     def close(self) -> None:
         chains, self._chains = self._chains, []

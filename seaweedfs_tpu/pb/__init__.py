@@ -84,6 +84,9 @@ VOLUME_METHODS = [
     Method("VolumeEcShardsRebuild",
            volume_server_pb2.VolumeEcShardsRebuildRequest,
            volume_server_pb2.VolumeEcShardsRebuildResponse),
+    Method("VolumeEcShardsRebuildBatch",
+           volume_server_pb2.VolumeEcShardsRebuildBatchRequest,
+           volume_server_pb2.VolumeEcShardsRebuildBatchResponse),
     Method("VolumeEcShardsCopy",
            volume_server_pb2.VolumeEcShardsCopyRequest,
            volume_server_pb2.VolumeEcShardsCopyResponse),

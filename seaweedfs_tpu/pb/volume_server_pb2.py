@@ -13,7 +13,7 @@ _sym_db = _symbol_database.Default()
 
 
 
-DESCRIPTOR = _descriptor_pool.Default().AddSerializedFile(b'\n\x13volume_server.proto\x12\x10volume_server_pb\"\x88\x01\n VolumeTierMoveDatToRemoteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12 \n\x18\x64\x65stination_backend_name\x18\x03 \x01(\t\x12\x1b\n\x13keep_local_dat_file\x18\x04 \x01(\x08\"L\n!VolumeTierMoveDatToRemoteResponse\x12\x13\n\x0bmoved_bytes\x18\x01 \x01(\x04\x12\x12\n\nobject_url\x18\x02 \x01(\t\"K\n\"VolumeTierMoveDatFromRemoteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\":\n#VolumeTierMoveDatFromRemoteResponse\x12\x13\n\x0bmoved_bytes\x18\x01 \x01(\x04\"A\n\x18VacuumVolumeCheckRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"2\n\x19VacuumVolumeCheckResponse\x12\x15\n\rgarbage_ratio\x18\x01 \x01(\x01\"C\n\x1aVacuumVolumeCompactRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x1d\n\x1bVacuumVolumeCompactResponse\"B\n\x19VacuumVolumeCommitRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"1\n\x1aVacuumVolumeCommitResponse\x12\x13\n\x0bvolume_size\x18\x01 \x01(\x04\"C\n\x1aVacuumVolumeCleanupRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x1d\n\x1bVacuumVolumeCleanupResponse\"u\n\x15\x41llocateVolumeRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x13\n\x0breplication\x18\x03 \x01(\t\x12\x0b\n\x03ttl\x18\x04 \x01(\t\x12\x13\n\x0bpreallocate\x18\x05 \x01(\x03\"\x18\n\x16\x41llocateVolumeResponse\"<\n\x13VolumeDeleteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x16\n\x14VolumeDeleteResponse\"B\n\x19VolumeMarkReadonlyRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x1c\n\x1aVolumeMarkReadonlyResponse\"B\n\x19VolumeMarkWritableRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x1c\n\x1aVolumeMarkWritableResponse\"<\n\x13VolumeStatusRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\";\n\x12VolumeMountRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x15\n\x13VolumeMountResponse\"=\n\x14VolumeUnmountRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x17\n\x15VolumeUnmountResponse\"T\n\x16VolumeConfigureRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x13\n\x0breplication\x18\x03 \x01(\t\"(\n\x17VolumeConfigureResponse\x12\r\n\x05\x65rror\x18\x01 \x01(\t\"y\n\x14VolumeStatusResponse\x12\x12\n\nhas_volume\x18\x01 \x01(\x08\x12\x10\n\x08\x64\x61t_size\x18\x02 \x01(\x04\x12\x12\n\nfile_count\x18\x03 \x01(\x04\x12\x11\n\tread_only\x18\x04 \x01(\x08\x12\x14\n\x0c\x65\x63_shard_ids\x18\x05 \x03(\r\"Q\n\x15ReadNeedleBlobRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tneedle_id\x18\x03 \x01(\x04\"=\n\x16ReadNeedleBlobResponse\x12\x13\n\x0bneedle_blob\x18\x01 \x01(\x0c\x12\x0e\n\x06offset\x18\x02 \x01(\x04\"g\n\x16WriteNeedleBlobRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tneedle_id\x18\x03 \x01(\x04\x12\x13\n\x0bneedle_blob\x18\x04 \x01(\x0c\")\n\x17WriteNeedleBlobResponse\x12\x0e\n\x06offset\x18\x01 \x01(\x04\"\x96\x01\n\x0f\x43opyFileRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x0b\n\x03\x65xt\x18\x03 \x01(\t\x12\x13\n\x0bstop_offset\x18\x04 \x01(\x04\x12$\n\x1cignore_source_file_not_found\x18\x05 \x01(\x08\x12\x14\n\x0cstart_offset\x18\x06 \x01(\x04\"(\n\x10\x43opyFileResponse\x12\x14\n\x0c\x66ile_content\x18\x01 \x01(\x0c\"T\n\x11VolumeCopyRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x18\n\x10source_data_node\x18\x03 \x01(\t\"/\n\x12VolumeCopyResponse\x12\x19\n\x11last_append_at_ns\x18\x01 \x01(\x04\"r\n\x1dVolumeEcShardsGenerateRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x13\n\x0b\x64\x61ta_shards\x18\x03 \x01(\r\x12\x15\n\rparity_shards\x18\x04 \x01(\r\" \n\x1eVolumeEcShardsGenerateResponse\"x\n\"VolumeEcShardsGenerateBatchRequest\x12\x12\n\nvolume_ids\x18\x01 \x03(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x13\n\x0b\x64\x61ta_shards\x18\x03 \x01(\r\x12\x15\n\rparity_shards\x18\x04 \x01(\r\"\xa0\x01\n#VolumeEcShardsGenerateBatchResponse\x12M\n\x07results\x18\x01 \x03(\x0b\x32<.volume_server_pb.VolumeEcShardsGenerateBatchResponse.Result\x1a*\n\x06Result\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\r\n\x05\x65rror\x18\x02 \x01(\t\"E\n\x1cVolumeEcShardsRebuildRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\":\n\x1dVolumeEcShardsRebuildResponse\x12\x19\n\x11rebuilt_shard_ids\x18\x01 \x03(\r\"\xb4\x01\n\x19VolumeEcShardsCopyRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tshard_ids\x18\x03 \x03(\r\x12\x15\n\rcopy_ecx_file\x18\x04 \x01(\x08\x12\x18\n\x10source_data_node\x18\x05 \x01(\t\x12\x15\n\rcopy_ecj_file\x18\x06 \x01(\x08\x12\x15\n\rcopy_vif_file\x18\x07 \x01(\x08\"\x1c\n\x1aVolumeEcShardsCopyResponse\"W\n\x1bVolumeEcShardsDeleteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tshard_ids\x18\x03 \x03(\r\"\x1e\n\x1cVolumeEcShardsDeleteResponse\"V\n\x1aVolumeEcShardsMountRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tshard_ids\x18\x03 \x03(\r\"\x1d\n\x1bVolumeEcShardsMountResponse\"D\n\x1cVolumeEcShardsUnmountRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x11\n\tshard_ids\x18\x02 \x03(\r\"\x1f\n\x1dVolumeEcShardsUnmountResponse\"o\n\x18VolumeEcShardReadRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x10\n\x08shard_id\x18\x02 \x01(\r\x12\x0e\n\x06offset\x18\x03 \x01(\x03\x12\x0c\n\x04size\x18\x04 \x01(\x03\x12\x10\n\x08\x66ile_key\x18\x05 \x01(\x04\"=\n\x19VolumeEcShardReadResponse\x12\x0c\n\x04\x64\x61ta\x18\x01 \x01(\x0c\x12\x12\n\nis_deleted\x18\x02 \x01(\x08\"F\n\x1dVolumeEcShardsToVolumeRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\" \n\x1eVolumeEcShardsToVolumeResponse\"e\n\x19VolumeEcBlobDeleteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x10\n\x08\x66ile_key\x18\x03 \x01(\x04\x12\x0f\n\x07version\x18\x04 \x01(\r\"\x1c\n\x1aVolumeEcBlobDeleteResponse2\xff\x18\n\x0cVolumeServer\x12\x65\n\x0e\x41llocateVolume\x12\'.volume_server_pb.AllocateVolumeRequest\x1a(.volume_server_pb.AllocateVolumeResponse\"\x00\x12_\n\x0cVolumeDelete\x12%.volume_server_pb.VolumeDeleteRequest\x1a&.volume_server_pb.VolumeDeleteResponse\"\x00\x12q\n\x12VolumeMarkReadonly\x12+.volume_server_pb.VolumeMarkReadonlyRequest\x1a,.volume_server_pb.VolumeMarkReadonlyResponse\"\x00\x12q\n\x12VolumeMarkWritable\x12+.volume_server_pb.VolumeMarkWritableRequest\x1a,.volume_server_pb.VolumeMarkWritableResponse\"\x00\x12_\n\x0cVolumeStatus\x12%.volume_server_pb.VolumeStatusRequest\x1a&.volume_server_pb.VolumeStatusResponse\"\x00\x12h\n\x0fVolumeConfigure\x12(.volume_server_pb.VolumeConfigureRequest\x1a).volume_server_pb.VolumeConfigureResponse\"\x00\x12\\\n\x0bVolumeMount\x12$.volume_server_pb.VolumeMountRequest\x1a%.volume_server_pb.VolumeMountResponse\"\x00\x12\x62\n\rVolumeUnmount\x12&.volume_server_pb.VolumeUnmountRequest\x1a\'.volume_server_pb.VolumeUnmountResponse\"\x00\x12U\n\x08\x43opyFile\x12!.volume_server_pb.CopyFileRequest\x1a\".volume_server_pb.CopyFileResponse\"\x00\x30\x01\x12Y\n\nVolumeCopy\x12#.volume_server_pb.VolumeCopyRequest\x1a$.volume_server_pb.VolumeCopyResponse\"\x00\x12}\n\x16VolumeEcShardsGenerate\x12/.volume_server_pb.VolumeEcShardsGenerateRequest\x1a\x30.volume_server_pb.VolumeEcShardsGenerateResponse\"\x00\x12\x8c\x01\n\x1bVolumeEcShardsGenerateBatch\x12\x34.volume_server_pb.VolumeEcShardsGenerateBatchRequest\x1a\x35.volume_server_pb.VolumeEcShardsGenerateBatchResponse\"\x00\x12z\n\x15VolumeEcShardsRebuild\x12..volume_server_pb.VolumeEcShardsRebuildRequest\x1a/.volume_server_pb.VolumeEcShardsRebuildResponse\"\x00\x12q\n\x12VolumeEcShardsCopy\x12+.volume_server_pb.VolumeEcShardsCopyRequest\x1a,.volume_server_pb.VolumeEcShardsCopyResponse\"\x00\x12w\n\x14VolumeEcShardsDelete\x12-.volume_server_pb.VolumeEcShardsDeleteRequest\x1a..volume_server_pb.VolumeEcShardsDeleteResponse\"\x00\x12t\n\x13VolumeEcShardsMount\x12,.volume_server_pb.VolumeEcShardsMountRequest\x1a-.volume_server_pb.VolumeEcShardsMountResponse\"\x00\x12z\n\x15VolumeEcShardsUnmount\x12..volume_server_pb.VolumeEcShardsUnmountRequest\x1a/.volume_server_pb.VolumeEcShardsUnmountResponse\"\x00\x12p\n\x11VolumeEcShardRead\x12*.volume_server_pb.VolumeEcShardReadRequest\x1a+.volume_server_pb.VolumeEcShardReadResponse\"\x00\x30\x01\x12}\n\x16VolumeEcShardsToVolume\x12/.volume_server_pb.VolumeEcShardsToVolumeRequest\x1a\x30.volume_server_pb.VolumeEcShardsToVolumeResponse\"\x00\x12q\n\x12VolumeEcBlobDelete\x12+.volume_server_pb.VolumeEcBlobDeleteRequest\x1a,.volume_server_pb.VolumeEcBlobDeleteResponse\"\x00\x12\x65\n\x0eReadNeedleBlob\x12\'.volume_server_pb.ReadNeedleBlobRequest\x1a(.volume_server_pb.ReadNeedleBlobResponse\"\x00\x12h\n\x0fWriteNeedleBlob\x12(.volume_server_pb.WriteNeedleBlobRequest\x1a).volume_server_pb.WriteNeedleBlobResponse\"\x00\x12n\n\x11VacuumVolumeCheck\x12*.volume_server_pb.VacuumVolumeCheckRequest\x1a+.volume_server_pb.VacuumVolumeCheckResponse\"\x00\x12t\n\x13VacuumVolumeCompact\x12,.volume_server_pb.VacuumVolumeCompactRequest\x1a-.volume_server_pb.VacuumVolumeCompactResponse\"\x00\x12q\n\x12VacuumVolumeCommit\x12+.volume_server_pb.VacuumVolumeCommitRequest\x1a,.volume_server_pb.VacuumVolumeCommitResponse\"\x00\x12t\n\x13VacuumVolumeCleanup\x12,.volume_server_pb.VacuumVolumeCleanupRequest\x1a-.volume_server_pb.VacuumVolumeCleanupResponse\"\x00\x12\x86\x01\n\x19VolumeTierMoveDatToRemote\x12\x32.volume_server_pb.VolumeTierMoveDatToRemoteRequest\x1a\x33.volume_server_pb.VolumeTierMoveDatToRemoteResponse\"\x00\x12\x8c\x01\n\x1bVolumeTierMoveDatFromRemote\x12\x34.volume_server_pb.VolumeTierMoveDatFromRemoteRequest\x1a\x35.volume_server_pb.VolumeTierMoveDatFromRemoteResponse\"\x00\x62\x06proto3')
+DESCRIPTOR = _descriptor_pool.Default().AddSerializedFile(b'\n\x13volume_server.proto\x12\x10volume_server_pb\"\x88\x01\n VolumeTierMoveDatToRemoteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12 \n\x18\x64\x65stination_backend_name\x18\x03 \x01(\t\x12\x1b\n\x13keep_local_dat_file\x18\x04 \x01(\x08\"L\n!VolumeTierMoveDatToRemoteResponse\x12\x13\n\x0bmoved_bytes\x18\x01 \x01(\x04\x12\x12\n\nobject_url\x18\x02 \x01(\t\"K\n\"VolumeTierMoveDatFromRemoteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\":\n#VolumeTierMoveDatFromRemoteResponse\x12\x13\n\x0bmoved_bytes\x18\x01 \x01(\x04\"A\n\x18VacuumVolumeCheckRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"2\n\x19VacuumVolumeCheckResponse\x12\x15\n\rgarbage_ratio\x18\x01 \x01(\x01\"C\n\x1aVacuumVolumeCompactRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x1d\n\x1bVacuumVolumeCompactResponse\"B\n\x19VacuumVolumeCommitRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"1\n\x1aVacuumVolumeCommitResponse\x12\x13\n\x0bvolume_size\x18\x01 \x01(\x04\"C\n\x1aVacuumVolumeCleanupRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x1d\n\x1bVacuumVolumeCleanupResponse\"u\n\x15\x41llocateVolumeRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x13\n\x0breplication\x18\x03 \x01(\t\x12\x0b\n\x03ttl\x18\x04 \x01(\t\x12\x13\n\x0bpreallocate\x18\x05 \x01(\x03\"\x18\n\x16\x41llocateVolumeResponse\"<\n\x13VolumeDeleteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x16\n\x14VolumeDeleteResponse\"B\n\x19VolumeMarkReadonlyRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x1c\n\x1aVolumeMarkReadonlyResponse\"B\n\x19VolumeMarkWritableRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x1c\n\x1aVolumeMarkWritableResponse\"<\n\x13VolumeStatusRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\";\n\x12VolumeMountRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x15\n\x13VolumeMountResponse\"=\n\x14VolumeUnmountRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\x17\n\x15VolumeUnmountResponse\"T\n\x16VolumeConfigureRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x13\n\x0breplication\x18\x03 \x01(\t\"(\n\x17VolumeConfigureResponse\x12\r\n\x05\x65rror\x18\x01 \x01(\t\"y\n\x14VolumeStatusResponse\x12\x12\n\nhas_volume\x18\x01 \x01(\x08\x12\x10\n\x08\x64\x61t_size\x18\x02 \x01(\x04\x12\x12\n\nfile_count\x18\x03 \x01(\x04\x12\x11\n\tread_only\x18\x04 \x01(\x08\x12\x14\n\x0c\x65\x63_shard_ids\x18\x05 \x03(\r\"Q\n\x15ReadNeedleBlobRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tneedle_id\x18\x03 \x01(\x04\"=\n\x16ReadNeedleBlobResponse\x12\x13\n\x0bneedle_blob\x18\x01 \x01(\x0c\x12\x0e\n\x06offset\x18\x02 \x01(\x04\"g\n\x16WriteNeedleBlobRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tneedle_id\x18\x03 \x01(\x04\x12\x13\n\x0bneedle_blob\x18\x04 \x01(\x0c\")\n\x17WriteNeedleBlobResponse\x12\x0e\n\x06offset\x18\x01 \x01(\x04\"\x96\x01\n\x0f\x43opyFileRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x0b\n\x03\x65xt\x18\x03 \x01(\t\x12\x13\n\x0bstop_offset\x18\x04 \x01(\x04\x12$\n\x1cignore_source_file_not_found\x18\x05 \x01(\x08\x12\x14\n\x0cstart_offset\x18\x06 \x01(\x04\"(\n\x10\x43opyFileResponse\x12\x14\n\x0c\x66ile_content\x18\x01 \x01(\x0c\"T\n\x11VolumeCopyRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x18\n\x10source_data_node\x18\x03 \x01(\t\"/\n\x12VolumeCopyResponse\x12\x19\n\x11last_append_at_ns\x18\x01 \x01(\x04\"r\n\x1dVolumeEcShardsGenerateRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x13\n\x0b\x64\x61ta_shards\x18\x03 \x01(\r\x12\x15\n\rparity_shards\x18\x04 \x01(\r\" \n\x1eVolumeEcShardsGenerateResponse\"x\n\"VolumeEcShardsGenerateBatchRequest\x12\x12\n\nvolume_ids\x18\x01 \x03(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x13\n\x0b\x64\x61ta_shards\x18\x03 \x01(\r\x12\x15\n\rparity_shards\x18\x04 \x01(\r\"\xa0\x01\n#VolumeEcShardsGenerateBatchResponse\x12M\n\x07results\x18\x01 \x03(\x0b\x32<.volume_server_pb.VolumeEcShardsGenerateBatchResponse.Result\x1a*\n\x06Result\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\r\n\x05\x65rror\x18\x02 \x01(\t\"E\n\x1cVolumeEcShardsRebuildRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\":\n\x1dVolumeEcShardsRebuildResponse\x12\x19\n\x11rebuilt_shard_ids\x18\x01 \x03(\r\"K\n!VolumeEcShardsRebuildBatchRequest\x12\x12\n\nvolume_ids\x18\x01 \x03(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\"\xb9\x01\n\"VolumeEcShardsRebuildBatchResponse\x12L\n\x07results\x18\x01 \x03(\x0b\x32;.volume_server_pb.VolumeEcShardsRebuildBatchResponse.Result\x1a\x45\n\x06Result\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x19\n\x11rebuilt_shard_ids\x18\x02 \x03(\r\x12\r\n\x05\x65rror\x18\x03 \x01(\t\"\xb4\x01\n\x19VolumeEcShardsCopyRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tshard_ids\x18\x03 \x03(\r\x12\x15\n\rcopy_ecx_file\x18\x04 \x01(\x08\x12\x18\n\x10source_data_node\x18\x05 \x01(\t\x12\x15\n\rcopy_ecj_file\x18\x06 \x01(\x08\x12\x15\n\rcopy_vif_file\x18\x07 \x01(\x08\"\x1c\n\x1aVolumeEcShardsCopyResponse\"W\n\x1bVolumeEcShardsDeleteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tshard_ids\x18\x03 \x03(\r\"\x1e\n\x1cVolumeEcShardsDeleteResponse\"V\n\x1aVolumeEcShardsMountRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x11\n\tshard_ids\x18\x03 \x03(\r\"\x1d\n\x1bVolumeEcShardsMountResponse\"D\n\x1cVolumeEcShardsUnmountRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x11\n\tshard_ids\x18\x02 \x03(\r\"\x1f\n\x1dVolumeEcShardsUnmountResponse\"o\n\x18VolumeEcShardReadRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x10\n\x08shard_id\x18\x02 \x01(\r\x12\x0e\n\x06offset\x18\x03 \x01(\x03\x12\x0c\n\x04size\x18\x04 \x01(\x03\x12\x10\n\x08\x66ile_key\x18\x05 \x01(\x04\"=\n\x19VolumeEcShardReadResponse\x12\x0c\n\x04\x64\x61ta\x18\x01 \x01(\x0c\x12\x12\n\nis_deleted\x18\x02 \x01(\x08\"F\n\x1dVolumeEcShardsToVolumeRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\" \n\x1eVolumeEcShardsToVolumeResponse\"e\n\x19VolumeEcBlobDeleteRequest\x12\x11\n\tvolume_id\x18\x01 \x01(\r\x12\x12\n\ncollection\x18\x02 \x01(\t\x12\x10\n\x08\x66ile_key\x18\x03 \x01(\x04\x12\x0f\n\x07version\x18\x04 \x01(\r\"\x1c\n\x1aVolumeEcBlobDeleteResponse2\x8b\x1a\n\x0cVolumeServer\x12\x65\n\x0e\x41llocateVolume\x12\'.volume_server_pb.AllocateVolumeRequest\x1a(.volume_server_pb.AllocateVolumeResponse\"\x00\x12_\n\x0cVolumeDelete\x12%.volume_server_pb.VolumeDeleteRequest\x1a&.volume_server_pb.VolumeDeleteResponse\"\x00\x12q\n\x12VolumeMarkReadonly\x12+.volume_server_pb.VolumeMarkReadonlyRequest\x1a,.volume_server_pb.VolumeMarkReadonlyResponse\"\x00\x12q\n\x12VolumeMarkWritable\x12+.volume_server_pb.VolumeMarkWritableRequest\x1a,.volume_server_pb.VolumeMarkWritableResponse\"\x00\x12_\n\x0cVolumeStatus\x12%.volume_server_pb.VolumeStatusRequest\x1a&.volume_server_pb.VolumeStatusResponse\"\x00\x12h\n\x0fVolumeConfigure\x12(.volume_server_pb.VolumeConfigureRequest\x1a).volume_server_pb.VolumeConfigureResponse\"\x00\x12\\\n\x0bVolumeMount\x12$.volume_server_pb.VolumeMountRequest\x1a%.volume_server_pb.VolumeMountResponse\"\x00\x12\x62\n\rVolumeUnmount\x12&.volume_server_pb.VolumeUnmountRequest\x1a\'.volume_server_pb.VolumeUnmountResponse\"\x00\x12U\n\x08\x43opyFile\x12!.volume_server_pb.CopyFileRequest\x1a\".volume_server_pb.CopyFileResponse\"\x00\x30\x01\x12Y\n\nVolumeCopy\x12#.volume_server_pb.VolumeCopyRequest\x1a$.volume_server_pb.VolumeCopyResponse\"\x00\x12}\n\x16VolumeEcShardsGenerate\x12/.volume_server_pb.VolumeEcShardsGenerateRequest\x1a\x30.volume_server_pb.VolumeEcShardsGenerateResponse\"\x00\x12\x8c\x01\n\x1bVolumeEcShardsGenerateBatch\x12\x34.volume_server_pb.VolumeEcShardsGenerateBatchRequest\x1a\x35.volume_server_pb.VolumeEcShardsGenerateBatchResponse\"\x00\x12z\n\x15VolumeEcShardsRebuild\x12..volume_server_pb.VolumeEcShardsRebuildRequest\x1a/.volume_server_pb.VolumeEcShardsRebuildResponse\"\x00\x12\x89\x01\n\x1aVolumeEcShardsRebuildBatch\x12\x33.volume_server_pb.VolumeEcShardsRebuildBatchRequest\x1a\x34.volume_server_pb.VolumeEcShardsRebuildBatchResponse\"\x00\x12q\n\x12VolumeEcShardsCopy\x12+.volume_server_pb.VolumeEcShardsCopyRequest\x1a,.volume_server_pb.VolumeEcShardsCopyResponse\"\x00\x12w\n\x14VolumeEcShardsDelete\x12-.volume_server_pb.VolumeEcShardsDeleteRequest\x1a..volume_server_pb.VolumeEcShardsDeleteResponse\"\x00\x12t\n\x13VolumeEcShardsMount\x12,.volume_server_pb.VolumeEcShardsMountRequest\x1a-.volume_server_pb.VolumeEcShardsMountResponse\"\x00\x12z\n\x15VolumeEcShardsUnmount\x12..volume_server_pb.VolumeEcShardsUnmountRequest\x1a/.volume_server_pb.VolumeEcShardsUnmountResponse\"\x00\x12p\n\x11VolumeEcShardRead\x12*.volume_server_pb.VolumeEcShardReadRequest\x1a+.volume_server_pb.VolumeEcShardReadResponse\"\x00\x30\x01\x12}\n\x16VolumeEcShardsToVolume\x12/.volume_server_pb.VolumeEcShardsToVolumeRequest\x1a\x30.volume_server_pb.VolumeEcShardsToVolumeResponse\"\x00\x12q\n\x12VolumeEcBlobDelete\x12+.volume_server_pb.VolumeEcBlobDeleteRequest\x1a,.volume_server_pb.VolumeEcBlobDeleteResponse\"\x00\x12\x65\n\x0eReadNeedleBlob\x12\'.volume_server_pb.ReadNeedleBlobRequest\x1a(.volume_server_pb.ReadNeedleBlobResponse\"\x00\x12h\n\x0fWriteNeedleBlob\x12(.volume_server_pb.WriteNeedleBlobRequest\x1a).volume_server_pb.WriteNeedleBlobResponse\"\x00\x12n\n\x11VacuumVolumeCheck\x12*.volume_server_pb.VacuumVolumeCheckRequest\x1a+.volume_server_pb.VacuumVolumeCheckResponse\"\x00\x12t\n\x13VacuumVolumeCompact\x12,.volume_server_pb.VacuumVolumeCompactRequest\x1a-.volume_server_pb.VacuumVolumeCompactResponse\"\x00\x12q\n\x12VacuumVolumeCommit\x12+.volume_server_pb.VacuumVolumeCommitRequest\x1a,.volume_server_pb.VacuumVolumeCommitResponse\"\x00\x12t\n\x13VacuumVolumeCleanup\x12,.volume_server_pb.VacuumVolumeCleanupRequest\x1a-.volume_server_pb.VacuumVolumeCleanupResponse\"\x00\x12\x86\x01\n\x19VolumeTierMoveDatToRemote\x12\x32.volume_server_pb.VolumeTierMoveDatToRemoteRequest\x1a\x33.volume_server_pb.VolumeTierMoveDatToRemoteResponse\"\x00\x12\x8c\x01\n\x1bVolumeTierMoveDatFromRemote\x12\x34.volume_server_pb.VolumeTierMoveDatFromRemoteRequest\x1a\x35.volume_server_pb.VolumeTierMoveDatFromRemoteResponse\"\x00\x62\x06proto3')
 
 _builder.BuildMessageAndEnumDescriptors(DESCRIPTOR, globals())
 _builder.BuildTopDescriptorsAndMessages(DESCRIPTOR, 'volume_server_pb2', globals())
@@ -106,34 +106,40 @@ if _descriptor._USE_C_DESCRIPTORS == False:
   _VOLUMEECSHARDSREBUILDREQUEST._serialized_end=2873
   _VOLUMEECSHARDSREBUILDRESPONSE._serialized_start=2875
   _VOLUMEECSHARDSREBUILDRESPONSE._serialized_end=2933
-  _VOLUMEECSHARDSCOPYREQUEST._serialized_start=2936
-  _VOLUMEECSHARDSCOPYREQUEST._serialized_end=3116
-  _VOLUMEECSHARDSCOPYRESPONSE._serialized_start=3118
-  _VOLUMEECSHARDSCOPYRESPONSE._serialized_end=3146
-  _VOLUMEECSHARDSDELETEREQUEST._serialized_start=3148
-  _VOLUMEECSHARDSDELETEREQUEST._serialized_end=3235
-  _VOLUMEECSHARDSDELETERESPONSE._serialized_start=3237
-  _VOLUMEECSHARDSDELETERESPONSE._serialized_end=3267
-  _VOLUMEECSHARDSMOUNTREQUEST._serialized_start=3269
-  _VOLUMEECSHARDSMOUNTREQUEST._serialized_end=3355
-  _VOLUMEECSHARDSMOUNTRESPONSE._serialized_start=3357
-  _VOLUMEECSHARDSMOUNTRESPONSE._serialized_end=3386
-  _VOLUMEECSHARDSUNMOUNTREQUEST._serialized_start=3388
-  _VOLUMEECSHARDSUNMOUNTREQUEST._serialized_end=3456
-  _VOLUMEECSHARDSUNMOUNTRESPONSE._serialized_start=3458
-  _VOLUMEECSHARDSUNMOUNTRESPONSE._serialized_end=3489
-  _VOLUMEECSHARDREADREQUEST._serialized_start=3491
-  _VOLUMEECSHARDREADREQUEST._serialized_end=3602
-  _VOLUMEECSHARDREADRESPONSE._serialized_start=3604
-  _VOLUMEECSHARDREADRESPONSE._serialized_end=3665
-  _VOLUMEECSHARDSTOVOLUMEREQUEST._serialized_start=3667
-  _VOLUMEECSHARDSTOVOLUMEREQUEST._serialized_end=3737
-  _VOLUMEECSHARDSTOVOLUMERESPONSE._serialized_start=3739
-  _VOLUMEECSHARDSTOVOLUMERESPONSE._serialized_end=3771
-  _VOLUMEECBLOBDELETEREQUEST._serialized_start=3773
-  _VOLUMEECBLOBDELETEREQUEST._serialized_end=3874
-  _VOLUMEECBLOBDELETERESPONSE._serialized_start=3876
-  _VOLUMEECBLOBDELETERESPONSE._serialized_end=3904
-  _VOLUMESERVER._serialized_start=3907
-  _VOLUMESERVER._serialized_end=7106
+  _VOLUMEECSHARDSREBUILDBATCHREQUEST._serialized_start=2935
+  _VOLUMEECSHARDSREBUILDBATCHREQUEST._serialized_end=3010
+  _VOLUMEECSHARDSREBUILDBATCHRESPONSE._serialized_start=3013
+  _VOLUMEECSHARDSREBUILDBATCHRESPONSE._serialized_end=3198
+  _VOLUMEECSHARDSREBUILDBATCHRESPONSE_RESULT._serialized_start=3129
+  _VOLUMEECSHARDSREBUILDBATCHRESPONSE_RESULT._serialized_end=3198
+  _VOLUMEECSHARDSCOPYREQUEST._serialized_start=3201
+  _VOLUMEECSHARDSCOPYREQUEST._serialized_end=3381
+  _VOLUMEECSHARDSCOPYRESPONSE._serialized_start=3383
+  _VOLUMEECSHARDSCOPYRESPONSE._serialized_end=3411
+  _VOLUMEECSHARDSDELETEREQUEST._serialized_start=3413
+  _VOLUMEECSHARDSDELETEREQUEST._serialized_end=3500
+  _VOLUMEECSHARDSDELETERESPONSE._serialized_start=3502
+  _VOLUMEECSHARDSDELETERESPONSE._serialized_end=3532
+  _VOLUMEECSHARDSMOUNTREQUEST._serialized_start=3534
+  _VOLUMEECSHARDSMOUNTREQUEST._serialized_end=3620
+  _VOLUMEECSHARDSMOUNTRESPONSE._serialized_start=3622
+  _VOLUMEECSHARDSMOUNTRESPONSE._serialized_end=3651
+  _VOLUMEECSHARDSUNMOUNTREQUEST._serialized_start=3653
+  _VOLUMEECSHARDSUNMOUNTREQUEST._serialized_end=3721
+  _VOLUMEECSHARDSUNMOUNTRESPONSE._serialized_start=3723
+  _VOLUMEECSHARDSUNMOUNTRESPONSE._serialized_end=3754
+  _VOLUMEECSHARDREADREQUEST._serialized_start=3756
+  _VOLUMEECSHARDREADREQUEST._serialized_end=3867
+  _VOLUMEECSHARDREADRESPONSE._serialized_start=3869
+  _VOLUMEECSHARDREADRESPONSE._serialized_end=3930
+  _VOLUMEECSHARDSTOVOLUMEREQUEST._serialized_start=3932
+  _VOLUMEECSHARDSTOVOLUMEREQUEST._serialized_end=4002
+  _VOLUMEECSHARDSTOVOLUMERESPONSE._serialized_start=4004
+  _VOLUMEECSHARDSTOVOLUMERESPONSE._serialized_end=4036
+  _VOLUMEECBLOBDELETEREQUEST._serialized_start=4038
+  _VOLUMEECBLOBDELETEREQUEST._serialized_end=4139
+  _VOLUMEECBLOBDELETERESPONSE._serialized_start=4141
+  _VOLUMEECBLOBDELETERESPONSE._serialized_end=4169
+  _VOLUMESERVER._serialized_start=4172
+  _VOLUMESERVER._serialized_end=7511
 # @@protoc_insertion_point(module_scope)

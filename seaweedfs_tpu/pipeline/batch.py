@@ -213,26 +213,34 @@ def _pool_size(planned, kept: bool) -> tuple[int, int]:
             cfg.pool_buffers or max(4, depth + 2))
 
 
-def _run_packed(planned, fetch, write_fn: Callable, scheme: EcScheme,
-                stats: pipe.PipeStats, publish: bool,
-                pool: pipe.HostBufferPool,
-                span_done: Optional[Callable[[object], None]] = None
-                ) -> None:
+def _packer(fetch: Callable[[object, int, np.ndarray], None],
+            span_done: Optional[Callable[[object], None]] = None):
+    """The batcher's fill for :func:`_run_packed`: every span's bytes
+    from its volume (``fetch(key, offset, out)``), under the span
+    ``pack``."""
+    def fill(seq: int, plan: PackedPlan, view: np.ndarray) -> None:
+        with flight.span("pack", batch=seq) as sp:
+            _fill(plan, view, fetch, span_done)
+            sp.nbytes = plan.nbytes
+    return fill
+
+
+def _run_packed(planned, fill: Callable, write_fn: Callable,
+                compute: Callable, stats: pipe.PipeStats, publish: bool,
+                pool: pipe.HostBufferPool, kind: str = "ec.batch") -> None:
     """Drive :func:`_plan`'s batches through the 3-stage pipeline: the
-    reader packs each into a buffer of ``pool`` (``fetch(key, offset,
-    out)`` fills ``out`` from a volume's bytes), and
-    ``write_fn(plan, batch, parity, release)`` runs on the writer
-    thread and owes one ``release()`` once nothing views ``batch`` any
-    more."""
+    reader lays each into a buffer of ``pool`` (``fill(seq, plan,
+    view)``), the compute stage applies ``compute`` (or the plan's
+    grouped function to several), and ``write_fn(plan, batch, result,
+    release)`` runs on the writer thread and owes one ``release()``
+    once nothing views ``batch`` any more."""
     plans, multi, group = planned
 
     def batches():
         for seq, plan in enumerate(plans):
             buf = pool.acquire()
             view = buf[:plan.nbytes]
-            with flight.span("pack", batch=seq) as sp:
-                _fill(plan, view, fetch, span_done)
-                sp.nbytes = plan.nbytes
+            fill(seq, plan, view)
             yield (encode_mod._BatchMeta(plan, buf),
                    view.reshape(plan.shape))
 
@@ -250,10 +258,10 @@ def _run_packed(planned, fetch, write_fn: Callable, scheme: EcScheme,
             meta.submitted = True
             pool.release(meta.buf)
 
-    pipe.run_pipeline(batches(), _pick_encode_fn(scheme), write,
+    pipe.run_pipeline(batches(), compute, write,
                       encode_multi_fn=multi, group=group,
                       recycle_fn=recycle,
-                      stats=stats, kind="ec.batch", publish=publish)
+                      stats=stats, kind=kind, publish=publish)
 
 
 def encode_packed(sources: Iterable[tuple[object, np.ndarray]],
@@ -283,8 +291,8 @@ def encode_packed(sources: Iterable[tuple[object, np.ndarray]],
 
     planned = _plan(((key, a.size) for key, a in arrays.items()),
                     scheme, max_batch_bytes)
-    _run_packed(planned, _array_fetch(arrays), write, scheme,
-                pipe.PipeStats(), publish=True,
+    _run_packed(planned, _packer(_array_fetch(arrays)), write,
+                _pick_encode_fn(scheme), pipe.PipeStats(), publish=True,
                 pool=pipe.HostBufferPool(*_pool_size(planned, kept=False)))
     return sum(p.nbytes for p in planned[0])
 
@@ -427,8 +435,9 @@ def encode_volumes(bases: Sequence[str | Path],
         # the pool is out until the last write that views it retires
         with pipe.lend_pool(pools, *_pool_size(
                 planned, kept=pools is not None)) as pool:
-            _run_packed(planned, fetch, write, scheme, st, publish=False,
-                        pool=pool, span_done=span_read)
+            _run_packed(planned, _packer(fetch, span_read), write,
+                        _pick_encode_fn(scheme), st, publish=False,
+                        pool=pool)
             writer.close()
     except BaseException:
         writer.abort()

@@ -410,6 +410,13 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            # the coalescing batcher's runs alone (pipeline/batch.py)
            "batch_volumes": 0, "batch_rows": 0, "batch_row_slots": 0,
            "batch_launches": 0,
+           # and the packed reconstruct's (pipeline/rebuild.py
+           # rebuild_volumes), folded once per batch of repairs: the
+           # same four, and the distinct loss patterns it met (a batch
+           # never mixes two)
+           "rebuild_batch_volumes": 0, "rebuild_batch_rows": 0,
+           "rebuild_batch_row_slots": 0, "rebuild_batch_launches": 0,
+           "rebuild_batch_patterns": 0,
            # every HostBufferPool.acquire, and those of a buffer never
            # lent before (its pages are faulted in by the fill)
            "pool_acquires": 0, "pool_fresh_acquires": 0,
@@ -549,7 +556,9 @@ def debug_payload() -> dict:
     ``groups`` = slabs per dispatch; ``group_ready_bytes`` /
     ``group_ready_seconds`` = the rate a dispatch's inputs crossed at,
     where a writer waited for them), the batcher's
-    ``batch_*`` counts, ``pool_acquires`` / ``pool_fresh_acquires``
+    ``batch_*`` counts and the packed reconstruct's ``rebuild_batch_*``
+    (volumes, rows, row slots, dispatches, loss patterns; once per
+    ``VolumeEcShardsRebuildBatch``), ``pool_acquires`` / ``pool_fresh_acquires``
     (every buffer a :class:`HostBufferPool` lent, and those it had
     never lent before), the stage spans' seconds (``compute`` =
     dispatch + sync; ``sync`` = ``sync_ready``, the writer's wait for a

@@ -655,7 +655,10 @@ def cmd_ec_rebuild(env: ClusterEnv, argv: list[str]) -> None:
     drops the fetched copies. The server, which reads the geometry from
     the ``.vif``, says which shards were missing; a volume it refuses
     as unrepairable (fewer than ``data_shards`` survive) is reported
-    and the walk goes on."""
+    and the walk goes on. A walk's volumes that one server rebuilds go
+    to it as ONE ``VolumeEcShardsRebuildBatch`` per collection, whose
+    restores share device batches; a group of one, and ``-volumeId``,
+    keep ``VolumeEcShardsRebuild``."""
     p = _parser("ec.rebuild")
     p.description = (
         "Restore the EC shards that no server holds. The rebuilder of a "
@@ -666,11 +669,18 @@ def cmd_ec_rebuild(env: ClusterEnv, argv: list[str]) -> None:
         "nothing of the volume and surviving shards from their holders "
         "until data_shards are local (prepareDataToRecover), restores "
         "and mounts the lost ones (generateMissingShards, "
-        "mountEcShards), and deletes the fetched copies.")
+        "mountEcShards), and deletes the fetched copies. A walk's "
+        "volumes that one server rebuilds go to it as one batch per "
+        "collection.")
     p.add_argument("-volumeId", type=int, default=0,
                    help="this volume only (default: every EC volume)")
     p.add_argument("-collection", default="",
                    help="only volumes of this collection")
+    p.add_argument("-force", action="store_true",
+                   help="apply the changes, as the maintenance script's "
+                        "'ec.rebuild -force' asks; upstream treats a run "
+                        "without it as a dry run, this shell applies "
+                        "them either way")
     args = p.parse_args(argv)
     with flight.span("step_locate", trace=True):
         nodes = env.collect_ec_nodes()
@@ -684,7 +694,8 @@ def cmd_ec_rebuild(env: ClusterEnv, argv: list[str]) -> None:
             present.setdefault(vid, set()).update(sids)
             col_of.setdefault(vid, n.collections.get(vid, ""))
     todo = [args.volumeId] if args.volumeId else sorted(present)
-    failures = 0
+    # (rebuilder url, collection) -> its volumes, in the walk's order
+    groups: dict[tuple[str, str], list[int]] = {}
     for vid in todo:
         have = present.get(vid, set())
         if not have:
@@ -697,29 +708,51 @@ def cmd_ec_rebuild(env: ClusterEnv, argv: list[str]) -> None:
         # rebuilder server is authoritative about which shards are
         # missing — never guess totals from shard ids here (a (12,4)
         # volume would silently skip, a (6,3) one would churn).
-        rebuilder = pick_rebuilder(nodes, vid)
-        try:
-            resp = env.volume(rebuilder.url).VolumeEcShardsRebuild(
-                volume_server_pb2.VolumeEcShardsRebuildRequest(
-                    volume_id=vid, collection=col))
-        except Exception as e:
-            if "unrepairable" in str(e):
+        groups.setdefault((pick_rebuilder(nodes, vid).url, col),
+                          []).append(vid)
+    outcome: dict[int, tuple] = {}
+    for (url, col), vids in groups.items():
+        outcome.update(_rebuild_on(env, url, col, vids))
+    failures = 0
+    for (url, _col), vids in groups.items():
+        for vid in vids:
+            rebuilt, error = outcome[vid]
+            if error and "unrepairable" in error:
                 env.println(f"ec.rebuild volume {vid}: unrepairable with "
-                            f"{len(have)} shards ({rebuilder.url})")
-                continue
-            # One broken volume must not abort the whole sweep.
-            env.println(f"ec.rebuild volume {vid}: failed on "
-                        f"{rebuilder.url}: {e}")
-            failures += 1
-            continue
-        if resp.rebuilt_shard_ids:
-            env.println(f"ec.rebuild volume {vid}: rebuilt "
-                        f"{list(resp.rebuilt_shard_ids)} on "
-                        f"{rebuilder.url}")
-        else:
-            env.println(f"ec.rebuild volume {vid}: all shards present")
+                            f"{len(present[vid])} shards ({url})")
+            elif error:
+                # One broken volume must not abort the whole sweep.
+                env.println(f"ec.rebuild volume {vid}: failed on {url}: "
+                            f"{error}")
+                failures += 1
+            elif rebuilt:
+                env.println(f"ec.rebuild volume {vid}: rebuilt {rebuilt} "
+                            f"on {url}")
+            else:
+                env.println(f"ec.rebuild volume {vid}: all shards present")
     if failures:
         raise ShellError(f"ec.rebuild: {failures} volume(s) failed")
+
+
+def _rebuild_on(env: ClusterEnv, url: str, col: str,
+                vids: list[int]) -> dict[int, tuple]:
+    """vid -> (rebuilt shard ids, error or "") of ``vids`` rebuilt on
+    ``url``: one ``VolumeEcShardsRebuildBatch`` for two or more, the
+    one-volume rpc for one. A call that fails fails each volume it
+    named."""
+    try:
+        if len(vids) == 1:
+            resp = env.volume(url).VolumeEcShardsRebuild(
+                volume_server_pb2.VolumeEcShardsRebuildRequest(
+                    volume_id=vids[0], collection=col))
+            return {vids[0]: (list(resp.rebuilt_shard_ids), "")}
+        resp = env.volume(url).VolumeEcShardsRebuildBatch(
+            volume_server_pb2.VolumeEcShardsRebuildBatchRequest(
+                volume_ids=vids, collection=col))
+    except Exception as e:  # noqa: BLE001 — reported per volume; the walk goes on
+        return {vid: ([], str(e) or type(e).__name__) for vid in vids}
+    return {r.volume_id: (list(r.rebuilt_shard_ids), r.error)
+            for r in resp.results}
 
 
 @cluster_command("ec.decode")

@@ -103,7 +103,12 @@ class VolumeInfo:
         p = vif_path(base)
         if not p.exists():
             return cls()
-        doc = json.loads(p.read_text())
+        return cls.parse(p.read_bytes())
+
+    @classmethod
+    def parse(cls, raw: bytes | str) -> "VolumeInfo":
+        """A ``.vif``'s bytes, wherever they were read from."""
+        doc = json.loads(raw)
         return cls(version=int(doc.get("version", 3)),
                    replication=doc.get("replication", ""),
                    ttl=doc.get("ttl", ""),

@@ -516,16 +516,24 @@ class Store:
             if m is None:
                 continue
             present = reality.get(key, set())
-            gone = sorted(set(m.shard_ids) - present)
-            if not gone:
+            if not set(m.shard_ids) - present:
                 continue
-            glog.warning(
-                "volume %d: ec shard file(s) %s vanished from disk; "
-                "unmounting them", key[1], gone)
+            col, vid = key
             with self._lock:
-                m.shard_ids.intersection_update(present)
+                # the scan is older than the mount table: a shard that
+                # landed and was mounted after it (a copy of the spread
+                # or of a rebuild) is on disk, and stays mounted
+                gone = sorted(i for i in m.shard_ids - present
+                              if not any(ec_files.shard_path(
+                                  loc.base_for(vid, col), i).exists()
+                                         for loc in self.locations))
+                m.shard_ids.difference_update(gone)
                 if not m.shard_ids:
                     self.ec_mounts.pop(key, None)
+            if gone:
+                glog.warning(
+                    "volume %d: ec shard file(s) %s vanished from disk; "
+                    "unmounting them", vid, gone)
 
     def status(self) -> dict:
         """Snapshot for heartbeats (§3.4): normal volumes + EC shard bits,

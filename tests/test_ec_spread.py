@@ -76,7 +76,7 @@ def small_rows():
         # the .vif carries the shard counts and not the block sizes: a
         # reader of these volumes has to be told the test's
         mp.setattr(volume_server_mod, "_scheme_from_vif",
-                   lambda base: SCHEME)
+                   lambda base, info=None: SCHEME)
         yield
     faults.clear()
 

@@ -259,3 +259,30 @@ def test_vanished_shard_file_leaves_the_masters_view_at_the_pulse(node):
     assert 5 not in m.shard_ids
     vs.heartbeat_now()
     assert 5 not in master.topology.lookup_ec_volume(300)
+
+
+def test_a_shard_mounted_while_the_pulse_scans_stays_mounted(node,
+                                                             monkeypatch):
+    """A copy that lands and is mounted after the pulse listed the
+    directory (a peer of the spread, pulling while its pulse runs) is
+    on disk: the stale listing must not unmount it, and the master keeps
+    seeing it. A file that really vanished in the same pulse goes."""
+    master, vs = node
+    _mount_synthetic_ec_volumes(vs.store, [400])
+    m = vs.store.ec_mounts[("", 400)]
+    ec_files.shard_path(m.base, 2).unlink()
+    real_scan = DiskLocation.scan_ec_shards
+
+    def scan_then_a_copy_lands(self):
+        listed = list(real_scan(self))
+        if self is vs.store.locations[-1]:
+            _mount_synthetic_ec_volumes(vs.store, [401])
+        return iter(listed)
+    monkeypatch.setattr(DiskLocation, "scan_ec_shards",
+                        scan_then_a_copy_lands)
+    vs._pulse_snapshot()
+    assert sorted(vs.store.ec_mounts[("", 401)].shard_ids) == ALL_SHARDS
+    assert 2 not in m.shard_ids and len(m.shard_ids) == 13
+    vs.heartbeat_now()
+    assert sorted(master.topology.lookup_ec_volume(401)) == ALL_SHARDS
+    assert 2 not in master.topology.lookup_ec_volume(400)
