@@ -978,153 +978,90 @@ class _VolumeServicer:
 
     @_ec_step("rebuild")
     def VolumeEcShardsRebuild(self, request, context):
-        """§3.5, the server half of ``ec.rebuild``: this server is the
-        rebuilder the shell chose (upstream's ``rebuildOneEcVolume``).
-        It brings the index files it lacks to its own disk, restores
-        the shards that no server holds, and keeps nothing else:
-
-        - where it holds nothing of the volume (an empty replacement),
-          ``.vif``, ``.ecx`` and ``.ecj`` (may be absent) come first,
-          from a holder, into the directory a new shard would get,
-          past the ``[storage] fsync`` barrier: they stay (upstream's
-          ``prepareDataToRecover`` with ``copyEcxFile``), and the
-          geometry is read from the ``.vif`` fetched;
-        - surviving shards it lacks, until ``data_shards`` survivors
-          are at hand, never become files here: each is one stream off
-          its holder (``_SurvivorFeed``), opened once and read chunk by
-          chunk into the pipeline run's pooled buffers, every source
-          server's streams on a thread of its own;
-        - ``generateMissingShards``: the pipeline run, which takes a
-          survivor from its file or from its stream, so the fetch of a
-          chunk runs beside the restore of the one before; then the
-          mount and one nudge of the master.
-
-        ``step_rebuild_fetch`` counts the fetch's wall: the first index
-        file asked to the last survivor byte landed, the part after the
-        streams were open added when they are closed.
-
-        All or nothing: a stream that cannot be opened on any holder,
-        ends short or has another length than the survivors fails the
-        call, and every restored file and index file this call placed
-        is removed again. A volume with fewer survivors than
-        ``data_shards`` is refused as unrepairable before any shard
-        moves."""
-        vs = self.vs
-        vid, col = request.volume_id, request.collection
-        resp = volume_server_pb2.VolumeEcShardsRebuildResponse()
-        # shard id -> the OTHER servers that hold it. The master's
-        # (briefly cached) map may still list this server for a shard
-        # just deleted here; the local disk is the authority on what
-        # this server holds.
-        remote = {sid: others
-                  for sid, urls in vs.ec_shard_table(vid).items()
-                  if (others := [u for u in urls if u != vs.url])}
-        base = vs.store.ec_base(vid, col)
-        plan = None if base is None else _RebuildPlan(base, remote)
-        if plan is not None and not plan.missing:
-            return resp
-        if plan is None and not remote:
-            raise StoreError(f"no ec files for volume {vid} here, and "
-                             f"no other server holds a shard of it")
-        placed: list[Path] = []
-        try:
-            # holds copy_recv / copy_commit, so it is no leaf
-            with flight_mod.span("step_rebuild_fetch", leaf=False,
-                                 trace=True):
-                if plan is None:
-                    base = _dest_base(vs, vid, col)
-                    plan = self._fetch_index_files(
-                        base, vid, col, remote, placed)
-                    if not plan.missing:
-                        for p in placed:
-                            p.unlink()
-                        return resp
-                # every survivor local: nothing to feed, no thread
-                feed = _SurvivorFeed(vs, vid, col, plan.fetch) \
-                    if plan.fetch else None
-            rebuilt = rebuild_mod.rebuild_ec_files(
-                base, plan.scheme, wanted=plan.missing,
-                pools=self._ec_pools, remote=feed)
-        except BaseException:
-            # as empty as it was: nothing of a failed call may look
-            # like a shard, or like a volume this server holds
-            for p in placed + ([] if plan is None else [
-                    ec_files.shard_path(base, sid)
-                    for sid in plan.missing]):
-                p.unlink(missing_ok=True)
-            raise
-        with flight_mod.span("step_store_mount", trace=True):
-            vs.store.mount_ec_shards(vid, rebuilt, col)
-        vs.heartbeat_now()
-        resp.rebuilt_shard_ids.extend(rebuilt)
-        return resp
-
-    def _pull(self, url: str, vid: int, col: str, ext: str, dest: Path,
-              ignore_missing: bool = False, company: bool = True) -> int:
-        """One index file of the rebuild's fetch, counted: its bytes,
-        the file, and (``company``) the seconds its stream had company.
-        A batch's index files come beside each other's commits, which
-        are no stream: they are not counted there."""
-        with self.vs.fetch_streams.stream() if company \
-                else contextlib.nullcontext():
-            n = _copy_remote_file(self.vs, url, vid, col, ext, dest,
-                                  ignore_missing=ignore_missing)
-        if n:
-            pipe_mod.fold(rebuild_fetch_bytes=n, rebuild_fetch_files=1)
-        return n
-
-    def _fetch_index_files(self, base: Path, vid: int, col: str,
-                           remote: dict, placed: list) -> "_RebuildPlan":
-        """A rebuilder that holds nothing of the volume: ``.vif`` first
-        (the geometry, and with it whether anything is missing and
-        whether enough survives), then ``.ecx`` and ``.ecj``, all from
-        the holder of the lowest surviving shard, durably: they stay.
-        What it placed is appended to ``placed`` as it lands."""
-        src = remote[min(remote)][0]
-        with flight_mod.span("step_rebuild_fetch_index", leaf=False,
-                             trace=True):
-            vif = ec_files.vif_path(base)
-            self._pull(src, vid, col, ".vif", vif)
-            placed.append(vif)
-            plan = _RebuildPlan(base, remote)
-            if plan.missing:
-                for ext, dest, optional in (
-                        (".ecx", ec_files.ecx_path(base), False),
-                        # no post-seal deletes yet: no journal
-                        (".ecj", ec_files.ecj_path(base), True)):
-                    if self._pull(src, vid, col, ext, dest,
-                                  ignore_missing=optional):
-                        placed.append(dest)
-        return plan
+        """§3.5, the server half of ``ec.rebuild -volumeId``: this server
+        is the rebuilder the shell chose (upstream's
+        ``rebuildOneEcVolume``), and the volume is repaired as a batch
+        of one (:meth:`_rebuild`). All or nothing: what failed the
+        volume is raised, and nothing this call placed is left; a
+        volume with nothing missing answers an empty response."""
+        vid = request.volume_id
+        # no barrier behind the restored files: this rpc has never had
+        # one (ROADMAP A0)
+        rebuilt, errors = self._rebuild([vid], request.collection,
+                                        durable=False)
+        if vid in errors:
+            raise errors[vid]
+        return volume_server_pb2.VolumeEcShardsRebuildResponse(
+            rebuilt_shard_ids=rebuilt[vid])
 
     @_ec_step("rebuild")
     def VolumeEcShardsRebuildBatch(self, request, context):
         """The server half of an ``ec.rebuild`` walk: every named volume
         of the collection that this server rebuilds, repaired by one
-        call, as :meth:`VolumeEcShardsRebuild` repairs one —
-        and their restores coalesced into shared device batches, one
-        run per loss pattern (``rebuild.rebuild_volumes``):
+        call (:meth:`_rebuild`), each restored file past the
+        ``[storage] fsync`` barrier; the response names what became of
+        each volume, in request order."""
+        rebuilt, errors = self._rebuild(list(request.volume_ids),
+                                        request.collection, durable=True)
+        resp = volume_server_pb2.VolumeEcShardsRebuildBatchResponse()
+        for vid in request.volume_ids:
+            e = errors.get(vid)
+            resp.results.add(volume_id=vid,
+                             rebuilt_shard_ids=rebuilt.get(vid, []),
+                             error=f"{type(e).__name__}: {e}" if e else "")
+        return resp
+
+    def _pull(self, url: str, vid: int, col: str, ext: str, dest: Path,
+              ignore_missing: bool = False) -> int:
+        """One index file of the rebuild's fetch, counted: its bytes
+        and the file. Index files come beside each other's commits,
+        which are no stream: their seconds are not counted among the
+        streams' company."""
+        n = _copy_remote_file(self.vs, url, vid, col, ext, dest,
+                              ignore_missing=ignore_missing)
+        if n:
+            pipe_mod.fold(rebuild_fetch_bytes=n, rebuild_fetch_files=1)
+        return n
+
+    def _rebuild(self, vids: list, col: str, durable: bool
+                 ) -> tuple[dict, dict]:
+        """Repair ``vids`` here (upstream's ``rebuildOneEcVolume``, for
+        each): the index files this server lacks are fetched, the
+        shards that no server holds are restored by one packed
+        reconstruct (``rebuild.rebuild_volumes``), their restores
+        coalesced into shared device batches, one run per loss pattern:
 
         - per volume, the plan: where this server holds nothing of it,
           the ``.vif`` read into memory from a holder decides the
           geometry and what is missing; a volume with nothing missing
           puts nothing on disk here; the others get their ``.vif``,
           ``.ecx`` and ``.ecj`` (may be absent) past the ``[storage]
-          fsync`` barrier (``step_rebuild_fetch_index``);
-        - the surviving shards it lacks come as streams, never files
-          (``_BatchSurvivorFeed``: a thread a source server, walking
-          the volumes in the order the slabs ask for them);
-        - the packed run writes each volume's restored files and
-          passes them through the barrier; then each volume is
-          mounted, and ONE nudge of the master goes for them all.
+          fsync`` barrier: they stay (upstream's
+          ``prepareDataToRecover`` with ``copyEcxFile``;
+          ``step_rebuild_fetch_index``);
+        - surviving shards it lacks, until ``data_shards`` survivors
+          are at hand, never become files here: each is one stream off
+          its holder (``_SurvivorFeed``: a thread a source server,
+          walking the volumes in the order the slabs ask for them), read
+          into the run's pooled buffers, so the fetch of a slab runs
+          beside the restore of the one before;
+        - the run writes each volume's restored files, and where
+          ``durable`` passes them through the barrier; then each volume
+          is mounted, and ONE nudge of the master goes for them all.
 
-        ``step_rebuild_fetch`` counts the fetch's wall, as for one
-        volume. Each volume ends restored and mounted, or with nothing
-        this call placed left of it (an unrepairable one before any
-        shard moves); the response names which, in request order."""
-        vs, col = self.vs, request.collection
-        vids = list(request.volume_ids)
-        errors: dict[int, str] = {}
+        ``step_rebuild_fetch`` counts the fetch's wall: the first index
+        file asked to the last survivor byte landed, the part after the
+        run started added when the feed is closed.
+
+        Returns (volume -> shard ids rebuilt, volume -> the exception
+        that left it as it was). Each volume ends restored and mounted,
+        or with nothing this call placed left of it: a stream that
+        cannot be opened on any holder, ends short or has another
+        length than the survivors fails it, and one with fewer
+        survivors than ``data_shards`` is refused as unrepairable before
+        any shard moves."""
+        vs = self.vs
+        errors: dict[int, BaseException] = {}
         rebuilt: dict[int, list] = {}
         placed: dict[int, list] = {vid: [] for vid in vids}
         repairs: list = []
@@ -1135,15 +1072,15 @@ class _VolumeServicer:
 
             def plan_one(vid: int):
                 try:
-                    plan, base, dat_size = self._batch_plan(
+                    plan, base, dat_size = self._plan_volume(
                         vid, col, placed[vid], parent)
                     if not plan.missing:
                         return vid, plan, None
                     return vid, plan, rebuild_mod.plan_repair(
                         vid, base, plan.scheme, plan.missing,
                         [sid for sid, _ in plan.fetch], dat_size)
-                except Exception as e:  # noqa: BLE001 — this volume is left as it was, the batch goes on
-                    return vid, None, f"{type(e).__name__}: {e}"
+                except Exception as e:  # noqa: BLE001 — this volume is left as it was, the others go on
+                    return vid, None, e
             # the volumes' index files at once: each commit is a wait on
             # the file store
             with futures.ThreadPoolExecutor(
@@ -1160,10 +1097,12 @@ class _VolumeServicer:
                     holders.update(((vid, sid), urls)
                                    for sid, urls in plan.fetch
                                    if sid in repair.streamed)
-            feed = _BatchSurvivorFeed(vs, col, holders, {
+            # every survivor local: nothing to feed, no thread
+            feed = _SurvivorFeed(vs, col, holders, {
                 r.key: r.size for r in repairs}) if holders else None
         failed = rebuild_mod.rebuild_volumes(
-            repairs, remote=feed, pools=self._ec_pools) if repairs else {}
+            repairs, remote=feed, pools=self._ec_pools,
+            durable=durable) if repairs else {}
         for r in repairs:
             if r.key not in failed:
                 try:
@@ -1171,31 +1110,30 @@ class _VolumeServicer:
                     rebuilt[r.key] = list(r.missing)
                     continue
                 except Exception as e:  # noqa: BLE001 — this volume is taken back, the others stand
-                    failed[r.key] = f"{type(e).__name__}: {e}"
+                    failed[r.key] = e
                     for i in r.missing:
                         ec_files.shard_path(r.base, i).unlink(missing_ok=True)
             errors[r.key] = failed[r.key]
-        for vid, why in errors.items():
-            glog.warning("rebuild batch: volume %d left as it was: %s", vid,
-                         why)
+        for vid, e in errors.items():
+            glog.warning("rebuild: volume %d left as it was: %s: %s", vid,
+                         type(e).__name__, e)
             for p in placed[vid]:
                 p.unlink(missing_ok=True)
         if any(rebuilt.values()):
             vs.heartbeat_now()
-        resp = volume_server_pb2.VolumeEcShardsRebuildBatchResponse()
-        for vid in request.volume_ids:
-            resp.results.add(volume_id=vid,
-                             rebuilt_shard_ids=rebuilt.get(vid, []),
-                             error=errors.get(vid, ""))
-        return resp
+        return rebuilt, errors
 
-    def _batch_plan(self, vid: int, col: str, placed: list, trace):
-        """(plan, base, the .vif's dat size) of one volume of a batch,
+    def _plan_volume(self, vid: int, col: str, placed: list, trace):
+        """(plan, base, the .vif's dat size) of one volume of a repair,
         on a thread that continues ``trace``. A server that holds
         nothing of the volume reads the holder's ``.vif`` into memory
         first, and only where something is missing puts it and ``.ecx``
         / ``.ecj`` on its disk (appended to ``placed`` as they land)."""
         vs = self.vs
+        # shard id -> the OTHER servers that hold it. The master's
+        # (briefly cached) map may still list this server for a shard
+        # just deleted here; the local disk is the authority on what
+        # this server holds.
         remote = {sid: others
                   for sid, urls in vs.ec_shard_table(vid).items()
                   if (others := [u for u in urls if u != vs.url])}
@@ -1227,15 +1165,15 @@ class _VolumeServicer:
                         (".ecx", ec_files.ecx_path(base), False),
                         (".ecj", ec_files.ecj_path(base), True)):
                     if self._pull(src, vid, col, ext, dest,
-                                  ignore_missing=optional, company=False):
+                                  ignore_missing=optional):
                         placed.append(dest)
         return plan, base, info.dat_file_size
 
     def _read_remote(self, url: str, vid: int, col: str, ext: str) -> bytes:
         """One small file of a volume off ``url`` into memory (a
         ``.vif``), counted as a file of the rebuild's fetch: one
-        ``copy_recv``, its bytes, the file (as a batch's index file, not
-        among the streams whose company is counted)."""
+        ``copy_recv``, its bytes, the file (as an index file, not among
+        the streams whose company is counted)."""
         over_http = tls_mod.installed() is None
         chunks_of = _http_chunks if over_http else _grpc_chunks
         t = _clock()
@@ -1378,7 +1316,7 @@ class _VolumeServicer:
 
 
 class _RebuildPlan:
-    """What one ``VolumeEcShardsRebuild`` has to do, from the ``.vif``
+    """What the repair of one volume has to do, from the ``.vif``
     under ``base``, the local disk and ``remote`` (shard id -> the
     other servers that hold it): ``missing``, the shards no server
     holds, and ``fetch``, the (shard id, holders) of the survivors to
@@ -1727,121 +1665,16 @@ class _SurvivorStream:
 
 
 class _SurvivorChain(threading.Thread):
-    """One source server's survivors of a rebuild, on a thread of its
-    own beneath the rpc's trace (``step_rebuild_fetch_source``): it
-    opens a :class:`_SurvivorStream` for each and says their sizes, then
-    for every chunk asked of it fills its shards' slices in turn and
-    says so. What it says goes to ``done``: a set of sizes, ``None`` for
-    a chunk filled, or the error that ended it."""
-
-    def __init__(self, feed: "_SurvivorFeed", n: int, shards: list):
-        super().__init__(name=f"rebuild-fetch-{n}", daemon=True)
-        self._feed = feed
-        self.shards = shards
-        #: ({shard id: slice}, last) per chunk; None: no more
-        self.todo: queue.SimpleQueue = queue.SimpleQueue()
-        self.done: queue.SimpleQueue = queue.SimpleQueue()
-        #: when this chain's newest byte landed
-        self.landed = 0.0
-
-    def said(self):
-        """What the chain says next, once it does; its error raised."""
-        said = self.done.get()
-        if isinstance(said, BaseException):
-            raise said
-        return said
-
-    def run(self) -> None:
-        feed = self._feed
-        cpu0 = time.thread_time()
-        with flight_mod.span("step_rebuild_fetch_source", leaf=False,
-                             trace=feed.parent), \
-                contextlib.ExitStack() as streams:
-            try:
-                opened = {}
-                for sid in self.shards:
-                    opened[sid] = st = _SurvivorStream(
-                        feed.vs, feed.volume_id, feed.collection, sid,
-                        feed.holders[sid])
-                    streams.callback(st.close)
-                self.done.put({st.size for st in opened.values()}
-                              - {None})
-                while (chunk := self.todo.get()) is not None:
-                    slices, last = chunk
-                    for sid, view in slices.items():
-                        opened[sid].fill(view, last)
-                        self.landed = _clock()
-                    self.done.put(None)
-            except BaseException as e:  # noqa: BLE001 — raised by the reader that waits for this chain
-                self.done.put(e)
-        pipe_mod.fold(copy_recv_cpu_seconds=time.thread_time() - cpu0)
-
-
-class _SurvivorFeed:
-    """The surviving shards a rebuild takes off other servers, as
-    ``rebuild_ec_files`` takes them (its ``RemoteSurvivors``): the
-    (shard id, holders) of ``_RebuildPlan.fetch``, one
-    :class:`_SurvivorChain` per source server (a shard's first holder),
-    all chains filling their slices of the reader's chunk at once. Made
-    inside the handler's ``step_rebuild_fetch``, whose trace the chains
-    continue and whose seconds :meth:`close` lengthens to the moment
-    the last byte landed."""
-
-    def __init__(self, vs: VolumeServer, volume_id: int, collection: str,
-                 fetch: list):
-        self.vs, self.volume_id, self.collection = vs, volume_id, collection
-        self.holders = dict(fetch)
-        self.shards = sorted(self.holders)
-        # what a chain's thread continues the call's trace from
-        self.parent = tracing.outbound_value() or True
-        self._chains: list[_SurvivorChain] = []
-        self._made = _clock()
-
-    def open(self, shards) -> set:
-        by_source: dict[str, list] = {}
-        for sid in shards:
-            by_source.setdefault(self.holders[sid][0], []).append(sid)
-        pipe_mod.count("rebuild_fetch_sources", len(by_source))
-        self._chains = [_SurvivorChain(self, n, sids)
-                        for n, sids in enumerate(by_source.values())]
-        for chain in self._chains:
-            chain.start()
-        return set().union(*(chain.said() for chain in self._chains))
-
-    def fill(self, slices: dict, last: bool):
-        for chain in self._chains:
-            chain.todo.put(({sid: slices[sid] for sid in chain.shards},
-                            last))
-        return self._filled
-
-    def _filled(self) -> None:
-        # every chain runs its chunk to the end or to its own error;
-        # the first error in the plan's order is the chunk's
-        for chain in self._chains:
-            chain.said()
-
-    def close(self) -> None:
-        chains, self._chains = self._chains, []
-        for chain in chains:
-            chain.todo.put(None)
-        for chain in chains:
-            chain.join()
-        landed = max((chain.landed for chain in chains), default=0.0)
-        flight_mod.lengthen("step_rebuild_fetch",
-                            max(0.0, landed - self._made))
-
-
-class _BatchChain(threading.Thread):
-    """One source server's survivors of a batch of rebuilds, on a thread
-    of its own beneath the rpc's trace (``step_rebuild_fetch_source``):
-    for every slab it is asked its pieces of, in order, opening a
-    :class:`_SurvivorStream` at a stream's first piece and closing it
-    behind its last. A piece that fails fails its volume: that stream
-    is closed, the volume's later pieces are passed over, and the chain
-    goes on with the others. ``done`` gets ``None`` per slab, or the
+    """One source server's survivors of a repair, on a thread of its own
+    beneath the rpc's trace (``step_rebuild_fetch_source``):
+    for every slab it is asked its pieces of, it opens the
+    :class:`_SurvivorStream` of each that is not open yet, then fills
+    them in order, closing a stream behind its last piece. A piece that
+    fails fails its volume: that stream is closed, the volume's later
+    pieces are passed over, and the chain goes on with the others. ``done`` gets ``None`` per slab, or the
     error that ended the chain."""
 
-    def __init__(self, feed: "_BatchSurvivorFeed", n: int):
+    def __init__(self, feed: "_SurvivorFeed", n: int):
         super().__init__(name=f"rebuild-fetch-{n}", daemon=True)
         self._feed = feed
         self.todo: queue.SimpleQueue = queue.SimpleQueue()
@@ -1862,6 +1695,11 @@ class _BatchChain(threading.Thread):
                              trace=feed.parent):
             try:
                 while (pieces := self.todo.get()) is not None:
+                    # a slab's streams are opened together, before its
+                    # first piece: each source starts sending while the
+                    # pieces before are read
+                    for vid, sid, _view, _last in pieces:
+                        self._open(opened, vid, sid)
                     for vid, sid, view, last in pieces:
                         self._piece(opened, vid, sid, view, last)
                     self.done.put(None)
@@ -1872,38 +1710,44 @@ class _BatchChain(threading.Thread):
                     st.close()
         pipe_mod.fold(copy_recv_cpu_seconds=time.thread_time() - cpu0)
 
+    def _open(self, opened: dict, vid: int, sid: int) -> None:
+        feed = self._feed
+        if (vid, sid) in opened or feed.has_failed(vid):
+            return
+        try:
+            st = opened[vid, sid] = _SurvivorStream(
+                feed.vs, vid, feed.collection, sid, feed.holders[vid, sid])
+            if st.size is not None and st.size != feed.sizes[vid]:
+                raise VolumeServerError(
+                    f"surviving shard sizes differ: {st.name} is "
+                    f"{st.size} bytes, not {feed.sizes[vid]}")
+        except Exception as e:  # noqa: BLE001 — this volume fails, the chain goes on
+            feed.fail(vid, e)
+
     def _piece(self, opened: dict, vid: int, sid: int, view, last: bool):
         feed = self._feed
-        st = opened.get((vid, sid))
         try:
             if feed.has_failed(vid):
                 # its other streams have nothing to fill any more
                 last = True
             else:
-                if st is None:
-                    st = opened[vid, sid] = _SurvivorStream(
-                        feed.vs, vid, feed.collection, sid,
-                        feed.holders[vid, sid])
-                    if st.size is not None and st.size != feed.sizes[vid]:
-                        raise VolumeServerError(
-                            f"surviving shard sizes differ: {st.name} is "
-                            f"{st.size} bytes, not {feed.sizes[vid]}")
-                st.fill(view, last)
+                opened[vid, sid].fill(view, last)
                 self.landed = _clock()
         except Exception as e:  # noqa: BLE001 — this volume fails, the chain goes on
-            feed.fail(vid, f"{type(e).__name__}: {e}")
+            feed.fail(vid, e)
             last = True
-        if last and st is not None:
+        if last and (vid, sid) in opened:
             opened.pop((vid, sid)).close()
 
 
-class _BatchSurvivorFeed:
-    """The surviving shards a batch of rebuilds takes off other servers,
-    as ``rebuild_volumes`` takes them (its ``StreamedSurvivors``): the
-    holders of each (volume, shard), one :class:`_BatchChain` per source
-    server (a shard's first holder), all chains filling their pieces of
-    a slab at once, each walking the batch's volumes in slab order. At
-    most the streams of the volumes in one slab are open at a time. Made
+class _SurvivorFeed:
+    """The surviving shards a repair takes off other servers, as
+    ``rebuild_volumes`` takes them (its ``StreamedSurvivors``): the
+    holders of each (volume, shard), one :class:`_SurvivorChain` per
+    source server (a shard's first holder), all chains filling their
+    pieces of a slab at once, each walking the volumes in slab order and
+    opening a slab's streams together before its first piece. At most
+    the streams of the volumes in one slab are open at a time. Made
     inside the handler's ``step_rebuild_fetch``, whose trace the chains
     continue and whose seconds :meth:`close` lengthens to the moment the
     last byte landed."""
@@ -1914,18 +1758,18 @@ class _BatchSurvivorFeed:
         self.holders, self.sizes = holders, sizes
         self.parent = tracing.outbound_value() or True
         self._lock = threading.Lock()
-        self._failed: dict[int, str] = {}
+        self._failed: dict[int, BaseException] = {}
         sources: dict[str, int] = {}
         self._chain_of = {key: sources.setdefault(urls[0], len(sources))
                           for key, urls in sorted(holders.items())}
         pipe_mod.count("rebuild_fetch_sources", len(sources))
-        self._chains = [_BatchChain(self, n) for n in range(len(sources))]
+        self._chains = [_SurvivorChain(self, n) for n in range(len(sources))]
         for chain in self._chains:
             chain.start()
         self._made = _clock()
 
     def fill(self, pieces: list):
-        mine: dict[_BatchChain, list] = {}
+        mine: dict[_SurvivorChain, list] = {}
         for piece in pieces:
             chain = self._chains[self._chain_of[piece[:2]]]
             mine.setdefault(chain, []).append(piece)
@@ -1937,7 +1781,7 @@ class _BatchSurvivorFeed:
                 chain.said()
         return filled
 
-    def fail(self, vid: int, why: str) -> None:
+    def fail(self, vid: int, why: BaseException) -> None:
         with self._lock:
             self._failed.setdefault(vid, why)
 

@@ -490,8 +490,9 @@ def _stay_on_host() -> bool:
 
 
 def host_dispatch_group() -> int:
-    """Group width for the host-slab pipelines (ONE policy for encode,
-    the coalescing batcher and rebuild): DISPATCH_GROUP on a
+    """Group width for the host-slab pipelines (ONE policy for encode
+    and the coalescing batcher; a rebuild launches one slab a
+    dispatch): DISPATCH_GROUP on a
     single-device accelerator backend, else 1 — multi-chip paths
     mesh-shard each batch instead (parallel/mesh), and CPU backends
     never take the word-form device path."""

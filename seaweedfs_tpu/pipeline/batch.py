@@ -185,7 +185,7 @@ def _pick_encode_fn(scheme: EcScheme):
 def _plan(sizes: Iterable[tuple[object, int]], scheme: EcScheme,
           max_batch_bytes: Optional[int]):
     """(plans, encode_multi_fn, group): the shared batches under the
-    one grouping policy of the encode / batcher / rebuild pipelines
+    one grouping policy of the encode and batcher pipelines
     (pipe.pick_grouped_dispatch). On a single accelerator runs of
     same-shaped coalesced batches share one device call (the buckets
     emit equal shapes until the tail, so steady state groups fully)

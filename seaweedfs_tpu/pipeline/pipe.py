@@ -147,8 +147,8 @@ def configure_from(conf: dict) -> None:
 
 def pick_grouped_dispatch(multi_fn, max_bytes: int,
                           cap_bytes: Optional[int] = None):
-    """ONE grouping policy for the encode / coalescing-batcher /
-    rebuild pipelines: returns (multi_fn or None, group, max_bytes).
+    """ONE grouping policy for the encode and coalescing-batcher
+    pipelines: returns (multi_fn or None, group, max_bytes).
 
     Group width comes from rs_jax.host_dispatch_group() — >1 only on a
     single-device accelerator (multi-chip paths mesh-shard each batch
@@ -411,9 +411,9 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            "batch_volumes": 0, "batch_rows": 0, "batch_row_slots": 0,
            "batch_launches": 0,
            # and the packed reconstruct's (pipeline/rebuild.py
-           # rebuild_volumes), folded once per batch of repairs: the
-           # same four, and the distinct loss patterns it met (a batch
-           # never mixes two)
+           # rebuild_volumes), folded once per repair run, one volume's
+           # included: the same four, and the distinct loss patterns it
+           # met (a slab never mixes two)
            "rebuild_batch_volumes": 0, "rebuild_batch_rows": 0,
            "rebuild_batch_row_slots": 0, "rebuild_batch_launches": 0,
            "rebuild_batch_patterns": 0,
@@ -427,12 +427,12 @@ _TOTALS = {"runs": 0, "batches": 0, "groups": 0, "bytes_in": 0,
            # those of them a rebuild's fetch pulled
            "copy_file_bytes": 0, "copy_recv_bytes": 0,
            "rebuild_fetch_bytes": 0,
-           # the same fetch (VolumeEcShardsRebuild on a rebuilder that
-           # lacks survivors): files pulled, index files included and
-           # a streamed survivor counted as one; the source servers it
+           # the same fetch (a rebuild rpc on a rebuilder that lacks
+           # survivors): files pulled, index files included and a
+           # streamed survivor counted as one; the source servers it
            # pulled from, a chain each; and the stream-seconds it
            # pulled while another of its streams was open
-           # (SharedSeconds: a source's streams are open together)
+           # (SharedSeconds: the sources' chains run at once)
            "rebuild_fetch_files": 0, "rebuild_fetch_sources": 0,
            "rebuild_fetch_shared_seconds": 0.0,
            # what of rebuild_fetch_bytes never was a file there: a
@@ -558,7 +558,7 @@ def debug_payload() -> dict:
     where a writer waited for them), the batcher's
     ``batch_*`` counts and the packed reconstruct's ``rebuild_batch_*``
     (volumes, rows, row slots, dispatches, loss patterns; once per
-    ``VolumeEcShardsRebuildBatch``), ``pool_acquires`` / ``pool_fresh_acquires``
+    repair run, one volume's included), ``pool_acquires`` / ``pool_fresh_acquires``
     (every buffer a :class:`HostBufferPool` lent, and those it had
     never lent before), the stage spans' seconds (``compute`` =
     dispatch + sync; ``sync`` = ``sync_ready``, the writer's wait for a

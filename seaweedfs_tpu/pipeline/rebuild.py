@@ -2,37 +2,39 @@
 
 The volume-server side of `ec.rebuild` (SURVEY.md §3.5): what
 erasure_coding ec_encoder.go RebuildEcFiles does — find which .ec?? files
-exist, and if at least k survive, produce the missing ones. The decode
-matrix is composed host-side once per run (Encoder.decode_matrix, span
-``decode_matrix``) and goes to the device as DATA with every dispatch:
-every missing shard — data or parity — comes out of a single device
-pass per chunk, through one program whatever shards were lost.
+exist, and if at least k survive, produce the missing ones. One run
+does it, the packed reconstruct (:func:`rebuild_volumes`), for one
+volume as for many: a one-volume repair (:func:`rebuild_ec_files`, the
+rebuild rpc) is a batch of one.
 
-Rebuild rides the same overlapped ingest plane as encode
-(pipe.py/writeback.py): survivor chunks are ``os.preadv``'d straight
-into pooled host buffers, reconstruction overlaps the next chunk's
-reads, and missing-shard chunks land at deterministic offsets in
-preallocated files via the positioned-write pool. Rebuilt bytes are
-fresh arrays (the D2H copy), so input buffers recycle as soon as a
-chunk's compute has synced — no writeback token needed. The pool is
-the caller's where it lends one (``pools``: the volume server's
-``pipe.PoolCache``), so a rebuild's reader fills buffers that an
-earlier command touched.
+Volumes that lost the same shards and use the same survivors share the
+coalescing batcher's slabs (pipeline/batch.py): a row is the k
+survivors' bytes at one shard offset, one pipeline run per such loss
+pattern, a slab never mixes two. The decode matrix is composed
+host-side once per run (Encoder.decode_matrix, span
+``decode_matrix``), padded to m rows, and goes to the device as DATA
+with every dispatch: every missing shard — data or parity — comes out
+of one device pass per slab, through one program whatever shards were
+lost.
 
-A survivor is a file under ``base`` or, where the caller says so
-(``remote``, :class:`RemoteSurvivors`), a stream from the server that
-holds it: the reader hands the stream's owner that shard's slice of the
-pooled buffer, reads the local survivors' slices meanwhile, and passes
-the chunk on when every slice is full. A fetched survivor is never a
-file here, and the fetch of chunk j+1 runs beside the restore of chunk j.
+It rides the same overlapped ingest plane as encode (pipe.py,
+writeback.py): survivor rows are ``os.preadv``'d straight into pooled
+host buffers, the reconstruct overlaps the next slab's reads, and the
+restored rows land at their offsets in preallocated files via the
+positioned-write pool. The pool is the caller's where it lends one
+(``pools``: the volume server's ``pipe.PoolCache``), so a rebuild's
+reader fills buffers that an earlier command touched.
 
-Many volumes at once (:func:`rebuild_volumes`, the server half of an
-``ec.rebuild`` walk): the packed reconstruct. Volumes that lost the same
-shards and use the same survivors share the coalescing batcher's slabs
-(pipeline/batch.py), one pipeline run per such loss pattern with that
-pattern's decode matrix; a slab never mixes two. Each volume's restored
-files pass the ``[storage] fsync`` barrier behind their last write, and
-each volume is all or nothing.
+A survivor is a file under the volume's base or, where the caller's
+feed holds it (``remote``, :class:`StreamedSurvivors`), a stream from
+the server that holds it: the reader hands the feed its slices of the
+slab, reads the local survivors' slices meanwhile, and passes the slab
+on when every slice is full. A fetched survivor is never a file here,
+and the fetch of slab j+1 runs beside the restore of slab j.
+
+The caller says whether a volume's restored files pass the ``[storage]
+fsync`` barrier behind their last write (``durable``); each volume is
+all or nothing.
 """
 
 from __future__ import annotations
@@ -49,234 +51,46 @@ from ..ops import rs_jax
 from ..ops.rs_ref import TooFewShardsError
 from ..storage import ec_files
 from . import batch as batch_mod
-from . import flight, pipe, writeback
+from . import pipe, writeback
 from .scheme import DEFAULT_SCHEME, EcScheme
-
-#: Chunk of shard-file bytes processed per device call; the live input
-#: bound is ``[pipeline] batch_bytes / data_shards`` when unset here.
-DEFAULT_CHUNK_BYTES = 64 * 1024 * 1024
-#: A packed reconstruct's slab, in grouped dispatches' slabs (``[pipeline]
-#: grouped_batch_bytes``): it launches one slab at a time, never a
-#: group, because each group width is a program of its own that a
-#: walk's first command would have to have met, and its reader, on the
-#: wire, seldom lets a group form; a slab carries what a group of two
-#: would.
-SLAB_GROUPS = 2
-
 
 class EcRebuildError(RuntimeError):
     pass
 
 
-class RemoteSurvivors(Protocol):
-    """Surviving shards that lie on other servers, as the run's reader
-    takes them (the volume server's ``_SurvivorFeed``): streams that
-    are opened once and then read chunk after chunk, each straight into
-    its shard's slice of the reader's pooled buffer."""
-
-    #: the shard ids it can deliver
-    shards: Sequence[int]
-
-    def open(self, shards: Sequence[int]) -> set[int]:
-        """Open a stream for each of ``shards``; the file sizes the
-        streams announced (none where a transport announces none)."""
-
-    def fill(self, slices: dict, last: bool) -> Callable[[], None]:
-        """Start reading each stream's next bytes into its slice
-        (shard id -> a uint8 view of the pooled buffer); the call
-        returned waits until every slice is full, and raises what a
-        stream raised. After the ``last`` chunk a stream has to be at
-        its end."""
-
-    def close(self) -> None:
-        """Close every stream, whatever state the run is in; nothing
-        touches a slice once this has returned."""
-
-
 def rebuild_ec_files(base: str | Path, scheme: EcScheme = DEFAULT_SCHEME,
                      wanted: Optional[Sequence[int]] = None,
-                     chunk_bytes: int = DEFAULT_CHUNK_BYTES,
                      pools: Optional[pipe.PoolCache] = None,
-                     remote: Optional[RemoteSurvivors] = None
-                     ) -> list[int]:
-    """Rebuild missing (or explicitly ``wanted``) shard files in place.
-    Returns the list of shard ids written. ``pools`` (a
-    :class:`pipe.PoolCache` of the caller's) lends the host buffers and
-    keeps them for the next call. ``remote`` delivers survivors that are
-    no files under ``base``: opened here, before the run is sized, and
-    closed here, whatever became of the run."""
+                     slab_bytes: Optional[int] = None) -> list[int]:
+    """Rebuild missing (or explicitly ``wanted``) shard files in place,
+    from survivors that are files under ``base``: a packed reconstruct
+    of one volume. Returns the list of shard ids written, and raises
+    what failed the volume. ``pools`` (a :class:`pipe.PoolCache` of the
+    caller's) lends the host buffers and keeps them for the next
+    call."""
     total = scheme.total_shards
-    local = ec_files.present_shards(base, total)
-    survive = sorted({*local, *(remote.shards if remote else ())})
-    missing = sorted(set(range(total)) - set(survive)) if wanted is None \
-        else sorted(wanted)
+    missing = sorted(set(range(total))
+                     - set(ec_files.present_shards(base, total))) \
+        if wanted is None else sorted(wanted)
     if not missing:
         return []
-    overlap = set(missing) & set(local)
-    if wanted is not None and overlap:
-        raise EcRebuildError(f"shards {sorted(overlap)} already exist")
-    if len(survive) < scheme.data_shards:
-        raise TooFewShardsError(
-            f"need {scheme.data_shards} surviving shards, "
-            f"have {len(survive)}")
-    # Only the first k survivors feed the decode matrix — don't read the
-    # rest from disk, or off another server, at all.
-    present = survive[:scheme.data_shards]
-    sizes = {ec_files.shard_path(base, i).stat().st_size
-             for i in present if i in local}
-    streamed = [i for i in present if i not in local]
-    try:
-        if streamed:
-            sizes |= remote.open(streamed)
-        if not sizes:
-            # no survivor is a file here and no stream said a length (a
-            # CopyFile stream says none): the .vif's, where it has one
-            dat_size = ec_files.VolumeInfo.load(base).dat_file_size
-            if dat_size:
-                sizes = {scheme.shard_file_size(dat_size)}
-        if len(sizes) != 1:
-            raise EcRebuildError(f"surviving shard sizes differ: {sizes}")
-        _restore(base, scheme, present, missing, sizes.pop(), remote,
-                 streamed, chunk_bytes, pools)
-    finally:
-        if streamed:
-            remote.close()
-    # Shard files changed under any reader holding cached post-decode
-    # needles for this volume — tell every live chunk cache.
-    from ..cache import invalidation as cache_invalidation
-
-    cache_invalidation.base_invalidated(base, reason="ec-rebuild")
+    repair = plan_repair(base, base, scheme, missing)
+    # no barrier behind the restored files: a one-volume repair has
+    # never had one (ROADMAP A0)
+    failed = rebuild_volumes([repair], pools=pools, slab_bytes=slab_bytes,
+                             durable=False)
+    if failed:
+        raise failed[repair.key]
     return missing
-
-
-def _restore(base, scheme: EcScheme, present: list, missing: list,
-             size: int, remote: Optional[RemoteSurvivors], streamed: list,
-             chunk_bytes: int, pools: Optional[pipe.PoolCache]) -> None:
-    """The run: the ``missing`` shard files of ``size`` bytes out of
-    the ``present`` survivors, the ``streamed`` ones taken from
-    ``remote``'s open streams, the others files under ``base`` read by
-    ``preadv``."""
-    k = scheme.data_shards
-    # Grouped dispatch on a single accelerator; multi-chip keeps
-    # per-chunk mesh sharding via _pick_reconstruct_fn.
-    group, chunk_bytes = plan_chunking(k, chunk_bytes)
-
-    cfg = pipe.current()
-    pool_nbytes = max(1, k * min(chunk_bytes, size or 1))
-    pool_count = cfg.pool_buffers or max(4, max(cfg.depth, group) + 2)
-    #: a survivor's slot in a chunk -> its file, where it is one
-    in_fds = {s: os.open(ec_files.shard_path(base, i), os.O_RDONLY)
-              for s, i in enumerate(present) if i not in streamed}
-    out_paths = [str(ec_files.shard_path(base, i)) for i in missing]
-    writer = writeback.WriterPool()
-    st = pipe.PipeStats()
-
-    def chunks():
-        pos = 0
-        while pos < size:
-            take = min(chunk_bytes, size - pos)
-            flight.record(flight.EV_ENQUEUE, arg=k * take)
-            buf = pool.acquire()
-            view = buf[:k * take]
-            # the wire first, the disk beside it
-            filled = remote.fill(
-                {i: view[s * take:(s + 1) * take]
-                 for s, i in enumerate(present) if s not in in_fds},
-                last=pos + take == size) if streamed else None
-            for s, fd in in_fds.items():
-                _pread_into(fd, view[s * take:(s + 1) * take], pos)
-            if filled is not None:
-                filled()
-            yield (buf, pos), view.reshape(1, k, take)
-            pos += take
-
-    def write(meta, _chunk, rebuilt):
-        # rebuilt (1, len(missing), take) is the fresh D2H array —
-        # positioned writes at the chunk offset, no buffer token.
-        _buf, pos = meta
-        for row, path in zip(rebuilt[0], out_paths):
-            writer.submit(path, pos, [row])
-
-    def recycle(meta, _chunk):
-        pool.release(meta[0])
-
-    from ..util import tracing
-
-    try:
-        # pipelined like encode: shard reads, device reconstruct and
-        # shard writes overlap, and on a single accelerator several
-        # chunks share one dispatch (the same grouped word-form path
-        # the encoder uses — see pipe.run_pipeline).
-        with tracing.span("ec.rebuild", base=str(base)) as sp:
-            sp.n_bytes = size * len(missing)
-            sp.tag(shards=",".join(str(i) for i in missing))
-            t0 = time.perf_counter()
-            matrix = scheme.encoder.decode_matrix(present, missing)
-            reconstruct = _pick_reconstruct_fn(scheme, present, missing,
-                                               matrix)
-            reconstruct_multi = None if group == 1 \
-                else matrix.apply_host_multi
-            for path in out_paths:
-                writer.open_file(path, size)
-            with pipe.lend_pool(pools, pool_nbytes, pool_count) as pool:
-                try:
-                    pipe.run_pipeline(chunks(), reconstruct, write,
-                                      encode_multi_fn=reconstruct_multi,
-                                      group=group, recycle_fn=recycle,
-                                      stats=st, publish=False)
-                except pipe.PipelineError:
-                    writer.abort()
-                    writer = None
-                    raise
-            writer.close()
-            st.write_seconds += writer.busy_seconds
-            writer = None
-            st.wall_seconds = time.perf_counter() - t0
-            pipe.publish_stats(st, kind="ec.rebuild")
-    finally:
-        if writer is not None:
-            writer.abort()
-        for fd in in_fds.values():
-            os.close(fd)
-
-
-def plan_chunking(k: int, chunk_bytes: int = DEFAULT_CHUNK_BYTES
-                  ) -> tuple[int, int]:
-    """(dispatch group width, per-shard bytes of one chunk) for a
-    rebuild in this process — one shared grouping policy
-    (pipe.pick_grouped_dispatch); a chunk's input is k x the per-shard
-    take, so the grouped clamp converts back through k."""
-    _, group, grouped_total = pipe.pick_grouped_dispatch(
-        None, k * chunk_bytes)
-    if group > 1:
-        # the per-shard take IS the word-form S here, so it must stay a
-        # multiple of the kernel's segment size or rs_pallas.conforms
-        # rejects every chunk and the fast path never engages (k=10
-        # makes a naive //k non-aligned)
-        from ..ops import rs_pallas
-        align = rs_pallas.SEG_BYTES
-        chunk_bytes = max(align, (grouped_total // k) // align * align)
-    return group, chunk_bytes
-
-
-def _pread_into(fd: int, view: np.ndarray, offset: int) -> None:
-    mv = memoryview(view)
-    want, got = len(mv), 0
-    while got < want:
-        n = os.preadv(fd, [mv[got:]], offset + got)
-        if n <= 0:
-            raise EcRebuildError(
-                f"short read from survivor shard at {offset + got}")
-        got += n
 
 
 def _pick_reconstruct_fn(scheme: EcScheme, present, missing, matrix):
     """When routing_mesh() says to shard — a multi-chip accelerator,
     or an explicit [mesh]/-mesh config (virtual CPU meshes included) —
-    the rebuild chunks shard over the whole mesh
+    the rebuild's slabs shard over the whole mesh
     (parallel/mesh.reconstruct_host_sharded); single-device backends
     keep the host fast path, the run's one decode ``matrix`` applied to
-    each chunk — same routing rule as the batcher's encode
+    each slab — same routing rule as the batcher's encode
     (pipeline/batch._pick_encode_fn)."""
     from ..parallel import mesh as mesh_mod
     enc = scheme.encoder
@@ -287,17 +101,13 @@ def _pick_reconstruct_fn(scheme: EcScheme, present, missing, matrix):
     return matrix.apply_host
 
 
-# --------------------------------------------------------------------------
-# many volumes: the packed reconstruct
-# --------------------------------------------------------------------------
-
 class StreamedSurvivors(Protocol):
     """Surviving shards of many volumes that lie on other servers, as
     :func:`rebuild_volumes`' reader takes them (the volume server's
-    ``_BatchSurvivorFeed``): a stream per (volume, shard), opened when
-    its first slice is asked and read on, slice after slice, in the
-    order the slabs ask. A stream that fails fails its volume and no
-    other."""
+    ``_SurvivorFeed``): a stream per (volume, shard), opened with the
+    others of the first slab that asks for it and read on, slice after
+    slice, in the order the slabs ask. A stream that fails fails its
+    volume and no other."""
 
     def fill(self, pieces: list) -> Callable[[], None]:
         """Start reading each of ``pieces`` — (volume key, shard id, a
@@ -306,7 +116,8 @@ class StreamedSurvivors(Protocol):
         every one is full or its volume has failed."""
 
     def failed(self) -> dict:
-        """volume key -> why, for the volumes whose streams failed."""
+        """volume key -> the exception that failed it, for the volumes
+        whose streams failed."""
 
     def close(self) -> None:
         """Close every stream; nothing touches a slice once this has
@@ -341,7 +152,7 @@ def plan_repair(key, base: str | Path, scheme: EcScheme,
     """What :func:`rebuild_ec_files` works out for one volume, for
     :func:`rebuild_volumes`: the survivors under ``base`` and those the
     caller's feed holds (``elsewhere``), the first k of them, and one
-    shard size for all — the local survivors' and, where the ``.vif``
+    shard size for all: the local survivors' and, where the ``.vif``
     gives it (``dat_size``), the one the volume was sealed with."""
     total, k = scheme.total_shards, scheme.data_shards
     local = ec_files.present_shards(base, total)
@@ -367,7 +178,8 @@ def plan_repair(key, base: str | Path, scheme: EcScheme,
 def rebuild_volumes(repairs: Sequence[Repair],
                     remote: Optional[StreamedSurvivors] = None,
                     pools: Optional[pipe.PoolCache] = None,
-                    slab_bytes: Optional[int] = None) -> dict:
+                    slab_bytes: Optional[int] = None, *,
+                    durable: bool) -> dict:
     """Restore the missing shard files of many volumes through shared
     device batches. Volumes of one loss pattern (:attr:`Repair.pattern`)
     are laid side by side in the batcher's slabs (``plan_packed_batches``
@@ -376,13 +188,18 @@ def rebuild_volumes(repairs: Sequence[Repair],
     matrix; patterns run one after the other. ``remote`` delivers the
     streamed survivors and is closed here, whatever became of the runs.
 
-    Returns volume key -> why, for the volumes that were not restored:
-    nothing this call wrote of them is left. Every other volume's
-    restored files have passed the ``[storage] fsync`` barrier. Folds
+    Returns volume key -> the exception that failed it, for the volumes
+    that were not restored: nothing this call wrote of them is left.
+    Every other volume's restored files are closed, and where
+    ``durable`` has passed the ``[storage] fsync`` barrier. Folds
     ``rebuild_batch_*`` into the totals once."""
     by_pattern: dict = {}
     for r in repairs:
         by_pattern.setdefault(r.pattern, []).append(r)
+    slab = slab_bytes or pipe.current().grouped_batch_bytes
+    # a call whose survivors fill less than one slab launches at the
+    # rows they have: a small volume's repair is not a full slab's
+    full_width = sum(r.scheme.data_shards * r.size for r in repairs) > slab
     failed: dict = {}
     rows = slots = launches = 0
     from ..util import tracing
@@ -392,16 +209,17 @@ def rebuild_volumes(repairs: Sequence[Repair],
             for group in by_pattern.values():
                 try:
                     r_rows, r_slots, r_launches = _restore_packed(
-                        group, remote, pools, slab_bytes, failed)
+                        group, remote, pools, slab, full_width, durable,
+                        failed)
                 except Exception as e:  # noqa: BLE001 — this pattern's volumes fail, the next pattern runs
                     for r in group:
-                        failed.setdefault(r.key, f"{type(e).__name__}: {e}")
+                        failed.setdefault(r.key, e)
                     continue
                 rows, slots = rows + r_rows, slots + r_slots
                 launches += r_launches
     finally:
         if remote is not None:
-            failed.update((key, why) for key, why in remote.failed().items()
+            failed.update((key, e) for key, e in remote.failed().items()
                           if key not in failed)
             remote.close()
     from ..cache import invalidation as cache_invalidation
@@ -419,37 +237,39 @@ def rebuild_volumes(repairs: Sequence[Repair],
 
 
 def _restore_packed(group: list, remote: Optional[StreamedSurvivors],
-                    pools: Optional[pipe.PoolCache],
-                    slab_bytes: Optional[int],
+                    pools: Optional[pipe.PoolCache], slab_bytes: int,
+                    full_width: bool, durable: bool,
                     failed: dict) -> tuple[int, int, int]:
     """One pattern's run: (rows, row slots, device dispatches). A
     volume whose survivor could not be read is added to ``failed`` and
-    the run goes on; a run that fails raises, its files removed by the
-    caller."""
+    the run goes on while another volume is left to restore; a run that
+    fails raises, its files removed by the caller."""
     first = group[0]
     scheme, k = first.scheme, first.scheme.data_shards
-    matrix = scheme.encoder.decode_matrix(first.present, first.missing)
-    # ONE device program for every pattern and every tail: the rows
-    # wanted padded to m with zero rows, every slab launched at its
-    # bucket's full width (the rows past its spans are computed and
-    # never written), one slab a dispatch. A walk whose volumes lost
-    # different shards, or left a bucket part full, compiles nothing
-    # its first slab did not
-    pad = scheme.parity_shards - len(first.missing)
-    if pad > 0:
-        matrix = rs_jax.DecodeMatrix(np.vstack(
-            [matrix.rows, np.zeros((pad, k), dtype=np.uint8)]))
+    matrix = padded_decode_matrix(scheme, first.present, first.missing)
     by_key = {r.key: r for r in group}
     # a row of a survivor slab is what a .dat row is to the batcher:
     # its layout over k x a shard's bytes covers [0, size) of every
     # shard once, so span.offset is a shard offset and a row's k slices
     # are the survivors' bytes there
     plans = list(batch_mod.plan_packed_batches(
-        ((r.key, k * r.size) for r in group), scheme,
-        slab_bytes or SLAB_GROUPS * pipe.current().grouped_batch_bytes))
+        ((r.key, k * r.size) for r in group), scheme, slab_bytes))
     rows = sum(sp.n for plan in plans for sp in plan.spans)
-    for plan in plans:
-        plan.shape = (plan.max_rows, *plan.shape[1:])
+    # ONE device program for every pattern and every tail of a call (the
+    # matrix padded to m rows): where the call fills more than a slab,
+    # every slab is launched at its bucket's full width (the rows past
+    # its spans are computed and never written), one slab a dispatch,
+    # never a group: each group width would be a program of its own. A
+    # walk whose volumes lost different shards, or left a bucket part
+    # full, compiles nothing its first slab did not. The slab is one
+    # grouped dispatch's (``[pipeline] grouped_batch_bytes``). Its result
+    # (rows x m x block: 24 MiB at 6 x 4 x 1 MiB) has to stay under
+    # 32 MiB, glibc's largest mmap threshold: a larger one is a fresh
+    # mapping faulted in at every copy home (12 rows on a v5e host: the
+    # copy home 2.5x slower, and no slower with mmap switched off)
+    if full_width:
+        for plan in plans:
+            plan.shape = (plan.max_rows, *plan.shape[1:])
     planned = plans, None, 1
     out_paths = {r.key: [str(ec_files.shard_path(r.base, i))
                          for i in r.missing] for r in group}
@@ -502,12 +322,17 @@ def _restore_packed(group: list, remote: Optional[StreamedSurvivors],
                                 by_key[key].base, sid), os.O_RDONLY)
                         _preadv_rows(fd, rows_of, off)
                 except (OSError, EcRebuildError) as e:
-                    failed.setdefault(key, f"{type(e).__name__}: {e}")
+                    failed.setdefault(key, e)
                 if fd is not None and not unread[key]:
                     os.close(in_fds.pop((key, sid)))
         finally:
             if filled is not None:
                 filled()
+        if remote is not None:
+            for key, e in remote.failed().items():
+                failed.setdefault(key, e)
+        if by_key.keys() <= failed.keys():
+            raise EcRebuildError("every volume of the run has failed")
 
     def write(plan, _batch, rebuilt, release) -> None:
         # rebuilt (rows, len(missing), block) is the fresh D2H array:
@@ -528,7 +353,7 @@ def _restore_packed(group: list, remote: Optional[StreamedSurvivors],
                               [rebuilt[sp.r0 + j, o, :take]
                                for j, take in enumerate(takes)])
             unwritten[r.key] -= 1
-            if not unwritten[r.key]:
+            if durable and not unwritten[r.key]:
                 for path in paths:
                     writer.finish(path)
 
@@ -551,7 +376,20 @@ def _restore_packed(group: list, remote: Optional[StreamedSurvivors],
     st.write_seconds += writer.busy_seconds
     st.wall_seconds = time.perf_counter() - t0
     pipe.publish_stats(st, kind="ec.rebuild")
-    return rows, sum(p.max_rows for p in plans), st.groups
+    return rows, sum(p.shape[0] for p in plans), st.groups
+
+
+def padded_decode_matrix(scheme: EcScheme, present, missing
+                         ) -> rs_jax.DecodeMatrix:
+    """The rows that restore ``missing`` from the first k survivors
+    ``present``, padded to m with zero rows: whatever was lost, the
+    device runs the program of an m-row matrix."""
+    matrix = scheme.encoder.decode_matrix(present, missing)
+    pad = scheme.parity_shards - len(missing)
+    if pad <= 0:
+        return matrix
+    return rs_jax.DecodeMatrix(np.vstack(
+        [matrix.rows, np.zeros((pad, scheme.data_shards), dtype=np.uint8)]))
 
 
 def _preadv_rows(fd: int, views: list, offset: int) -> None:

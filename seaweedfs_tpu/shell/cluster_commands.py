@@ -649,16 +649,19 @@ def cmd_ec_rebuild(env: ClusterEnv, argv: list[str]) -> None:
     rebuilder and ``VolumeEcShardsRebuild`` runs there
     (``rebuildOneEcVolume``): that server fetches what it lacks from
     the holders (``prepareDataToRecover``: the index files if it holds
-    nothing of the volume, and surviving shards until ``data_shards``
-    are local), restores the shards no server holds
-    (``generateMissingShards``), mounts them (``mountEcShards``) and
-    drops the fetched copies. The server, which reads the geometry from
-    the ``.vif``, says which shards were missing; a volume it refuses
-    as unrepairable (fewer than ``data_shards`` survive) is reported
-    and the walk goes on. A walk's volumes that one server rebuilds go
-    to it as ONE ``VolumeEcShardsRebuildBatch`` per collection, whose
-    restores share device batches; a group of one, and ``-volumeId``,
-    keep ``VolumeEcShardsRebuild``."""
+    nothing of the volume, and surviving shards, as streams that never
+    become files, until ``data_shards`` are at hand), restores the
+    shards no server holds (``generateMissingShards``) and mounts them
+    (``mountEcShards``). The server, which reads the geometry from the
+    ``.vif``, says which shards were missing; a volume it refuses as
+    unrepairable (fewer than ``data_shards`` survive) is reported and
+    the walk goes on. A walk's volumes that one server rebuilds go to
+    it as ONE ``VolumeEcShardsRebuildBatch`` per collection. Both rpcs
+    run the server's one repair, the packed reconstruct (a volume alone
+    is a batch of one); they differ in the ``[storage] fsync`` barrier
+    behind the restored files, which only the batch passes (ROADMAP
+    A0): a group of one, and ``-volumeId``, keep
+    ``VolumeEcShardsRebuild``."""
     p = _parser("ec.rebuild")
     p.description = (
         "Restore the EC shards that no server holds. The rebuilder of a "
@@ -738,8 +741,8 @@ def _rebuild_on(env: ClusterEnv, url: str, col: str,
                 vids: list[int]) -> dict[int, tuple]:
     """vid -> (rebuilt shard ids, error or "") of ``vids`` rebuilt on
     ``url``: one ``VolumeEcShardsRebuildBatch`` for two or more, the
-    one-volume rpc for one. A call that fails fails each volume it
-    named."""
+    one-volume rpc, whose restored files skip the barrier (ROADMAP
+    A0), for one. A call that fails fails each volume it named."""
     try:
         if len(vids) == 1:
             resp = env.volume(url).VolumeEcShardsRebuild(

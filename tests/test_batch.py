@@ -170,14 +170,15 @@ def _encode_each(bases, cache):
 
 def _rebuild_each(bases, cache):
     """Two shards of each (encoded) volume lost and rebuilt, several
-    chunks to a shard file."""
+    slabs to a shard file."""
     for base in bases:
         if not ec_files.present_shards(base, SCHEME.total_shards):
             encode_mod.write_ec_files(base, SCHEME)
         for s in (1, 11):
             os.remove(ec_files.shard_path(base, s))
         assert rebuild_mod.rebuild_ec_files(
-            base, SCHEME, chunk_bytes=16 * 1024, pools=cache) == [1, 11]
+            base, SCHEME, slab_bytes=2 * 10 * 8 * 1024,
+            pools=cache) == [1, 11]
 
 
 @pytest.mark.parametrize("run, cleans_up", [
@@ -210,7 +211,7 @@ def test_a_kept_pool_serves_the_next_run_and_a_failed_run_drops_it(
     def broken_read(*_a):
         raise OSError("disk")
     monkeypatch.setattr(encode_mod, "_pread_into", broken_read)
-    monkeypatch.setattr(rebuild_mod, "_pread_into", broken_read)
+    monkeypatch.setattr(rebuild_mod, "_preadv_rows", broken_read)
     with pytest.raises(Exception, match="disk"):
         run(third, cache)
     assert cache._pool is None

@@ -223,7 +223,7 @@ def encoded_volume(tmp_path_factory):
                          ids=lambda lost: "-".join(map(str, lost)))
 def test_rebuild_ec_files_restores_a_seed_drawn_pattern(
         device_leg, monkeypatch, encoded_volume, lost):
-    """rebuild_ec_files through the grouped word-form dispatch, one of
+    """rebuild_ec_files through the word-form dispatch, one of
     twenty seed-drawn four-shard losses: the four files byte-exact, the
     matrix composed once for the run, and (after the file's first case)
     nothing traced for this pattern."""
@@ -238,7 +238,7 @@ def test_rebuild_ec_files_restores_a_seed_drawn_pattern(
     first = rs_jax._jitted_apply_mat.cache_info().currsize == 0
     before = rs_jax.debug_payload()
     calls = pipe.debug_payload()["decode_matrix_calls"]
-    assert rebuild_ec_files(base, scheme, chunk_bytes=SEG) == lost
+    assert rebuild_ec_files(base, scheme, slab_bytes=10 * SEG) == lost
     for i in range(14):
         assert ec_files.shard_path(base, i).read_bytes() == want[i], i
     after = rs_jax.debug_payload()

@@ -170,22 +170,31 @@ def test_run_splits_into_power_of_two_widths(forced_pallas, monkeypatch,
 
 def test_rebuild_grouped_chunks_stay_seg_aligned(forced_pallas,
                                                  monkeypatch, tmp_path):
-    """Regression (round-5 review): the grouped clamp divides the byte
-    bound by k, which for most k is not segment-aligned — rebuild must
-    re-align the per-shard take or rs_pallas.conforms rejects every chunk
-    and the fast path silently never engages. Proven end to end: an
-    unaligned chunk_bytes request still rebuilds byte-identically AND
-    the decode executable (the matrix its argument) actually runs."""
+    """Regression (round-5 review): a rebuild's slab must stay
+    segment-aligned, or rs_pallas.conforms rejects every slab and the
+    fast path silently never engages. The packed reconstruct's slab is
+    the scheme's block: for a segment-block scheme every slab of a run
+    that spans several conforms, the rebuild is byte-identical AND the
+    decode executable (the matrix its argument) actually runs."""
+    from seaweedfs_tpu.pipeline import batch as batch_mod
     from seaweedfs_tpu.pipeline.encode import encode_volume
     from seaweedfs_tpu.pipeline.rebuild import rebuild_ec_files
     from seaweedfs_tpu.pipeline.scheme import EcScheme
     from seaweedfs_tpu.storage import ec_files
     from seaweedfs_tpu.storage.volume import generate_synthetic_volume
 
-    # the conftest forces 8 virtual CPU devices, which the real policy
-    # reads as "multi-chip -> mesh-shard, don't group"; pin the
+    from seaweedfs_tpu.parallel import mesh as mesh_mod
+    # the conftest forces 8 virtual CPU devices, which the real policy,
+    # the kernels forced, reads as "multi-chip -> mesh-shard"; pin the
     # single-accelerator answer the test is about
-    monkeypatch.setattr(rs_jax, "host_dispatch_group", lambda: 4)
+    monkeypatch.setattr(mesh_mod, "routing_mesh", lambda: None)
+    plans = []
+    real_plan = batch_mod.plan_packed_batches
+
+    def plan(*a, **kw):
+        plans.extend(real_plan(*a, **kw))
+        return plans
+    monkeypatch.setattr(batch_mod, "plan_packed_batches", plan)
 
     seg = rs_pallas.SEG_BYTES
     base = tmp_path / "9"
@@ -198,14 +207,15 @@ def test_rebuild_grouped_chunks_stay_seg_aligned(forced_pallas,
     want0 = ec_files.shard_path(base, 0).read_bytes()
     ec_files.shard_path(base, 0).unlink()
     before = rs_jax._jitted_apply_mat.cache_info()
-    # deliberately unaligned request: the clamp must fix it, not
-    # forward it into the dispatch
-    assert rebuild_ec_files(base, scheme,
-                            chunk_bytes=seg + 1000) == [0]
+    # two rows a slab: the shard's rows cross from slab to slab
+    assert rebuild_ec_files(base, scheme, slab_bytes=2 * 4 * seg) == [0]
     assert ec_files.shard_path(base, 0).read_bytes() == want0
+    assert len(plans) > 1
+    for p in plans:
+        assert rs_pallas.conforms(p.shape[2]), p.shape
     after = rs_jax._jitted_apply_mat.cache_info()
     assert (after.misses + after.hits) > (before.misses + before.hits), \
-        "grouped word-form dispatch never engaged in rebuild"
+        "word-form dispatch never engaged in rebuild"
 
 
 # -- pipeline group-drain mechanics (no jax involved) ---------------------

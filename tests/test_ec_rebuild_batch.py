@@ -75,7 +75,7 @@ class FileFeed:
                 continue
             self.pieces += 1
             if key == self.cut and self.pieces > 1:
-                self._failed[key] = "cut"
+                self._failed[key] = OSError("cut")
                 continue
             f = self._files.get((key, sid))
             if f is None:
@@ -164,7 +164,9 @@ def test_the_packed_restore_is_the_oracles_and_one_rebuild_per_volume(
     repairs = repairs_of(vols, lost)
     # three rows a slab: a volume's rows cross from one slab to the next
     failed = rebuild_mod.rebuild_volumes(repairs, remote=feed,
-                                         slab_bytes=3 * K * BLOCK)
+                                         slab_bytes=3 * K * BLOCK,
+                                         durable=True)
+    after = pipe.debug_payload()
     assert failed == {}
     assert feed is None or feed.closed
     for key, (base, _far, shards, fed) in vols.items():
@@ -174,7 +176,26 @@ def test_the_packed_restore_is_the_oracles_and_one_rebuild_per_volume(
         # nothing else was written beside the survivors that lay there
         assert sorted(ec_files.present_shards(base, 14)) == sorted(
             set(range(14)) - set(fed))
-    # one rebuild_ec_files per volume, on copies, says the same
+    # a slab never mixes two patterns, and there is a run per pattern
+    patterns = {r.key: r.pattern for r in repairs}
+    assert len(plans) == len(set(patterns.values()))
+    for packed in plans:
+        for p in packed:
+            assert len({patterns[sp.key] for sp in p.spans}) == 1
+            # every slab is launched at its bucket's full width
+            assert p.shape[0] == p.max_rows
+    moved = {k: after[k] - before[k] for k in after
+             if k.startswith("rebuild_batch_")}
+    assert moved["rebuild_batch_volumes"] == len(SIZES)
+    assert moved["rebuild_batch_patterns"] == len(set(patterns.values()))
+    assert moved["rebuild_batch_rows"] == sum(
+        sp.n for packed in plans for p in packed for sp in p.spans)
+    assert 0 < moved["rebuild_batch_rows"] <= \
+        moved["rebuild_batch_row_slots"]
+    # one slab a dispatch
+    assert moved["rebuild_batch_launches"] == sum(len(p) for p in plans)
+    # one rebuild_ec_files per volume, on copies, says the same (a
+    # batch of one each)
     for key, (base, far, shards, fed) in vols.items():
         one = tmp_path / f"one{key}"
         one.mkdir()
@@ -187,25 +208,6 @@ def test_the_packed_restore_is_the_oracles_and_one_rebuild_per_volume(
         for s in lost[key]:
             assert ec_files.shard_path(solo, s).read_bytes() == \
                 ec_files.shard_path(base, s).read_bytes()
-    # a slab never mixes two patterns, and there is a run per pattern
-    patterns = {r.key: r.pattern for r in repairs}
-    assert len(plans) == len(set(patterns.values()))
-    for packed in plans:
-        for p in packed:
-            assert len({patterns[sp.key] for sp in p.spans}) == 1
-            # every slab is launched at its bucket's full width
-            assert p.shape[0] == p.max_rows
-    after = pipe.debug_payload()
-    moved = {k: after[k] - before[k] for k in after
-             if k.startswith("rebuild_batch_")}
-    assert moved["rebuild_batch_volumes"] == len(SIZES)
-    assert moved["rebuild_batch_patterns"] == len(set(patterns.values()))
-    assert moved["rebuild_batch_rows"] == sum(
-        sp.n for packed in plans for p in packed for sp in p.spans)
-    assert 0 < moved["rebuild_batch_rows"] <= \
-        moved["rebuild_batch_row_slots"]
-    # one slab a dispatch
-    assert moved["rebuild_batch_launches"] == sum(len(p) for p in plans)
 
 
 def test_a_stream_that_fails_takes_its_volume_and_no_other(tmp_path):
@@ -214,8 +216,9 @@ def test_a_stream_that_fails_takes_its_volume_and_no_other(tmp_path):
     feed = FileFeed({key: v[1] for key, v in vols.items()}, cut=3)
     failed = rebuild_mod.rebuild_volumes(repairs_of(vols, lost),
                                          remote=feed,
-                                         slab_bytes=3 * K * BLOCK)
-    assert failed == {3: "cut"}
+                                         slab_bytes=3 * K * BLOCK,
+                                         durable=True)
+    assert {key: str(e) for key, e in failed.items()} == {3: "cut"}
     # nothing of what the run wrote for it; its survivors as they were
     assert not any(ec_files.shard_path(vols[3][0], s).exists()
                    for s in lost[3])
@@ -236,8 +239,8 @@ def test_a_survivor_read_short_fails_its_volume(tmp_path):
                                   if s not in lost[key]][:K]),
         tuple(lost[key]), shards[0].size)
         for key, (base, _far, shards, _fed) in vols.items()]
-    failed = rebuild_mod.rebuild_volumes(repairs)
-    assert list(failed) == [4] and "short read" in failed[4]
+    failed = rebuild_mod.rebuild_volumes(repairs, durable=True)
+    assert list(failed) == [4] and "short read" in str(failed[4])
     assert sorted(ec_files.present_shards(vols[4][0], 14)) == sorted(
         set(range(14)) - set(lost[4]))
     assert all(ec_files.shard_path(vols[2][0], s).exists()
@@ -481,6 +484,72 @@ def test_volume_id_keeps_its_rpc(racks):
     assert f"ec.rebuild volume 2: rebuilt {lost}" in reply
     assert rpcs_named("grpc.VolumeEcShardsRebuildBatch") == 1
     assert rpcs_named("grpc.VolumeEcShardsRebuild") == 0
+
+
+def test_the_one_volume_rpc_is_a_batch_of_one_without_the_barrier(racks):
+    """``VolumeEcShardsRebuild`` restores through the packed reconstruct
+    as a batch of one volume and closes its restored files without the
+    ``[storage] fsync`` barrier (the one-volume repair has never had
+    one); ``VolumeEcShardsRebuildBatch`` of the same loss passes each
+    restored file through it."""
+    rack = sealed(racks, {1: ROW})
+    stub = rack.servers[0].peer_stub(rack.servers[0].url)
+
+    def fsyncs() -> int:
+        return flight.totals().get("fsync", (0, 0))[1]
+    for rpc, barriers in (("one", 0), ("batch", 1)):
+        lost = take(rack, 0, 1)
+        before, fsynced = rack.pipeline_vars(), fsyncs()
+        if rpc == "one":
+            rebuilt = list(stub.VolumeEcShardsRebuild(
+                vpb.VolumeEcShardsRebuildRequest(
+                    volume_id=1, collection=COL)).rebuilt_shard_ids)
+        else:
+            (result,) = stub.VolumeEcShardsRebuildBatch(
+                vpb.VolumeEcShardsRebuildBatchRequest(
+                    volume_ids=[1], collection=COL)).results
+            assert result.error == ""
+            rebuilt = list(result.rebuilt_shard_ids)
+        assert rebuilt == lost
+        assert rack.held(1)[0] == lost
+        d = {k: v - before[k] for k, v in rack.pipeline_vars().items()
+             if isinstance(v, (int, float))}
+        assert d["rebuild_batch_volumes"] == 1
+        assert d["step_rebuild_calls"] == 1
+        assert fsyncs() - fsynced == barriers * len(lost), rpc
+    assert sorted(s for ids in rack.held(1) for s in ids) \
+        == list(range(TOTAL))
+
+
+@pytest.mark.parametrize("rpc", ["one", "batch"])
+def test_survivors_that_disagree_with_the_vif_are_refused(racks, rpc):
+    """Either rebuild rpc holds the local survivors to the size the
+    ``.vif`` says the volume was sealed with: a mismatch restores
+    nothing and leaves the volume as it was."""
+    import grpc
+    rack = sealed(racks, {1: ROW})
+    stub = rack.servers[0].peer_stub(rack.servers[0].url)
+    base = rack.base(0, 1)
+    # one of its shards: the others stay, and so do its index files
+    lost = take(rack, 0, 1, rack.held(1)[0][:1])
+    kept = rack.held(1)[0]
+    info = ec_files.VolumeInfo.load(base)
+    info.dat_file_size += ROW
+    info.save(base)
+    if rpc == "one":
+        with pytest.raises(grpc.RpcError) as raised:
+            stub.VolumeEcShardsRebuild(vpb.VolumeEcShardsRebuildRequest(
+                volume_id=1, collection=COL))
+        error = raised.value.details()
+    else:
+        (result,) = stub.VolumeEcShardsRebuildBatch(
+            vpb.VolumeEcShardsRebuildBatchRequest(
+                volume_ids=[1], collection=COL)).results
+        assert list(result.rebuilt_shard_ids) == []
+        error = result.error
+    assert "surviving shard sizes differ" in error, error
+    assert not any(ec_files.shard_path(base, s).exists() for s in lost)
+    assert rack.held(1)[0] == kept
 
 
 @pytest.mark.parametrize("line", ["ec.rebuild -force",

@@ -217,9 +217,10 @@ def test_an_emptied_server_fetches_restores_and_holds_the_lost_shards(
     assert d["step_rebuild_fetch_calls"] == 1
     assert d["step_rebuild_fetch_index_calls"] == 1
     assert d["step_rebuild_fetch_source_calls"] == 3
-    # one chunk per file here: a stream's one slice, an index file's
-    # one write
-    assert d["copy_recv_chunks"] == 10 + 2
+    # a chunk a row here: a stream's slice of each of its rows, an
+    # index file's one read or write
+    assert d["copy_recv_chunks"] == 10 * (
+        shard_size // SCHEME.small_block_size) + 2
     # the fetch's wall holds the longest stream, and ends before the
     # handler does
     assert 0 < d["step_rebuild_fetch_seconds"] < d["step_rebuild_seconds"]
@@ -268,7 +269,7 @@ def test_the_fetch_is_one_trace_beneath_the_rebuild_rpc(racks):
              if "step_rebuild_fetch_source" in ancestors(s)]
     assert len(pulls) == 10
     # the restore is the handler's, beside the fetch and not beneath it
-    (restore,) = named("ec.rebuild")
+    (restore,) = named("ec.rebuild_batch")
     assert "step_rebuild_fetch" not in ancestors(restore)
     assert "grpc.VolumeEcShardsRebuild" in ancestors(restore)
 
@@ -276,10 +277,10 @@ def test_the_fetch_is_one_trace_beneath_the_rebuild_rpc(racks):
 def test_three_sources_are_pulled_at_once(racks, chains):
     rack = sealed_rack(racks)
     gone = empty(rack, 0)
-    # .vif, .ecx and the absent .ecj are asked for in turn, then the
-    # three chains' first streams meet, each on its chain's thread
+    # the index files are no streams: the three chains' first streams
+    # meet, each on its chain's thread
     rack.servers[0].fetch_streams = Meeting(
-        3, "rebuild_fetch_shared_seconds", skip=3)
+        3, "rebuild_fetch_shared_seconds")
     before = rack.pipeline_vars()
     reply, err = rack.run("ec.rebuild -volumeId 1")
     assert err is None and f"rebuilt {gone}" in reply, (reply, err)
@@ -294,7 +295,8 @@ def test_three_sources_are_pulled_at_once(racks, chains):
 def test_one_source_has_one_chain_and_no_pool_of_them(racks, chains):
     """Everything that survives lies on one peer (it pulled the other
     holders' shards before they were lost): one chain holds the ten
-    streams, on the one thread the reader needs beside itself."""
+    streams, on the one thread the reader needs beside itself, and
+    reads them in the slab's order."""
     rack = sealed_rack(racks)
     held = rack.held(1)
     keeper = next(i for i in (1, 2, 3) if len(held[i]) == 4)
@@ -394,8 +396,8 @@ def test_under_tls_the_survivors_come_as_copyfile_streams(racks, tmp_path):
 def test_no_survivor_is_a_file_on_the_rebuilder_at_any_point_of_a_round(
         racks, monkeypatch):
     """The replacement's directory listed from the fault point, behind
-    every chunk landed (twelve here) and from the positioned writes of
-    the restore: the index files, their ``.part`` while they come, the
+    every chunk landed (a row of each stream, and ``.ecx``) and from the
+    positioned writes of the restore: the index files, their ``.part`` while they come, the
     shards being restored, and never a survivor's shard or ``.part``."""
     rack = sealed_rack(racks)
     gone = empty(rack, 0)
@@ -419,7 +421,9 @@ def test_no_survivor_is_a_file_on_the_rebuilder_at_any_point_of_a_round(
     monkeypatch.setattr(writeback.WriterPool, "submit", submitted)
     reply, err = rack.run("ec.rebuild -volumeId 1")
     assert err is None and f"rebuilt {gone}" in reply, (reply, err)
-    assert looks.count("chunk") == 10 + 2 and "write" in looks
+    rows = SCHEME.shard_file_size(rack.dats[1].size) \
+        // SCHEME.small_block_size
+    assert looks.count("chunk") == 10 * rows + 1 and "write" in looks
     restored = {f"{COL}_1.ec{s:02d}" for s in gone}
     index = {f"{COL}_1{ext}" for ext in (".vif", ".ecx")}
     assert restored <= seen and index <= seen
@@ -448,9 +452,9 @@ def left_empty_and_repairable(rack, survivors, mapped) -> None:
 @pytest.mark.parametrize("nth", [1, 6], ids=["in_the_index_files",
                                              "among_the_survivors"])
 def test_a_fault_mid_fetch_leaves_the_server_empty(racks, nth):
-    """One chunk per file here: .vif, .ecx, then ten survivors' slices
-    over three chains. The ``nth`` chunk landed fails its file or its
-    stream; the command fails, and nothing of the volume is left on
+    """A chunk a row here: .ecx (the .vif is read into memory, and has
+    no fault point), then the ten survivors' rows over three chains.
+    The ``nth`` chunk landed fails its file or its stream; the command fails, and nothing of the volume is left on
     the replacement: no index file, no restored shard, no file of any
     other kind."""
     rack = sealed_rack(racks)

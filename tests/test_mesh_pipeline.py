@@ -190,7 +190,7 @@ def test_mesh_rebuild_lost_shards_matches_bytes(tmp_path):
         ec_files.shard_path(base, i).unlink()
     with mesh_mod.scoped("2,4"):
         done = rebuild_mod.rebuild_ec_files(base, SCHEME,
-                                            chunk_bytes=32 * 1024)
+                                            slab_bytes=10 * 8192)
     assert sorted(done) == lost
     for i in lost:
         assert ec_files.shard_path(base, i).read_bytes() == originals[i]

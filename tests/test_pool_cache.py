@@ -258,6 +258,11 @@ def test_a_servers_later_ec_commands_fill_the_buffers_its_first_touched(
     """Two ``ec.encode -volumeId`` and one ``ec.rebuild`` on one server:
     after the first command no buffer is lent for the first time."""
     monkeypatch.setattr(volume_server_mod, "DEFAULT_SCHEME", SERVER_SCHEME)
+    # the .vif carries the shard counts and not the block sizes: the
+    # rebuild, which holds its survivors to the .vif's size, has to be
+    # told the test's
+    monkeypatch.setattr(volume_server_mod, "_scheme_from_vif",
+                        lambda base, info=None: SERVER_SCHEME)
     for vid in (1, 2):
         write_volume(tmp_path, "c", vid, 2 * ROW + ROW // 3, 2 * HOURS)
     cluster = Cluster([tmp_path])

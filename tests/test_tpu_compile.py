@@ -27,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from seaweedfs_tpu.ops import rs_jax, rs_pallas
 from seaweedfs_tpu.parallel import mesh as mesh_mod
+from seaweedfs_tpu.pipeline import batch as batch_mod
 from seaweedfs_tpu.pipeline import encode as encode_mod
 from seaweedfs_tpu.pipeline import pipe, rebuild as rebuild_mod
 from seaweedfs_tpu.pipeline.scheme import DEFAULT_SCHEME, EcScheme
@@ -141,17 +142,30 @@ def _operand_spec(n_lost, k, sharding):
                                 sharding=sharding)
 
 
+def _rebuild_slab():
+    """(rows, k, block) of the packed reconstruct's slab for a 1 GiB
+    volume: its bucket at full width, as every slab is launched."""
+    k = DEFAULT_SCHEME.data_shards
+    plan = next(batch_mod.plan_packed_batches(
+        [(0, k * DEFAULT_SCHEME.shard_file_size(GIB))], DEFAULT_SCHEME,
+        pipe.current().grouped_batch_bytes))
+    return plan.max_rows, k, plan.shape[2]
+
+
 @pytest.mark.parametrize("n_lost", [1, 4])
 def test_rebuild_decode_rows(one_chip, one_tpu_dispatch, n_lost):
-    """The rebuild's program at its own chunk shape: the decode matrix
-    is its first argument, so what is compiled here is what every loss
-    of ``n_lost`` shards runs."""
-    k = DEFAULT_SCHEME.data_shards
-    group, take = rebuild_mod.plan_chunking(k)
-    assert group > 1
-    fn = rs_jax._jitted_apply_mat(n_lost, k, 1, donate=True)
-    _compile(fn, _operand_spec(n_lost, k, one_chip),
-             _words((1, k, take), one_chip))
+    """The rebuild's program at its own slab shape: the decode matrix,
+    padded to m rows, is its first argument, so what is compiled here is
+    what every loss of one to m shards runs."""
+    k, m = DEFAULT_SCHEME.data_shards, DEFAULT_SCHEME.parity_shards
+    lost = list(range(n_lost))
+    present = [i for i in range(14) if i not in lost][:k]
+    operand = rebuild_mod.padded_decode_matrix(DEFAULT_SCHEME, present,
+                                               lost).operand
+    assert operand.shape == _operand_spec(m, k, one_chip).shape
+    fn = rs_jax._jitted_apply_mat(m, k, 1, donate=True)
+    _compile(fn, _operand_spec(m, k, one_chip),
+             _words(_rebuild_slab(), one_chip))
 
 
 def test_two_loss_patterns_lower_to_one_text(one_chip, one_tpu_dispatch):
@@ -160,9 +174,8 @@ def test_two_loss_patterns_lower_to_one_text(one_chip, one_tpu_dispatch):
     ``rs_pallas_words_mat_g<w>`` around kernels named ``rs_words_mat``:
     nothing of a pattern is in the program."""
     k = DEFAULT_SCHEME.data_shards
-    _, take = rebuild_mod.plan_chunking(k)
     enc = DEFAULT_SCHEME.encoder
-    x = _words((1, k, take), one_chip)
+    x = _words(_rebuild_slab(), one_chip)
     texts = []
     for lost in ([1, 6, 11, 13], [0, 2, 3, 12]):
         present = [i for i in range(14) if i not in lost]
